@@ -16,9 +16,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from jax.lax import axis_size
 
 from burst_attn_tpu.parallel.ring import ppermute_next
-from burst_attn_tpu.utils.compat import axis_size, shard_map
 
 
 def _ring_scores(q, k, axis_name):

@@ -86,11 +86,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
 
 from benchmarks.benchmark import bench_fn, flops
 from burst_attn_tpu.parallel import burst, layouts
 from burst_attn_tpu.parallel.ring import ppermute_next
-from burst_attn_tpu.utils.compat import shard_map
 
 
 def _mesh(world):
@@ -116,7 +116,7 @@ def _shard_fwd(mesh, cfg, no_rotate=False, n_rounds=None):
         # compute-only: W self-spec rounds against the resident chunk
         from burst_attn_tpu.ops.masks import round_spec
         from burst_attn_tpu.parallel.ring import my_partition
-        from burst_attn_tpu.utils.compat import axis_size
+        from jax.lax import axis_size
 
         world = n_rounds or axis_size(cfg.intra_axis)
         me = my_partition(cfg.intra_axis, None)
@@ -164,7 +164,7 @@ def _comm_only(mesh, world, topology="uni", factor=None, n_rounds=None,
     spec4 = P(None, None, "sp", None)
 
     def rot(t, hops):
-        from burst_attn_tpu.utils.compat import axis_size
+        from jax.lax import axis_size
         import jax.lax as lax
 
         n = axis_size("sp")
@@ -241,7 +241,7 @@ def _shard_bwd(mesh, cfg, no_rotate=False, n_rounds=None):
                 jnp.float32)
         from burst_attn_tpu.ops.masks import round_spec
         from burst_attn_tpu.parallel.ring import my_partition
-        from burst_attn_tpu.utils.compat import axis_size
+        from jax.lax import axis_size
 
         world = n_rounds or axis_size(cfg.intra_axis)
         me = my_partition(cfg.intra_axis, None)

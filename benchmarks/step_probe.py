@@ -22,7 +22,6 @@ import functools
 import json
 import os
 import sys
-from burst_attn_tpu.utils.compat import tpu_compiler_params
 
 
 def main():
@@ -103,7 +102,7 @@ def main():
                     out_specs=pl.BlockSpec((1, bq, 128), lambda j: (0, 0, 0)),
                     out_shape=jax.ShapeDtypeStruct((1, bq, 128), jnp.float32),
                     scratch_shapes=[pltpu.VMEM((bq, 128), jnp.float32)],
-                    compiler_params=tpu_compiler_params(
+                    compiler_params=pltpu.CompilerParams(
                         dimension_semantics=("arbitrary",),
                     ),
                 )

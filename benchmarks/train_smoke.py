@@ -27,18 +27,17 @@ PEAK_BF16 = {"v5e": 197e12, "v4": 275e12, "v5p": 459e12, "v6": 918e12}
 
 
 def peak_flops(device):
-    """(peak bf16 FLOPs/s, known) — falls back to the v5e peak for an
-    unrecognized generation, flagged so the recorded MFU is not mistaken
-    for a calibrated number."""
+    """Peak bf16 FLOPs/s of `device`; a generation without a row is an
+    error, not another chip's peak."""
     from burst_attn_tpu.ops.tuning import canonical_kind
 
     kind = canonical_kind(device)
-    if kind in PEAK_BF16:
-        return PEAK_BF16[kind], True
-    print(f"train_smoke: unrecognized device kind "
-          f"{getattr(device, 'device_kind', '?')!r}; MFU uses the v5e peak",
-          file=sys.stderr)
-    return 197e12, False
+    if kind not in PEAK_BF16:
+        raise ValueError(
+            f"no bf16 peak for device kind "
+            f"{getattr(device, 'device_kind', '?')!r}; add it to "
+            f"benchmarks/train_smoke.PEAK_BF16")
+    return PEAK_BF16[kind]
 
 
 def main(argv=None):
@@ -58,8 +57,10 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     import jax
-    import jax.numpy as jnp
 
+    from burst_attn_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     if jax.default_backend() != "tpu":
         print("train_smoke: not on TPU; refusing to record numbers",
               file=sys.stderr)
@@ -69,7 +70,7 @@ def main(argv=None):
     from burst_attn_tpu.models.train import (
         TrainConfig, init_train_state, make_batch, make_mesh, make_train_step,
     )
-    from burst_attn_tpu.utils.profiling import StepTimer
+    from burst_attn_tpu.obs import StepTimer
 
     cfg = ModelConfig(
         vocab=args.vocab, d_model=args.d_model, n_layers=args.n_layers,
@@ -113,7 +114,7 @@ def main(argv=None):
                   * args.n_heads * (args.d_model // args.n_heads) / 2)
     flops_step = 6.0 * n_params * tokens + attn_flops
     dev = jax.devices()[0]
-    peak, peak_known = peak_flops(dev)
+    peak = peak_flops(dev)
     mfu = flops_step / step_s / peak
     rec = {
         "device": dev.device_kind, "params": n_params, "batch": args.batch,
@@ -124,7 +125,6 @@ def main(argv=None):
         "model_tflops_per_s": round(flops_step / step_s / 1e12, 1),
         "mfu": round(mfu, 4),
         "peak_bf16_tflops": peak / 1e12,
-        "peak_extrapolated": not peak_known,
         "trace_dir": args.trace_dir,
     }
     print(json.dumps(rec))

@@ -154,7 +154,7 @@ def check_all() -> List[Finding]:
     from jax.sharding import Mesh, PartitionSpec as P
 
     from ..parallel import burst
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     findings: List[Finding] = []
     devs = jax.devices()

@@ -236,7 +236,7 @@ def verify_ring_entry(entry: RingEntry) -> List[Finding]:
     from jax.sharding import Mesh, PartitionSpec as P
 
     from ..parallel import burst
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     findings: List[Finding] = []
     axes = entry.axes
@@ -607,7 +607,7 @@ def verify_fused_ring() -> List[Finding]:
 
     from ..ops import fused_ring as fr
     from ..parallel import burst, ring
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     findings: List[Finding] = []
     anchor_plan = _anchor(ring.fused_slot_schedule)
@@ -765,7 +765,7 @@ def verify_fused_topologies() -> List[Finding]:
 
     from ..ops import fused_ring as fr
     from ..parallel import burst, schedule as sched
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     findings: List[Finding] = []
     anchor_fwd = _anchor(fr.fused_ring_fwd)
@@ -901,7 +901,7 @@ def verify_ulysses() -> List[Finding]:
     from jax.sharding import Mesh, PartitionSpec as P
 
     from ..parallel import ulysses
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     findings: List[Finding] = []
     anchor = _anchor(ulysses._ulysses_shard)

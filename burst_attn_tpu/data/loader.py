@@ -16,6 +16,7 @@ and cached beside the source.
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -49,6 +50,14 @@ def _build_lib(src: Path, out: Path) -> None:
     try:
         subprocess.run(cmd, check=True, capture_output=True)
         os.replace(tmp, out)
+    except FileNotFoundError as e:
+        raise RuntimeError(
+            f"the data loader is native code and needs g++ to build {src}: "
+            f"{e}") from e
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"building {src} failed ({' '.join(cmd)}):\n"
+            f"{e.stderr.decode(errors='replace')}") from e
     finally:
         tmp.unlink(missing_ok=True)
 
@@ -60,8 +69,14 @@ def _load_lib() -> ctypes.CDLL:
             return _lib
         src = _native_dir() / "dataloader.cpp"
         out = _native_dir() / "build" / "libdataloader.so"
-        if not out.exists() or out.stat().st_mtime < src.stat().st_mtime:
+        # keyed on the source's hash, recorded beside the library: after a
+        # copy or a checkout, mtimes say nothing about which is newer
+        stamp = out.with_suffix(".so.sha256")
+        digest = hashlib.sha256(src.read_bytes()).hexdigest()
+        if not (out.exists() and stamp.exists()
+                and stamp.read_text().strip() == digest):
             _build_lib(src, out)
+            stamp.write_text(digest + "\n")
         lib = ctypes.CDLL(str(out))
         lib.dl_open.restype = ctypes.c_void_p
         lib.dl_open.argtypes = [

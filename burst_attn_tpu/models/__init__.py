@@ -1,6 +1,6 @@
 from .transformer import ModelConfig, init_params, forward, forward_with_aux, param_specs
 from .train import (TrainConfig, make_mesh, init_train_state, train_step,
-                    loss_fn, packed_fields, probe_model_tri_bwd)
+                    loss_fn, packed_fields)
 from .decode import Cache, forward_cached, generate, init_cache, prefill, sample_logits
 from .dist_decode import DistCache, dist_generate, dist_prefill
 from .paged_decode import (
@@ -26,7 +26,6 @@ __all__ = [
     "init_train_state",
     "train_step",
     "packed_fields",
-    "probe_model_tri_bwd",
     "loss_fn",
     "Cache",
     "forward_cached",

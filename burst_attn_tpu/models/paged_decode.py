@@ -41,13 +41,13 @@ import numpy as np
 from jax import lax
 
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from .transformer import ModelConfig, _attn_out, _mlp, _qkv_proj, _rms_norm
 from .decode import _flash_prompt_attention, sample_logits
 from ..ops.paged_attention import (
     QUANT_DTYPES, paged_decode_attention, quantize_tokens,
 )
-from ..utils.compat import shard_map
 
 
 def resolve_pool_dtype(quantize, default):

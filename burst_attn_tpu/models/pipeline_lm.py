@@ -47,13 +47,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from jax.lax import axis_size
 
 from ..parallel.burst import BurstConfig, burst_attn_shard, _resolve_backend
 # the pure math MUST be shared with the regular path: a numerics change
 # there must not silently break pp=1 vs pp=N parity (_mlp's dense path is
 # per-shard pure math too — cfg=None selects it)
 from .transformer import _attn_out, _mlp, _qkv_proj, _rms_norm, param_specs
-from ..utils.compat import axis_size, shard_map
 
 
 def stack_layers(layers):

@@ -25,13 +25,14 @@ import numpy as np
 
 from .train import (
     TrainConfig, batch_from_host, init_train_state, make_mesh, make_train_step,
-    prefetch_batches, probe_model_tri_bwd,
+    prefetch_batches,
 )
 from .transformer import ModelConfig
 from .. import obs
 from ..data import DataLoader
 from ..obs import StepTimer, get_logger
 from ..utils import log_helper
+from ..utils.compile_cache import place_compile_cache
 
 
 @dataclass(frozen=True)
@@ -163,7 +164,8 @@ def _parse_mesh(spec: str) -> dict:
     return out
 
 
-def main(argv=None):
+def parse_args(argv=None):
+    """The CLI's arguments as fit()'s (cfg, tcfg, run, mesh)."""
     p = argparse.ArgumentParser(description="Train the flagship LM on a token file.")
     p.add_argument("--data", required=True, help="BATD token file (data.write_token_file)")
     p.add_argument("--steps", type=int, required=True)
@@ -198,19 +200,6 @@ def main(argv=None):
                         "attention never crosses them (segment_ids)")
     p.add_argument("--multihost", action="store_true",
                    help="call multihost.initialize() before touching jax")
-    p.add_argument("--probe-tri-bwd", action="store_true", default=True,
-                   help="(default ON) before building the train step, "
-                        "actually COMPILE the wrapped-diagonal fused "
-                        "backward at this run's per-shard sequence length; "
-                        "if Mosaic rejects it (possible on generations "
-                        "without a measured block table) fall back to the "
-                        "rectangular kernel instead of crashing the full "
-                        "train-step compile (costs one extra kernel compile "
-                        "at startup, memoized process-wide)")
-    p.add_argument("--no-probe-tri-bwd", dest="probe_tri_bwd",
-                   action="store_false",
-                   help="skip the startup tri-backward compile probe (the "
-                        "first train step still runs it via make_train_step)")
     args = p.parse_args(argv)
 
     if args.multihost:
@@ -259,20 +248,6 @@ def main(argv=None):
         layout=args.layout,
         remat=not args.no_remat,
     )
-    if args.probe_tri_bwd:
-        # memoized (ensure_tri_bwd): make_train_step's first-step probe
-        # then hits this result for free — running it eagerly here only
-        # moves the one compile before startup so the outcome prints.
-        # probe_model_tri_bwd owns the model-to-kernel shape mapping (ring
-        # division, packed segment variant, jnp/window/non-TPU gates) so
-        # this probes exactly the kernel the train step will take.
-        ok = probe_model_tri_bwd(cfg, mesh, seq_len=args.seq_len,
-                                 packed=args.packed_eos is not None)
-        if ok is not None:
-            print(f"probe_tri_bwd(seq={args.seq_len}, d={cfg.d_head}, "
-                  f"gqa={cfg.n_heads != cfg.n_kv_heads}, "
-                  f"packed={args.packed_eos is not None}): "
-                  f"{'tri' if ok else 'RECT FALLBACK'}")
     tcfg = TrainConfig(lr=args.lr, grad_accum=args.grad_accum)
     run = RunConfig(
         data_path=args.data, steps=args.steps, batch=args.batch,
@@ -281,7 +256,13 @@ def main(argv=None):
         eval_data_path=args.eval_data, eval_every=args.eval_every,
         eval_batches=args.eval_batches, packed_eos_id=args.packed_eos,
     )
-    fit(cfg, tcfg, run, mesh)
+    return cfg, tcfg, run, mesh
+
+
+def main(argv=None):
+    """Train as the CLI's arguments say; returns fit()'s (state, history)."""
+    place_compile_cache()
+    return fit(*parse_args(argv))
 
 
 if __name__ == "__main__":

@@ -39,8 +39,9 @@ logger = obs.get_logger(__name__)
 # obs.StepTimer/runner for blocking per-step times).
 _M_STEPS = obs.counter("train.steps")
 _M_EVENTS = obs.counter(
-    "train.events", "exceptional train-loop events by kind (probe_failure; "
-                    "loss-scale kinds reserved for a mixed-precision scaler)")
+    "train.events", "exceptional train-loop events by kind "
+                    "(devstats_publish_failure; loss-scale kinds reserved "
+                    "for a mixed-precision scaler)")
 _M_STEP_S = obs.histogram("train.step_interval_s")
 _M_TPS = obs.gauge("train.tokens_per_s")
 
@@ -229,42 +230,9 @@ def packed_fields_np(tokens, eos_id: int):
     return seg, positions, labels
 
 
-def probe_model_tri_bwd(cfg: ModelConfig, mesh: Mesh, batch=None, *,
-                        seq_len: int = None, packed: bool = None):
-    """Map a model/mesh onto the flash backward's per-shard kernel shapes
-    and run the memoized tri-backward compile probe
-    (ops/pallas_flash.ensure_tri_bwd).  Called automatically by
-    make_train_step's first step; callable eagerly with explicit
-    seq_len/packed (runner does, so the probe outcome prints before
-    training starts).
-
-    Returns None when this model can never compile the tri backward —
-    jnp backend, windowed attention (banded kernels, not tri), or a
-    non-TPU backend (interpret mode) — True/False for the probe outcome
-    otherwise."""
-    if batch is not None:
-        seq_len = int(batch["tokens"].shape[1])
-        if packed is None:
-            packed = batch.get("segment_ids") is not None
-    if cfg.attn_backend == "jnp" or cfg.window is not None or not cfg.causal:
-        return None  # tri grids are causal-only; window takes the band path
-    if jax.default_backend() != "tpu":
-        return None  # pallas runs interpreted: nothing can fail Mosaic
-    if cfg.attn_strategy == "ulysses":
-        # all-to-all re-gathers the full sequence; heads split instead
-        s_kernel = seq_len
-    else:  # burst ring: each round's kernel sees the per-shard chunk
-        ring = int(np.prod([mesh.shape.get(a, 1) for a in cfg.seq_axes]))
-        s_kernel = seq_len // ring
-    from ..ops.pallas_flash import ensure_tri_bwd
-
-    return ensure_tri_bwd(
-        s_kernel, cfg.d_head, n=cfg.n_heads, n_kv=cfg.n_kv_heads,
-        segments=bool(packed), block_q=cfg.block_q, block_kv=cfg.block_kv)
-
-
-def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh):
-    """Returns jitted step((params, opt_state), batch) -> (state, metrics).
+def jit_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh):
+    """The jitted step((params, opt_state), batch) -> (state, metrics)
+    itself (state donated), for callers that lower or compile it.
 
     batch = dict(tokens, positions, labels), each [B, S] in layout order,
     sharded (dp, sp).
@@ -356,35 +324,17 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh):
             metrics["devstats"] = devstats_out
         return (params, opt_state), metrics
 
-    jit_step = jax.jit(step, donate_argnums=(0,))
-    probed = []
+    return jax.jit(step, donate_argnums=(0,))
+
+
+def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh):
+    """jit_train_step behind the host-side train-loop metrics: returns
+    step((params, opt_state), batch) -> (state, metrics)."""
+    jit_step = jit_train_step(cfg, tcfg, mesh)
+    collect = tcfg.collect_devstats
     last_dispatch = []  # [t_prev] once the first step has gone out
 
     def guarded_step(state, batch):
-        # Default tri-backward probe (round-4 verdict #8): before the first
-        # step's (much larger) jit compiles, ACTUALLY compile the
-        # wrapped-diagonal fused backward this config would take, so a
-        # Mosaic rejection on an untested TPU generation degrades to the
-        # rectangular kernel (BURST_NO_TRI_BWD, see ops/pallas_flash.
-        # probe_tri_bwd) instead of crashing the training step.  Memoized
-        # process-wide (ensure_tri_bwd) — one compile per config, shared
-        # with every other entry point.
-        # The probe is a BEST-EFFORT guard: it must never be able to fail
-        # training itself (a raise here would crash the first step, and a
-        # retried step would silently skip the guard since `probed` is
-        # already marked) — any failure degrades to running unprobed.
-        if not probed:
-            probed.append(True)
-            try:
-                probe_model_tri_bwd(cfg, mesh, batch)
-            except Exception as e:  # noqa: BLE001
-                _M_EVENTS.inc(kind="probe_failure")
-                logger.warning(
-                    "tri-backward compile probe failed (%s: %s); training "
-                    "proceeds unprobed — a Mosaic rejection would now "
-                    "surface from the first step's jit instead of "
-                    "degrading to the rectangular kernel",
-                    type(e).__name__, e)
         out = jit_step(state, batch)
         now = time.perf_counter()
         _M_STEPS.inc()

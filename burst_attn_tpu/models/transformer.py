@@ -29,9 +29,9 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from ..parallel.burst import burst_attn
-from ..utils.compat import shard_map
 
 
 @dataclass(frozen=True)

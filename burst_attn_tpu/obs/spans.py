@@ -58,12 +58,7 @@ def _tracing() -> bool:
     """True when the calling thread is inside a jax trace (jit/scan/vmap
     tracing, abstract eval) — spans must not read clocks or mutate the
     registry there."""
-    try:
-        return not jax.core.trace_state_clean()
-    except Exception:  # noqa: BLE001 — renamed across jax versions
-        # unknown tracing state: assume host context (the conservative
-        # failure is a trace-time wall-clock read, not a wrong program)
-        return False
+    return not jax.core.trace_ctx.is_top_level()
 
 
 @dataclass

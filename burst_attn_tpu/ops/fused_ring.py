@@ -76,6 +76,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.lax import axis_size
 
 from .masks import live_round_prefix, round_spec, spec_live, spec_pair_count
 from .pallas_flash import (
@@ -94,7 +95,6 @@ from .pallas_flash import (
 from .tuning import resolve_fused
 from ..parallel import schedule as sched_ir
 from ..parallel.ring import device_roles, ring_coords, wire_quantize
-from ..utils.compat import axis_size, tpu_compiler_params
 
 # barrier-semaphore namespace for the startup neighbor barrier; any stable
 # id distinct from other collective pallas kernels in the same program works
@@ -965,23 +965,23 @@ def fused_ring_fwd(q, k, v, cfg, *, seg=None, interpret=None,
         # only at round boundaries (see _fused_fwd_kernel); one row per
         # bank/direction (dir=cw|ccw in the published counter)
         out_specs.append(
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.SMEM))
+            pl.BlockSpec(memory_space=pltpu.SMEM))
         out_shape.append(
             jax.ShapeDtypeStruct((prog.n_banks, max_slots), jnp.int32))
 
     scratch = []
     for bank in range(prog.n_banks):
-        scratch.append(pltpu.ANY((prog.slots[bank], b, n_kv, s, d),
-                                 k_in.dtype))
-        scratch.append(pltpu.ANY((prog.slots[bank], b, n_kv, s, d),
-                                 v_in.dtype))
+        scratch.append(pl.ANY((prog.slots[bank], b, n_kv, s, d),
+                              k_in.dtype))
+        scratch.append(pl.ANY((prog.slots[bank], b, n_kv, s, d),
+                              v_in.dtype))
     if wire is not None:
         for bank in range(prog.n_banks):
             # scale sub-banks: same slot layout, fp32, O(1) per chunk
-            scratch.append(pltpu.ANY((prog.slots[bank], b, n_kv, 1, 1),
-                                     jnp.float32))
-            scratch.append(pltpu.ANY((prog.slots[bank], b, n_kv, 1, 1),
-                                     jnp.float32))
+            scratch.append(pl.ANY((prog.slots[bank], b, n_kv, 1, 1),
+                                  jnp.float32))
+            scratch.append(pl.ANY((prog.slots[bank], b, n_kv, 1, 1),
+                                  jnp.float32))
     scratch += [
         pltpu.VMEM((s, d), k_in.dtype),               # kchunk
         pltpu.VMEM((s, d), v_in.dtype),               # vchunk
@@ -994,7 +994,7 @@ def fused_ring_fwd(q, k, v, cfg, *, seg=None, interpret=None,
     scratch += [
         pltpu.VMEM((b, n, s // lp, lp), jnp.float32),  # mstat (base-2)
         pltpu.VMEM((b, n, s // lp, lp), jnp.float32),  # lstat (linear)
-        pltpu.ANY((b, n, nqb, bq, d), jnp.float32),   # accbuf (carry)
+        pl.ANY((b, n, nqb, bq, d), jnp.float32),      # accbuf (carry)
         pltpu.VMEM((bq, d), jnp.float32),             # acc_in
         pltpu.VMEM((bq, d), jnp.float32),             # acc_scr
         pltpu.VMEM((bq, 1), jnp.float32),             # m_sw
@@ -1015,15 +1015,15 @@ def fused_ring_fwd(q, k, v, cfg, *, seg=None, interpret=None,
 
     in_specs = [
         pl.BlockSpec((1, 1, bq, d), q_map),
-        pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-        pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     inputs = [sched, q, k_in, v_in]
     if wire is not None:
         # per-block fp32 scales ride along as ANY inputs; the kernel pops
         # them right after k/v and copies them into the scale slot banks
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY))
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
         inputs.append(kscale)
         inputs.append(vscale)
     if seg is not None:
@@ -1031,7 +1031,7 @@ def fused_ring_fwd(q, k, v, cfg, *, seg=None, interpret=None,
         # in ANY space and the kernel pulls one partition's row per round
         in_specs.append(pl.BlockSpec((1, s, 1),
                                      lambda r, b_, h, i, sp: (b_, 0, 0)))
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
         inputs.append(seg.astype(jnp.int32)[:, :, None])
         inputs.append(gather_seg_table(seg, cfg))
         scratch += [
@@ -1053,7 +1053,7 @@ def fused_ring_fwd(q, k, v, cfg, *, seg=None, interpret=None,
         # everything is sequential by construction: the ring choreography,
         # the VMEM-resident stats, and the acc carry all assume one core
         # walks the grid in order — a megacore split would race them
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT,
             dimension_semantics=("arbitrary",) * 4,
             collective_id=_COLLECTIVE_ID,

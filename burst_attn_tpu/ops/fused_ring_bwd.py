@@ -80,6 +80,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.lax import axis_size
 
 from .pallas_flash import (
     BIG_LSE,
@@ -98,7 +99,6 @@ from .fused_ring import (build_sched_table, dma_sem_wait, gather_seg_table,
                          kernel_statics, _SENDC, _GRANTC)
 from ..parallel import schedule as sched_ir
 from ..parallel.ring import WIRE_QMAX, wire_quantize
-from ..utils.compat import axis_size, tpu_compiler_params
 
 # barrier-semaphore namespace, distinct from the fused forward's (13) so a
 # program tracing both kernels never aliases their startup barriers
@@ -943,20 +943,20 @@ def fused_ring_bwd(cfg, q, k, v, o, lse, do, *, seg=None, interpret=None,
 
     dq_out_dtype = jnp.float32 if wire is None else jnp.dtype(
         jnp.int8 if wire == "int8" else jnp.float8_e4m3fn)
-    out_specs = [pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+    out_specs = [pl.BlockSpec(memory_space=pl.ANY)
                  for _ in home_banks]                      # dq partial(s)
     out_shape = [jax.ShapeDtypeStruct((b, n, nqb, bq, d), dq_out_dtype)
                  for _ in home_banks]
     if wire is not None:
         # the arriving quantized partials' per-block scales, dequantized
         # against their payload outputs by XLA just below
-        out_specs += [pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+        out_specs += [pl.BlockSpec(memory_space=pl.ANY)
                       for _ in home_banks]
         out_shape += [jax.ShapeDtypeStruct((b, n, nqb, 1, 1), jnp.float32)
                       for _ in home_banks]
     out_specs += [
-        pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),  # dk
-        pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),  # dv
+        pl.BlockSpec(memory_space=pl.ANY),  # dk
+        pl.BlockSpec(memory_space=pl.ANY),  # dv
     ]
     out_shape += [
         jax.ShapeDtypeStruct((b, n_kv, s, d), jnp.float32),
@@ -964,7 +964,7 @@ def fused_ring_bwd(cfg, q, k, v, o, lse, do, *, seg=None, interpret=None,
     ]
     if collect_stats:
         out_specs.append(
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.SMEM))
+            pl.BlockSpec(memory_space=pltpu.SMEM))
         out_shape.append(jax.ShapeDtypeStruct(
             (prog.n_banks, max(prog.slots)), jnp.int32))
 
@@ -973,18 +973,18 @@ def fused_ring_bwd(cfg, q, k, v, o, lse, do, *, seg=None, interpret=None,
     for bank in range(prog.n_banks):
         sl = prog.slots[bank]
         scratch += [
-            pltpu.ANY((sl,) + first_slot_shape, first_dtype),   # firstbuf
-            pltpu.ANY((sl, b, n, nqb, bq, d), do_in.dtype),     # dobuf
-            pltpu.ANY((sl, b, n, nqb, bq, d), q_in.dtype),      # qbuf
-            pltpu.ANY((sl, b, n, nqb, rows, lp), jnp.float32),  # lsebuf
+            pl.ANY((sl,) + first_slot_shape, first_dtype),      # firstbuf
+            pl.ANY((sl, b, n, nqb, bq, d), do_in.dtype),        # dobuf
+            pl.ANY((sl, b, n, nqb, bq, d), q_in.dtype),         # qbuf
+            pl.ANY((sl, b, n, nqb, rows, lp), jnp.float32),     # lsebuf
         ]
     if wire is not None:
         for bank in range(prog.n_banks):
             sl = prog.slots[bank]
             scratch += [
-                pltpu.ANY((sl, b, n, 1, 1), jnp.float32),   # fscbuf
-                pltpu.ANY((sl, b, n, 1, 1), jnp.float32),   # doscbuf
-                pltpu.ANY((sl, b, n, 1, 1), jnp.float32),   # qscbuf
+                pl.ANY((sl, b, n, 1, 1), jnp.float32),      # fscbuf
+                pl.ANY((sl, b, n, 1, 1), jnp.float32),      # doscbuf
+                pl.ANY((sl, b, n, 1, 1), jnp.float32),      # qscbuf
             ]
     dq_bank_slots = []
     for bank in range(dq_ring_banks):
@@ -992,18 +992,18 @@ def fused_ring_bwd(cfg, q, k, v, o, lse, do, *, seg=None, interpret=None,
         # dedicated return-home slot just past them
         extra = 1 if bank in home_banks or topology == "double" else 0
         dq_bank_slots.append(prog.dq_slots[bank] + extra)
-        scratch.append(pltpu.ANY(
+        scratch.append(pl.ANY(
             (prog.dq_slots[bank] + extra, b, n, nqb, bq, d), dq_ring_dtype))
     if wire is not None:
         for sl in dq_bank_slots:
-            scratch.append(pltpu.ANY((sl, b, n, nqb, 1, 1),
-                                     jnp.float32))          # dqscbuf
+            scratch.append(pl.ANY((sl, b, n, nqb, 1, 1),
+                                  jnp.float32))          # dqscbuf
     if has_dqi:
-        scratch.append(pltpu.ANY((prog.dq_slots[1], b, n, nqb, bq, d),
-                                 dq_ring_dtype))            # dqibuf
+        scratch.append(pl.ANY((prog.dq_slots[1], b, n, nqb, bq, d),
+                              dq_ring_dtype))            # dqibuf
         if wire is not None:
-            scratch.append(pltpu.ANY((prog.dq_slots[1], b, n, nqb, 1, 1),
-                                     jnp.float32))          # dqiscbuf
+            scratch.append(pl.ANY((prog.dq_slots[1], b, n, nqb, 1, 1),
+                                  jnp.float32))          # dqiscbuf
     scratch += [
         pltpu.VMEM((s, d), k.dtype),                  # kchunk
         pltpu.VMEM((s, d), v.dtype),                  # vchunk
@@ -1057,19 +1057,19 @@ def fused_ring_bwd(cfg, q, k, v, o, lse, do, *, seg=None, interpret=None,
     for _ in home_banks:
         scratch.append(pltpu.SemaphoreType.DMA((2,)))  # home_sems[b]
 
-    in_specs = [pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)] * 6
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 6
     inputs = [sched, first_in, do_in, q_in, lse_in, k, v]
     if wire is not None:
         # per-(batch, head) bundle scales: popped by the kernel right
         # after the six dense operands, ahead of any segment inputs
-        in_specs += [pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)] * 3
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 3
         inputs += [fsc, dosc, qsc]
     if seg is not None:
         # local KV-side ids resident per batch; the gathered table (q-side
         # orientation: [B, world, S, 1]) stays in ANY space
         in_specs.append(pl.BlockSpec((1, 1, s),
                                      lambda r, b_, h, i, sp: (b_, 0, 0)))
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
         inputs.append(seg.astype(jnp.int32)[:, None, :])
         inputs.append(jnp.swapaxes(gather_seg_table(seg, cfg), 2, 3))
         scratch += [
@@ -1091,7 +1091,7 @@ def fused_ring_bwd(cfg, q, k, v, o, lse, do, *, seg=None, interpret=None,
         # sequential by construction: the ring choreography, the VMEM
         # dk/dv accumulators and the dq streams all assume one core walks
         # the grid in order — a megacore split would race them
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT,
             dimension_semantics=("arbitrary",) * 4,
             collective_id=_COLLECTIVE_ID,

@@ -41,7 +41,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_flash import LOG2E, NEG_INF, VMEM_LIMIT, _interpret_default
-from ..utils.compat import tpu_compiler_params
 
 
 def _pad_group(q):
@@ -251,7 +250,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, gp, d), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),

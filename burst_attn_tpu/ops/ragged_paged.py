@@ -46,7 +46,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_flash import LOG2E, NEG_INF, VMEM_LIMIT, _interpret_default
-from ..utils.compat import tpu_compiler_params
 
 # hard ceiling on the padded rows-per-block tile (block_q * group rounded
 # to sublanes): past this the [rows, page] score tile plus the fp32
@@ -351,7 +350,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_lens, kv_lens,
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT,
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary"),

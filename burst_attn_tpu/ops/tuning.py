@@ -97,8 +97,8 @@ class ResolvedBlocks(NamedTuple):
 
 
 _TABLE = {
-    # measured with benchmarks/sweep_blocks.py on one v5e chip; see
-    # docs/design.md §3 for the cliff analysis
+    # measured with benchmarks/sweep_blocks.py on one v5e chip (rounds
+    # 2-3); see docs/design.md §3 for the cliff analysis
     "v5e": BlockTable(2048, 2048, 1024, 1024, 2048, True),
     # v4/v5p have roughly twice the v5e per-core VMEM, so the area at which
     # a grid step's live blocks spill — the cliff — should sit one power of
@@ -151,11 +151,11 @@ def _log_resolution(kind: str, canonical: Optional[str], row: BlockTable,
         logger.info("kernel blocks for %r: measured row %r", kind, canonical)
     else:
         logger.warning(
-            "kernel blocks for %r resolved to %s row (NOT measured on this "
+            "kernel blocks for %r resolved to %r row (NOT measured on this "
             "generation — defaults extrapolated from the v5e sweep; run "
             "`python -m benchmarks.sweep_blocks` and record the optimum in "
             "burst_attn_tpu/ops/tuning.py)",
-            kind, repr(canonical) if canonical else "the default",
+            kind, canonical,
         )
 
 
@@ -198,8 +198,10 @@ def canonical_kind(device=None):
 def block_defaults(device=None) -> BlockTable:
     """Best-known kernel blocks for `device` (default: first jax device).
 
-    Off-TPU (CPU interpret runs) the values only affect tiling granularity,
-    not correctness; the default row is returned.
+    A TPU whose device_kind the table does not know is an error: blocks
+    guessed for it would be timed as if they were tuned.  Off-TPU (CPU
+    interpret runs) the values only affect tiling granularity, not
+    correctness; the default row is returned.
     """
     if device is None:
         devs = jax.devices()
@@ -209,6 +211,11 @@ def block_defaults(device=None) -> BlockTable:
     kind = getattr(device, "device_kind", "").lower()
     platform = getattr(device, "platform", "")
     canonical = canonical_kind(device)
+    if canonical is None and platform == "tpu":
+        raise ValueError(
+            f"no kernel block row for TPU device kind {kind!r}; sweep it "
+            f"(benchmarks/sweep_blocks.py) and add the row to "
+            f"burst_attn_tpu/ops/tuning.py (known: {sorted(_TABLE)})")
     row = _TABLE[canonical] if canonical else _DEFAULT
     _log_resolution(kind, canonical, row, platform)
     return row
@@ -224,8 +231,8 @@ def block_defaults(device=None) -> BlockTable:
 # slope — exceeding the budget is never a trade-off worth making, hence a
 # clamp rather than a warning.  The budgets live in the per-generation
 # BlockTable rows (a v5p with twice the VMEM must not be clamped to v5e's
-# areas); unknown device kinds inherit the v5e-measured values through the
-# BlockTable field defaults (and _DEFAULT).
+# areas); the CPU's _DEFAULT row carries the v5e-measured values through the
+# BlockTable field defaults.
 
 
 def _cliff_ok():

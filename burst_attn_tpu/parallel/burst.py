@@ -46,6 +46,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from jax.lax import axis_size
 
 from .. import obs
 from ..obs import devstats
@@ -55,7 +57,6 @@ from ..ops.masks import (full_spec, live_round_prefix, round_spec, spec_live,
 from .ring import (ppermute_by, ppermute_next, my_partition,
                    partition_at_round, ring_round_counts,
                    wire_dequantize, wire_quantize)
-from ..utils.compat import axis_size, shard_map
 
 logger = logging.getLogger("burst_attn_tpu")
 
@@ -1027,14 +1028,7 @@ def _note_dispatch(cfg: BurstConfig, mesh, q_shape, k_shape, has_seg: bool,
 
 def _resolve_backend(backend: str) -> str:
     if backend == "auto":
-        if jax.default_backend() == "tpu":
-            try:
-                from ..ops import pallas_flash  # noqa: F401
-
-                return "pallas"
-            except ImportError:
-                return "jnp"
-        return "jnp"
+        return "pallas" if jax.default_backend() == "tpu" else "jnp"
     return backend
 
 

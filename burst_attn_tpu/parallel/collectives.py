@@ -9,7 +9,7 @@ analogue of the reference's object gather over NCCL)."""
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ..utils.compat import axis_size
+from jax.lax import axis_size
 
 
 # ---- in-shard_map collectives (SPMD) ----

@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from ..utils.compat import shard_map
+from jax import shard_map
 
 
 class MoEParams(NamedTuple):

@@ -33,7 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from ..utils.compat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 
 
 def pipeline_shard(stage_fn, stage_params, x_mb, axis: str):

@@ -18,7 +18,7 @@ device's rotating buffer holds at ring round r.
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ..utils.compat import axis_size
+from jax.lax import axis_size
 
 
 def ppermute_next(x, axis_name: str):

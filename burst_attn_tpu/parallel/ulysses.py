@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from ..utils.compat import shard_map
+from jax import shard_map
 
 
 def _local_attention(q, k, v, scale, causal, backend, block_q, block_kv,

@@ -37,13 +37,9 @@ def trace(log_dir: str, *, host_tracer_level: int = 2):
     the block appear on the same timeline (spans wrap
     jax.profiler.TraceAnnotation).
     """
-    from .compat import profile_options
-
-    opts = profile_options(host_tracer_level)
-    if opts is not None:
-        jax.profiler.start_trace(log_dir, profiler_options=opts)
-    else:  # older jax: no ProfileOptions — default tracer levels
-        jax.profiler.start_trace(log_dir)
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = host_tracer_level
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
     try:
         yield
     finally:

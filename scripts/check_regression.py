@@ -7,7 +7,7 @@ freshest on-chip records bench.py fsyncs) and the driver-captured
 numbers, when it carries any).  This gate fails — exit 1 — when any current
 headline value drops more than `--tolerance` below the BEST prior value for
 the same metric string, so a perf regression is caught at bench time
-instead of three rounds later in a VERDICT.
+instead of three rounds later.
 
     python scripts/check_regression.py                # gate (exit 1 on regression)
     python scripts/check_regression.py --dry-run      # report only, exit 0

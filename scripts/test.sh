@@ -1,7 +1,8 @@
 #!/usr/bin/env bash
 # Test launcher (reference test/test.sh:6 analogue).  No torchrun, no GPU
 # fleet: the distributed tests run on a simulated 8-device CPU mesh anywhere;
-# pass --tpu to also run the real-hardware kernel tests on this machine.
+# pass --tpu to also run the real-hardware kernel tests (on a machine with a
+# chip: one process at a time holds it).
 # --fast selects the <10-min lane (-m "not slow"); default runs everything.
 set -euo pipefail
 cd "$(dirname "$0")/.."

@@ -13,8 +13,8 @@ os.environ["XLA_FLAGS"] = (
 import jax
 
 # BURST_TESTS_TPU=1 runs on real hardware instead (for the TPU-only kernel
-# tests, e.g. tests/test_fused_bwd.py); default stays CPU so the whole suite
-# runs anywhere.
+# tests, tests/test_fused_bwd.py, through the chip tool); default stays CPU
+# so the whole suite runs anywhere.
 if not os.environ.get("BURST_TESTS_TPU"):
     jax.config.update("jax_platforms", "cpu")
     # deterministic f32 CPU matmuls for the numerics oracle; NOT set on TPU
@@ -28,10 +28,11 @@ if not os.environ.get("BURST_TESTS_TPU"):
 # marker across runs — hysteresis, not churn) are marked slow here,
 # plus the >= ~10 s fused parity matrices whose coverage the focused
 # lanes (--fused / --schedule) re-run: the fast lane keeps one canary
-# per matrix and must clear the tier-1 870 s budget with headroom
-# in ONE place rather than as decorators in 15 files, so the list can be
-# regenerated mechanically from any fresh --durations log.
-# `pytest -m "not slow"` = the fast lane (~13 min); full suite for releases.
+# per matrix.  The list lives in ONE place rather than as decorators in 15
+# files, so it can be regenerated mechanically from any fresh --durations
+# log.  `pytest -m "not slow"` = the fast lane (tier-1: about 4 minutes on
+# six workers, --dist loadfile, so the longest FILE bounds it); the full
+# suite is for releases.
 
 _SLOW = {
     ("test_burst.py", "test_causal_double_ring"),
@@ -161,9 +162,8 @@ _SLOW = {
     ("test_analysis.py", "test_poolcheck_refcount_leak_fires"),
     # grouped-kernel parity: tier-1 keeps the fp32 canary
     ("test_prefix_cache.py", "test_grouped_matches_plain_variants"),
-    # 2026-08-05 re-trim (the fast lane had crept to 818 s of the 870 s
-    # budget): the heaviest elision/window accounting tests move out of
-    # tier-1 — the --schedule lane re-runs all three via its
+    # 2026-08-05 re-trim: the heaviest elision/window accounting tests move
+    # out of tier-1 — the --schedule lane re-runs all three via its
     # window/segment/elided -k selections, and the fast lane keeps
     # test_window_and_segments_dispatch_fused as the dispatch canary
     ("test_devstats.py", "test_rounds_elided_live_vs_executed"),

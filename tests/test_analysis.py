@@ -16,11 +16,11 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
 
 from burst_attn_tpu.analysis import astlint, numerics, oracle, ringcheck
 from burst_attn_tpu.analysis.core import RULES, run_analysis
 from burst_attn_tpu.parallel.ring import ppermute_by
-from burst_attn_tpu.utils.compat import shard_map
 
 ANCHOR = ("seeded.py", 7)
 
@@ -672,7 +672,7 @@ def test_pipe_fused_remote_dma_fires():
     from jax.sharding import Mesh, PartitionSpec as P
 
     from burst_attn_tpu.analysis import obscheck
-    from burst_attn_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     devs = jax.devices()[:2]
     mesh = Mesh(np.asarray(devs), ("sp",))

@@ -4,9 +4,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
 
 from burst_attn_tpu.parallel import collectives as C
-from burst_attn_tpu.utils.compat import shard_map
 
 
 def _mesh():

@@ -130,3 +130,53 @@ def test_prepare_cli_byte_level(tmp_path):
     assert len(toks) == 11 + 1 + 3
     assert toks[11] == 1  # separator between docs
     assert toks[0] == ord("h") + 2
+
+
+@pytest.fixture
+def native_copy(tmp_path, monkeypatch):
+    """A private copy of native/ with no library built yet."""
+    import shutil
+
+    from burst_attn_tpu.data import loader
+
+    shutil.copy(loader._native_dir() / "dataloader.cpp", tmp_path)
+    monkeypatch.setattr(loader, "_native_dir", lambda: tmp_path)
+    monkeypatch.setattr(loader, "_lib", None)
+    return tmp_path
+
+
+def test_library_rebuilds_on_source_hash_not_mtime(native_copy, monkeypatch):
+    """The library is built on first use and again only when the hash
+    recorded beside it is absent or is not the source's: a copy of the
+    tree keeps no meaningful mtimes."""
+    from burst_attn_tpu.data import loader
+
+    builds = []
+    real_build = loader._build_lib
+    monkeypatch.setattr(loader, "_build_lib",
+                        lambda src, out: (builds.append(1), real_build(src, out)))
+    so = native_copy / "build" / "libdataloader.so"
+    stamp = native_copy / "build" / "libdataloader.so.sha256"
+
+    loader._load_lib()
+    assert so.exists() and len(stamp.read_text().strip()) == 64
+    monkeypatch.setattr(loader, "_lib", None)
+    loader._load_lib()
+    assert len(builds) == 1  # hash matches: no rebuild, whatever the mtimes
+    stamp.write_text("stale\n")
+    monkeypatch.setattr(loader, "_lib", None)
+    loader._load_lib()
+    assert len(builds) == 2
+    stamp.unlink()
+    monkeypatch.setattr(loader, "_lib", None)
+    loader._load_lib()
+    assert len(builds) == 3
+
+
+def test_failed_build_raises_with_compiler_output(native_copy):
+    from burst_attn_tpu.data import loader
+
+    (native_copy / "dataloader.cpp").write_text("this is not c++\n")
+    with pytest.raises(RuntimeError, match="error"):
+        loader._load_lib()
+    assert not (native_copy / "build" / "libdataloader.so").exists()

@@ -139,11 +139,18 @@ def test_segments_on_tpu():
         assert err < 5e-2, f"{name} max abs err {err}"
 
 
-@pytest.mark.parametrize("window", [512, 1024, 3000])
+@pytest.mark.parametrize("window", [512, 1024, 2048, 3000])
 def test_fused_banded_window_bwd_matches_split(window):
     """The window-banded fused sweep (grid dim 3 = nbq*group instead of
     nqb*group, _bwd_fused_iq) vs the split kernels, production tiles.
-    Covers block-aligned and unaligned windows."""
+    Covers block-aligned and unaligned windows.
+
+    The fused kernel is forced only where flash_bwd's own gate admits it
+    (a sweep of >= 4 steps): its in-place dq accumulation is not race-free
+    under that.  Measured on the v5e (PR 22, libtpu 0.0.34): forced at a
+    2-step sweep of 512x512 blocks (window=512) dq is off by 0.07 while
+    dk/dv are exact; 3-step sweeps and 256x256 blocks agree to 1e-7.  Below
+    the gate the default dispatch must BE the split pair."""
     b, n, s, d = 1, 4, 4096, 128
     ks = jax.random.split(jax.random.PRNGKey(21), 4)
     dt = jnp.bfloat16
@@ -161,11 +168,13 @@ def test_fused_banded_window_bwd_matches_split(window):
     args = (do, q, k, v, delta, lse, scale, spec)
     split = pf.flash_bwd(*args, block_q=512, block_kv=512, fused=False,
                          window=window)
-    fused = pf.flash_bwd(*args, block_q=512, block_kv=512, fused=True,
-                         window=window)
-    for name, a, b_ in zip(("dq", "dk", "dv"), split, fused):
+    gated_in = pf.bwd_band_nbq(512, 512, s // 512, window) >= 4
+    assert gated_in == (window >= 2048)
+    other = pf.flash_bwd(*args, block_q=512, block_kv=512, window=window,
+                         fused=True if gated_in else None)
+    for name, a, b_ in zip(("dq", "dk", "dv"), split, other):
         err = float(jnp.max(jnp.abs(a - b_)))
-        assert err < 1e-3, f"{name} max abs err {err}"
+        assert err < (1e-3 if gated_in else 1e-9), f"{name} max abs err {err}"
 
 
 def test_fused_segments_bwd_matches_split():
@@ -257,12 +266,24 @@ def test_tall_q_and_empty_carry_on_tpu():
                         block_q=1024, block_kv=256, triangular=True)
     empty = pf.flash_fwd(q, k, v, None, None, None, scale, spec,
                          block_q=1024, block_kv=256, triangular=True)
-    for name, a, b_ in zip(("m", "lse", "acc"), base, tall):
+    # m, lse and the normalized output compare across block shapes; the
+    # unnormalized acc does not: p is rounded to bf16 against a running max
+    # that depends on the block width, and |acc| reaches 38 here.  Measured
+    # on the v5e (PR 22): acc differs by 3.6e-2 between ANY two block widths
+    # (256x256 vs 512x512 too), o by 8.3e-4, and every variant is the same
+    # 6.6e-3 from the float32 reference.
+    def outputs(state):
+        m, lse, acc = state
+        return m, lse, T.finalize(m, lse, acc, jnp.float32)
+
+    for name, a, b_ in zip(("m", "lse", "o"), outputs(base), outputs(tall)):
         err = float(jnp.max(jnp.abs(a - b_)))
-        assert err < 1e-3, f"tall {name} max abs err {err}"
-    for name, a, b_ in zip(("m", "lse", "acc"), base, empty):
+        assert err < (4e-3 if name == "o" else 1e-3), \
+            f"tall {name} max abs err {err}"
+    # the same blocks with no carried state: the same arithmetic
+    for name, a, b_ in zip(("m", "lse", "acc"), tall, empty):
         err = float(jnp.max(jnp.abs(a - b_)))
-        assert err < 1e-3, f"empty-carry {name} max abs err {err}"
+        assert err < 1e-4, f"empty-carry {name} max abs err {err}"
 
 
 def test_bwd_loop_sweep_on_tpu():

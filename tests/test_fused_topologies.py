@@ -21,11 +21,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from burst_attn_tpu import burst_attn
 from burst_attn_tpu.ops.reference import dense_attention
 from burst_attn_tpu.parallel import burst, layouts, schedule
-from burst_attn_tpu.utils.compat import shard_map
 from burst_attn_tpu.utils.testing import check_close, random_qkv
 
 pytestmark = pytest.mark.fused_ring

@@ -140,65 +140,3 @@ def test_moe_model_trains_with_remat():
     batch = make_batch(jax.random.PRNGKey(1), cfg, mesh, batch=2, seq=64)
     state, metrics = step(state, batch)
     assert np.isfinite(float(metrics["loss"]))
-
-
-def test_train_step_runs_tri_bwd_probe_once(monkeypatch):
-    """make_train_step's returned step runs the tri-backward startup
-    probe exactly once (first call), passing the batch it was called
-    with — the default-on gate of round-4 verdict #8."""
-    from burst_attn_tpu.models import train as train_mod
-
-    calls = []
-    monkeypatch.setattr(train_mod, "probe_model_tri_bwd",
-                        lambda cfg, mesh, batch: calls.append(
-                            int(batch["tokens"].shape[1])))
-    cfg = ModelConfig(**CFG)
-    tcfg = TrainConfig(lr=1e-2)
-    mesh = make_mesh({"dp": 2, "sp": 2, "tp": 2})
-    state = init_train_state(jax.random.PRNGKey(0), cfg, tcfg, mesh)
-    step = make_train_step(cfg, tcfg, mesh)
-    batch = make_batch(jax.random.PRNGKey(1), cfg, mesh, batch=2, seq=64)
-    state, m1 = step(state, batch)
-    state, m2 = step(state, batch)
-    assert calls == [64]  # once, with the first batch's seq length
-    assert np.isfinite(float(m2["loss"]))
-
-
-def test_probe_model_tri_bwd_shape_mapping(monkeypatch):
-    """probe_model_tri_bwd maps (model, mesh, batch) onto the kernel's
-    per-shard shapes: burst divides seq by the ring, ulysses keeps the
-    full sequence, packed batches probe the segment variant, and jnp /
-    windowed / non-TPU configs return None without probing."""
-    from burst_attn_tpu.models.train import probe_model_tri_bwd
-    from burst_attn_tpu.ops import pallas_flash
-
-    seen = []
-    monkeypatch.setattr(
-        pallas_flash, "ensure_tri_bwd",
-        lambda s, d, **kw: seen.append((s, d, kw["segments"])) or True)
-    mesh = make_mesh({"dp": 2, "sp": 2, "tp": 2})
-    batch = {"tokens": np.zeros((2, 64), np.int32), "segment_ids": None}
-
-    base = {**CFG, "attn_backend": "auto"}
-    # non-TPU backend: interpret mode, nothing can fail Mosaic
-    assert probe_model_tri_bwd(ModelConfig(**base), mesh, batch) is None
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-
-    assert probe_model_tri_bwd(ModelConfig(**base), mesh, batch) is True
-    assert seen.pop() == (32, 16, False)  # burst: 64 / ring(sp=2)
-
-    packed = {"tokens": np.zeros((2, 64), np.int32),
-              "segment_ids": np.zeros((2, 64), np.int32)}
-    probe_model_tri_bwd(ModelConfig(**base), mesh, packed)
-    assert seen.pop() == (32, 16, True)
-
-    probe_model_tri_bwd(
-        ModelConfig(**{**base, "attn_strategy": "ulysses"}), mesh, batch)
-    assert seen.pop() == (64, 16, False)  # ulysses re-gathers full seq
-
-    # jnp backend / windowed attention: the tri bwd is never compiled
-    assert probe_model_tri_bwd(ModelConfig(**CFG), mesh, batch) is None
-    assert probe_model_tri_bwd(
-        ModelConfig(**{**base, "window": 32, "layout": "contig"}),
-        mesh, batch) is None
-    assert not seen

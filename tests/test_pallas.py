@@ -3,8 +3,6 @@ the TPU build's analogue of validating lao.py's Triton kernels against the
 pure-torch tile (reference burst_utils.py:42-148); run per ring-round mask
 spec, with carry-in state, GQA, and both backward kernels."""
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -191,7 +189,12 @@ def test_block_tuning_table():
     assert block_defaults(FakeDev("TPU v5")) is _tuning._TABLE["v5p"]
     assert block_defaults(FakeDev("TPU v4")) is _tuning._TABLE["v4"]
     assert not block_defaults(FakeDev("TPU v4")).measured
-    assert not block_defaults(FakeDev("weird-accelerator")).measured
+    # an unknown kind gets the CPU's row off-chip and is an error on a TPU
+    weird = FakeDev("weird-accelerator")
+    assert not block_defaults(weird).measured
+    weird.platform = "tpu"
+    with pytest.raises(ValueError, match="no kernel block row"):
+        block_defaults(weird)
     assert block_defaults(FakeDev("TPU v6e")) is _tuning._TABLE["v6"]
     assert block_defaults(FakeDev("TPU v6 lite")) is _tuning._TABLE["v6"]
     # resolve_blocks always returns the uniform 5-field shape
@@ -463,50 +466,6 @@ def test_tri_bwd_loop_sweep_matches_unrolled(qkv, block_q, block_kv, bkc,
                                    atol=1e-6, err_msg=name)
 
 
-def test_probe_tri_bwd(monkeypatch):
-    """probe_tri_bwd: gate-fail returns False without compiling; interpret
-    mode returns True; a COMPILE failure (mocked) flips BURST_NO_TRI_BWD so
-    later triangular BACKWARD calls fall back to the rectangular kernel
-    instead of crashing the caller's jit — while the forward tri/band
-    grids stay enabled (round-4 advisor: a bwd-only Mosaic rejection must
-    not demote the validated forward grids)."""
-    monkeypatch.delenv("BURST_NO_TRI", raising=False)
-    monkeypatch.delenv("BURST_NO_TRI_BWD", raising=False)
-    # gate-fail: odd kv-block count (nkb = 3) never reaches the compile
-    assert pallas_flash.probe_tri_bwd(96, 16, block_q=32, block_kv=32) is False
-    assert "BURST_NO_TRI_BWD" not in os.environ
-
-    # interpret mode (CPU): gate passes, probe trusts interpret
-    assert pallas_flash.probe_tri_bwd(64, 16, block_q=32, block_kv=32) is True
-
-    # mocked Mosaic rejection: non-interpret path whose jit compile raises
-    monkeypatch.setattr(pallas_flash, "_interpret_default", lambda: False)
-
-    class _Boom:
-        def lower(self, *a, **k):
-            raise RuntimeError("Mosaic: scoped vmem exceeded (mock)")
-
-    monkeypatch.setattr(jax, "jit", lambda fn: _Boom())
-    assert pallas_flash.probe_tri_bwd(64, 16, block_q=32, block_kv=32) is False
-    assert os.environ.get("BURST_NO_TRI_BWD") == "1"
-    assert "BURST_NO_TRI" not in os.environ
-    # bwd-scoped: the backward dispatch sees the disable, the forward does not
-    assert pallas_flash._tri_bwd_disabled() is True
-    assert pallas_flash._tri_disabled() is False
-    monkeypatch.delenv("BURST_NO_TRI_BWD", raising=False)
-
-
-def test_probe_tri_bwd_gqa_declines_without_compile(monkeypatch):
-    """GQA (n != n_kv) never takes the tri path in production, so the
-    probe must return False WITHOUT burning a compile."""
-    monkeypatch.setattr(pallas_flash, "_interpret_default", lambda: False)
-
-    def boom(fn):
-        raise AssertionError("probe compiled despite GQA")
-
-    monkeypatch.setattr(jax, "jit", boom)
-    assert pallas_flash.probe_tri_bwd(64, 16, n=8, n_kv=4,
-                                      block_q=32, block_kv=32) is False
 
 
 def test_fwd_random_config_property_sweep():
@@ -693,27 +652,3 @@ def test_bwd_random_config_property_sweep():
                 err_msg=f"{name} @ {msg}")
     assert seen["wnd_seg"] >= 1 and seen["tri_eff"] >= 1 \
         and seen["split"] >= 1 and seen["ragged"] >= 1, seen
-
-
-def test_ensure_tri_bwd_memoizes_and_short_circuits(monkeypatch):
-    """ensure_tri_bwd runs the real probe once per distinct config
-    (process-wide memo shared by every entry point) and returns False
-    instantly — no probe — once the backward tri path is disabled."""
-    monkeypatch.setattr(pallas_flash, "_TRI_BWD_PROBED", {})
-    monkeypatch.delenv("BURST_NO_TRI", raising=False)
-    monkeypatch.delenv("BURST_NO_TRI_BWD", raising=False)
-
-    calls = []
-    monkeypatch.setattr(pallas_flash, "probe_tri_bwd",
-                        lambda s, d, **kw: calls.append((s, d)) or True)
-    assert pallas_flash.ensure_tri_bwd(64, 16, block_q=32, block_kv=32)
-    assert pallas_flash.ensure_tri_bwd(64, 16, block_q=32, block_kv=32)
-    assert calls == [(64, 16)]  # second call served from the memo
-    pallas_flash.ensure_tri_bwd(128, 16, block_q=32, block_kv=32)
-    assert calls == [(64, 16), (128, 16)]  # distinct config -> new probe
-
-    # once disabled (a previous probe failed, or operator override),
-    # every config answers False without probing
-    monkeypatch.setenv("BURST_NO_TRI_BWD", "1")
-    assert pallas_flash.ensure_tri_bwd(256, 16, block_q=32, block_kv=32) is False
-    assert len(calls) == 2

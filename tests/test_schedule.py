@@ -8,10 +8,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
 import pytest
 
 from burst_attn_tpu.parallel.ring import partition_at_round, ring_schedule
-from burst_attn_tpu.utils.compat import shard_map
 
 
 @pytest.mark.parametrize("shape", [(8,), (2, 4), (4, 2)])

@@ -1,0 +1,239 @@
+"""The main path compiled for a described (not attached) TPU v5e 2x2, at real
+widths: what the chip's compiler refuses, it refuses here, at no chip time.
+
+Shapes only — nothing runs, so nothing here is a time or a result.  The
+topology is described inside a module-scoped fixture and every test compiles
+in this process: only one process may hold libtpu, so all of these stay in
+this one file (see the on-chip-measurement guide, section 2).
+"""
+
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import (NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+import burst_attn_tpu as bat
+from burst_attn_tpu.ops import pallas_flash, tuning
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+
+import chip_smoke  # noqa: E402  (the sizes the chip run uses)
+
+HBM_BYTES = 16e9  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from loading
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one; keep the cache out of these compiles
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def on_chip(monkeypatch):
+    """The code asks jax.default_backend() which tile and which kernel mode
+    to take, and the first device for its block row; answer as the chip
+    would (here both would say CPU).  conftest's "highest" matmul precision
+    is for the CPU oracle; Mosaic refuses it on bf16 dots, and no chip run
+    sets it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(tuning, "block_defaults",
+                        lambda device=None: tuning.generation_row("v5e"))
+    with jax.default_matmul_precision("default"):
+        yield
+
+
+def _seq_mesh(topo, world):
+    return chip_smoke._seq_mesh(topo.devices[:world])
+
+
+def _device_bytes(compiled):
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def _kernels(text):
+    return chip_smoke._kernel_facts(text)["kernels"]
+
+
+def _mosaic_calls(text):
+    return chip_smoke._kernel_facts(text)["mosaic_calls"]
+
+
+def _compile_attn_grad(mesh, *, seq, heads=32, kv_heads=32, backend="auto",
+                       layout="zigzag", window=None, grad=True):
+    sharding = NamedSharding(mesh, P(None, None, "sp", None))
+    q = jax.ShapeDtypeStruct((1, heads, seq, 128), jnp.bfloat16,
+                             sharding=sharding)
+    kv = jax.ShapeDtypeStruct((1, kv_heads, seq, 128), jnp.bfloat16,
+                              sharding=sharding)
+
+    def fwd(q, k, v):
+        return bat.burst_attn(q, k, v, mesh=mesh, causal=True, layout=layout,
+                              backend=backend, window=window)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32) ** 2)
+
+    fn = jax.grad(loss, (0, 1, 2)) if grad else fwd
+    return jax.jit(fn).lower(q, kv, kv).compile()
+
+
+def test_grad_burst_attn_one_chip_64k(topo, on_chip):
+    """The paper's op shape (BASELINE.json) on one chip: both passes are
+    Mosaic kernels (no kernel gave way to the jnp tile), the backward is
+    the triangular fused one, and a one-device ring has no hops."""
+    c = _compile_attn_grad(_seq_mesh(topo, 1), **{
+        k: chip_smoke.REAL["op"][k] for k in ("seq", "heads")})
+    text = c.as_text()
+    assert _mosaic_calls(text) == 2
+    assert _kernels(text) == ["burst_flash_bwd_tri", "burst_flash_fwd"]
+    assert "collective-permute" not in text
+    assert _device_bytes(c) < HBM_BYTES
+
+
+def test_grad_burst_attn_sp4_at_the_multichip_length(topo, on_chip):
+    """chip_smoke.py --multichip's ring: hops are collective-permutes and a
+    chip's share fits it.  (At 64K per shard, ROADMAP S2's shape, the same
+    program needs 16.1 GB a chip.)"""
+    size = chip_smoke.REAL_MULTICHIP["op"]
+    c = _compile_attn_grad(_seq_mesh(topo, 4), seq=size["seq"],
+                           heads=size["heads"])
+    text = c.as_text()
+    assert text.count("collective-permute-start") > 0
+    assert _mosaic_calls(text) > 2
+    assert _device_bytes(c) < HBM_BYTES
+
+
+@pytest.mark.parametrize("kw,bwd", [
+    (dict(window=4096, layout="contig"), "burst_flash_bwd_rect"),
+    (dict(kv_heads=4), "burst_flash_bwd_rect"),
+], ids=["window4k_band_grid", "gqa_32q_4kv"])
+def test_grad_variants_one_chip_64k(topo, on_chip, kw, bwd):
+    """The band grids and a GQA group of 8 at 64K: neither admits the
+    triangular backward, both take the rectangular fused kernel."""
+    c = _compile_attn_grad(_seq_mesh(topo, 1), seq=65536, **kw)
+    text = c.as_text()
+    assert _mosaic_calls(text) == 2
+    assert _kernels(text) == [bwd, "burst_flash_fwd"]
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["plain", "packed"])
+def test_tri_bwd_compiles_at_the_largest_shape_it_admits(topo, on_chip,
+                                                         packed):
+    """tri_bwd_supported is a hand model of Mosaic's VMEM use and nothing
+    catches a compile failure behind it: the largest sequence it admits at
+    the default blocks must compile, with and without segment ids."""
+    from burst_attn_tpu.ops.masks import round_spec
+
+    rb = tuning.resolve_blocks()
+    bq, bkv = rb.block_q_bwd, rb.block_kv_bwd
+    s = max(s for s in range(2 * bkv, 1 << 18, 2 * bkv)
+            if pallas_flash.tri_bwd_supported(s, s, 1, 1, 128, block_q=bq,
+                                              block_kv=bkv))
+    assert s >= 65536  # the paper's per-chip length takes this kernel
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    args = ([shape((1, 1, s, 128), jnp.bfloat16)] * 4
+            + [shape((1, 1, s), jnp.float32)] * 2
+            + ([shape((1, s), jnp.int32)] * 2 if packed else []))
+
+    def bwd(do, q, k, v, delta, lse, *segments):
+        spec = round_spec(jnp.int32(0), jnp.int32(0), s, s, True, "contig")
+        return pallas_flash.flash_bwd(
+            do, q, k, v, delta, lse, 128 ** -0.5, spec, block_q=bq,
+            block_kv=bkv, triangular=True, segments=segments or None)
+
+    text = jax.jit(bwd).lower(*args).compile().as_text()
+    assert _kernels(text) == ["burst_flash_bwd_tri"]
+    assert _mosaic_calls(text) == 1
+
+
+def test_train_step_at_the_chip_smoke_size(topo, on_chip):
+    """chip_smoke.py's model at its sequence length: the whole jitted step
+    (forward, backward, AdamW) fits one chip."""
+    from burst_attn_tpu.models import runner, train
+    from burst_attn_tpu.models.transformer import init_params
+
+    cfg, tcfg, run, _ = runner.parse_args(chip_smoke._train_argv(
+        "unused.batd", mesh="sp=1", seed=0, **chip_smoke.REAL["train"]))
+    mesh = train.make_mesh({"sp": 1}, devices=topo.devices)
+    opt = train._optimizer(tcfg)
+
+    def init(key):
+        params = init_params(key, cfg)
+        return params, opt.init(params)
+
+    params, opt_state = jax.eval_shape(init, jax.random.PRNGKey(0))
+    specs = train._state_specs(cfg, tcfg, params)
+
+    def placed(shapes, specs):
+        return jax.tree.map(
+            lambda spec, x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, spec)),
+            specs, shapes, is_leaf=lambda x: isinstance(x, P))
+
+    state = (placed(params, specs[0]), placed(opt_state, specs[1]))
+    tokens = jax.ShapeDtypeStruct((run.batch, run.seq_len), jnp.int32,
+                                  sharding=NamedSharding(mesh, P(None, "sp")))
+    batch = {"tokens": tokens, "positions": tokens, "labels": tokens}
+    c = train.jit_train_step(cfg, tcfg, mesh).lower(state, batch).compile()
+    text = c.as_text()
+    assert sum(x.size for x in jax.tree.leaves(params)) == 1_208_027_136
+    assert _kernels(text) == ["burst_flash_bwd_tri", "burst_flash_fwd"]
+    # one forward, one recomputed forward and one backward a layer
+    assert _mosaic_calls(text) == 3 * cfg.n_layers
+    assert _device_bytes(c) < 15.75 * 2**30 - 2**30  # a GiB to spare
+
+
+@pytest.mark.xfail(
+    strict=True, raises=jax.errors.JaxRuntimeError,
+    reason="Mosaic refuses both fused ring kernels: 'Cannot infer the memory "
+           "space of main's argument 6' — their HBM slot banks are pl.ANY "
+           "scratch shapes; as pltpu.HBM scratch it answers 'Scratch memref "
+           "allocation only supported for vmem, smem and semaphore_mem', so "
+           "the banks have to become operands of the call (ROADMAP S2)")
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
+def test_fused_ring_sp4(topo, on_chip, grad):
+    _compile_attn_grad(_seq_mesh(topo, 4), seq=32768, backend="fused_ring",
+                       grad=grad)
+
+
+def test_ragged_paged_attention_compiles(topo, on_chip):
+    """The serving kernel at d_head 128, page 128, 32 q / 4 kv heads, a
+    mixed prefill chunk: the next bring-up's first fact."""
+    from burst_attn_tpu.ops.ragged_paged import ragged_paged_attention
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    slots, n_q, n_kv, chunk, d, page, n_pages, cols = 8, 32, 4, 128, 128, 128, 512, 64
+    pool = shape((n_pages, n_kv, page, d), jnp.bfloat16)
+    text = jax.jit(ragged_paged_attention).lower(
+        shape((slots, n_q, chunk, d), jnp.bfloat16), pool, pool,
+        shape((slots, cols), jnp.int32), shape((slots,), jnp.int32),
+        shape((slots,), jnp.int32)).compile().as_text()
+    assert _mosaic_calls(text) == 1

@@ -49,3 +49,25 @@ def test_make_hybrid_mesh_single_host():
 def test_initialize_single_process_noop():
     multihost.initialize()  # must not raise in a single-process run
     assert jax.process_count() == 1
+
+
+def test_place_compile_cache(monkeypatch):
+    """Placed from outside (the environment variable JAX reads itself), the
+    repo sets no directory; otherwise it is <checkout>/.jax_cache, a fixed
+    path derived from the package's own location."""
+    from pathlib import Path
+
+    import burst_attn_tpu
+    from burst_attn_tpu.utils.compile_cache import place_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+        assert place_compile_cache() == "/somewhere/else"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        root = Path(burst_attn_tpu.__file__).resolve().parents[1]
+        assert place_compile_cache() == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(root / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
