@@ -104,6 +104,7 @@ def main(argv=None):
             for _ in range(args.trace_steps):
                 state, metrics = step(state, batch)
             float(metrics["loss"])
+    step.close()  # the last step's open `train.step` span
 
     tokens = args.batch * args.seq
     step_s = min(timer.times)  # best step; summary() has the spread

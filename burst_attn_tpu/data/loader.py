@@ -20,14 +20,23 @@ import hashlib
 import os
 import subprocess
 import threading
+import time
 from pathlib import Path
 from typing import Optional, Tuple
 
 import numpy as np
 
 from ..obs.logs import get_logger, safe_warn
+from ..obs.registry import default_registry
 
 _logger = get_logger("burst_attn_tpu.data")
+
+# a `dl_next` that blocked this long found no window ready: the worker
+# threads are behind the consumer
+STALL_S = 1e-3
+_M_STALLS = default_registry().counter(
+    "data.loader_stalls", "DataLoader.next() calls whose dl_next blocked "
+                          "over 1 ms (no window was ready)")
 
 _MAGIC = 0x44544142  # "BATD"
 _HEADER = 16
@@ -176,7 +185,10 @@ class DataLoader:
     def next(self) -> Tuple[np.ndarray, np.ndarray]:
         window = np.empty((self.batch, self.seq_len + 1), np.int32)
         ptr = window.ctypes.data_as(ctypes.POINTER(ctypes.c_int32))
+        t0 = time.perf_counter()
         got = self._lib.dl_next(self._h, ptr)
+        if time.perf_counter() - t0 > STALL_S:
+            _M_STALLS.inc()
         if got < 0:
             raise RuntimeError("dl_next failed")
         self.step = got + 1

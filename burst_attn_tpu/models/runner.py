@@ -18,7 +18,7 @@ import argparse
 import json
 import time
 from dataclasses import dataclass
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import numpy as np
@@ -30,7 +30,7 @@ from .train import (
 from .transformer import ModelConfig
 from .. import obs
 from ..data import DataLoader
-from ..obs import StepTimer, get_logger
+from ..obs import get_logger
 from ..utils import log_helper
 from ..utils.compile_cache import place_compile_cache
 
@@ -56,11 +56,19 @@ class RunConfig:
     packed_eos_id: Optional[int] = None
 
 
-def fit(cfg: ModelConfig, tcfg: TrainConfig, run: RunConfig, mesh):
+def fit(cfg: ModelConfig, tcfg: TrainConfig, run: RunConfig, mesh,
+        on_step: Optional[Callable[[dict], Optional[bool]]] = None):
     """Train for run.steps, checkpointing and resuming as configured.
 
     Returns (state, history) where history is a list of {step, loss, ...}
     dicts (rank-0 view).
+
+    `on_step(record)` is called after every step with that step's closed
+    `train.step` span as a dict (obs.Span.record(): `duration_s`, and under
+    `attrs` what make_train_step documents) plus `step` (1-based, as in
+    history) and `blocked_s` (seconds from the step's dispatch until its
+    outputs were ready; fit is what blocks).  Returning False ends the run
+    after that step, so a caller can bound a run by the clock.
     """
     log = get_logger("runner")
     primary = log_helper.is_primary()
@@ -79,7 +87,7 @@ def fit(cfg: ModelConfig, tcfg: TrainConfig, run: RunConfig, mesh):
         state = init_train_state(jax.random.PRNGKey(run.seed), cfg, tcfg, mesh)
 
     step_fn = make_train_step(cfg, tcfg, mesh)
-    timer = StepTimer()
+    blocked = []  # seconds, one a step
     history = []
 
     evaluator = None
@@ -113,34 +121,49 @@ def fit(cfg: ModelConfig, tcfg: TrainConfig, run: RunConfig, mesh):
                 dl.seek(start_step)
             batches = prefetch_batches(dl, cfg, mesh,
                                        packed_eos_id=run.packed_eos_id)
+            # the batch of step N+1 is asked for inside step N's span, so
+            # that its loader wait and transfer are that span's children
+            batch = next(batches) if start_step < run.steps else None
             for step in range(start_step, run.steps):
-                batch = next(batches)
-                with timer as t:
-                    state, metrics = step_fn(state, batch)
-                    t.watch(state)
-                if (step + 1) % run.log_every == 0 or step + 1 == run.steps:
-                    row = {
-                        "step": step + 1,
-                        "loss": float(metrics["loss"]),
-                        "grad_norm": float(metrics["grad_norm"]),
-                        "step_s": timer.times[-1],
-                    }
-                    history.append(row)
-                    if primary:
-                        log.info("%s", json.dumps(row))
+                last = step + 1 == run.steps
+                t0 = time.perf_counter()
+                state, metrics = step_fn(state, batch)
+                jax.block_until_ready(state)
+                blocked.append(time.perf_counter() - t0)
+                if (step + 1) % run.log_every == 0 or last:
+                    with obs.span("train.log", step=step + 1):
+                        row = {
+                            "step": step + 1,
+                            "loss": float(metrics["loss"]),
+                            "grad_norm": float(metrics["grad_norm"]),
+                            "step_s": blocked[-1],
+                        }
+                        history.append(row)
+                        if primary:
+                            log.info("%s", json.dumps(row))
                 maybe_eval(step)
-                if ckpt and ((step + 1) % run.ckpt_every == 0 or step + 1 == run.steps):
-                    ckpt.save(step + 1, state)
+                if ckpt and ((step + 1) % run.ckpt_every == 0 or last):
+                    with obs.span("train.ckpt_save", step=step + 1):
+                        ckpt.save(step + 1, state)
+                if not last:
+                    batch = next(batches)
+                record = step_fn.close().record()
+                if on_step is not None and on_step(
+                        {**record, "step": step + 1,
+                         "blocked_s": blocked[-1]}) is False:
+                    break
     finally:
+        step_fn.close()  # a step an exception cut short still gets its span
         # flush the async orbax save even on an exception mid-run — the
         # crash case is exactly when the newest checkpoint matters
         if ckpt:
             ckpt.close()
         if evaluator is not None:
             evaluator.close()
-    s = timer.summary()
-    if s["steps"] and primary:
-        log.info("done: %d steps, mean %.3fs/step", s["steps"], s["mean_s"])
+    steady = blocked[1:] or blocked  # the first step compiles
+    if steady and primary:
+        log.info("done: %d steps, mean %.3fs/step", len(steady),
+                 sum(steady) / len(steady))
     # BURST_OBS_EXPORT=<path>: drop the run's full metric/span state as an
     # obs JSONL export (readable with `python -m burst_attn_tpu.obs`)
     import os

@@ -14,6 +14,8 @@ shifted BEFORE the permutation — shifting after would cross shard boundaries.
 `positions` carries true global positions for rotary (layouts.position_ids).
 """
 
+import gc
+import itertools
 import time
 from dataclasses import dataclass
 from functools import partial
@@ -36,14 +38,25 @@ logger = obs.get_logger(__name__)
 # between consecutive dispatches equals steady-state step time once the
 # pipeline fills, WITHOUT inserting a device sync that would serialize the
 # host-to-device prefetch against the running step (use
-# obs.StepTimer/runner for blocking per-step times).
+# obs.StepTimer/runner for blocking per-step times).  Each interval is also
+# one `train.step` span in the ring (make_train_step), which carries what
+# the loop's thread did inside it and why it may have been late.
 _M_STEPS = obs.counter("train.steps")
 _M_EVENTS = obs.counter(
     "train.events", "exceptional train-loop events by kind "
                     "(devstats_publish_failure; loss-scale kinds reserved "
                     "for a mixed-precision scaler)")
 _M_STEP_S = obs.histogram("train.step_interval_s")
-_M_TPS = obs.gauge("train.tokens_per_s")
+_M_COMPILES = obs.counter(
+    "train.backend_compiles", "XLA backend compiles (persistent-cache reads "
+                              "included) since the first make_train_step")
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_listener = []  # [listener] once registered: one per process
+
+try:
+    import resource
+except ImportError:  # no such module off POSIX: the attr is then absent
+    resource = None
 
 from .transformer import ModelConfig, forward, forward_with_aux, init_params, param_specs
 from ..parallel import layouts
@@ -88,7 +101,7 @@ def _optimizer(tcfg: TrainConfig):
     )
 
 
-def _state_specs(cfg: ModelConfig, tcfg: TrainConfig, params_shape):
+def state_specs(cfg: ModelConfig, tcfg: TrainConfig, params_shape):
     """PartitionSpec pytree for (params, opt_state): optimizer moments shard
     like their parameters.
 
@@ -131,7 +144,7 @@ def init_train_state(key, cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh):
         return params, opt.init(params)
 
     params_shape, opt_shape = jax.eval_shape(init_fn, key)
-    _, opt_specs = _state_specs(cfg, tcfg, params_shape)
+    _, opt_specs = state_specs(cfg, tcfg, params_shape)
     out_shardings = (
         jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
                      is_leaf=lambda x: isinstance(x, P)),
@@ -152,11 +165,13 @@ def _loss_parts(params, tokens, positions, labels, cfg: ModelConfig, mesh,
         logits, aux, stats = out
     else:
         logits, aux = out
-    valid = labels >= 0
-    labels_safe = jnp.where(valid, labels, 0)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, labels_safe[..., None], axis=-1)[..., 0]
-    nll_sum = jnp.sum(jnp.where(valid, nll, 0.0))
+    with jax.named_scope("obs.train.loss"):
+        valid = labels >= 0
+        labels_safe = jnp.where(valid, labels, 0)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, labels_safe[..., None],
+                                   axis=-1)[..., 0]
+        nll_sum = jnp.sum(jnp.where(valid, nll, 0.0))
     if collect_stats:
         return nll_sum, aux, stats
     return nll_sum, aux
@@ -316,9 +331,10 @@ def jit_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh):
             (s_sum, grads), _ = jax.lax.scan(body, (jnp.float32(0.0), zeros), mb)
             loss = s_sum / v_total
             grads = jax.tree.map(lambda g: g / v_total, grads)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope("obs.train.optimizer"):
+            updates, opt_state = opt.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+            gnorm = optax.global_norm(grads)
         metrics = {"loss": loss, "grad_norm": gnorm}
         if collect:
             metrics["devstats"] = devstats_out
@@ -327,30 +343,98 @@ def jit_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh):
     return jax.jit(step, donate_argnums=(0,))
 
 
+def _count_compiles():
+    """Register, once a process, the jax.monitoring listener behind
+    `train.backend_compiles` (jax keeps listeners for the process's life)."""
+    if _compile_listener:
+        return
+
+    def on_duration(event, seconds, **_):
+        if event == _COMPILE_EVENT:
+            _M_COMPILES.inc()
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    _compile_listener.append(on_duration)
+
+
+def _nivcsw():
+    """Involuntary context switches of the calling thread so far; None
+    where the platform does not count them per thread.  One syscall."""
+    if resource is None or not hasattr(resource, "RUSAGE_THREAD"):
+        return None
+    return resource.getrusage(resource.RUSAGE_THREAD).ru_nivcsw
+
+
 def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh):
     """jit_train_step behind the host-side train-loop metrics: returns
-    step((params, opt_state), batch) -> (state, metrics)."""
+    step((params, opt_state), batch) -> (state, metrics).
+
+    Each call opens one `train.step` span (obs.begin) and closes the one the
+    previous call opened, so a step's span runs dispatch to dispatch and is
+    the parent of what the loop's thread does in between: `train.dispatch`
+    (the jitted call), the `train.loader_wait` / `train.h2d` of the next
+    `next(batches)`, the runner's log / eval / checkpoint spans.  Its self
+    time is the wait for the device plus the caller's own code.  Closed, it
+    carries `seq`, `wall_ns`, `loader_wait_s` / `h2d_s` / `dispatch_s` (sums
+    of those children), `compiles`, `gc2`, and where the platform counts it
+    `nivcsw` of the loop's thread (docs/observability.md "How to read a
+    stall").  `train.step_interval_s` stays what it was, the time from
+    one dispatch to the next, and leaves out the first, which holds the
+    compile.
+
+    `step.close()`, on the loop's thread, ends the open span and returns it
+    (the runner does, after each step).  The last step's span is open as
+    long as the step function lives, and spans entered on that thread
+    meanwhile nest under it: close it when the loop is done, or drop the
+    function, which takes the span with it unrecorded."""
     jit_step = jit_train_step(cfg, tcfg, mesh)
     collect = tcfg.collect_devstats
-    last_dispatch = []  # [t_prev] once the first step has gone out
+    _count_compiles()
+    open_step = []  # [live span, counters at its dispatch] while one is open
+    dispatched = []  # [perf_counter at the last dispatch]
+    seq = itertools.count()
+    # a platform counts these or it does not: asked once, not a step
+    read_nivcsw = _nivcsw if _nivcsw() is not None else lambda: None
+
+    def counters():
+        return (_M_COMPILES.get(), gc.get_stats()[2]["collections"],
+                read_nivcsw())
+
+    def close(now=None):
+        """End the open `train.step` span; its obs.Span, None if none."""
+        if not open_step:
+            return None
+        live, (compiles0, gc0, nivcsw0) = open_step
+        del open_step[:]
+        compiles, gc2, nivcsw = now or counters()
+        for attr, child in (("loader_wait_s", "train.loader_wait"),
+                            ("h2d_s", "train.h2d"),
+                            ("dispatch_s", "train.dispatch")):
+            live.set(attr, live.child_s.get(child, 0.0))
+        live.set("compiles", int(compiles - compiles0))
+        live.set("gc2", gc2 - gc0)
+        if nivcsw is not None:
+            live.set("nivcsw", nivcsw - nivcsw0)
+        # train.step_interval_s is the interval's one histogram
+        return obs.end(live, observe=False)
 
     def guarded_step(state, batch):
-        out = jit_step(state, batch)
-        now = time.perf_counter()
+        now = counters()  # one reading ends the last span and starts this
+        close(now)
+        n, t = next(seq), time.perf_counter()
+        if n > 1:  # the first interval holds the compile
+            _M_STEP_S.observe(t - dispatched[0])
+        dispatched[:] = [t]
+        open_step[:] = [obs.begin("train.step", seq=n,
+                                  wall_ns=time.time_ns()), now]
+        with obs.span("train.dispatch"):
+            out = jit_step(state, batch)
         _M_STEPS.inc()
-        if last_dispatch:
-            dt = now - last_dispatch[0]
-            _M_STEP_S.observe(dt)
-            if dt > 0:
-                # .size on a sharded array is the static GLOBAL element
-                # count — no device sync
-                _M_TPS.set(batch["tokens"].size / dt)
-        last_dispatch[:] = [now]
         if collect:
             # fold the (tiny) device stats into the host registry AFTER the
-            # dispatch interval is measured; publish reads the arrays back,
-            # so this is the one host<->device sync the knob buys.  Best
-            # effort: telemetry must never be able to fail a train step.
+            # dispatch; publish reads the arrays back, so this is the one
+            # host<->device sync the knob buys.  Best effort: telemetry
+            # must never be able to fail a train step.
             new_state, metrics = out
             stats = metrics.pop("devstats")
             try:
@@ -363,12 +447,17 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh):
             out = (new_state, metrics)
         return out
 
+    guarded_step.close = close
     return guarded_step
 
 
 def train_step(state, batch, cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh):
     """Convenience one-shot (compiles per call; prefer make_train_step)."""
-    return make_train_step(cfg, tcfg, mesh)(state, batch)
+    step = make_train_step(cfg, tcfg, mesh)
+    try:
+        return step(state, batch)
+    finally:
+        step.close()
 
 
 def batch_from_host(tokens, labels, cfg: ModelConfig, mesh: Mesh,
@@ -433,16 +522,23 @@ def prefetch_batches(dl, cfg: ModelConfig, mesh: Mesh, depth: int = 2,
     it = iter(dl)
     mk = partial(batch_from_host, cfg=cfg, mesh=mesh,
                  packed_eos_id=packed_eos_id)
+
+    def fetch():
+        """Queue the next device batch, its two host phases each in a span
+        (children of the open `train.step` when the loop's thread asks)."""
+        with obs.span("train.loader_wait"):
+            x, y = next(it)
+        with obs.span("train.h2d"):
+            q.append(mk(x, y))
+
     try:
         for _ in range(depth):
-            x, y = next(it)
-            q.append(mk(x, y))
-    except StopIteration:
-        pass  # source shorter than depth
-    else:
-        for x, y in it:
-            q.append(mk(x, y))
+            fetch()
+        while True:
+            fetch()
             yield q.popleft()
+    except StopIteration:
+        pass  # the source ran out, perhaps before the queue was full
     while q:  # finite iterator: drain what is already in flight
         yield q.popleft()
 

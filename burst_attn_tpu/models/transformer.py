@@ -403,24 +403,32 @@ def forward_with_aux(params: Params, tokens, positions, cfg: ModelConfig, mesh,
     act_spec = NamedSharding(mesh, P(cfg.batch_axis, seq_spec, None))
     logit_spec = NamedSharding(mesh, P(cfg.batch_axis, seq_spec, cfg.head_axis))
 
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    x = jax.lax.with_sharding_constraint(x, act_spec)
+    # obs.model.* named scopes: the module half of an op's `op_name`, which
+    # obs.spans.phase_of reads back from a device trace (metadata only, no
+    # equations; docs/observability.md)
+    with jax.named_scope("obs.model.embed"):
+        x = params["embed"].astype(cfg.dtype)[tokens]
+        x = jax.lax.with_sharding_constraint(x, act_spec)
 
     def block(carry, p):
         if collect_stats:
             from ..obs import devstats
 
             x, aux, stats = carry
-            a, st = _attention(p, x, positions, cfg, mesh,
-                               segment_ids=segment_ids, collect_stats=True)
-            x = x + a
+            with jax.named_scope("obs.model.attn"):
+                a, st = _attention(p, x, positions, cfg, mesh,
+                                   segment_ids=segment_ids,
+                                   collect_stats=True)
+                x = x + a
             stats = st if stats is None else devstats.merge(stats, st)
         else:
             x, aux = carry
-            x = x + _attention(p, x, positions, cfg, mesh,
-                               segment_ids=segment_ids)
-        m, aux_l = _mlp(p, x, cfg, mesh)
-        x = jax.lax.with_sharding_constraint(x + m, act_spec)
+            with jax.named_scope("obs.model.attn"):
+                x = x + _attention(p, x, positions, cfg, mesh,
+                                   segment_ids=segment_ids)
+        with jax.named_scope("obs.model.mlp"):
+            m, aux_l = _mlp(p, x, cfg, mesh)
+            x = jax.lax.with_sharding_constraint(x + m, act_spec)
         if collect_stats:
             return x, aux + aux_l, stats
         return x, aux + aux_l
@@ -437,11 +445,13 @@ def forward_with_aux(params: Params, tokens, positions, cfg: ModelConfig, mesh,
     else:
         x, aux = carry
 
-    x = _rms_norm(x, params["final_norm"])
-    logits = jnp.einsum(
-        "bsd,vd->bsv", x, params["lm_head"], preferred_element_type=jnp.float32
-    )
-    logits = jax.lax.with_sharding_constraint(logits, logit_spec)
+    with jax.named_scope("obs.model.loss_head"):
+        x = _rms_norm(x, params["final_norm"])
+        logits = jnp.einsum(
+            "bsd,vd->bsv", x, params["lm_head"],
+            preferred_element_type=jnp.float32
+        )
+        logits = jax.lax.with_sharding_constraint(logits, logit_spec)
     if collect_stats:
         return logits, aux, stats
     return logits, aux

@@ -32,8 +32,9 @@ from .registry import (
     default_registry,
 )
 from .spans import (
-    Span, StepTimer, annotate, completed_spans, current_span, reset_spans,
-    span, span_records, traced,
+    Span, StepTimer, annotate, begin, completed_spans, current_span, end,
+    instruction_texts, phase_of, reset_spans, scope_map, span, span_records,
+    traced,
 )
 from .logs import dropped_messages, get_logger, safe_warn
 # request tracing: per-request causal timelines (TraceContext propagation,
@@ -105,9 +106,10 @@ def reset() -> None:
 
 __all__ = [
     "Counter", "DevStats", "Gauge", "Histogram", "Registry", "Span",
-    "StepTimer", "LATENCY_BUCKETS_S", "TraceContext", "annotate",
+    "StepTimer", "LATENCY_BUCKETS_S", "TraceContext", "annotate", "begin",
     "completed_spans", "counter", "current_span", "default_registry",
-    "devstats", "dropped_messages", "export_jsonl", "gauge", "get_logger",
-    "histogram", "reset", "reset_spans", "safe_warn", "snapshot", "span",
+    "devstats", "dropped_messages", "end", "export_jsonl", "gauge",
+    "get_logger", "histogram", "instruction_texts", "phase_of", "reset",
+    "reset_spans", "safe_warn", "scope_map", "snapshot", "span",
     "span_records", "to_prometheus", "trace", "traced",
 ]

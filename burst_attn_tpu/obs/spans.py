@@ -20,19 +20,28 @@ registry state is touched.  This is the no-op path the burstlint
 `obs-jit-safe` rule assumes; instrumentation is still expected to live at
 host boundaries, the degrade just makes an accidental traced call harmless.
 
-`StepTimer` and `annotate` moved here from utils/profiling.py (which keeps
-deprecation shims); `trace()` — the XLA profiler capture — stays in
-utils/profiling.py since it is about device timelines, not obs state.
+`begin(name)` / `end(live)` are the two halves of `span()` for a span that
+stays open across calls (the trainer's `train.step`, dispatch to dispatch):
+spans entered meanwhile on that thread nest under it through the same stack.
+
+`phase_of` / `scope_map` read the DEVICE side of the same convention: the
+`obs.<layer>.<what>` named scopes and JAX's own transform components in an
+HLO instruction's `op_name`, so that a profiler event can be charged to
+forward / recomputed forward / backward / optimizer and to a module.
+
+`StepTimer` and `annotate` live here; `trace()` — the XLA profiler capture —
+stays in utils/profiling.py since it is about device timelines, not obs state.
 """
 
 import collections
-import contextlib
 import functools
 import itertools
+import re
 import threading
 import time
+import weakref
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 
@@ -48,10 +57,24 @@ _tls = threading.local()
 
 
 def _stack() -> list:
+    """This thread's open spans, outermost first, as weak references: a
+    span is open as long as someone holds its handle (a `with` block, the
+    caller of `begin`).  One whose holder went away without `end()` is
+    skipped from then on (`_top`), by the thread that owns the stack."""
     st = getattr(_tls, "stack", None)
     if st is None:
         st = _tls.stack = []
     return st
+
+
+def _top(stack):
+    """The innermost span of `stack` that is still held, else None."""
+    while stack:
+        live = stack[-1]()
+        if live is not None:
+            return live
+        stack.pop()
+    return None
 
 
 def _tracing() -> bool:
@@ -83,16 +106,26 @@ class Span:
 
 
 class _LiveSpan:
-    """Handle yielded inside a `span()` block; `set(k, v)` attaches attrs."""
+    """Handle of an open span (`begin()` returns it, `span()` yields it);
+    `set(k, v)` attaches attrs.  `child_s` holds the summed seconds of the
+    spans that ended directly under it, by name: a span's self time, and a
+    parent's account of its children, without a walk of the ring."""
 
-    __slots__ = ("name", "span_id", "parent_id", "depth", "attrs")
+    __slots__ = ("name", "span_id", "parent_id", "depth", "attrs", "t0",
+                 "child_s", "stack", "parent", "__weakref__")
 
-    def __init__(self, name, span_id, parent_id, depth):
+    def __init__(self, name, span_id, parent, stack):
         self.name = name
         self.span_id = span_id
-        self.parent_id = parent_id
-        self.depth = depth
+        self.stack = stack  # the opening thread's; end() takes it off there
+        # the parent OBJECT is held only while this span is open (end() adds
+        # to its child_s and lets go): what outlives the span is the id
+        self.parent = parent
+        self.parent_id = None if parent is None else parent.span_id
+        self.depth = 0 if parent is None else parent.depth + 1
         self.attrs: Dict[str, object] = {}
+        self.t0 = 0.0
+        self.child_s: Dict[str, float] = {}
 
     def set(self, key: str, value) -> None:
         self.attrs[key] = value
@@ -113,8 +146,64 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
-@contextlib.contextmanager
-def span(name: str, **attrs):
+def _open(name, attrs, parent, stack):
+    live = _LiveSpan(name, next(_ids), parent, stack)
+    live.attrs.update(attrs)
+    stack.append(weakref.ref(live))
+    live.t0 = time.perf_counter()
+    return live
+
+
+def begin(name: str, **attrs):
+    """Open a span and return its handle; `end(handle)`, on the same thread,
+    completes it.  The two halves of `span()`, for a span that stays open
+    across calls: spans entered meanwhile on this thread nest under it.
+
+    Such a span outlives the block that opened it, so it belongs to none:
+    it is a root (no parent, depth 0) whatever is open when it begins.  A
+    loop that opens each one inside a child of the last (the trainer's
+    `train.step`, begun inside the caller's span around the step) would
+    otherwise chain every span of the run under the first.  It is open
+    while its handle is held: one dropped without `end()` is gone from the
+    stack too, and goes to no ring.  No profiler annotation (that is a
+    context manager's to hold).  Under a jax trace it returns the no-op
+    handle and touches nothing."""
+    if _tracing():
+        return _NOOP
+    return _open(name, attrs, None, _stack())
+
+
+def end(live, observe: bool = True) -> Optional["Span"]:
+    """Complete an open span: the Span that went into the ring, None for
+    the no-op handle.  `observe=False` leaves the registry histogram
+    `span.<name>` alone, for a caller that keeps the duration in a
+    histogram of its own."""
+    if live is _NOOP:
+        return None
+    dur = time.perf_counter() - live.t0
+    stack = live.stack
+    # the last entry, but for a span opened across calls and ended under
+    # later ones (or over entries whose holders went away)
+    for i in range(len(stack) - 1, -1, -1):
+        if stack[i]() is live:
+            del stack[i]
+            break
+    parent, live.parent = live.parent, None
+    if parent is not None:
+        sums = parent.child_s
+        sums[live.name] = sums.get(live.name, 0.0) + dur
+    done = Span(name=live.name, span_id=live.span_id,
+                parent_id=live.parent_id, depth=live.depth,
+                thread=threading.current_thread().name,
+                start_s=live.t0, duration_s=dur, attrs=live.attrs)
+    with _completed_lock:
+        _completed.append(done)
+    if observe:
+        default_registry().histogram("span." + live.name).observe(dur)
+    return done
+
+
+class span:
     """Context manager: time a host-side block as a named span.
 
         with span("serve.step", live=3) as sp:
@@ -122,31 +211,32 @@ def span(name: str, **attrs):
             sp.set("admitted", 2)
 
     Under a jax trace this is a no-op that only applies `jax.named_scope`
-    (see module docstring)."""
-    if _tracing():
-        with jax.named_scope(name):
-            yield _NOOP
-        return
-    stack = _stack()
-    parent = stack[-1] if stack else None
-    live = _LiveSpan(name, next(_ids),
-                     parent.span_id if parent else None, len(stack))
-    live.attrs.update(attrs)
-    stack.append(live)
-    t0 = time.perf_counter()
-    try:
-        with jax.profiler.TraceAnnotation(name):
-            yield live
-    finally:
-        dur = time.perf_counter() - t0
-        stack.pop()
-        done = Span(name=name, span_id=live.span_id,
-                    parent_id=live.parent_id, depth=live.depth,
-                    thread=threading.current_thread().name,
-                    start_s=t0, duration_s=dur, attrs=live.attrs)
-        with _completed_lock:
-            _completed.append(done)
-        default_registry().histogram("span." + name).observe(dur)
+    (see module docstring).  A class and not a generator: a span costs a
+    few microseconds, and the trainer opens three a step."""
+
+    __slots__ = ("_name", "_attrs", "_live", "_mark")
+
+    def __init__(self, name: str, **attrs):
+        self._name = name
+        self._attrs = attrs
+
+    def __enter__(self):
+        if _tracing():
+            self._live = _NOOP
+            self._mark = jax.named_scope(self._name)
+        else:
+            stack = _stack()
+            self._live = _open(self._name, self._attrs, _top(stack), stack)
+            self._mark = jax.profiler.TraceAnnotation(self._name)
+        self._mark.__enter__()
+        return self._live
+
+    def __exit__(self, *exc):
+        try:
+            self._mark.__exit__(*exc)
+        finally:
+            end(self._live)
+        return False
 
 
 def traced(name: Optional[str] = None):
@@ -168,8 +258,7 @@ def traced(name: Optional[str] = None):
 
 def current_span():
     """The innermost live span on this thread (None at top level)."""
-    stack = _stack()
-    return stack[-1] if stack else None
+    return _top(_stack())
 
 
 def completed_spans(limit: Optional[int] = None) -> List[Span]:
@@ -189,10 +278,138 @@ def reset_spans() -> None:
         _completed.clear()
 
 
+# -- the device side of the naming convention --------------------------------
+#
+# An HLO instruction's `op_name` is the name stack JAX traced it under:
+#   jit(step)/jvp(obs.model.attn)/bsd,dnh->bnsh/dot_general
+#   jit(step)/transpose(jvp(jvp()))/checkpoint/rematted_computation/obs.model.mlp/mul
+#   jit(step)/transpose(jvp(jvp()))/checkpoint/obs.model.attn/bsd,dnh->bnsh/dot_general
+#   jit(step)/obs.train.optimizer/add
+# The transforms write the phase (`transpose(...)`: backward;
+# `rematted_computation`: the forward run again under jax.checkpoint), the
+# `obs.model.*` / `obs.train.*` scopes the module.
+
+PHASES = ("fwd", "remat", "bwd", "optimizer", "other")
+MODULES = ("embed", "attn", "mlp", "loss_head", "other")
+_MODULE_SCOPE = re.compile(r"obs\.(?:model\.(embed|attn|mlp|loss_head)"
+                           r"|train\.(loss))\b")
+
+
+def phase_of(op_name: str) -> Tuple[str, str]:
+    """(phase, module) of an HLO instruction from its `op_name`: phase in
+    PHASES, module in MODULES.  `obs.train.loss` (log-softmax and nll over
+    the logits) counts to module `loss_head`.  A name that carries neither
+    a transform nor a scope (a parameter's name, a bare `reduce_sum`, no
+    name at all) is ("other", "other")."""
+    scope = _MODULE_SCOPE.search(op_name)
+    module = "other" if scope is None else scope.group(1) or "loss_head"
+    if "obs.train.optimizer" in op_name:
+        return "optimizer", module
+    if "rematted_computation" in op_name:
+        return "remat", module
+    if "transpose(" in op_name:
+        return "bwd", module
+    if "jvp(" in op_name or scope is not None:
+        return "fwd", module
+    return "other", module
+
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?(%[^\s(]+)\s*\(.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?(%[^\s=]+)\s*=\s*(.*)$")
+_HLO_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="((?:[^"\\]|\\.)*)"')
+_HLO_CALLS = re.compile(r"\bcalls=(%[^\s,)}]+)")
+_HLO_REF = re.compile(r"%[\w.\-]+")
+_HLO_TRAILER = re.compile(r",\s*(?:metadata|backend_config|"
+                          r"frontend_attributes)=")
+
+
+def scope_map(hlo_text: str) -> Dict[str, str]:
+    """{instruction name (as a profiler event's name begins: `%fusion.388`):
+    op_name} over an executable's `as_text()`, every computation's
+    instructions alike.
+
+    The compiler's own instructions carry no metadata: an async copy or
+    slice it put in to prefetch an operand, a fusion it built around them.
+    Such an instruction takes, in this order, the op_name of the ROOT of
+    the computation it calls, of the nearest instruction that uses its
+    result (data movement is for its consumer), of the nearest that makes
+    its operands; "" where none of these has one."""
+    names: Dict[str, str] = {}
+    roots: Dict[str, str] = {}        # computation -> its ROOT instruction
+    calls: Dict[str, str] = {}        # instruction -> computation it calls
+    operands: Dict[str, List[str]] = {}
+    users: Dict[str, List[str]] = {}
+    computation = None
+    for line in hlo_text.splitlines():
+        opened = _HLO_COMPUTATION.match(line)
+        if opened:
+            computation = opened.group(1)
+            continue
+        head = _HLO_INSTRUCTION.match(line)
+        if head is None:
+            continue
+        is_root, name, rest = head.groups()
+        if name in names:  # a name two computations share: keep the first
+            continue
+        found = _HLO_OP_NAME.search(rest)
+        names[name] = found.group(1) if found else ""
+        if is_root and computation is not None:
+            roots[computation] = name
+        called = _HLO_CALLS.search(rest)
+        if called:
+            calls[name] = called.group(1)
+        trailer = _HLO_TRAILER.search(rest)
+        body = rest[:trailer.start()] if trailer else rest
+        operands[name] = [r for r in _HLO_REF.findall(body)
+                          if r != name and r != calls.get(name)]
+        for ref in operands[name]:
+            users.setdefault(ref, []).append(name)
+
+    def nearest(start, edges):
+        seen, frontier = {start}, [start]
+        while frontier:
+            nxt = []
+            for node in frontier:
+                for other in edges.get(node, ()):
+                    if other in seen:
+                        continue
+                    if names.get(other):
+                        return names[other]
+                    seen.add(other)
+                    nxt.append(other)
+            frontier = nxt
+        return ""
+
+    out = dict(names)
+    for name, op_name in names.items():
+        if op_name:
+            continue
+        root = roots.get(calls.get(name))
+        out[name] = (names.get(root, "") or nearest(name, users)
+                     or nearest(name, operands))
+    return out
+
+
+def instruction_texts(hlo_text: str) -> Dict[str, str]:
+    """{instruction name: the instruction as `as_text()` prints it after the
+    `=`, less its trailing metadata / backend_config}: what a profiler
+    event's name, the same instruction printed with operand types, can be
+    held against before the two are joined by name."""
+    out: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        head = _HLO_INSTRUCTION.match(line)
+        if head is None or head.group(2) in out:
+            continue
+        rest = head.group(3)
+        trailer = _HLO_TRAILER.search(rest)
+        out[head.group(2)] = rest[:trailer.start()] if trailer else rest
+    return out
+
+
 def annotate(name: str):
     """Named region on the xprof timeline only (no clocks, no registry) —
     the raw `jax.profiler.TraceAnnotation`, kept for callers that want the
-    profiler mark without obs state (moved from utils/profiling.py)."""
+    profiler mark without obs state."""
     return jax.profiler.TraceAnnotation(name)
 
 
@@ -206,8 +423,7 @@ class StepTimer:
             state, metrics = step(state, batch)
             t.watch(state)
 
-    Moved here from utils/profiling.py (shim kept there); each completed
-    step also feeds the registry histogram `span.step_timer` so step times
+    Each completed step also feeds the registry histogram `span.step_timer` so step times
     show up in obs exports alongside explicit spans.
     """
 

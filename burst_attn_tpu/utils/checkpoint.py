@@ -47,14 +47,14 @@ class Checkpointer:
     def restore(self, step: int, cfg, tcfg, mesh: Mesh) -> Tuple[Any, int]:
         """Restore the state saved at `step`, placed per the model's
         PartitionSpec tree on `mesh` (no host round trip of full arrays)."""
-        from ..models.train import _optimizer, _state_specs, init_params
+        from ..models.train import _optimizer, state_specs, init_params
 
         def shapes():
             params = init_params(jax.random.PRNGKey(0), cfg)
             return params, _optimizer(tcfg).init(params)
 
         params_shape, opt_shape = jax.eval_shape(shapes)
-        pspecs, opt_specs = _state_specs(cfg, tcfg, params_shape)
+        pspecs, opt_specs = state_specs(cfg, tcfg, params_shape)
 
         def as_target(shape_leaf, spec):
             return jax.ShapeDtypeStruct(

@@ -1,17 +1,13 @@
-"""XLA profiler capture + deprecation shims for the moved timing helpers.
+"""XLA profiler capture.
 
 The reference has no profiling subsystem beyond its benchmark harness
 (SURVEY.md §5); here the device side lives in this module and the host
-side in the obs subsystem:
+side in the obs subsystem (`obs.span`, `obs.StepTimer`, `obs.annotate`):
 
   * `trace(log_dir)` — XLA profiler capture (XProf/TensorBoard, incl. the
     collective-permute/compute overlap of the ring scan).  Device
-    timelines are profiler state, not obs registry state, so it stays
+    timelines are profiler state, not obs registry state, so it lives
     here.
-  * `StepTimer` / `annotate` — MOVED to `burst_attn_tpu.obs.spans` (they
-    are host-side timing, which is obs's job; StepTimer now also feeds the
-    registry histogram `span.step_timer`).  Re-exported here so existing
-    imports keep working; new code should import from `burst_attn_tpu.obs`.
 
     with trace("/tmp/profile"):
         step(state, batch)          # -> /tmp/profile/plugins/profile/...
@@ -21,10 +17,7 @@ import contextlib
 
 import jax
 
-# deprecation shims — canonical home is obs.spans (see module docstring)
-from ..obs.spans import StepTimer, annotate  # noqa: F401
-
-__all__ = ["trace", "StepTimer", "annotate"]
+__all__ = ["trace"]
 
 
 @contextlib.contextmanager

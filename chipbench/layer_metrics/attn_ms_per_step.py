@@ -1,0 +1,11 @@
+"""model layer: device self time per step of the attention sublayers
+(`obs.model.attn`: norm, projections, rope, the ring with its flash kernels,
+output projection), every phase. Flash kernels included, mean over chips and
+traced steps. The op_name comes from the step's executable
+(`program_readings.device_ms`); None where the program has no scopes."""
+
+from chipbench import program_readings as p
+
+
+def read(reading):
+    return p.scope_ms(reading, module="attn")

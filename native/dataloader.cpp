@@ -288,7 +288,13 @@ int64_t dl_windows_per_epoch(DLHandle* h) { return h ? h->windows_per_epoch : -1
 
 void dl_close(DLHandle* h) {
   if (!h) return;
-  h->stop.store(true);
+  {
+    // under the workers' mutex: a worker that has just found its predicate
+    // false and not yet blocked would otherwise miss both the flag and the
+    // notify below, and the join would wait for ever
+    std::lock_guard<std::mutex> lk(h->mu);
+    h->stop.store(true);
+  }
   h->cv_free.notify_all();
   h->cv_full.notify_all();
   for (auto& t : h->workers) t.join();

@@ -97,6 +97,26 @@ def test_shuffle_is_permutation(tmp_path):
     assert firsts != sorted(firsts), "shuffle did nothing"
 
 
+def test_close_right_after_next_comes_back(token_file):
+    """`dl_close` sets its stop flag under the workers' mutex: without it a
+    worker between its predicate and its wait missed the wake-up and the
+    join never returned (under contention: tier-1 once hung here)."""
+    import threading
+
+    def churn():
+        for _ in range(150):
+            with DataLoader(token_file, batch=2, seq_len=32,
+                            num_threads=1) as dl:
+                dl.next()
+
+    threads = [threading.Thread(target=churn, daemon=True) for _ in range(8)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(timeout=60)
+    assert not any(th.is_alive() for th in threads)
+
+
 def test_windows_per_epoch(token_file):
     with DataLoader(token_file, batch=1, seq_len=99, num_shards=4) as dl:
         assert dl.windows_per_epoch == (100_000 // 100) // 4

@@ -396,15 +396,6 @@ def test_steptimer_skip_first_honored_with_multiple_steps():
     assert s["steps"] == 2 and s["mean_s"] == 2.0 and s["max_s"] == 3.0
 
 
-def test_profiling_shims_still_import():
-    from burst_attn_tpu.utils import profiling
-
-    assert profiling.StepTimer is obs.StepTimer
-    assert profiling.annotate is obs.annotate
-    with profiling.annotate("shim"):  # still a usable context manager
-        pass
-
-
 # ---------------------------------------------------------------------------
 # subsystem instrumentation: serve engine + ring dispatch
 

@@ -186,7 +186,7 @@ def test_train_step_at_the_chip_smoke_size(topo, on_chip):
         return params, opt.init(params)
 
     params, opt_state = jax.eval_shape(init, jax.random.PRNGKey(0))
-    specs = train._state_specs(cfg, tcfg, params)
+    specs = train.state_specs(cfg, tcfg, params)
 
     def placed(shapes, specs):
         return jax.tree.map(

@@ -7,11 +7,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from burst_attn_tpu import obs
 from burst_attn_tpu.utils import multihost, profiling
 
 
 def test_step_timer():
-    t = profiling.StepTimer()
+    t = obs.StepTimer()
     x = jnp.ones((256, 256))
     f = jax.jit(lambda x: x @ x)
     for _ in range(3):
@@ -23,7 +24,7 @@ def test_step_timer():
 
 
 def test_step_timer_requires_watch():
-    t = profiling.StepTimer()
+    t = obs.StepTimer()
     with pytest.raises(RuntimeError, match="watch"):
         with t:
             pass
@@ -32,7 +33,7 @@ def test_step_timer_requires_watch():
 def test_trace_writes_profile(tmp_path):
     d = str(tmp_path / "prof")
     with profiling.trace(d):
-        with profiling.annotate("matmul"):
+        with obs.annotate("matmul"):
             jnp.ones((64, 64)) @ jnp.ones((64, 64))
     found = [f for _, _, fs in os.walk(d) for f in fs]
     assert found, "no profile artifacts written"
