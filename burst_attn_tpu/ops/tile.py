@@ -58,11 +58,76 @@ def _with_segments(mask, segments):
             (q_seg[:, None, :, None] == kv_seg[:, None, None, :]))
 
 
+def _rows(x, rng, axis=2):
+    """Static row range [lo, hi) of x along `axis`; None = all of it."""
+    if x is None or rng is None:
+        return x
+    return jax.lax.slice_in_dim(x, rng[0], rng[1], axis=axis)
+
+
+def _seg_rows(segments, q_range, kv_range):
+    if segments is None:
+        return None
+    return _rows(segments[0], q_range, 1), _rows(segments[1], kv_range, 1)
+
+
+def fwd_on_ranges(fn, q, k, v, m, lse, acc, scale, spec, *, segments=None,
+                  q_range=None, kv_range=None):
+    """The SLICED form of a forward round that covers only the q rows
+    `q_range` and the kv columns `kv_range` (static (lo, hi) pairs; `spec`
+    is local to them): slice, run `fn(q, k, v, m, lse, acc, scale, spec,
+    segments=)` on the slices, write the updated rows back into the state.
+    What every tile without an in-place sub-range form does (this oracle;
+    pallas_flash.flash_fwd where its grid cannot take the ranges), and the
+    definition of what the in-place form must return."""
+    out = fn(_rows(q, q_range), _rows(k, kv_range), _rows(v, kv_range),
+             _rows(m, q_range), _rows(lse, q_range), _rows(acc, q_range),
+             scale, spec, segments=_seg_rows(segments, q_range, kv_range))
+    if q_range is None:
+        return out
+    return tuple(
+        jax.lax.dynamic_update_slice_in_dim(full, part, q_range[0], axis=2)
+        for full, part in zip((m, lse, acc), out))
+
+
+def _pad_rows(g, rng, s):
+    """Contribution of the rows `rng` laid into s zero rows (axis 2)."""
+    if rng is None:
+        return g
+    pad = [(0, 0)] * g.ndim
+    pad[2] = (rng[0], s - rng[1])
+    return jnp.pad(g, pad)
+
+
+def bwd_on_ranges(fn, do, q, k, v, delta, lse, scale, spec, *, segments=None,
+                  q_range=None, kv_range=None, carry=None):
+    """The SLICED form of a backward round (see fwd_on_ranges): `fn(do, q,
+    k, v, delta, lse, scale, spec, segments=)` on the slices, each gradient
+    padded with zeros to its full length, and dk, dv added to `carry` =
+    (dk, dv) where one is given.  Returns (dq, dk, dv), full-size float32."""
+    dq, dk, dv = fn(_rows(do, q_range), _rows(q, q_range), _rows(k, kv_range),
+                    _rows(v, kv_range), _rows(delta, q_range),
+                    _rows(lse, q_range), scale, spec,
+                    segments=_seg_rows(segments, q_range, kv_range))
+    dq = _pad_rows(dq, q_range, q.shape[2])
+    dk = _pad_rows(dk, kv_range, k.shape[2])
+    dv = _pad_rows(dv, kv_range, k.shape[2])
+    if carry is not None:
+        dk, dv = carry[0] + dk, carry[1] + dv
+    return dq, dk, dv
+
+
 def tile_fwd(q, k, v, m, lse, acc, scale, spec: MaskSpec, window=None,
-             segments=None):
+             segments=None, q_range=None, kv_range=None):
     """One online-softmax round; returns updated (m, lse, acc).
     `window` (static): sliding-window lower bound, see masks.dense_mask.
-    `segments`: packed-sequence ids, see _with_segments."""
+    `segments`: packed-sequence ids, see _with_segments.
+    `q_range` / `kv_range`: the round covers only those rows / columns of
+    the full arrays (fwd_on_ranges); rows outside keep their state."""
+    if q_range is not None or kv_range is not None:
+        return fwd_on_ranges(
+            partial(tile_fwd, window=window), q, k, v, m, lse, acc, scale,
+            spec, segments=segments, q_range=q_range, kv_range=kv_range)
     s_q, s_kv = q.shape[2], k.shape[2]
     k = _expand_kv(k, q.shape[1])
     v = _expand_kv(v, q.shape[1])
@@ -95,14 +160,21 @@ def finalize(m, lse, acc, dtype):
 
 
 def tile_bwd(do, q, k, v, delta, lse, scale, spec: MaskSpec, window=None,
-             segments=None):
+             segments=None, q_range=None, kv_range=None, carry=None):
     """One backward ring round; returns this round's (dq, dk, dv) in float32.
+    With `carry` = (dk, dv) the last two are the carry plus this round's;
+    `q_range` / `kv_range` as in tile_fwd (bwd_on_ranges).
 
     delta = sum(o * do, axis=-1) [B, N, S] float32 (precomputed once — the
     reference's optimize_bwd_comm quantity, burst_attn_interface.py:269-278).
     lse is the FINAL log-sum-exp of the query rows, so p = exp(s - lse) is the
     true softmax probability; masked entries are forced to zero.
     """
+    if q_range is not None or kv_range is not None or carry is not None:
+        return bwd_on_ranges(
+            partial(tile_bwd, window=window), do, q, k, v, delta, lse, scale,
+            spec, segments=segments, q_range=q_range, kv_range=kv_range,
+            carry=carry)
     n_q = q.shape[1]
     n_kv = k.shape[1]
     s_q, s_kv = q.shape[2], k.shape[2]

@@ -70,6 +70,11 @@ _M_FALLBACK = obs.counter(
     "burst.fused_fallback", "fused_ring dispatches declined, by reason")
 _M_ROUNDS = obs.counter(
     "burst.ring_rounds", "scheduled ring rounds (incl. the self round)")
+_M_INPLACE = obs.counter(
+    "burst.inplace_rounds",
+    "scheduled scan-ring rounds after the self round, by pass and by where "
+    "the round folds into its carry: path=kernel (the tile writes into the "
+    "carry in place) or path=xla (slice / run / add around the tile)")
 _M_HOPS = obs.counter(
     "burst.ring_hops", "scheduled KV ring hops, by mesh axis role")
 _M_WIRE = obs.counter(
@@ -243,7 +248,13 @@ def _tile_backend(cfg) -> str:
 
 
 def _tile_fwd(cfg, q, k, v, m, lse, acc, scale, spec, triangular=False,
-              segments=None):
+              segments=None, q_range=None, kv_range=None):
+    """One forward round folded into the carried (m, lse, acc).  `q_range` /
+    `kv_range`: static (lo, hi) rows of the FULL arrays the round covers
+    (`spec` is local to them); the state's other rows come back untouched.
+    Both tiles take the same call; which form it lowers to (the kernel on
+    the carry in place, or slice / run / write back) is the tile's own
+    static decision (_round_in_kernel reads the same one)."""
     if _tile_backend(cfg) == "pallas":
         from ..ops import pallas_flash
 
@@ -253,6 +264,7 @@ def _tile_fwd(cfg, q, k, v, m, lse, acc, scale, spec, triangular=False,
             q, k, v, m, lse, acc, scale, spec,
             block_q=bq, block_kv=bkv, triangular=triangular,
             window=cfg.window, segments=segments,
+            q_range=q_range, kv_range=kv_range,
         )
     if m is None:
         # jnp oracle has no None-carry fast path; materialize the empty
@@ -260,11 +272,16 @@ def _tile_fwd(cfg, q, k, v, m, lse, acc, scale, spec, triangular=False,
         b, n, s, d = q.shape
         m, lse, acc = jnp_tile.init_state(b, n, s, d)
     return jnp_tile.tile_fwd(q, k, v, m, lse, acc, scale, spec,
-                             window=cfg.window, segments=segments)
+                             window=cfg.window, segments=segments,
+                             q_range=q_range, kv_range=kv_range)
 
 
 def _tile_bwd(cfg, do, q, k, v, delta, lse, scale, spec, triangular=False,
-              segments=None):
+              segments=None, q_range=None, kv_range=None, carry=None):
+    """One backward round: (dq, dk, dv) float32, full-size.  dq is this
+    round's alone (it rides a ring: the caller adds it to the arriving
+    partial); dk, dv are `carry` = (dk, dv) plus this round's where a carry
+    is given.  Ranges as in _tile_fwd."""
     if _tile_backend(cfg) == "pallas":
         from ..ops import pallas_flash
 
@@ -273,9 +290,54 @@ def _tile_bwd(cfg, do, q, k, v, delta, lse, scale, spec, triangular=False,
         return pallas_flash.flash_bwd(
             do, q, k, v, delta, lse, scale, spec, block_q=bq, block_kv=bkv,
             triangular=triangular, window=cfg.window, segments=segments,
+            q_range=q_range, kv_range=kv_range, carry=carry,
         )
     return jnp_tile.tile_bwd(do, q, k, v, delta, lse, scale, spec,
-                             window=cfg.window, segments=segments)
+                             window=cfg.window, segments=segments,
+                             q_range=q_range, kv_range=kv_range, carry=carry)
+
+
+def _round_tiles(cfg, s, s_kv):
+    """The tile calls a round AFTER the self round can make, as static
+    (triangular, q_range, kv_range) triples in the forward's roles (the
+    backward swaps nothing: its q side is the rotating one, its ranges the
+    same rows).  The zigzag case split picks one of two at run time; every
+    other schedule has one shape of round."""
+    if cfg.causal and cfg.case_split and s_kv == s:
+        if cfg.layout == "zigzag":
+            half = s // 2
+            return [(False, None, (0, half)), (False, (half, s), None)]
+        if cfg.layout == "striped":
+            return [(True, None, None)]
+    band = cfg.layout == "contig" and cfg.causal and cfg.window is not None
+    return [(band, None, None)]
+
+
+def _round_in_kernel(cfg, pass_, q_shape, k_shape) -> bool:
+    """Whether a round after the self round folds into its carry INSIDE the
+    kernel (the forward's state, the backward's dk / dv) on these per-shard
+    shapes, or takes the sliced / added form in XLA: the tile entry's own
+    static gate (pallas_flash.fwd_covers_ranges / bwd_folds_carry), asked
+    for every tile call the round can make."""
+    if _tile_backend(cfg) != "pallas":
+        return False
+    from ..ops import pallas_flash
+
+    (_, n, s, d), (_, n_kv, s_kv, _) = q_shape, k_shape
+    rb = cfg.resolved_blocks()
+    if pass_ == "fwd":
+        return all(
+            pallas_flash.fwd_covers_ranges(
+                s, s_kv, q_rng, kv_rng, block_q=rb.block_q,
+                block_kv=rb.block_kv, triangular=tri)
+            for tri, q_rng, kv_rng in _round_tiles(cfg, s, s_kv))
+    return all(
+        pallas_flash.bwd_folds_carry(
+            n, n_kv, s, s_kv, d, q_rng, kv_rng, block_q=rb.block_q_bwd,
+            block_kv=rb.block_kv_bwd,
+            # the forward's band grid has no backward twin to ask for
+            triangular=tri and cfg.window is None, window=cfg.window)
+        for tri, q_rng, kv_rng in _round_tiles(cfg, s, s_kv))
 
 
 def _sizes(cfg):
@@ -363,44 +425,28 @@ def _fwd_impl(q, k, v, cfg: BurstConfig, seg=None, collect=False):
         segs = None if seg is None else (seg, kvseg_c)
         s_kv = k_c.shape[2]
         if cfg.causal and cfg.case_split and cfg.layout == "zigzag" and s_kv == s:
-            # 3-way structural split (reference burst_attn_interface.py:221-235)
+            # structural split (reference burst_attn_interface.py:221-235).
+            # Its third case, the own partition, is round 0 alone (peeled
+            # below: a ring visits each partition once), so a round here is
+            # one of two.  Both hand the tile the FULL arrays and the rows
+            # the round covers (_round_tiles): no slice of k / v / the
+            # state before the kernel, no update-slice after it
             half = s // 2
-
-            def eq_case(st):
-                # own partition: plain causal on the local layout
-                spec = round_spec(part_me, part_me, s, s_kv, True, "zigzag")
-                return _tile_fwd(cfg, q, k_c, v_c, *st, scale, spec,
-                                 triangular=True, segments=segs)
+            (_, _, kv_first), (_, q_second, _) = _round_tiles(cfg, s, s_kv)
 
             def past_case(st):
                 # kv's first half entirely in the local past: dense half-kv
-                return _tile_fwd(
-                    cfg, q, k_c[:, :, :half], v_c[:, :, :half], *st, scale,
-                    full_spec(s, half),
-                    segments=None if seg is None else (seg, kvseg_c[:, :half]),
-                )
+                return _tile_fwd(cfg, q, k_c, v_c, *st, scale,
+                                 full_spec(s, half), segments=segs,
+                                 kv_range=kv_first)
 
             def future_case(st):
                 # only the local q's second half attends (to all of kv)
-                m, lse, acc = st
-                m2, lse2, acc2 = _tile_fwd(
-                    cfg, q[:, :, half:], k_c, v_c,
-                    m[:, :, half:], lse[:, :, half:], acc[:, :, half:],
-                    scale, full_spec(s - half, s_kv),
-                    segments=None if seg is None else (seg[:, half:], kvseg_c),
-                )
-                # write the updated half back in place rather than
-                # rebuilding the full [B,N,S,D] f32 state via concatenate —
-                # one fewer full-state HBM copy per future round
-                upd = lambda a, bpart: lax.dynamic_update_slice_in_dim(
-                    a, bpart, half, axis=2)
-                return upd(m, m2), upd(lse, lse2), upd(acc, acc2)
+                return _tile_fwd(cfg, q, k_c, v_c, *st, scale,
+                                 full_spec(s - half, s_kv), segments=segs,
+                                 q_range=q_second)
 
-            return lax.cond(
-                kv_part == part_me, eq_case,
-                lambda st: lax.cond(kv_part < part_me, past_case, future_case, st),
-                st,
-            )
+            return lax.cond(kv_part < part_me, past_case, future_case, st)
         if cfg.causal and cfg.case_split and cfg.layout == "striped" and s_kv == s:
             # every striped round is full-window causal (offset 0 or -1):
             # the triangular grid applies round-independently
@@ -622,12 +668,18 @@ def _bwd_impl(cfg: BurstConfig, q, k, v, o, lse, do, seg=None):
             g8, gsc = ppermute_next((g8, gsc), axis)
             return wire_dequantize(g8, gsc, jnp.float32)
 
-    dk = jnp.zeros(k.shape, jnp.float32)
-    dv = jnp.zeros(v.shape, jnp.float32)
+    dkv = None  # [dk, dv]: resident, folded by the tile round after round
     dq_intra = jnp.zeros(q.shape, jnp.float32)
     dq_inter = jnp.zeros(q.shape, jnp.float32)
 
-    def compute(pay, r):
+    def compute(pay, r, dkv, own=False):
+        """One round: (dq of this round, dk, dv with this round folded in).
+        dkv = (dk, dv) of the rounds before, resident like k and v, or None
+        ahead of the first.  The tile adds into it (in the kernel where
+        _round_in_kernel says so); dq is NOT carried this way: it rides the
+        ring, and a kernel that took the arriving partial as its carry
+        would wait for the hop that today hides under it.  `own` (static):
+        round 0, the one round whose payload is this device's own."""
         q_part = partition_at_round(r, cfg.intra_axis, cfg.inter_axis)
         # roles flip vs forward: the rotating payload is the query side,
         # local k/v are resident.
@@ -649,64 +701,58 @@ def _bwd_impl(cfg: BurstConfig, q, k, v, o, lse, do, seg=None):
         else:
             delta_r = jnp.sum(first.astype(jnp.float32) * do_r.astype(jnp.float32), axis=-1)
         if cfg.causal and cfg.case_split and cfg.layout == "zigzag":
-            # 3-way structural split, bwd roles (reference :303-367)
+            # structural split, bwd roles (reference :303-367): the own
+            # partition in round 0 and there alone, one of two half-shard
+            # cases in every other round.  Those as in the forward: full
+            # arrays plus the rows the round covers, dk / dv written into
+            # the carry's own blocks
             half = s // 2
-
-            def eq_case(_):
+            (_, _, kv_first), (_, q_second, _) = _round_tiles(cfg, s, s)
+            if own:
                 spec = round_spec(part_me, part_me, s, s, True, "zigzag")
                 return _tile_bwd(cfg, do_r, q_r, k, v, delta_r, lse_r, scale,
-                                 spec, triangular=True, segments=segs)
+                                 spec, triangular=True, segments=segs,
+                                 carry=dkv)
 
-            def kv_past_case(_):
+            def kv_past_case(dkv):
                 # resident kv precedes the rotated q side: only kv's first
-                # half participates -> dense tile, zero-padded dk/dv
-                dq_c, dk_h, dv_h = _tile_bwd(
-                    cfg, do_r, q_r, k[:, :, :half], v[:, :, :half],
-                    delta_r, lse_r, scale, full_spec(s, half),
-                    segments=None if seg is None else (qseg_r, seg[:, :half]),
-                )
-                pad = lambda g: jnp.concatenate(
-                    [g, jnp.zeros((b,) + g.shape[1:2] + (s - half, d), g.dtype)], axis=2)
-                return dq_c, pad(dk_h), pad(dv_h)
+                # half participates -> dense tile over those columns
+                return _tile_bwd(cfg, do_r, q_r, k, v, delta_r, lse_r, scale,
+                                 full_spec(s, half), segments=segs,
+                                 kv_range=kv_first, carry=dkv)
 
-            def q_future_case(_):
+            def q_future_case(dkv):
                 # only the rotated q side's second half attends
-                dq_h, dk_c, dv_c = _tile_bwd(
-                    cfg, do_r[:, :, half:], q_r[:, :, half:], k, v,
-                    delta_r[:, :, half:], lse_r[:, :, half:],
-                    scale, full_spec(s - half, s),
-                    segments=None if seg is None else (qseg_r[:, half:], seg),
-                )
-                dq_c = jnp.concatenate(
-                    [jnp.zeros((b, n, half, d), dq_h.dtype), dq_h], axis=2)
-                return dq_c, dk_c, dv_c
+                return _tile_bwd(cfg, do_r, q_r, k, v, delta_r, lse_r, scale,
+                                 full_spec(s - half, s), segments=segs,
+                                 q_range=q_second, carry=dkv)
 
-            return lax.cond(
-                q_part == part_me, eq_case,
-                lambda _: lax.cond(part_me < q_part, kv_past_case, q_future_case, None),
-                None,
-            )
+            return lax.cond(part_me < q_part, kv_past_case, q_future_case,
+                            dkv)
         if cfg.causal and cfg.case_split and cfg.layout == "striped":
             spec = round_spec(q_part, part_me, s, s, True, "striped")
             return _tile_bwd(cfg, do_r, q_r, k, v, delta_r, lse_r, scale, spec,
-                             triangular=True, segments=segs)
+                             triangular=True, segments=segs, carry=dkv)
         # cross-attention (s_kv_local != s): the resident kv side's length
         # comes from k, not from the rotating q payload
         spec = round_spec(q_part, part_me, s, k.shape[2], cfg.causal,
                           cfg.layout, window=cfg.window)
         if cfg.layout == "contig" and cfg.causal:
-            # dead-round skip, bwd roles (fwd comment above): contribute
-            # exact zeros without touching the kernels
+            # dead-round skip, bwd roles (fwd comment above): a zero dq and
+            # the carry as it came, without touching the kernels
+            def dead(dkv):
+                if dkv is None:
+                    dkv = (jnp.zeros(k.shape, jnp.float32),
+                           jnp.zeros(v.shape, jnp.float32))
+                return (jnp.zeros((b, n, s, d), jnp.float32), *dkv)
+
             return lax.cond(
                 spec_live(spec, cfg.window),
-                lambda _: _tile_bwd(cfg, do_r, q_r, k, v, delta_r, lse_r,
-                                    scale, spec, segments=segs),
-                lambda _: (jnp.zeros((b, n, s, d), jnp.float32),
-                           jnp.zeros(k.shape, jnp.float32),
-                           jnp.zeros(v.shape, jnp.float32)),
-                None)
+                lambda dkv: _tile_bwd(cfg, do_r, q_r, k, v, delta_r, lse_r,
+                                      scale, spec, segments=segs, carry=dkv),
+                dead, dkv)
         return _tile_bwd(cfg, do_r, q_r, k, v, delta_r, lse_r, scale, spec,
-                         segments=segs)
+                         segments=segs, carry=dkv)
 
     # Static round truncation, bwd roles (fwd comment in _fwd_impl): with the
     # q side rotating, round r's offset is delta = -r*s (dead causally) for
@@ -733,13 +779,11 @@ def _bwd_impl(cfg: BurstConfig, q, k, v, o, lse, do, seg=None):
             dq_inter = dq_hop(dq_inter + dq_intra, cfg.inter_axis)
             dq_intra = jnp.zeros_like(dq_intra)
         # ---- first round of the cycle (r = c*I): no dq rotation ----
-        dqc, dkc, dvc = compute(payload, jnp.int32(c * n_intra))
+        dqc, *dkv = compute(payload, jnp.int32(c * n_intra), dkv, own=c == 0)
         if truncated:
             dq_home = dqc
         else:
             dq_intra = dq_intra + dqc
-        dk = dk + dkc
-        dv = dv + dvc
         if r_live > 1:
             # start == 1 without truncation; the jump is a single hop then.
             # dq_intra is still all-zero at the jump when truncated
@@ -749,24 +793,24 @@ def _bwd_impl(cfg: BurstConfig, q, k, v, o, lse, do, seg=None):
             if n_intra - 1 > start:
 
                 def body(carry, s_idx, c=c):
-                    pay, dq_i, dk_c, dv_c = carry
+                    pay, dq_i, dkv_c = carry
                     pay_next = ppermute_next(pay, cfg.intra_axis)
                     # dq leaves with the payload it accumulated for; the
                     # arriving dq belongs to the payload we hold this round.
                     dq_rot = dq_hop(dq_i, cfg.intra_axis)
-                    dqc, dkc, dvc = compute(pay, c * n_intra + s_idx)
-                    return (pay_next, dq_rot + dqc, dk_c + dkc, dv_c + dvc), None
+                    dqc, *dkv_c = compute(pay, c * n_intra + s_idx, dkv_c)
+                    # the one full-size float32 add left in a round
+                    return (pay_next, dq_rot + dqc, dkv_c), None
 
-                (payload, dq_intra, dk, dv), _ = lax.scan(
-                    body, (payload, dq_intra, dk, dv),
+                (payload, dq_intra, dkv), _ = lax.scan(
+                    body, (payload, dq_intra, dkv),
                     jnp.arange(start, n_intra - 1)
                 )
             # ---- last round of the cycle: rotate dq but not the payload ----
             dq_rot = dq_hop(dq_intra, cfg.intra_axis)
-            dqc, dkc, dvc = compute(payload, jnp.int32(c * n_intra + n_intra - 1))
+            dqc, *dkv = compute(payload,
+                                jnp.int32(c * n_intra + n_intra - 1), dkv)
             dq_intra = dq_rot + dqc
-            dk = dk + dkc
-            dv = dv + dvc
         if c < n_inter - 1:
             payload = pay_base = pay_base_next
 
@@ -780,6 +824,7 @@ def _bwd_impl(cfg: BurstConfig, q, k, v, o, lse, do, seg=None):
         dq = dq_hop(dq, cfg.intra_axis)
     if dq_home is not None:
         dq = dq + dq_home
+    dk, dv = dkv
     return dq, dk, dv
 
 
@@ -982,7 +1027,7 @@ def _note_dispatch(cfg: BurstConfig, mesh, q_shape, k_shape, has_seg: bool,
                max(1, q_shape[2] // world), q_shape[3])
     k_local = (max(1, k_shape[0] // b_div), max(1, k_shape[1] // h_div),
                max(1, k_shape[2] // world), k_shape[3])
-    path, reason = "scan", None
+    path, reason, reason_bwd = "scan", None, None
     if cfg.backend == "fused_ring":
         reason = fused_ring.supported(cfg, q_local, k_local, has_seg,
                                       world=n_intra, n_inter=n_inter,
@@ -1006,6 +1051,15 @@ def _note_dispatch(cfg: BurstConfig, mesh, q_shape, k_shape, has_seg: bool,
     rounds, intra_hops, inter_hops = ring_round_counts(n_inter, n_intra,
                                                        r_live)
     _M_ROUNDS.inc(rounds)
+    # of the scan ring's rounds after the self round, which fold into their
+    # carry inside the kernel: the tile entry's own static gate, per pass
+    scans = {"fwd": path == "scan",
+             "bwd": cfg.backend != "fused_ring" or reason_bwd is not None}
+    for pass_ in ("fwd", "bwd"):
+        if rounds > 1 and scans[pass_]:
+            in_kernel = _round_in_kernel(cfg, pass_, q_local, k_local)
+            _M_INPLACE.inc(rounds - 1, **{
+                "pass": pass_, "path": "kernel" if in_kernel else "xla"})
     if intra_hops:
         _M_HOPS.inc(intra_hops, axis="intra")
     if inter_hops:

@@ -1,23 +1,29 @@
-"""Fused backward kernel vs split kernels — REAL TPU only.
+"""Fused backward kernel vs split kernels.
 
 The fused dq+dk+dv kernel accumulates dq in place through
 input_output_aliasing (ops/pallas_flash.py:_bwd_fused_kernel); its
 correctness depends on Mosaic pipeline flush/fetch ordering that interpret
-mode does not model, so this test self-skips off-TPU.  Shapes cover every
-mask regime the ring produces (zigzag three-way split, striped shift, GQA,
-rectangular KV) — the on-chip analogue of the reference's all-config sweep
-(reference test/test_burst.py:239-247).
+mode does not model, so the `on_tpu` tests self-skip off-TPU
+(`BURST_TESTS_TPU=1 python -m pytest tests/test_fused_bwd.py` on the chip).
+Shapes cover every mask regime the ring produces (zigzag three-way split,
+striped shift, GQA, rectangular KV) — the on-chip analogue of the
+reference's all-config sweep (reference test/test_burst.py:239-247).
+
+The tile CONTRACT of a ring round that folds into its carry (`carry`,
+`q_range`, `kv_range` of flash_bwd / tile_bwd) is arithmetic, not timing,
+and is tested here in interpret mode on the CPU.
 """
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from burst_attn_tpu.ops import pallas_flash as pf
 from burst_attn_tpu.ops import tile as T
-from burst_attn_tpu.ops.masks import round_spec
+from burst_attn_tpu.ops.masks import full_spec, round_spec
 
-pytestmark = pytest.mark.skipif(
+on_tpu = pytest.mark.skipif(
     jax.default_backend() != "tpu", reason="fused bwd kernel is TPU-only"
 )
 
@@ -34,6 +40,7 @@ CASES = [
 ]
 
 
+@on_tpu
 @pytest.mark.parametrize("case", CASES, ids=[c[0] for c in CASES])
 def test_fused_matches_split(case):
     _, b, n, nkv, sq, skv, causal, layout, qp, kp = case
@@ -62,6 +69,7 @@ def test_fused_matches_split(case):
         assert err < 1e-3, f"{name} max abs err {err}"
 
 
+@on_tpu
 @pytest.mark.parametrize("block_q,block_kv", [(512, 512), (256, 512)])
 def test_triangular_matches_rect_on_tpu(block_q, block_kv):
     """Wrapped-diagonal causal grids (fwd triangular + bwd tri kernel) vs the
@@ -99,6 +107,7 @@ def test_triangular_matches_rect_on_tpu(block_q, block_kv):
         assert err < 1e-3, f"bwd {name} max abs err {err}"
 
 
+@on_tpu
 def test_segments_on_tpu():
     """Packed-sequence masking at production tile sizes, on-chip: fp32
     oracle comparison of flash_attention(segment_ids=...) fwd + grads.  The
@@ -139,6 +148,7 @@ def test_segments_on_tpu():
         assert err < 5e-2, f"{name} max abs err {err}"
 
 
+@on_tpu
 @pytest.mark.parametrize("window", [512, 1024, 2048, 3000])
 def test_fused_banded_window_bwd_matches_split(window):
     """The window-banded fused sweep (grid dim 3 = nbq*group instead of
@@ -177,6 +187,7 @@ def test_fused_banded_window_bwd_matches_split(window):
         assert err < (1e-3 if gated_in else 1e-9), f"{name} max abs err {err}"
 
 
+@on_tpu
 def test_fused_segments_bwd_matches_split():
     """Packed-segment masking through the FUSED kernel (seg tiles ride the
     masked path) vs the split kernels, production tiles + GQA."""
@@ -210,6 +221,7 @@ def test_fused_segments_bwd_matches_split():
         assert err < 1e-3, f"{name} max abs err {err}"
 
 
+@on_tpu
 def test_tri_segments_bwd_matches_split():
     """Packed segments through the WRAPPED-DIAGONAL bwd kernel (seg only
     narrows the fast path, same as the fwd tri grid) vs split kernels."""
@@ -244,6 +256,7 @@ def test_tri_segments_bwd_matches_split():
         assert err < 1e-3, f"{name} max abs err {err}"
 
 
+@on_tpu
 def test_tall_q_and_empty_carry_on_tpu():
     """Round-4 fwd paths on real Mosaic: the tall-q tri grid (block_q =
     r*block_kv) and the statically-empty carry (no state inputs at all)
@@ -286,6 +299,7 @@ def test_tall_q_and_empty_carry_on_tpu():
         assert err < 1e-4, f"empty-carry {name} max abs err {err}"
 
 
+@on_tpu
 def test_bwd_loop_sweep_on_tpu():
     """The tri backward's fori_loop sweep on real Mosaic: its dynamic-offset
     scratch stores (dv_scr/dk_scr at traced sub-block rows) have no
@@ -317,3 +331,228 @@ def test_bwd_loop_sweep_on_tpu():
     for name, a, b_ in zip(("dq", "dk", "dv"), base, loop):
         err = float(jnp.max(jnp.abs(a - b_)))
         assert err < 1e-3, f"loop {name} max abs err {err}"
+
+
+# ---------------------------------------------------------------------------
+# a ring round that folds into its carry: the tile contract, on the CPU
+
+
+def _round_inputs(n, nkv, s=64, d=32, b=2, seed=31):
+    """q-side and kv-side arrays of one half-shard round, a float32 (dk, dv)
+    carry of rounds before, and packed-segment ids for both sides."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    f32 = jnp.float32
+    q = jax.random.normal(ks[0], (b, n, s, d), f32)
+    k = jax.random.normal(ks[1], (b, nkv, s, d), f32)
+    v = jax.random.normal(ks[2], (b, nkv, s, d), f32)
+    do = jax.random.normal(ks[3], (b, n, s, d), f32)
+    # any row statistics do: the round's arithmetic is what is compared
+    lse = 3.0 + jax.random.normal(ks[4], (b, n, s), f32)
+    delta = jax.random.normal(ks[5], (b, n, s), f32)
+    carry = (jax.random.normal(ks[6], (b, nkv, s, d), f32),
+             jax.random.normal(ks[7], (b, nkv, s, d), f32))
+    seg = jnp.broadcast_to((jnp.arange(s) // 24).astype(jnp.int32), (b, s))
+    return do, q, k, v, delta, lse, carry, seg
+
+
+def _sl(x, rng, axis=2):
+    return x if rng is None else jax.lax.slice_in_dim(x, *rng, axis=axis)
+
+
+RANGES = {"kv_first_half": (None, (0, 32)), "q_second_half": ((32, 64), None)}
+BWD_TILES = {
+    # fused=True is forced as above: off the chip flash_bwd's own gate takes
+    # the split kernels, and the in-place form is the fused kernel's
+    "fused_kernel": dict(fused=True),
+    "split_kernels": dict(fused=False),
+    "jnp_tile": None,
+}
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["plain", "packed"])
+@pytest.mark.parametrize("heads", [(4, 4), (8, 2)], ids=["mha", "gqa4"])
+@pytest.mark.parametrize("half", list(RANGES))
+@pytest.mark.parametrize("tile", list(BWD_TILES))
+def test_round_folds_into_carry(tile, half, heads, packed):
+    """flash_bwd / tile_bwd with a carry and a half sub-range equals
+    `carry + pad(contribution)` of the sliced call, bit for bit in float32:
+    dq is zero outside the q range, dk / dv outside the kv range are the
+    carry's bytes.  One call signature for the fused kernel (in place), the
+    split kernels and the jnp tile (both the sliced form)."""
+    do, q, k, v, delta, lse, carry, seg = _round_inputs(*heads)
+    q_range, kv_range = RANGES[half]
+    s, scale = q.shape[2], q.shape[3] ** -0.5
+    spec = full_spec(32 if q_range else s, 32 if kv_range else s)
+    segments = (seg, seg) if packed else None
+    kw = BWD_TILES[tile]
+    if kw is None:
+        run = T.tile_bwd
+    else:
+        kw = dict(kw, block_q=8, block_kv=8, interpret=True)
+        run = lambda *a, **r: pf.flash_bwd(*a, **kw, **r)  # noqa: E731
+        assert pf.bwd_folds_carry(
+            *heads, s, s, q.shape[3], q_range, kv_range, **kw) == (
+                tile == "fused_kernel")
+
+    got = run(do, q, k, v, delta, lse, scale, spec, segments=segments,
+              q_range=q_range, kv_range=kv_range, carry=carry)
+
+    # today's sliced call, by hand
+    part = run(_sl(do, q_range), _sl(q, q_range), _sl(k, kv_range),
+               _sl(v, kv_range), _sl(delta, q_range), _sl(lse, q_range),
+               scale, spec,
+               segments=(_sl(seg, q_range, 1), _sl(seg, kv_range, 1))
+               if packed else None)
+    lo_q = q_range[0] if q_range else 0
+    lo_kv = kv_range[0] if kv_range else 0
+    want_dq = jnp.zeros_like(got[0]).at[:, :, lo_q:lo_q + part[0].shape[2]
+                                        ].set(part[0])
+    pad_kv = lambda g: jnp.zeros_like(got[1]).at[  # noqa: E731
+        :, :, lo_kv:lo_kv + g.shape[2]].set(g)
+    want = (want_dq, carry[0] + pad_kv(part[1]), carry[1] + pad_kv(part[2]))
+    for name, a, w in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(w), name)
+    if kv_range is not None:
+        for a, c in zip(got[1:], carry):
+            np.testing.assert_array_equal(np.asarray(a[:, :, 32:]),
+                                          np.asarray(c[:, :, 32:]))
+    if q_range is not None:
+        assert not np.asarray(got[0][:, :, :32]).any()
+
+
+@pytest.mark.parametrize("tile", ["fused_kernel", "split_kernels"])
+def test_carry_alone_and_range_alone(tile):
+    """The two halves of the contract apart: a carry over the whole arrays
+    is the round's gradients added to it; a kv range with NO carry leaves
+    exact zeros outside the range."""
+    do, q, k, v, delta, lse, carry, _ = _round_inputs(4, 4)
+    s, scale = q.shape[2], q.shape[3] ** -0.5
+    kw = dict(BWD_TILES[tile], block_q=8, block_kv=8, interpret=True)
+    args = (do, q, k, v, delta, lse, scale)
+    plain = pf.flash_bwd(*args, full_spec(s, s), **kw)
+    carried = pf.flash_bwd(*args, full_spec(s, s), carry=carry, **kw)
+    np.testing.assert_array_equal(np.asarray(carried[0]), np.asarray(plain[0]))
+    for a, c, p in zip(carried[1:], carry, plain[1:]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(c + p))
+    ranged = pf.flash_bwd(*args, full_spec(s, 32), kv_range=(0, 32), **kw)
+    for a in ranged[1:]:
+        assert a.shape == k.shape and not np.asarray(a[:, :, 32:]).any()
+
+
+# ---------------------------------------------------------------------------
+# the same contract on the chip, where the in-place forms are Mosaic's to
+# schedule: shard 8,192, half 4,096, four q blocks of 1,024 — the shortest
+# sweep flash_bwd's own gate admits for the in-place dq (PR 22 met a race
+# at a 2-step sweep; the gate is asked on the RANGE's sweep, and this is
+# the test that shows it holds there)
+
+
+def _max_err(a, b):
+    return float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                 - b.astype(jnp.float32))))
+
+
+@on_tpu
+@pytest.mark.parametrize("half", ["kv_first_half", "q_second_half"])
+def test_round_in_place_matches_sliced_on_tpu(half):
+    b, n, s, d = 1, 4, 8192, 128
+    h = s // 2
+    q_range, kv_range = {"kv_first_half": (None, (0, h)),
+                         "q_second_half": ((h, s), None)}[half]
+    ks = jax.random.split(jax.random.PRNGKey(26), 8)
+    dt = jnp.bfloat16
+    q = jax.random.normal(ks[0], (b, n, s, d), dt)
+    k = jax.random.normal(ks[1], (b, n, s, d), dt)
+    v = jax.random.normal(ks[2], (b, n, s, d), dt)
+    do = jax.random.normal(ks[3], (b, n, s, d), dt)
+    carry = (jax.random.normal(ks[4], (b, n, s, d), jnp.float32),
+             jax.random.normal(ks[5], (b, n, s, d), jnp.float32))
+    scale = d ** -0.5
+    spec = full_spec(h if q_range else s, h if kv_range else s)
+    blocks = dict(block_q=1024, block_kv=1024)
+
+    # forward: a state left by the own-partition round, then this round
+    st = pf.flash_fwd(q, k, v, None, None, None, scale,
+                      round_spec(jnp.int32(0), jnp.int32(0), s, s, True,
+                                 "contig"), **blocks)
+    assert pf.fwd_covers_ranges(s, s, q_range, kv_range, **blocks)
+    got = pf.flash_fwd(q, k, v, *st, scale, spec, q_range=q_range,
+                       kv_range=kv_range, **blocks)
+    part = pf.flash_fwd(_sl(q, q_range), _sl(k, kv_range), _sl(v, kv_range),
+                        *(_sl(x, q_range) for x in st), scale, spec, **blocks)
+    lo = q_range[0] if q_range else 0
+    errs = {}
+    for name, a, c, p_ in zip(("m", "lse", "acc"), got, st, part):
+        want = c.at[:, :, lo:lo + p_.shape[2]].set(p_)
+        errs["fwd_" + name] = _max_err(jnp.nan_to_num(a, neginf=-1e30),
+                                       jnp.nan_to_num(want, neginf=-1e30))
+    m, lse, acc = got
+    delta = jnp.sum(T.finalize(m, lse, acc, jnp.float32)
+                    * do.astype(jnp.float32), axis=-1)
+
+    # backward: the gate takes the in-place kernel on the range's own sweep
+    assert pf.bwd_folds_carry(n, n, s, s, d, q_range, kv_range, **blocks)
+    args = (do, q, k, v, delta, lse, scale, spec)
+    got = pf.flash_bwd(*args, q_range=q_range, kv_range=kv_range, carry=carry,
+                       **blocks)
+    # the sliced form through the SPLIT kernels: no in-place dq, no alias
+    sliced = T.bwd_on_ranges(
+        lambda *a, segments: pf.flash_bwd(*a, fused=False, **blocks),
+        *args, q_range=q_range, kv_range=kv_range, carry=carry)
+    for name, a, w in zip(("dq", "dk", "dv"), got, sliced):
+        errs["bwd_" + name] = _max_err(a, w)
+    print("\nPARITY", half, errs)
+    assert max(errs[k_] for k_ in ("fwd_m", "fwd_lse", "fwd_acc")) < 1e-5, errs
+    assert max(errs[k_] for k_ in ("bwd_dq", "bwd_dk", "bwd_dv")) < 1e-3, errs
+    if kv_range is not None:  # the carry's bytes outside the range
+        for a, c in zip(got[1:], carry):
+            assert bool(jnp.all(a[:, :, h:] == c[:, :, h:]))
+    else:
+        assert not bool(jnp.any(got[0][:, :, :h]))
+
+
+@on_tpu
+def test_ring_sp4_equals_one_chip_on_tpu():
+    """The ring over sp=4 at 4 x 8,192 against the same call on one chip:
+    both half-shard branches run on some chip in every round, through the
+    in-place kernels (the benchmark's own parity at 8,192 tokens is 2,048 a
+    shard, where the gate takes the split kernels)."""
+    import burst_attn_tpu as bat
+    from burst_attn_tpu import obs
+    from jax.sharding import Mesh
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four chips")
+    b, n, s, d = 1, 8, 4 * 8192, 128
+    ks = jax.random.split(jax.random.PRNGKey(27), 4)
+    q, k, v, do = (jax.random.normal(x, (b, n, s, d), jnp.bfloat16)
+                   for x in ks)
+
+    def grads(world):
+        mesh = Mesh(np.array(jax.devices()[:world]), ("sp",))
+        lay = lambda x: bat.layouts.to_layout(x, "zigzag", world, 2)  # noqa: E731
+        unlay = lambda x: bat.layouts.from_layout(x, "zigzag", world, 2)  # noqa: E731
+
+        def loss(q, k, v):
+            o = bat.burst_attn(lay(q), lay(k), lay(v), mesh=mesh, causal=True,
+                               layout="zigzag", backend="auto")
+            return jnp.sum(unlay(o).astype(jnp.float32)
+                           * do.astype(jnp.float32)), o
+
+        (_, o), g = jax.jit(jax.value_and_grad(loss, (0, 1, 2),
+                                               has_aux=True))(q, k, v)
+        return (unlay(o),) + g
+
+    counter = obs.counter("burst.inplace_rounds")
+    before = {p_: counter.get(**{"pass": p_, "path": "kernel"})
+              for p_ in ("fwd", "bwd")}
+    ring = grads(4)
+    rounds = {p_: counter.get(**{"pass": p_, "path": "kernel"}) - before[p_]
+              for p_ in ("fwd", "bwd")}
+    one = [jax.device_get(x) for x in grads(1)]
+    errs = {name: _max_err(jnp.asarray(jax.device_get(a)), jnp.asarray(w))
+            for name, a, w in zip(("o", "dq", "dk", "dv"), ring, one)}
+    print("\nRING_PARITY", errs, rounds)
+    assert rounds == {"fwd": 3, "bwd": 3}
+    assert errs["o"] < 1e-2 and max(
+        errs[x] for x in ("dq", "dk", "dv")) < 3e-2, errs

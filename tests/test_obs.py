@@ -486,6 +486,43 @@ def test_ring_round_and_hop_counters_match_schedule():
         == world - 1
 
 
+def _inplace_rounds():
+    c = obs.counter("burst.inplace_rounds")
+    return {(p, path): c.get(**{"pass": p, "path": path})
+            for p in ("fwd", "bwd") for path in ("kernel", "xla")}
+
+
+@pytest.mark.parametrize("backend,shard,block,path", [
+    ("jnp", 16, None, "xla"),        # the oracle tile slices and adds
+    ("pallas", 64, 8, "kernel"),     # whole blocks: the kernels' own grid
+    ("pallas", 64, 24, "xla"),       # blocks that do not tile the half
+], ids=["jnp_tile", "pallas_whole_blocks", "pallas_ragged_half"])
+def test_inplace_round_counter_follows_the_tile_gate(backend, shard, block,
+                                                     path):
+    """burst.inplace_rounds: the W-1 rounds after the self round, per pass,
+    under path=kernel where the tile entry's own static gate takes the
+    round's carry in the kernel and path=xla where it slices and adds; a
+    one-device ring has no such round.  (Off the chip flash_bwd's gate
+    takes the split kernels, so the backward reads xla here: its kernel
+    form is counted in tests/test_tpu_compile.py, for the chip.)"""
+    import burst_attn_tpu as bat
+
+    world = 4
+    q = jax.random.normal(jax.random.PRNGKey(0), (1, 2, world * shard, 8),
+                          jnp.float32)
+    for w, want_fwd in ((world, path), (1, None)):
+        mesh = Mesh(np.asarray(jax.devices()[:w]), ("sp",))
+        before = _inplace_rounds()
+        o = bat.burst_attn(q, q, q, mesh=mesh, causal=True, layout="zigzag",
+                           backend=backend, block_q=block, block_kv=block,
+                           block_q_bwd=block, block_kv_bwd=block)
+        jax.block_until_ready(o)
+        delta = {k: v - before[k] for k, v in _inplace_rounds().items()
+                 if v != before[k]}
+        assert delta == ({("fwd", want_fwd): w - 1, ("bwd", "xla"): w - 1}
+                         if want_fwd else {})
+
+
 def test_fused_dispatch_fallback_counter(monkeypatch):
     """A fused_ring dispatch off-TPU without the interpret opt-in counts a
     scan-path dispatch plus an off-tpu fallback reason."""

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from burst_attn_tpu.ops import pallas_flash, tile
-from burst_attn_tpu.ops.masks import round_spec
+from burst_attn_tpu.ops.masks import full_spec, round_spec
 from burst_attn_tpu.ops.reference import dense_attention
 
 B, N, NK, S, D = 2, 4, 2, 64, 32
@@ -244,7 +244,7 @@ def test_loop_sweep_matches_unrolled(causal, tri, window, segs):
     probe variant) is numerically identical to the unrolled pipeline,
     including its independently-implemented window band and segment
     terms in mask_of."""
-    from burst_attn_tpu.ops.masks import round_spec
+    from burst_attn_tpu.ops.masks import full_spec, round_spec
     from burst_attn_tpu.ops.tile import init_state
 
     b, n, s, d = 1, 2, 128, 16
@@ -652,3 +652,98 @@ def test_bwd_random_config_property_sweep():
                 err_msg=f"{name} @ {msg}")
     assert seen["wnd_seg"] >= 1 and seen["tri_eff"] >= 1 \
         and seen["split"] >= 1 and seen["ragged"] >= 1, seen
+
+
+# ---------------------------------------------------------------------------
+# a ring round over part of a shard, written into the carried state in place
+# (flash_fwd / tile_fwd `q_range`, `kv_range`; the backward's half of the
+# contract is in tests/test_fused_bwd.py)
+
+FWD_RANGES = {"kv_first_half": (None, (0, S // 2)),
+              "q_second_half": ((S // 2, S), None)}
+FWD_TILES = {
+    "kernel_in_place": dict(block_q=8, block_kv=8),
+    # blocks that do not tile the half: the kernel call takes the sliced form
+    "kernel_sliced": dict(block_q=24, block_kv=24),
+    "jnp_tile": None,
+}
+
+
+def _rows(x, rng, axis=2):
+    return x if rng is None else jax.lax.slice_in_dim(x, *rng, axis=axis)
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["plain", "packed"])
+@pytest.mark.parametrize("heads", [(4, 4), (8, 2)], ids=["mha", "gqa4"])
+@pytest.mark.parametrize("half", list(FWD_RANGES))
+@pytest.mark.parametrize("tile_name", list(FWD_TILES))
+def test_fwd_round_over_a_range_updates_the_carry_in_place(
+        tile_name, half, heads, packed):
+    """A forward round with a carried state and a half sub-range equals the
+    sliced call of the same round written back into the state, bit for bit
+    in float32; the rows outside the q range are the carry's bytes.  One
+    call signature for the kernel (its grid over the range, state aliased),
+    the kernel's sliced form and the jnp tile."""
+    n, nk = heads
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+    q = jax.random.normal(ks[0], (B, n, S, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, nk, S, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, nk, S, D), jnp.float32)
+    # a carry as an earlier round leaves it, with some rows still empty
+    m0 = jax.random.normal(ks[3], (B, n, S), jnp.float32)
+    m0 = jnp.where(jnp.arange(S) % 7 == 3, -jnp.inf, m0)
+    lse0 = jnp.where(jnp.isneginf(m0), -jnp.inf, m0 + 1.5)
+    acc0 = jnp.where(jnp.isneginf(m0)[..., None], 0.0,
+                     jax.random.normal(ks[4], (B, n, S, D), jnp.float32))
+    seg = jnp.broadcast_to((jnp.arange(S) // 24).astype(jnp.int32), (B, S))
+    q_range, kv_range = FWD_RANGES[half]
+    spec = full_spec(S // 2 if q_range else S, S // 2 if kv_range else S)
+    kw = FWD_TILES[tile_name]
+    if kw is None:
+        run = tile.tile_fwd
+    else:
+        kw = dict(kw, interpret=True, cast_p=False)
+        run = lambda *a, **r: pallas_flash.flash_fwd(*a, **kw, **r)  # noqa: E731
+        assert pallas_flash.fwd_covers_ranges(
+            S, S, q_range, kv_range, block_q=kw["block_q"],
+            block_kv=kw["block_kv"]) == (tile_name == "kernel_in_place")
+
+    got = run(q, k, v, m0, lse0, acc0, SCALE, spec, q_range=q_range,
+              kv_range=kv_range, segments=(seg, seg) if packed else None)
+
+    part = run(_rows(q, q_range), _rows(k, kv_range), _rows(v, kv_range),
+               _rows(m0, q_range), _rows(lse0, q_range), _rows(acc0, q_range),
+               SCALE, spec,
+               segments=(_rows(seg, q_range, 1), _rows(seg, kv_range, 1))
+               if packed else None)
+    lo = q_range[0] if q_range else 0
+    for name, a, c, p_ in zip(("m", "lse", "acc"), got, (m0, lse0, acc0), part):
+        want = c.at[:, :, lo:lo + p_.shape[2]].set(p_)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(want), name)
+        if q_range is not None:
+            np.testing.assert_array_equal(np.asarray(a[:, :, :lo]),
+                                          np.asarray(c[:, :, :lo]), name)
+    # and the round did fold something in: the visited rows moved
+    assert not np.array_equal(np.asarray(got[2]), np.asarray(acc0))
+
+
+def test_no_carry_no_range_lowers_to_the_kernel_it_was():
+    """A call with no carry and no range (every one-device program) has no
+    operand and no alias more than before: spec, q, k, v in, three out."""
+    q = jax.ShapeDtypeStruct((1, 2, 64, 32), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: pallas_flash.flash_fwd(
+        q, k, v, None, None, None, SCALE,
+        round_spec(jnp.int32(0), jnp.int32(0), 64, 64, True, "contig"),
+        block_q=16, block_kv=16, interpret=True))(q, q, q)
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(call.invars) == 4 and len(call.outvars) == 3
+    assert not call.params["input_output_aliases"]
+    do = q
+    st = jax.ShapeDtypeStruct((1, 2, 64), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda do, q, k, v, delta, lse: pallas_flash.flash_bwd(
+        do, q, k, v, delta, lse, SCALE, full_spec(64, 64), block_q=16,
+        block_kv=16, interpret=True, fused=True))(do, q, q, q, st, st)
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    # spec, do, q, k, v, delta, lse and the zeros dq accumulates into
+    assert len(call.invars) == 8
+    assert tuple(call.params["input_output_aliases"]) == ((7, 0),)

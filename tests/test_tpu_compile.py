@@ -7,6 +7,7 @@ in this process: only one process may hold libtpu, so all of these stay in
 this one file (see the on-chip-measurement guide, section 2).
 """
 
+import re
 import sys
 from pathlib import Path
 
@@ -17,6 +18,7 @@ from jax.sharding import (NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 import burst_attn_tpu as bat
+from burst_attn_tpu import obs
 from burst_attn_tpu.ops import pallas_flash, tuning
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
@@ -78,6 +80,14 @@ def _mosaic_calls(text):
     return chip_smoke._kernel_facts(text)["mosaic_calls"]
 
 
+def _inplace_rounds():
+    """burst.inplace_rounds as {(pass, path): count} (a dispatch counter: it
+    advances when the program is traced)."""
+    c = obs.counter("burst.inplace_rounds")
+    return {(p, path): c.get(**{"pass": p, "path": path})
+            for p in ("fwd", "bwd") for path in ("kernel", "xla")}
+
+
 def _compile_attn_grad(mesh, *, seq, heads=32, kv_heads=32, backend="auto",
                        layout="zigzag", window=None, grad=True):
     sharding = NamedSharding(mesh, P(None, None, "sp", None))
@@ -101,8 +111,10 @@ def test_grad_burst_attn_one_chip_64k(topo, on_chip):
     """The paper's op shape (BASELINE.json) on one chip: both passes are
     Mosaic kernels (no kernel gave way to the jnp tile), the backward is
     the triangular fused one, and a one-device ring has no hops."""
+    before = _inplace_rounds()
     c = _compile_attn_grad(_seq_mesh(topo, 1), **{
         k: chip_smoke.REAL["op"][k] for k in ("seq", "heads")})
+    assert _inplace_rounds() == before  # no round after the self round
     text = c.as_text()
     assert _mosaic_calls(text) == 2
     assert _kernels(text) == ["burst_flash_bwd_tri", "burst_flash_fwd"]
@@ -121,6 +133,82 @@ def test_grad_burst_attn_sp4_at_the_multichip_length(topo, on_chip):
     assert text.count("collective-permute-start") > 0
     assert _mosaic_calls(text) > 2
     assert _device_bytes(c) < HBM_BYTES
+
+
+def _computations(text):
+    """{computation name: its instruction lines} of an HLO module's text."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head and not line.startswith(" "):
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None and line.startswith(" "):
+            comps[name].append(line)
+    return comps
+
+
+def _reachable(comps, root):
+    """`root` and every computation it calls (fusions, branches, loops)."""
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            for called in re.findall(
+                    r"(?:calls|body|condition|to_apply|true_computation|"
+                    r"false_computation)=%([\w.\-]+)", line):
+                todo.append(called)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+                todo += re.findall(r"%([\w.\-]+)", group)
+    return seen
+
+
+def _instructions(lines, opcode, shapes):
+    """Names of the `opcode` instructions whose result is one of `shapes`."""
+    pat = re.compile(r"\s+(?:ROOT )?%([\w.\-]+) = (\S+?)\{[^ ]* "
+                     + re.escape(opcode) + r"\(")
+    return [m.group(1) for m in map(pat.match, lines)
+            if m and m.group(2) in shapes]
+
+
+def test_ring_rounds_write_into_their_carries_at_the_cell_length(topo,
+                                                                 on_chip):
+    """`ring4_causal_128k`'s program (1 x 32 x 131,072 x 128 over sp=4): a
+    round after the self round hands its kernel the full-size carry, so XLA
+    has nothing left to do around the kernels but add the arriving dq.  The
+    instruction names are the chip's (PERF.md section 6, PR 26): before,
+    the scan bodies held `add.172`-`174`, `copy.164`, pads and slices of
+    the 512 MiB float32 arrays, and the program needed 7.52 GiB a chip."""
+    assert chip_smoke.REAL_MULTICHIP["op"]["seq"] == 131072
+    before = _inplace_rounds()
+    c = _compile_attn_grad(_seq_mesh(topo, 4), seq=131072)
+    rounds = {k: v - before[k] for k, v in _inplace_rounds().items()}
+    assert rounds == {("fwd", "kernel"): 3, ("bwd", "kernel"): 3,
+                      ("fwd", "xla"): 0, ("bwd", "xla"): 0}
+    text = c.as_text()
+    comps = _computations(text)
+    full = "f32[1,32,32768,128]"
+    halves = ["f32[1,32,16384,128]", "bf16[1,32,16384,128]"]
+    everything = [line for lines in comps.values() for line in lines]
+
+    loops = re.findall(r" while\(.*?body=%([\w.\-]+)", text)
+    assert len(loops) == 2  # the forward's scan and the backward's
+    for body in loops:
+        adds = [name for comp in _reachable(comps, body)
+                for name in _instructions(comps[comp], "add", [full])]
+        assert len(adds) <= 1, (body, adds)  # dq_rot + dqc, and no other
+    for opcode in ("pad", "concatenate", "slice"):
+        assert not _instructions(everything, opcode, [full] + halves)
+    # the forward's accumulator is updated where it lies (was copy.164)
+    assert not _instructions(everything, "copy", [full])
+    assert not _instructions(everything, "dynamic-update-slice", [full])
+
+    assert _kernels(text) == ["burst_flash_bwd_rect", "burst_flash_bwd_tri",
+                              "burst_flash_fwd"]
+    assert _device_bytes(c) < (7.52 - 1.0) * 2 ** 30
 
 
 @pytest.mark.parametrize("kw,bwd", [
