@@ -319,15 +319,16 @@ def dense_mask(spec: MaskSpec, s_q: int, s_kv: int, window=None) -> jnp.ndarray:
 
 class Quadrant(NamedTuple):
     """One live quadrant as a tile call: static row ranges of the stream, the
-    spec (in blocks, local to the ranges), the tile's `window`, and whether
-    the spec keeps the triangular grid's contract (full-window causal,
-    offset 0 or -1)."""
+    spec (in blocks, local to the ranges) and the tile's `window`.  Every
+    quadrant's spec keeps the static contract of the kernels' all-live grids
+    (whole ranges, causal, offset 0 or -1), so each call asks for them
+    (`triangular=True`): the two unwindowed ones get the triangular grid,
+    the block-diagonal one, whose window is one block, the band grid."""
 
     q_range: tuple
     kv_range: tuple
     spec: MaskSpec
     window: BlockUnits
-    triangular: bool
 
 
 def bd_quadrants(seq: int, block: int):
@@ -347,9 +348,9 @@ def bd_quadrants(seq: int, block: int):
         return MaskSpec(_i32(0), _i32(nb), _i32(nb), _i32(1), _i32(offset))
 
     return (
-        Quadrant(clean, clean, causal(0), BlockUnits(block), True),
-        Quadrant(noised, clean, causal(-1), BlockUnits(block), True),
-        Quadrant(noised, noised, causal(0), BlockUnits(block, 1), False),
+        Quadrant(clean, clean, causal(0), BlockUnits(block)),
+        Quadrant(noised, clean, causal(-1), BlockUnits(block)),
+        Quadrant(noised, noised, causal(0), BlockUnits(block, 1)),
     )
 
 

@@ -960,7 +960,9 @@ def flash_fwd(q, k, v, m, lse, acc, scale, spec: MaskSpec, *,
     )
     m_new, lse_new, acc_new = pl.pallas_call(
         kernel,
-        name="burst_flash_fwd",
+        # a band grid runs under a name of its own (same prefix): a trace
+        # shows a windowed call's time beside the other forward calls'
+        name="burst_flash_fwd" + ("_band" if band_nb is not None else ""),
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
@@ -1820,7 +1822,8 @@ def _flash_bwd_fused(do, q, k, v, delta, lse, scale, spec, *,
             n_q_blocks=nqb, group=group, nbq=nbq, wnd=window,
             seg=segments is not None, carry=carry is not None, q_off=q_off,
         ),
-        name="burst_flash_bwd_rect",
+        # the banded sweep under its own name, as the forward's band grid
+        name="burst_flash_bwd_" + ("band" if nbq < nqb else "rect"),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, n_kv, nkb, nbq * group),
@@ -2212,7 +2215,8 @@ def _flash_attention_fwd_impl(q, k, v, scale, causal, block_q, block_kv,
     if window is not None and not causal:
         raise ValueError("window attention requires causal=True")
     block_q, block_kv, _, _, block_kv_compute = resolve_blocks(
-        block_q, block_kv, block_kv_compute=block_kv_compute)
+        block_q, block_kv, block_kv_compute=block_kv_compute,
+        s_q=s, s_kv=k.shape[2], window=window)
     # single-device: the windowed spec is the plain causal spec (delta = 0);
     # the static `window` is what narrows the band
     spec = round_spec(jnp.int32(0), jnp.int32(0), s, k.shape[2], causal, "contig")
@@ -2251,7 +2255,8 @@ def _flash_attention_vjp_bwd(scale, causal, block_q, block_kv, block_q_bwd,
     if scale is None:
         scale = d**-0.5
     _, _, block_q_bwd, block_kv_bwd, _ = resolve_blocks(
-        block_q, block_kv, block_q_bwd, block_kv_bwd)
+        block_q, block_kv, block_q_bwd, block_kv_bwd,
+        s_q=q.shape[2], s_kv=k.shape[2], window=window)
     spec = round_spec(jnp.int32(0), jnp.int32(0), q.shape[2], k.shape[2], causal, "contig")
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
     dq, dk, dv = flash_bwd(
@@ -2296,7 +2301,8 @@ def _flash_attention_seg_vjp_bwd(scale, causal, block_q, block_kv, block_q_bwd,
     if scale is None:
         scale = d**-0.5
     _, _, block_q_bwd, block_kv_bwd, _ = resolve_blocks(
-        block_q, block_kv, block_q_bwd, block_kv_bwd)
+        block_q, block_kv, block_q_bwd, block_kv_bwd,
+        s_q=q.shape[2], s_kv=k.shape[2], window=window)
     spec = round_spec(jnp.int32(0), jnp.int32(0), q.shape[2], k.shape[2],
                       causal, "contig")
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)

@@ -1,4 +1,5 @@
-"""Per-TPU-generation kernel block-size table.
+"""Kernel block sizes: one measured row per TPU generation, and the rule
+that fits it to one tile call.
 
 SURVEY.md §7 build order item 2 calls for a "block-size autotuning table per
 TPU generation": the measured optimum differs per chip (VMEM size, MXU/VPU
@@ -11,12 +12,21 @@ Values are (fwd block_q, fwd block_kv, fwd block_kv_compute,
 bwd block_q, bwd block_kv).  The v5e row is measured (seq=64K, 32 heads,
 d=128, causal bf16); other rows start from the v5e optimum scaled by VMEM
 headroom and are marked estimated until swept on hardware.
+
+A row is what a LONG, UNWINDOWED call wants.  `resolve_blocks` is handed
+what a tile call can see statically (the rows of q and of kv it covers, its
+static window) and `call_row` fits the row to it: a call under a band far
+narrower than the row's tiles gets tiles of the band's width.  Blocks a
+caller sets win over both; the VMEM-cliff clamp applies to whatever was
+resolved.
 """
 
 import logging
 from typing import NamedTuple, Optional
 
 import jax
+
+from .masks import unit_of
 
 logger = logging.getLogger("burst_attn_tpu")
 
@@ -35,6 +45,12 @@ class BlockTable(NamedTuple):
     # measured=False until a sweep pins them.
     fwd_cliff_area: int = 2048 * 2048
     bwd_cliff_area: int = 1024 * 2048
+    # Tile edge under a narrow band (call_row): the least square tile a
+    # banded call takes.  Below it the grid's steps (about 0.35 us each on
+    # the v5e) cost more than the dead area a smaller tile saves.  MEASURED
+    # on the v5e (benchmarks/sweep_tile_calls.py; the numbers are at
+    # call_row); the other generations inherit it unswept.
+    band_block: int = 512
     # Fused ring kernel (ops/fused_ring.py): KV communication-slot count
     # (2 = plain double buffering; more slots let the RDMA pipeline run
     # deeper ahead of compute at the cost of one extra KV chunk of HBM per
@@ -327,11 +343,71 @@ def resolve_fused(block_q=None, block_kv=None, kv_slots=None,
                          bqb, bkvb, bslots, cslots, bcslots, wire)
 
 
+def _pow2_ceil(x: int) -> int:
+    return 1 << max(x - 1, 0).bit_length()
+
+
+def call_row(t: BlockTable, s_q=None, s_kv=None, window=None):
+    """(fwd block_q, fwd block_kv, bwd block_q, bwd block_kv) that the
+    generation's row `t` gives ONE tile call, from what the call can see
+    statically: the rows of q and of kv it covers and its `window` (tokens,
+    or a masks.BlockUnits).  One rule in rows and band width; the measured
+    rows are its fixed points, and it names no model and no shape.
+    Measured on the v5e with benchmarks/sweep_tile_calls.py (PR 29: device
+    ms of the kernel of one call, d_head 128, bf16, the second of two
+    sweeps that agree to 0.01 ms; PERF.md section 6).
+
+    Band width.  On the band grids a q tile visits the kv tiles its band of
+    `win * unit` tokens can touch: the band plus about one tile edge.  Under
+    a band far narrower than the row's tiles nearly all of that is dead, and
+    what is visited takes the masked path, which the VPU bounds.  So the
+    tile shrinks to the band's width rounded up to a power of two, and not
+    below `t.band_block`, where grid steps take over; a band as wide as the
+    row's tiles keeps them (window 4096 at 64K: the row).  Fixed points, at
+    8,192 rows.  A band of 4 tokens, 32 / 4 heads (the block-diagonal call
+    of a block-diffusion stream): forward 3.05 ms in the row's 2048 x 2048
+    on the rectangular grid, 3.02 on the band grid, then 2.10 / 1.77 / 2.00
+    / 2.48 in squares of 1024 / 512 / 256 / 128 (256 x 512: 2.00,
+    512 x 256: 2.82); backward 4.48 in the row's 1024 x 2048, then 2.43 /
+    1.64 / 1.72 / 2.81.  A window of 256 tokens, 32 / 8 heads: forward 4.38
+    in the row's tiles, 2.84 / 2.13 / 2.56 in 1024 / 512 / 256; backward
+    6.11, 4.39 / 2.77 / 2.64.  A window of 1,024: forward 4.38, 2.84 in
+    1024, 2.95 in 512; backward 6.11, 4.39 in 1024, 3.96 in 512 (the rule's
+    1024 is not the backward's best there; it is 28 % under the row).
+
+    Rows.  An unwindowed call keeps the row at every length measured: no
+    departure was 3 % faster on the call.  At 8,192 rows (32 / 4 and 32 / 8
+    heads) the forward reads 4.76 ms in 2048 x 2048 and 4.78 in
+    1024 x 1024 (9 tile-units of area for 10, four times the steps), 5.69 in
+    2048 x 1024, 7.24 in 4096 x 1024, 7.70 in 512 x 512; the backward 10.22
+    / 10.31 / 10.27 in 1024 x 2048 and 9.93 / 10.03 / 9.99 in 1024 x 1024
+    (2.7-2.9 % faster, under the bar), 10.47 in 512 x 2048, 10.70 in
+    512 x 1024, 10.78 in 2048 x 1024, 12.53 in 512 x 512.  At 1,024 rows
+    (batch 8, 32 / 8 heads), where the row's tiles are clamped to one
+    1024 x 1024 a head: forward 1.53 against 1.63 in 512 x 512 (the
+    triangular grid, 3 of 4 quarter tiles) and 2.91 in 256 x 256; backward
+    2.15 against 2.13 in 512 x 512, 2.32 in 512 x 1024, 2.88 in 256 x 512.
+    So `s_q` and `s_kv` change nothing today; they are what the next
+    measured departure keys on.
+    """
+    del s_q, s_kv  # see Rows
+    row = (t.fwd_block_q, t.fwd_block_kv, t.bwd_block_q, t.bwd_block_kv)
+    unit, win = unit_of(window)
+    if win is not None:
+        edge = max(t.band_block, _pow2_ceil(win * unit))
+        row = tuple(min(b, edge) for b in row)
+    return row
+
+
 def resolve_blocks(block_q=None, block_kv=None, block_q_bwd=None,
                    block_kv_bwd=None, block_kv_compute=None,
                    device=None,
-                   table: Optional[BlockTable] = None) -> ResolvedBlocks:
-    """Fill unspecified kernel block sizes from the per-generation table.
+                   table: Optional[BlockTable] = None, *,
+                   s_q=None, s_kv=None, window=None) -> ResolvedBlocks:
+    """Fill unspecified kernel block sizes from the per-generation table
+    and, where the caller gives it, the tile call's own geometry (`s_q`,
+    `s_kv`: the rows the call covers; `window`: its static window; see
+    call_row).  Without geometry the row stands as it is.
 
     The bwd defaults never exceed the (resolved) fwd blocks, so a caller who
     shrinks the fwd blocks for VMEM keeps that budget in bwd; likewise the
@@ -343,10 +419,11 @@ def resolve_blocks(block_q=None, block_kv=None, block_q_bwd=None,
     explicit BlockTable row (see resolve_fused).
     """
     t = block_defaults(device) if table is None else table
-    bq = t.fwd_block_q if block_q is None else block_q
-    bkv = t.fwd_block_kv if block_kv is None else block_kv
-    bqb = min(t.bwd_block_q, bq) if block_q_bwd is None else block_q_bwd
-    bkvb = min(t.bwd_block_kv, bkv) if block_kv_bwd is None else block_kv_bwd
+    fwd_q, fwd_kv, bwd_q, bwd_kv = call_row(t, s_q, s_kv, window)
+    bq = fwd_q if block_q is None else block_q
+    bkv = fwd_kv if block_kv is None else block_kv
+    bqb = min(bwd_q, bq) if block_q_bwd is None else block_q_bwd
+    bkvb = min(bwd_kv, bkv) if block_kv_bwd is None else block_kv_bwd
     bq, bkv = _clamp_cliff(bq, bkv, t.fwd_cliff_area, "fwd")
     bqb, bkvb = _clamp_cliff(bqb, bkvb, t.bwd_cliff_area, "bwd")
     if block_kv_compute is None:

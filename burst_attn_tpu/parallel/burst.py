@@ -107,11 +107,12 @@ class BurstConfig:
     # with the pallas (TPU) / jnp (CPU) tile backend.
     backend: str = "jnp"
     optimize_bwd_comm: bool = True  # rotate delta=sum(o*do) [B,N,S] f32, not o
-    # kernel blocks; None = resolved from the per-TPU-generation table
-    # (ops/tuning.py) by resolved_blocks() in the tile dispatch, with bwd
-    # blocks never defaulting larger than the fwd ones (a caller who tunes
-    # block_q/block_kv down for VMEM keeps that budget in the backward too).
-    # burst_attn() pre-resolves these at construction.
+    # kernel blocks; None = resolved per tile CALL by resolved_blocks() in
+    # the tile dispatch, from the per-TPU-generation table and the call's
+    # own geometry (ops/tuning.py resolve_blocks: rows covered, band width),
+    # with bwd blocks never defaulting larger than the fwd ones (a caller
+    # who tunes block_q/block_kv down for VMEM keeps that budget in the
+    # backward too).  A block set here wins over both.
     block_q: Optional[int] = None
     block_kv: Optional[int] = None
     block_q_bwd: Optional[int] = None
@@ -237,14 +238,16 @@ class BurstConfig:
                                tuple((str(a), int(sz))
                                      for a, sz in self.mesh_axes))
 
-    def resolved_blocks(self):
-        """ResolvedBlocks with None fields filled from the
-        per-TPU-generation table (ops/tuning.py) — the one source of block
-        defaults."""
+    def resolved_blocks(self, s_q=None, s_kv=None, window=None):
+        """ResolvedBlocks with None fields filled by ops/tuning.py — the one
+        source of block defaults — for a tile call that covers `s_q` query
+        and `s_kv` key rows under the static `window` (no geometry: the
+        per-TPU-generation row as it stands)."""
         from ..ops.tuning import resolve_blocks
 
         return resolve_blocks(self.block_q, self.block_kv,
-                              self.block_q_bwd, self.block_kv_bwd)
+                              self.block_q_bwd, self.block_kv_bwd,
+                              s_q=s_q, s_kv=s_kv, window=window)
 
 
 # ---------------------------------------------------------------------------
@@ -261,6 +264,17 @@ def _tile_backend(cfg) -> str:
     return "pallas" if jax.default_backend() == "tpu" else "jnp"
 
 
+def _call_blocks(cfg, s_q, s_kv, q_range, kv_range, window):
+    """The blocks of ONE tile call, resolved from what it covers: the rows
+    of its `q_range` / `kv_range` (the whole length-s arrays without one)
+    under its static window."""
+    def rows(s, rng):
+        return s if rng is None else rng[1] - rng[0]
+
+    return cfg.resolved_blocks(rows(s_q, q_range), rows(s_kv, kv_range),
+                               window)
+
+
 def _tile_fwd(cfg, q, k, v, m, lse, acc, scale, spec, triangular=False,
               segments=None, q_range=None, kv_range=None, window=None):
     """One forward round folded into the carried (m, lse, acc).  `q_range` /
@@ -274,7 +288,8 @@ def _tile_fwd(cfg, q, k, v, m, lse, acc, scale, spec, triangular=False,
     if _tile_backend(cfg) == "pallas":
         from ..ops import pallas_flash
 
-        rb = cfg.resolved_blocks()
+        rb = _call_blocks(cfg, q.shape[2], k.shape[2], q_range, kv_range,
+                          window)
         bq, bkv = rb.block_q, rb.block_kv
         return pallas_flash.flash_fwd(
             q, k, v, m, lse, acc, scale, spec,
@@ -303,7 +318,8 @@ def _tile_bwd(cfg, do, q, k, v, delta, lse, scale, spec, triangular=False,
     if _tile_backend(cfg) == "pallas":
         from ..ops import pallas_flash
 
-        rb = cfg.resolved_blocks()
+        rb = _call_blocks(cfg, q.shape[2], k.shape[2], q_range, kv_range,
+                          window)
         bq, bkv = rb.block_q_bwd, rb.block_kv_bwd
         return pallas_flash.flash_bwd(
             do, q, k, v, delta, lse, scale, spec, block_q=bq, block_kv=bkv,
@@ -342,20 +358,21 @@ def _round_in_kernel(cfg, pass_, q_shape, k_shape) -> bool:
     from ..ops import pallas_flash
 
     (_, n, s, d), (_, n_kv, s_kv, _) = q_shape, k_shape
-    rb = cfg.resolved_blocks()
-    if pass_ == "fwd":
-        return all(
-            pallas_flash.fwd_covers_ranges(
+
+    def folds(tri, q_rng, kv_rng):
+        # the blocks the tile call itself resolves (_tile_fwd / _tile_bwd)
+        rb = _call_blocks(cfg, s, s_kv, q_rng, kv_rng, cfg.window)
+        if pass_ == "fwd":
+            return pallas_flash.fwd_covers_ranges(
                 s, s_kv, q_rng, kv_rng, block_q=rb.block_q,
                 block_kv=rb.block_kv, triangular=tri)
-            for tri, q_rng, kv_rng in _round_tiles(cfg, s, s_kv))
-    return all(
-        pallas_flash.bwd_folds_carry(
+        return pallas_flash.bwd_folds_carry(
             n, n_kv, s, s_kv, d, q_rng, kv_rng, block_q=rb.block_q_bwd,
             block_kv=rb.block_kv_bwd,
             # the forward's band grid has no backward twin to ask for
             triangular=tri and cfg.window is None, window=cfg.window)
-        for tri, q_rng, kv_rng in _round_tiles(cfg, s, s_kv))
+
+    return all(folds(*tile) for tile in _round_tiles(cfg, s, s_kv))
 
 
 def _sizes(cfg):
@@ -413,7 +430,7 @@ def _bd_fwd(q, k, v, cfg: BurstConfig):
     def fold(quad, state):
         return _tile_fwd(cfg, _rows_of(q, quad.q_range),
                          _rows_of(k, quad.kv_range), _rows_of(v, quad.kv_range),
-                         *state, scale, quad.spec, triangular=quad.triangular,
+                         *state, scale, quad.spec, triangular=True,
                          window=quad.window)
 
     empty = (None, None, None)
@@ -443,8 +460,7 @@ def _bd_bwd(cfg: BurstConfig, q, k, v, o, lse, do):
                          _rows_of(k, kr), _rows_of(v, kr),
                          lax.slice_in_dim(delta, *qr, axis=2),
                          lax.slice_in_dim(lse, *qr, axis=2), scale, quad.spec,
-                         triangular=quad.triangular, carry=carry,
-                         window=quad.window)
+                         triangular=True, carry=carry, window=quad.window)
 
     with jax.named_scope("obs.bd.clean"):
         dq_c, *dkv_c = grads(clean)
@@ -1257,7 +1273,7 @@ def burst_attn(
         inter_axis, intra_axis = seq_axes
     else:
         raise ValueError(f"seq_axes must have 1 or 2 names, got {seq_axes}")
-    from ..ops.tuning import block_defaults, resolve_blocks
+    from ..ops.tuning import block_defaults
 
     if block_diffusion is not None:
         world = 1
@@ -1274,9 +1290,9 @@ def burst_attn(
             raise ValueError(
                 "block_diffusion takes no segment_ids and collects no ring "
                 "stats (one document a stream, no ring)")
-    # window validation lives in BurstConfig.__post_init__ (constructed below)
-    block_q, block_kv, block_q_bwd, block_kv_bwd, _ = resolve_blocks(
-        block_q, block_kv, block_q_bwd, block_kv_bwd)
+    # window validation lives in BurstConfig.__post_init__ (constructed
+    # below); the blocks stay as the caller gave them (None = unset): each
+    # tile call resolves its own from its geometry (cfg.resolved_blocks)
     if wire_dtype is None:
         # per-generation wire default (every table row is None today — the
         # wire stays bit-exact unless the caller opts in per call)

@@ -5,6 +5,7 @@ compute, the refusal over a ring, and that a call with no block mask traces
 what it traced before the mask existed."""
 
 import hashlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -68,13 +69,18 @@ def test_stream_length_must_be_twice_whole_blocks():
 
 
 @pytest.mark.parametrize("backend", ["jnp", "pallas"])
-@pytest.mark.parametrize("length,block,tile", [(192, 4, 128), (160, 32, 128)])
-def test_kernels_against_the_dense_mask(backend, length, block, tile):
+@pytest.mark.parametrize("length,block,tile,heads,kv_heads", [
+    (192, 4, 128, 2, 1), (160, 32, 128, 2, 1),
+    # whole tiles, so the diagonal quadrant runs on the band grid (4 q
+    # tiles x 2 steps, folded into the `below` call's state), GQA 32 / 4
+    (256, 4, 64, 32, 4), (128, 32, 32, 32, 4)])
+def test_kernels_against_the_dense_mask(backend, length, block, tile, heads,
+                                        kv_heads):
     """o, dq, dk, dv through burst_attn at sp=1 (the Pallas kernels
-    interpreted), L not a multiple of the tile, against dense softmax
-    attention under the rule's mask."""
+    interpreted), L not a multiple of the tile or in small whole tiles,
+    against dense softmax attention under the rule's mask."""
     mesh = Mesh(np.array(jax.devices()[:1]), ("sp",))
-    q, k, v, do = _inputs(length, length + block)
+    q, k, v, do = _inputs(length, length + block, heads, kv_heads)
     mask = jnp.asarray(masks.bd_dense_mask(2 * length, block))
 
     def system(q, k, v):
@@ -90,8 +96,10 @@ def test_kernels_against_the_dense_mask(backend, length, block, tile):
         assert float(jnp.max(jnp.abs(a - b))) < 2e-5, name
 
 
+@pytest.mark.parametrize("heads,kv_heads,blk", [(4, 2, 64), (32, 4, 32)])
 @pytest.mark.parametrize("quadrant", [0, 1, 2])
-def test_fused_backward_kernel_against_the_dense_mask(quadrant):
+def test_fused_backward_kernel_against_the_dense_mask(quadrant, heads,
+                                                      kv_heads, blk):
     """The chip's backward for these shapes is the fused rectangular kernel
     (interpret mode picks the split ones): forced here, with a carried
     dk / dv, one quadrant at a time against the jnp tile.  dk and dv only:
@@ -100,26 +108,37 @@ def test_fused_backward_kernel_against_the_dense_mask(quadrant):
     holds all three to the dense mask."""
     from burst_attn_tpu.ops import tile
 
-    length, block, blk = 256, 4, 64
+    length, block = 256, 4
     quad = masks.bd_quadrants(2 * length, block)[quadrant]
-    q, k, v, do = (x[:, :, :length] for x in _inputs(length, 7, heads=4,
-                                                     kv_heads=2))
+    q, k, v, do = (x[:, :, :length] for x in _inputs(
+        length, 7, heads=heads, kv_heads=kv_heads))
     ks = jax.random.split(jax.random.PRNGKey(3), 4)
     lse = jax.random.normal(ks[0], q.shape[:3]) + 3.0
     delta = jax.random.normal(ks[1], q.shape[:3])
     carry = (jax.random.normal(ks[2], k.shape), jax.random.normal(ks[3], k.shape))
     got = pf.flash_bwd(do, q, k, v, delta, lse, 0.25, quad.spec, block_q=blk,
                        block_kv=blk, interpret=True, fused=True,
-                       window=quad.window, carry=carry)
+                       triangular=True, window=quad.window, carry=carry)
     want = tile.tile_bwd(do, q, k, v, delta, lse, 0.25, quad.spec,
                          window=quad.window, carry=carry)
+    # the block-diagonal quadrant's sweep is the banded one (2 of the 4 or
+    # 8 q tiles a kv tile), the other two sweep every q tile
+    text = str(jax.make_jaxpr(lambda *xs: pf.flash_bwd(
+        *xs, 0.25, quad.spec, block_q=blk, block_kv=blk, interpret=True,
+        fused=True, triangular=True, window=quad.window, carry=carry))(
+        do, q, k, v, delta, lse))
+    assert ("burst_flash_bwd_band" in text) == (quadrant == 2)
+    assert ("burst_flash_bwd_rect" in text) == (quadrant != 2)
     for name, a, b in zip(("dk", "dv"), got[1:], want[1:]):
         assert float(jnp.max(jnp.abs(a - b))) < 1e-4, name
 
 
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="the compiled kernels, on the chip "
-                           "(BURST_TESTS_TPU=1)")
+on_the_chip = pytest.mark.skipif(jax.default_backend() != "tpu",
+                                 reason="the compiled kernels, on the chip "
+                                        "(BURST_TESTS_TPU=1)")
+
+
+@on_the_chip
 @pytest.mark.parametrize("block", [4, 32])
 def test_compiled_kernels_against_the_dense_mask_on_the_chip(block):
     """o, dq, dk, dv of the compiled kernels (the fused rectangular
@@ -145,15 +164,120 @@ def test_compiled_kernels_against_the_dense_mask_on_the_chip(block):
         assert err < tol * max(1.0, float(jnp.max(jnp.abs(b)))), (name, err)
 
 
+@on_the_chip
+def test_the_cell_s_attention_against_the_dense_mask_on_the_chip():
+    """`train_sdar_bd_1x8k`'s attention as the cell runs it (a stream of
+    2 x 8,192 rows, 32 / 4 heads x 128, blocks of 4, every tile and grid
+    from ops/tuning.call_row: the block-diagonal quadrant on the band grids
+    in tiles of 512, folded into the `below` call's state) against dense
+    float32 softmax attention under the rule's mask, one query head at a
+    time (a head's scores are 1 GiB)."""
+    length, block, heads, kv_heads = 8192, 4, 32, 4
+    mesh = Mesh(np.array(jax.devices()[:1]), ("sp",))
+    q, k, v, do = (x.astype(jnp.bfloat16) for x in _inputs(
+        length, block, heads=heads, kv_heads=kv_heads, d=128))
+    attn = lambda q, k, v: bat.burst_attn(q, k, v, mesh=mesh,
+                                          block_diffusion=block)
+    assert re.findall(r"burst_flash_\w+", str(jax.make_jaxpr(
+        lambda *x: jax.vjp(attn, *x[:3])[1](x[3]))(q, k, v, do))) == [
+        "burst_flash_fwd", "burst_flash_fwd", "burst_flash_fwd_band",
+        "burst_flash_bwd_rect", "burst_flash_bwd_rect",
+        "burst_flash_bwd_band"]
+    got, vjp = jax.vjp(attn, q, k, v)
+    got = (got, *vjp(do))
+    mask = jnp.asarray(masks.bd_dense_mask(2 * length, block))
+
+    @jax.jit
+    def head(q, k, v, do, mask):
+        want, vjp_ref = jax.vjp(lambda q, k, v: _dense(q, k, v, mask),
+                                q, k, v)
+        return (want, *vjp_ref(do))
+
+    f32 = lambda x: x.astype(jnp.float32)
+    group = heads // kv_heads
+    errs, peak = np.zeros(4), np.zeros(4)
+    with jax.default_matmul_precision("highest"):
+        for g in range(kv_heads):
+            dkv = [0.0, 0.0]
+            for h in range(g * group, (g + 1) * group):
+                o, dq, dk, dv = head(f32(q[:, h:h + 1]), f32(k[:, g:g + 1]),
+                                     f32(v[:, g:g + 1]), f32(do[:, h:h + 1]),
+                                     mask)
+                dkv = [dkv[0] + dk, dkv[1] + dv]
+                for i, (a, b) in enumerate(((got[0][:, h:h + 1], o),
+                                            (got[1][:, h:h + 1], dq))):
+                    errs[i] = max(errs[i], float(jnp.max(jnp.abs(f32(a) - b))))
+                    peak[i] = max(peak[i], float(jnp.max(jnp.abs(b))))
+            for i, b in zip((2, 3), dkv):
+                a = got[i][:, g:g + 1]
+                errs[i] = max(errs[i], float(jnp.max(jnp.abs(f32(a) - b))))
+                peak[i] = max(peak[i], float(jnp.max(jnp.abs(b))))
+    print("PARITY cell attention vs dense f32, max abs err (max |ref|):",
+          {n: (float(e), float(p)) for n, e, p in zip(
+              ("o", "dq", "dk", "dv"), errs, peak)})
+    for name, err, top, tol in zip(("o", "dq", "dk", "dv"), errs, peak,
+                                   (4e-2, 5e-2, 5e-2, 5e-2)):
+        assert err < tol * max(1.0, top), (name, err)
+
+
+@on_the_chip
+def test_the_diagonal_call_s_fused_backward_equals_the_split_kernels_on_the_chip():
+    """The block-diagonal call of the cell (8,192 rows, 32 / 4 heads) in the
+    tiles the rule gives it: the fused kernel on the banded sweep, where `dq`
+    is added in place and a race would show, against the split kernels."""
+    from burst_attn_tpu.ops import tile
+    from burst_attn_tpu.parallel.burst import BurstConfig
+
+    length, block = 8192, 4
+    quad = masks.bd_quadrants(2 * length, block)[2]
+    rb = BurstConfig().resolved_blocks(length, length, quad.window)
+    q, k, v, do = (x[:, :, :length].astype(jnp.bfloat16) for x in _inputs(
+        length, 11, heads=32, kv_heads=4, d=128))
+    scale = 128 ** -0.5
+
+    @jax.jit
+    def prep(q, k, v, do):
+        m, lse, acc = pf.flash_fwd(q, k, v, None, None, None, scale,
+                                   quad.spec, block_q=rb.block_q,
+                                   block_kv=rb.block_kv, triangular=True,
+                                   window=quad.window)
+        o = tile.finalize(m, lse, acc, jnp.float32)
+        return lse, jnp.sum(o * do.astype(jnp.float32), -1)
+
+    lse, delta = prep(q, k, v, do)
+
+    def bwd(fused):
+        return jax.jit(lambda *xs: pf.flash_bwd(
+            *xs, scale, quad.spec, block_q=rb.block_q_bwd,
+            block_kv=rb.block_kv_bwd, triangular=True, window=quad.window,
+            fused=fused))
+
+    assert "burst_flash_bwd_band" in str(jax.make_jaxpr(bwd(None))(
+        do, q, k, v, delta, lse))
+    errs = {name: float(jnp.max(jnp.abs(a - b))) for name, a, b in zip(
+        ("dq", "dk", "dv"), bwd(None)(do, q, k, v, delta, lse),
+        bwd(False)(do, q, k, v, delta, lse))}
+    print("PARITY diagonal call, fused (band) vs split, tiles",
+          tuple(rb[2:4]), errs)
+    assert max(errs.values()) < 1e-6, errs
+
+
 @pytest.mark.parametrize("length,block,bq,bkv", [(256, 4, 64, 64),
                                                  (256, 32, 64, 128),
-                                                 (512, 4, 128, 64)])
+                                                 (512, 4, 128, 64),
+                                                 (512, 4, 32, 32),
+                                                 (512, 4, 32, 64),
+                                                 (512, 32, 64, 32),
+                                                 (2048, 4, 512, 512)])
 def test_tiles_computed_are_the_tiles_with_a_visible_pair(length, block, bq,
                                                           bkv):
     """The kernels compute a (q tile, kv tile) iff `_block_has_work` and the
     row's `_kv_jmax` clamp admit it (every kernel's `live`): over the three
     quadrants that is exactly the tiles of the rule's mask that hold a
-    visible pair, and no tile of the empty quadrant."""
+    visible pair, and no tile of the empty quadrant.  The block-diagonal
+    quadrant's band grids (the forward's `band_nb` kv steps a q tile, the
+    backward's `nbq` q steps a kv tile) step over every one of its live
+    tiles, in a small multiple of their number of steps."""
     rule = masks.bd_dense_mask(2 * length, block)
     nq, nk = length // bq, length // bkv
     i, j = np.indices((nq, nk))
@@ -168,6 +292,22 @@ def test_tiles_computed_are_the_tiles_with_a_visible_pair(length, block, bq,
             spec, i * bq, j * bkv, bq, bkv, quad.window)) & (
             i >= np.asarray(pf._q_imin(spec, j, bq, bkv, nq, quad.window)))
         assert (live == live_bwd).all()
+        unit, win = masks.unit_of(quad.window)
+        if win is not None:
+            nb = min(nk, pf.fwd_band_nb(bq // unit, bkv // unit, win))
+            first = np.asarray(pf._kv_jmin(spec, i, bq, bkv, nk, quad.window))
+            assert (live <= ((j >= first) & (j < first + nb))).all()
+            nbq = pf.bwd_band_nbq(bq, bkv, nq, quad.window)
+            swept = np.zeros_like(live)
+            for c in range(nbq):
+                iq, clamped = pf._bwd_fused_iq(spec, j[0], c, bq, bkv, nq,
+                                               quad.window)
+                swept[np.asarray(iq)[~np.asarray(clamped)],
+                      j[0][~np.asarray(clamped)]] = True
+            assert (live <= swept).all()
+            # steps of the two band grids against the live tiles
+            assert nq * nb <= 3 * live.sum() and nk * nbq <= 3 * live.sum()
+            assert (nb < nk and nbq < nq) or min(nq, nk) < 4
         q0, k0 = quad.q_range[0] // bq, quad.kv_range[0] // bkv
         computed[q0:q0 + nq, k0:k0 + nk] = live
     visible = rule.reshape(2 * nq, bq, 2 * nk, bkv).any(axis=(1, 3))
@@ -203,8 +343,13 @@ def test_mask_blocks_must_divide_the_tiles():
 
 
 def _digest(fn, *args):
-    return hashlib.sha256(
-        str(jax.make_jaxpr(fn)(*args)).encode()).hexdigest()[:16]
+    """Of the jaxpr as the parent of PR 27 printed it: since PR 29 a call on
+    a band grid runs under a name of its own, the one thing that PR changed
+    in such a call (test_band_grids_run_under_their_own_names)."""
+    text = str(jax.make_jaxpr(fn)(*args))
+    text = text.replace("burst_flash_fwd_band", "burst_flash_fwd").replace(
+        "burst_flash_bwd_band", "burst_flash_bwd_rect")
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
 # sha256[:16] of str(make_jaxpr(...)) of calls with NO block mask, taken on
@@ -262,6 +407,19 @@ def _plain_calls(window=lambda w: w):
 def test_a_call_with_no_block_mask_traces_the_parent_s_jaxpr(name):
     fn, args = _plain_calls()[name]
     assert _digest(fn, *args) == PARENT_JAXPRS[name]
+
+
+@pytest.mark.parametrize("name,kernel", [
+    ("fwd_tri", "burst_flash_fwd"), ("fwd_window", "burst_flash_fwd_band"),
+    ("bwd_rect_carry", "burst_flash_bwd_rect"),
+    ("bwd_rect_window", "burst_flash_bwd_band"),
+    ("bwd_tri", "burst_flash_bwd_tri")])
+def test_band_grids_run_under_their_own_names(name, kernel):
+    """`flash_ms_per_step` sums `burst_flash_*`; a trace lists a windowed
+    call's time beside the others' under `_band`."""
+    fn, args = _plain_calls()[name]
+    assert set(re.findall(r"burst_flash_\w+",
+                          str(jax.make_jaxpr(fn)(*args)))) == {kernel}
 
 
 @pytest.mark.parametrize("name", ["fwd_carry_range", "fwd_window",

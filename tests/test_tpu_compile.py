@@ -211,17 +211,48 @@ def test_ring_rounds_write_into_their_carries_at_the_cell_length(topo,
     assert _device_bytes(c) < (7.52 - 1.0) * 2 ** 30
 
 
-@pytest.mark.parametrize("kw,bwd", [
-    (dict(window=4096, layout="contig"), "burst_flash_bwd_rect"),
-    (dict(kv_heads=4), "burst_flash_bwd_rect"),
+@pytest.mark.parametrize("kw,kernels", [
+    (dict(window=4096, layout="contig"),
+     ["burst_flash_bwd_band", "burst_flash_fwd_band"]),
+    (dict(kv_heads=4), ["burst_flash_bwd_rect", "burst_flash_fwd"]),
 ], ids=["window4k_band_grid", "gqa_32q_4kv"])
-def test_grad_variants_one_chip_64k(topo, on_chip, kw, bwd):
-    """The band grids and a GQA group of 8 at 64K: neither admits the
-    triangular backward, both take the rectangular fused kernel."""
+def test_grad_variants_one_chip_64k(topo, on_chip, kw, kernels):
+    """The band grids (under their own names, in the row's tiles: a window
+    of 4096 is no narrower than they) and a GQA group of 8 at 64K: neither
+    admits the triangular backward, both take the rectangular fused kernel."""
     c = _compile_attn_grad(_seq_mesh(topo, 1), seq=65536, **kw)
     text = c.as_text()
     assert _mosaic_calls(text) == 2
-    assert _kernels(text) == [bwd, "burst_flash_fwd"]
+    assert _kernels(text) == kernels
+
+
+def test_grad_block_diffusion_at_the_cell_geometry(topo, on_chip):
+    """`train_sdar_bd_1x8k`'s attention (a stream of 2 x 8,192 rows, 32 / 4
+    heads x 128, blocks of 4) forward and backward: the block-diagonal
+    quadrant's two calls are the band-grid kernels, in the tiles
+    ops/tuning.call_row gives a band of 4 tokens, and the program holds one
+    call a live quadrant and pass: none over the empty quadrant."""
+    sharding = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((1, 32, 16384, 128), jnp.bfloat16,
+                             sharding=sharding)
+    kv = jax.ShapeDtypeStruct((1, 4, 16384, 128), jnp.bfloat16,
+                              sharding=sharding)
+    mesh = _seq_mesh(topo, 1)
+
+    def loss(q, k, v):
+        return jnp.sum(bat.burst_attn(q, k, v, mesh=mesh, backend="auto",
+                                      block_diffusion=4).astype(
+                                          jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile(
+        ).as_text()
+    assert _kernels(text) == ["burst_flash_bwd_band", "burst_flash_bwd_rect",
+                              "burst_flash_fwd", "burst_flash_fwd_band"]
+    assert _mosaic_calls(text) == 6
+    calls = re.findall(r"%(burst_flash_\w+?)(?:\.\d+)? = ", text)
+    assert sorted(calls) == ["burst_flash_bwd_band"] + [
+        "burst_flash_bwd_rect"] * 2 + ["burst_flash_fwd"] * 2 + [
+        "burst_flash_fwd_band"]
 
 
 @pytest.mark.parametrize("packed", [False, True], ids=["plain", "packed"])
