@@ -34,11 +34,10 @@ def main(argv=None) -> int:
     ap.add_argument("--list-rules", action="store_true",
                     help="print registered rules and exit")
     ap.add_argument("--cost-json", action="store_true",
-                    help="print the burstcost static resource/roofline "
-                         "table (schema burstcost-v2) as JSON and exit: "
-                         "the full tuning-table x topology x wire-dtype x "
-                         "pass matrix the autotuner prunes on and "
-                         "fleet/sim.py prices replicas with")
+                    help="print the burstcost roofline table (schema "
+                         "burstcost-v3) as JSON and exit: the generation x "
+                         "topology x wire-dtype x pass matrix fleet/sim.py "
+                         "prices replicas with")
     args = ap.parse_args(argv)
 
     # the jaxpr family needs 8 simulated devices and must never grab a TPU:

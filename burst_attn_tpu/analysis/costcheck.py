@@ -1,17 +1,10 @@
 """cost-*: the static resource & roofline verifier (burstlint family 4).
 
 Three rules back onto burstcost (analysis/costmodel.py), which prices
-every fused-kernel config with no device in hand:
+with no device in hand:
 
-  kernel-vmem-budget     every tuning-table row x {uni, bidi, double} x
-                         {fp32, int8, fp8} x {fwd, bwd} config fits: the
-                         dispatch gate's plan stays within the row's
-                         fused_vmem_budget at the canonical shape, the
-                         FULL kernel scratch inventory stays within the
-                         Mosaic VMEM_LIMIT at the LARGEST shard the gate
-                         admits (admitted ⟹ compiles), the semaphore
-                         census stays under its tripwires, and every
-                         ragged-paged serving shape fits its plan
+  kernel-vmem-budget     every ragged-paged serving shape fits its VMEM
+                         plan under the Mosaic VMEM_LIMIT
   cost-model-consistent  the roofline's inputs agree with production
                          counters: closed-form pass pairs == the devstats
                          per-round pair algebra summed over the compiled
@@ -19,31 +12,23 @@ every fused-kernel config with no device in hand:
                          the model's independent stream-bytes derivation
                          == schedule.wire_round_bytes (the single source
                          the burst.wire_bytes counter integrates) over
-                         pass x wire x opt_comm x itemsize; measured TPU
-                         rows in results/ring_overlap.jsonl must sit
-                         within the calibration band of the model's floor
+                         pass x wire x opt_comm x itemsize
   tuning-table-sound     the raw tables obey the invariants dispatch
                          assumes: bwd blocks never resolve larger than
                          fwd, cliff clamps are monotone and in-budget,
-                         slots >= 2, wire dtypes legal, budgets within
-                         the Mosaic limit, blocks lane-aligned, aliases
-                         resolve, and later generations never shrink the
-                         v5e-measured cliff areas
+                         blocks lane-aligned, aliases resolve, and later
+                         generations never shrink the v5e-measured cliff
+                         areas
 
 The checks compute through the SAME resolution algebra production
-dispatch runs (tuning.resolve_fused(table=row), sched.compile_fwd/bwd,
-ops gates' formulas re-derived) so they watch real code, not a spec that
-can drift.  The gate prices the full 90-config matrix — pure host
-arithmetic, well under a second.  tests/test_costmodel.py re-runs deep
-per-generation shape sweeps under @slow with a fast canary.  Mutation
-coverage (tests/test_analysis.py): an inflated slot plan with an
-unchanged budget, a window-blind pair function, and a fwd<bwd table
-inversion each fire exactly one rule.
+dispatch runs (tuning.resolve_blocks(table=row), sched.compile_fwd/bwd)
+so they watch real code, not a spec that can drift.  Pure host
+arithmetic, well under a second.  Mutation coverage
+(tests/test_analysis.py): a window-blind pair function and a fwd<bwd
+table inversion each fire exactly one rule.
 """
 
-import json
-import os
-from typing import List, Optional
+from typing import List
 
 from .core import Finding, rule
 from . import costmodel as cm
@@ -51,27 +36,16 @@ from ..ops import tuning
 from ..parallel import schedule as sched
 
 rule("kernel-vmem-budget", "cost",
-     "every tuning-table x topology x wire-dtype x pass config fits its "
-     "generation's VMEM budget, and the full kernel scratch inventory "
-     "fits the Mosaic limit at the largest gate-admitted shard")(None)
+     "every ragged-paged serving shape's VMEM plan fits the Mosaic "
+     "limit")(None)
 rule("cost-model-consistent", "cost",
      "roofline FLOPs == devstats pair algebra over compiled programs "
      "(incl. elided rounds); model stream bytes == wire_round_bytes (the "
-     "burst.wire_bytes formula); measured TPU floors within band")(None)
+     "burst.wire_bytes formula)")(None)
 rule("tuning-table-sound", "cost",
      "tuning tables obey dispatch's invariants: bwd blocks <= fwd, "
-     "cliff clamps monotone + in-budget, slots/wire/alignment legal, "
-     "budgets within the Mosaic limit, aliases resolve")(None)
-
-_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-_OVERLAP_JSONL = os.path.join(_ROOT, "results", "ring_overlap.jsonl")
-
-# calibration band for measured TPU floors: the model's t_comm floor is a
-# spec-sheet lower bound, so a measured comm-only time should not beat it
-# by more than 2x (model overestimates -> autotuner mispruning) nor exceed
-# it by more than 20x (model wildly optimistic -> floors meaningless)
-_CALIB_FAST, _CALIB_SLOW = 0.5, 20.0
+     "cliff clamps monotone + in-budget, blocks lane-aligned, aliases "
+     "resolve")(None)
 
 # the small consistency mesh: exact identities are shape-independent, so
 # the gate proves them at a cheap shard size on the canonical 8-ring
@@ -84,10 +58,7 @@ def _anchor(which: str):
     import inspect
 
     try:
-        if which == "gate":
-            from ..ops import fused_ring
-            fn = fused_ring.supported
-        elif which == "ragged":
+        if which == "ragged":
             from ..ops import ragged_paged
             fn = ragged_paged.ragged_supported
         elif which == "wire":
@@ -106,84 +77,18 @@ def _anchor(which: str):
 # kernel-vmem-budget
 
 
-def check_vmem_budget(table=None, world: int = cm.DEFAULT_WORLD,
-                      shape: Optional[dict] = None) -> List[Finding]:
-    """Prove the full config matrix within budget.  `table` narrows the
-    sweep to one injected BlockTable row (the mutation seam — an inflated
-    row with an unchanged budget must fire); default sweeps every
-    generation."""
+def check_vmem_budget() -> List[Finding]:
+    """Every serving shape of the ragged matrix within the Mosaic limit."""
     findings: List[Finding] = []
-    shp = dict(cm.DEFAULT_SHAPE if shape is None else shape)
-    b, n, n_kv, s, d = (shp[k] for k in ("b", "n", "n_kv", "s", "d"))
-    gens = ((("<injected>", table),) if table is not None
-            else tuple((g, tuning.generation_row(g))
-                       for g in tuning.generations()))
-    gate_f, gate_ln = _anchor("gate")
-    for gen, row in gens:
-        for wire in sched.WIRE_DTYPES:
-            rf = tuning.resolve_fused(table=row, wire_dtype=wire)
-            for topo in sched.TOPOLOGIES:
-                for pass_ in cm.PASSES:
-                    program = cm.compile_program(pass_, topo, world, rf)
-                    pl = cm.plan(pass_, rf, program, b=b, n=n, n_kv=n_kv,
-                                 s=s, d=d)
-                    ctx = (f"{gen}/{topo}/{wire or 'fp32'}/{pass_}")
-                    if pl.gate_bytes > rf.vmem_budget:
-                        findings.append(Finding(
-                            rule="kernel-vmem-budget",
-                            message=(f"{ctx}: gate plan {pl.gate_bytes} B "
-                                     f"exceeds fused_vmem_budget "
-                                     f"{rf.vmem_budget} B at the canonical "
-                                     f"shape (s={s}) — the dispatch gate "
-                                     "would reject its own generation"),
-                            file=gate_f, line=gate_ln))
-                    if pl.vmem_bytes > cm.VMEM_LIMIT:
-                        findings.append(Finding(
-                            rule="kernel-vmem-budget",
-                            message=(f"{ctx}: full scratch inventory "
-                                     f"{pl.vmem_bytes} B exceeds the Mosaic "
-                                     f"VMEM_LIMIT {cm.VMEM_LIMIT} B at the "
-                                     f"canonical shape (s={s})"),
-                            file=gate_f, line=gate_ln))
-                    s_max = cm.max_admitted_shard(pass_, rf, b=b, n=n, d=d)
-                    if s_max:
-                        pl_max = cm.plan(pass_, rf, program, b=b, n=n,
-                                         n_kv=n_kv, s=s_max, d=d)
-                        if pl_max.vmem_bytes > cm.VMEM_LIMIT:
-                            findings.append(Finding(
-                                rule="kernel-vmem-budget",
-                                message=(f"{ctx}: the gate ADMITS shard "
-                                         f"s={s_max} but the full scratch "
-                                         f"inventory there is "
-                                         f"{pl_max.vmem_bytes} B > "
-                                         f"VMEM_LIMIT {cm.VMEM_LIMIT} B — "
-                                         "admitted config would fail to "
-                                         "compile; tighten the budget or "
-                                         "the gate formula"),
-                                file=gate_f, line=gate_ln))
-                    if (pl.sem_dma > cm.SEM_DMA_BUDGET
-                            or pl.sem_regular > cm.SEM_REGULAR_BUDGET):
-                        findings.append(Finding(
-                            rule="kernel-vmem-budget",
-                            message=(f"{ctx}: semaphore census "
-                                     f"(dma={pl.sem_dma}, "
-                                     f"regular={pl.sem_regular}) exceeds "
-                                     f"the tripwire "
-                                     f"({cm.SEM_DMA_BUDGET}/"
-                                     f"{cm.SEM_REGULAR_BUDGET}) — an "
-                                     "unintended per-slot array grew the "
-                                     "schedule"),
-                            file=gate_f, line=gate_ln))
     rag_f, rag_ln = _anchor("ragged")
-    if table is None:
-        for cfgr in cm.RAGGED_MATRIX:
-            pb = cm.ragged_plan_bytes(**cfgr)
-            if pb > cm.VMEM_LIMIT:
-                findings.append(Finding(
-                    rule="kernel-vmem-budget",
-                    message=(f"ragged {cfgr}: plan {pb} B exceeds "
-                             f"VMEM_LIMIT {cm.VMEM_LIMIT} B"),
-                    file=rag_f, line=rag_ln))
+    for cfgr in cm.RAGGED_MATRIX:
+        pb = cm.ragged_plan_bytes(**cfgr)
+        if pb > cm.VMEM_LIMIT:
+            findings.append(Finding(
+                rule="kernel-vmem-budget",
+                message=(f"ragged {cfgr}: plan {pb} B exceeds "
+                         f"VMEM_LIMIT {cm.VMEM_LIMIT} B"),
+                file=rag_f, line=rag_ln))
     return findings
 
 
@@ -191,16 +96,13 @@ def check_vmem_budget(table=None, world: int = cm.DEFAULT_WORLD,
 # cost-model-consistent
 
 
-def check_cost_consistency(pair_fn=None,
-                           overlap_path: str = _OVERLAP_JSONL
-                           ) -> List[Finding]:
+def check_cost_consistency(pair_fn=None) -> List[Finding]:
     """Pin the roofline's inputs to production counters.  `pair_fn`
     substitutes the devstats per-round pair twin (the mutation seam — a
     window-blind variant must fire); default is the production twin."""
     findings: List[Finding] = []
     s, world = _CONSIST_S, _CONSIST_WORLD
     pairs_f, pairs_ln = _anchor("pairs")
-    rf = tuning.resolve_fused(table=tuning.generation_row("default"))
     cases = [(layout, topo, True, None)
              for layout in ("zigzag", "striped", "contig")
              for topo in sched.TOPOLOGIES]
@@ -213,7 +115,7 @@ def check_cost_consistency(pair_fn=None,
             rl = live_round_prefix(layout, s, world, causal=causal,
                                    window=window)
             r_live = rl if rl < world else None
-        program = cm.compile_program("fwd", topo, world, rf, r_live=r_live)
+        program = cm.compile_program("fwd", topo, world, r_live=r_live)
         closed = cm.pass_pairs(layout, s, world, causal=causal,
                                window=window)
         summed = cm.devstats_pass_pairs(program, layout, s, causal=causal,
@@ -248,60 +150,6 @@ def check_cost_consistency(pair_fn=None,
                                      f"burst.wire_bytes formula) says "
                                      f"{theirs}"),
                             file=wire_f, line=wire_ln))
-    findings.extend(_check_measured_floors(overlap_path))
-    return findings
-
-
-def _check_measured_floors(path: str) -> List[Finding]:
-    """Calibrate against measured TPU rows of results/ring_overlap.jsonl:
-    a comm-only measurement outside [0.5x, 20x] of the model's floor means
-    the HW table (or the hop model) is wrong enough to misprune.  CPU
-    smoke rows are skipped — interpret-mode timing calibrates nothing."""
-    findings: List[Finding] = []
-    if not os.path.exists(path):
-        return findings
-    wire_f, wire_ln = _anchor("wire")
-    with open(path, encoding="utf-8") as f:
-        for lineno, raw in enumerate(f, 1):
-            raw = raw.strip()
-            if not raw:
-                continue
-            try:
-                row = json.loads(raw)
-            except ValueError:
-                continue
-            if row.get("backend") != "tpu":
-                continue
-            meas = row.get("t_comm_only_s")
-            if not meas or row.get("pass") not in cm.PASSES:
-                continue
-            try:
-                t_comm, _ = cm.predict_floors(
-                    row["pass"], b=1, n=int(row["heads"]),
-                    n_kv=int(row["heads"]),
-                    s=int(row["seq"]) // int(row["world"]),
-                    d=int(row["dim"]), world=int(row["world"]),
-                    topology=row.get("topology", "uni"),
-                    generation="v5e", wire=row.get("wire_dtype"),
-                    layout=row.get("layout", "zigzag"),
-                    causal=bool(row.get("causal", True)),
-                    window=row.get("window"))
-            except (KeyError, TypeError, ValueError):
-                continue
-            if t_comm <= 0:
-                continue
-            ratio = float(meas) / t_comm
-            if not (_CALIB_FAST <= ratio <= _CALIB_SLOW):
-                findings.append(Finding(
-                    rule="cost-model-consistent",
-                    message=(f"ring_overlap.jsonl:{lineno}: measured TPU "
-                             f"comm-only {meas:.6f}s is {ratio:.2f}x the "
-                             f"model floor {t_comm:.6f}s (band "
-                             f"[{_CALIB_FAST}, {_CALIB_SLOW}]) for "
-                             f"{row.get('pass')}/{row.get('topology')} "
-                             f"seq={row.get('seq')} — recalibrate "
-                             "costmodel.HW"),
-                    file=wire_f, line=wire_ln))
     return findings
 
 
@@ -324,47 +172,23 @@ def check_tuning_sound(table=None) -> List[Finding]:
             else tuple((g, tuning.generation_row(g))
                        for g in tuning.generations()))
     for gen, row in gens:
-        # raw bwd fields never exceed their fwd partner: resolve_fused
-        # min()-clamps bwd blocks against fwd, so an inverted RAW entry is
-        # silently ignored dead weight — exactly the drift this rule exists
-        # to catch before a tuner trusts the number
-        for bwd_field, fwd_field in (
-                ("fused_block_q_bwd", "fused_block_q"),
-                ("fused_block_kv_bwd", "fused_block_kv"),
-                ("bwd_cliff_area", "fwd_cliff_area")):
-            bv, fv = getattr(row, bwd_field), getattr(row, fwd_field)
-            if bv > fv:
-                bad(f"{gen}: {bwd_field}={bv} > {fwd_field}={fv} — the "
-                    "bwd pass tiles a strict subset of fwd VMEM; an "
-                    "inverted raw entry is dead weight resolve_fused "
-                    "silently clamps away")
-        # resolved view agrees (resolve through the SAME algebra dispatch
-        # runs): bwd blocks never resolve larger than fwd
-        for wire in sched.WIRE_DTYPES:
-            rf = tuning.resolve_fused(table=row, wire_dtype=wire)
-            if rf.block_q_bwd > rf.block_q or rf.block_kv_bwd > rf.block_kv:
-                bad(f"{gen}/{wire or 'fp32'}: resolved bwd blocks "
-                    f"({rf.block_q_bwd},{rf.block_kv_bwd}) exceed fwd "
-                    f"({rf.block_q},{rf.block_kv})")
-            for slot_field in ("kv_slots", "ccw_slots", "bwd_slots",
-                               "bwd_ccw_slots"):
-                if getattr(rf, slot_field) < 2:
-                    bad(f"{gen}/{wire or 'fp32'}: {slot_field}="
-                        f"{getattr(rf, slot_field)} < 2 — the ring "
-                        "needs a landing slot while one is in flight")
-        if row.fused_wire_dtype not in sched.WIRE_DTYPES:
-            bad(f"{gen}: fused_wire_dtype={row.fused_wire_dtype!r} not in "
-                f"{sched.WIRE_DTYPES}")
-        if row.fused_vmem_budget > cm.VMEM_LIMIT:
-            bad(f"{gen}: fused_vmem_budget={row.fused_vmem_budget} exceeds "
-                f"the Mosaic VMEM_LIMIT {cm.VMEM_LIMIT} — the gate would "
-                "admit configs Mosaic rejects")
+        # the raw bwd cliff area never exceeds the fwd one: the bwd step
+        # keeps more live per block pair, so its cliff cannot sit higher
+        if row.bwd_cliff_area > row.fwd_cliff_area:
+            bad(f"{gen}: bwd_cliff_area={row.bwd_cliff_area} > "
+                f"fwd_cliff_area={row.fwd_cliff_area} — the bwd pass "
+                "keeps more of a block pair live than the fwd")
+        # resolved view (the SAME algebra dispatch runs): bwd blocks never
+        # resolve larger than fwd
+        rb = tuning.resolve_blocks(table=row)
+        if rb.block_q_bwd > rb.block_q or rb.block_kv_bwd > rb.block_kv:
+            bad(f"{gen}: resolved bwd blocks "
+                f"({rb.block_q_bwd},{rb.block_kv_bwd}) exceed fwd "
+                f"({rb.block_q},{rb.block_kv})")
         for field in ("fwd_block_q", "fwd_block_kv", "fwd_block_kv_compute",
-                      "bwd_block_q", "bwd_block_kv", "fused_block_q",
-                      "fused_block_kv", "fused_block_q_bwd",
-                      "fused_block_kv_bwd"):
+                      "bwd_block_q", "bwd_block_kv", "band_block"):
             v = getattr(row, field)
-            if v <= 0 or v % 128:
+            if v is not None and (v <= 0 or v % 128):
                 bad(f"{gen}: {field}={v} is not a positive multiple of "
                     "the 128-lane tile")
         # cliff clamp monotone: never grows kv, never exceeds the area
@@ -372,7 +196,7 @@ def check_tuning_sound(table=None) -> List[Finding]:
         # result.  Probed at areas below AND above the blocks' product so
         # the clamping branch itself is exercised; the tuning logger is
         # muted around the probes (the warning is for real dispatches).
-        bq, bkv = row.fused_block_q_bwd, row.fused_block_kv_bwd
+        bq, bkv = row.bwd_block_q, row.bwd_block_kv
         prev = 0
         if tuning._cliff_ok():
             continue  # BURST_ALLOW_CLIFF=1: the clamp is deliberately off

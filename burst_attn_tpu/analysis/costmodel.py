@@ -1,19 +1,9 @@
-"""burstcost: static resource plans + analytic roofline for the ring kernels.
+"""burstcost: analytic roofline for the ring, static plans for the ragged
+serving kernel.
 
-Every knob in ops/tuning.py is hand-entered, and until this module the only
-proof that a (generation, topology, wire-dtype, pass) config actually fits
-its VMEM budget was an on-device Mosaic allocation failure.  Following the
-IO-aware analyses of FlashAttention (arXiv 2205.14135) and the CUTLASS case
-study (arXiv 2312.11918), the tile shapes and traffic are all statically
-derivable, so this module computes — with no device in hand —
-
-  * a VMEM/slot/semaphore PLAN per fused fwd, fused bwd and ragged-paged
-    config, mirroring (a) the dispatch gates' admission formulas
-    (ops/fused_ring.supported, ops/ragged_paged.ragged_supported) and
-    (b) the kernels' full scratch_shapes inventories, so burstlint can
-    prove at lint time that any shard a gate ADMITS also COMPILES
-    (full plan <= the Mosaic VMEM_LIMIT) across the whole tuning-table x
-    {uni, bidi, double} x {fp32, int8, fp8} x {fwd, bwd} matrix;
+Following the IO-aware analyses of FlashAttention (arXiv 2205.14135) and the
+CUTLASS case study (arXiv 2312.11918), the traffic of a ring pass is
+statically derivable, so this module computes — with no device in hand —
 
   * an analytic ROOFLINE cost model: FLOPs from the masks.spec_pair_count
     closed forms (elided rounds contribute exactly zero — the identity the
@@ -21,16 +11,16 @@ derivable, so this module computes — with no device in hand —
     ICI bytes from schedule.wire_round_bytes times the compiled program's
     send census, HBM bytes from the block plans — exported as a machine-
     readable table (python -m burst_attn_tpu.analysis --cost-json) that
-    the autotuner (ROADMAP item 1) consumes to prune infeasible/dominated
-    configs and fleet/sim.py consumes as its replica cost function.
+    fleet/sim.py consumes as its replica cost function;
 
-Cross-validation story (analysis/costcheck.py runs all three at lint time):
+  * the VMEM plan of the ragged-paged serving kernel, mirroring its
+    dispatch gate (ops/ragged_paged.ragged_supported), and the per-pool-
+    dtype decode HBM pricing.
+
+Cross-validation story (analysis/costcheck.py runs both at lint time):
 against the devstats pair/flop counters (closed form == per-round sum over
-the compiled program), against the burst.wire_bytes counter formula
-(stream_bytes == schedule.wire_round_bytes, the single derivation), and
-against measured results/ring_overlap.jsonl floors where TPU rows exist
-(the benchmark records t_comm_pred_s/t_compute_pred_s per row via
-predict_floors, so every future TPU window calibrates HW for free).
+the compiled program) and against the burst.wire_bytes counter formula
+(stream_bytes == schedule.wire_round_bytes, the single derivation).
 
 Everything here is host-side integer/float arithmetic over compiled
 RingPrograms — no tracing, no devices; safe in the burstlint gate.
@@ -40,7 +30,7 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 
 from ..ops import tuning
 from ..ops.masks import _host_round_pairs, live_round_prefix
-from ..ops.pallas_flash import VMEM_LIMIT, _pick_block
+from ..ops.pallas_flash import VMEM_LIMIT
 from ..ops.ragged_paged import _block_rows
 from ..parallel import schedule as sched
 
@@ -51,8 +41,7 @@ from ..parallel import schedule as sched
 # benchmarks/train_smoke.PEAK_BF16 — pinned by tests/test_costmodel.py and
 # the cost-model-consistent rule); hbm_bw is the published HBM bandwidth;
 # ici_bw is the usable ONE-DIRECTION bandwidth of a single ring link —
-# spec-sheet derived and calibration-pending until ring_overlap.jsonl
-# carries TPU rows (pred_ratio on every row tracks the correction factor).
+# spec-sheet derived, not calibrated against a measured ring.
 class HwSpec(NamedTuple):
     peak_flops: float  # dense bf16 FLOPs/s per chip
     hbm_bw: float      # HBM bytes/s per chip
@@ -69,16 +58,9 @@ HW: Dict[str, HwSpec] = {
     "default": HwSpec(197e12, 819e9, 45e9),
 }
 
-# Semaphore tripwires: Mosaic semaphores are cheap SMEM words, but a
-# schedule whose semaphore census grows past these bounds has almost
-# certainly gained an unintended per-slot or per-bank array — the plan
-# counts them so a regression is a lint finding, not an on-device surprise.
-SEM_DMA_BUDGET = 128
-SEM_REGULAR_BUDGET = 64
-
 # canonical 8-device benchmark shape class (bench.py headline: seq=65536 on
 # an 8-ring, 32 heads, d=128 -> per-shard s=8192); the cost table prices
-# every config at this shape AND at the largest shard its gate admits
+# every config at this shape
 DEFAULT_SHAPE = dict(b=1, n=32, n_kv=32, s=8192, d=128)
 DEFAULT_WORLD = 8
 PASSES = ("fwd", "bwd")
@@ -91,8 +73,8 @@ def _hw(generation: str) -> HwSpec:
 
 
 def _factor(world: int) -> Tuple[int, int]:
-    """(n_inter, n_intra) the double ring factors a flat world into —
-    benchmarks/ring_overlap.py's factorization (smallest n_inter >= 2)."""
+    """(n_inter, n_intra) the double ring factors a flat world into
+    (smallest n_inter >= 2)."""
     n_i = 2
     while world % n_i or (world // n_i) < 2:
         n_i += 1
@@ -102,184 +84,17 @@ def _factor(world: int) -> Tuple[int, int]:
 
 
 def compile_program(pass_: str, topology: str, world: int,
-                    rf: tuning.ResolvedFused,
+                    wire: Optional[str] = None,
                     r_live: Optional[int] = None) -> sched.RingProgram:
-    """The RingProgram the fused dispatch would run for this config —
-    same compiler entry, same slot/wire plumbing (ops/fused_ring._compile_for
-    without a cfg object)."""
+    """The RingProgram of this config, at the schedule compiler's own slot
+    defaults."""
     n_inter, n_intra = (1, world) if topology != "double" else _factor(world)
-    if pass_ == "fwd":
-        return sched.compile_fwd(topology, n_intra, n_inter,
-                                 slots=rf.kv_slots, slots1=rf.ccw_slots,
-                                 r_live=r_live, wire=rf.wire_dtype)
-    return sched.compile_bwd(topology, n_intra, n_inter,
-                             slots=rf.bwd_slots, slots1=rf.bwd_ccw_slots,
-                             dq_slots=rf.bwd_slots, r_live=r_live,
-                             wire=rf.wire_dtype)
+    compiler = sched.compile_fwd if pass_ == "fwd" else sched.compile_bwd
+    return compiler(topology, n_intra, n_inter, r_live=r_live, wire=wire)
 
 
 # ---------------------------------------------------------------------------
-# VMEM / slot / semaphore plans (the kernel inventories, priced statically)
-
-
-class ResourcePlan(NamedTuple):
-    """Static resource footprint of one fused kernel launch.
-
-    gate_bytes  the dispatch gate's admission formula (supported()'s plan)
-    vmem_bytes  the full VMEM-space scratch + block-window inventory the
-                kernel's pallas_call declares (mirrors scratch_shapes)
-    slot_bytes  the ANY-space rotating slot banks (HBM-resident payload +
-                scale + accumulator staging)
-    sem_dma / sem_regular  semaphore census of the launch
-    """
-
-    gate_bytes: int
-    vmem_bytes: int
-    slot_bytes: int
-    sem_dma: int
-    sem_regular: int
-
-
-def fwd_gate_bytes(rf: tuning.ResolvedFused, *, b: int, n: int, s: int,
-                   d: int) -> int:
-    """ops/fused_ring.supported's forward VMEM plan, re-derived: resident
-    k+v chunk (wire itemsize), packed m/l stats, acc staging."""
-    bq = _pick_block(s, rf.block_q)
-    return 2 * s * d * rf.wire_itemsize + 2 * b * n * s * 4 + 3 * bq * d * 4
-
-
-def bwd_gate_bytes(rf: tuning.ResolvedFused, *, s: int, d: int) -> int:
-    """ops/fused_ring.supported's backward VMEM plan, re-derived: resident
-    k+v chunk, fp32 dk/dv accumulators, per-step bundle + dq tiles."""
-    bqb = _pick_block(s, rf.block_q_bwd)
-    return (2 * s * d * 4 + 2 * s * d * 4
-            + 3 * bqb * d * rf.wire_itemsize + 4 * bqb * d * 4)
-
-
-def fwd_plan(rf: tuning.ResolvedFused, program: sched.RingProgram, *,
-             b: int, n: int, n_kv: int, s: int, d: int,
-             itemsize: int = 4) -> ResourcePlan:
-    """Full static plan of one fused forward launch (ops/fused_ring.py's
-    scratch_shapes + block windows, priced in bytes)."""
-    wi = rf.wire_itemsize
-    bq = _pick_block(s, rf.block_q)
-    quant = program.wire is not None
-    # ANY space: per-bank payload slot banks (+ fp32 scale sub-banks when
-    # quantized) and the accbuf carry
-    slot = 0
-    for bank_slots in program.slots:
-        slot += bank_slots * 2 * b * n_kv * s * d * wi
-        if quant:
-            slot += bank_slots * 2 * b * n_kv * 4
-    slot += b * n * s * d * 4                      # accbuf (fp32 carry)
-    # VMEM space: resident chunk, packed stats, acc tiles, block windows
-    vmem = (2 * s * d * wi                         # kchunk + vchunk
-            + (8 if quant else 0)                  # ksc_t + vsc_t
-            + 2 * b * n * s * 4                    # mstat + lstat
-            + 2 * bq * d * 4                       # acc_in + acc_scr
-            + 2 * bq * 4                           # m_sw + l_sw
-            + 2 * bq * d * itemsize                # q block window + o block
-            + b * n * s * 4)                       # lse output block
-    sem_dma = ((2 if not quant else 4) * len(program.copy_in)
-               + (2 if not quant else 4)           # chunk_sem
-               + 2)                                # acc_sem
-    sem_reg = 0
-    for bank_slots in program.slots:
-        sem_dma += 4 * bank_slots                  # k/v send+recv per slot
-        sem_reg += bank_slots                      # free credits
-    gate = fwd_gate_bytes(rf, b=b, n=n, s=s, d=d)
-    return ResourcePlan(gate, vmem, slot, sem_dma, sem_reg)
-
-
-def bwd_plan(rf: tuning.ResolvedFused, program: sched.RingProgram, *,
-             b: int, n: int, n_kv: int, s: int, d: int,
-             itemsize: int = 4, opt_comm: bool = True) -> ResourcePlan:
-    """Full static plan of one fused backward launch (ops/fused_ring_bwd.py's
-    scratch_shapes + block windows, priced in bytes).  The dq-bank home
-    slot is priced for EVERY dq ring bank (the kernel allocates it only on
-    banks that receive a home stream) — a deliberate upper bound: a budget
-    proof may over-count, never under-count."""
-    wi = rf.wire_itemsize
-    bqb = _pick_block(s, rf.block_q_bwd)
-    quant = program.wire is not None
-    dq_item = 1 if quant else 4
-    first_elems = b * n * s if opt_comm else b * n * s * d
-    slot = 0
-    for bank_slots in program.slots:
-        slot += bank_slots * (first_elems * wi       # firstbuf (delta | o)
-                              + 2 * b * n * s * d * wi  # dobuf + qbuf
-                              + b * n * s * 4)          # lsebuf (fp32)
-        if quant:
-            slot += bank_slots * 3 * b * n * 4         # f/do/q scale banks
-    dq_ring_banks = (program.n_dq_banks if program.topology != "double"
-                     else 1)
-    for bank in range(dq_ring_banks):
-        sl = program.dq_slots[bank] + 1                # +1 home slot bound
-        slot += sl * b * n * s * d * dq_item
-        if quant:
-            slot += sl * b * n * 4                     # dqscbuf
-    has_dqi = program.topology == "double"
-    if has_dqi:
-        slot += program.dq_slots[1] * b * n * s * d * dq_item
-        if quant:
-            slot += program.dq_slots[1] * b * n * 4    # dqiscbuf
-    vmem = (2 * s * d * 4                              # kchunk + vchunk
-            + 2 * s * d * 4                            # dk_acc + dv_acc
-            + 2 * bqb * d * wi                         # q_t + do_t
-            + (bqb * wi if opt_comm else bqb * d * wi)  # first_t
-            + bqb * 4                                  # lse_t
-            + 2 * bqb * d * dq_item                    # dq_arr + dqi_arr
-            + bqb * d * 4                              # dq_scr
-            + 2 * s * d * 4)                           # dk + dv out windows
-    if quant:
-        vmem += 5 * 4 + bqb * d * dq_item + 4          # scale tiles + dq_q
-    sem_dma = (8                                       # cp_sem bound
-               + 2 + 4                                 # chunk_sem + kvio_sem
-               + (7 if quant else 4)                   # tile_sem
-               + (6 if quant else 3))                  # dqio_sem
-    sem_reg = 0
-    for bank_slots in program.slots:
-        sem_dma += 2 * bank_slots                      # psend + precv
-        sem_reg += bank_slots                          # free_pay
-    for bank in range(dq_ring_banks):
-        sl = program.dq_slots[bank] + 1
-        sem_dma += 2 * sl                              # dqsend + dqrecv
-        sem_reg += sl                                  # free_dq
-        sem_dma += 2                                   # home_sems
-    if has_dqi:
-        sem_dma += 2 * program.dq_slots[1]
-        sem_reg += program.dq_slots[1]
-    gate = bwd_gate_bytes(rf, s=s, d=d)
-    return ResourcePlan(gate, vmem, slot, sem_dma, sem_reg)
-
-
-def plan(pass_: str, rf: tuning.ResolvedFused, program: sched.RingProgram,
-         *, b: int, n: int, n_kv: int, s: int, d: int, itemsize: int = 4,
-         opt_comm: bool = True) -> ResourcePlan:
-    if pass_ == "fwd":
-        return fwd_plan(rf, program, b=b, n=n, n_kv=n_kv, s=s, d=d,
-                        itemsize=itemsize)
-    if pass_ != "bwd":
-        raise ValueError(f"pass_ must be 'fwd' or 'bwd', got {pass_!r}")
-    return bwd_plan(rf, program, b=b, n=n, n_kv=n_kv, s=s, d=d,
-                    itemsize=itemsize, opt_comm=opt_comm)
-
-
-def max_admitted_shard(pass_: str, rf: tuning.ResolvedFused, *, b: int,
-                       n: int, d: int, cap: int = 1 << 22) -> int:
-    """Largest power-of-two per-shard s the dispatch gate admits against
-    this generation's fused_vmem_budget — the shard the budget-soundness
-    theorem must prove compiles (kernel-vmem-budget checks the FULL plan
-    at this s stays under the Mosaic VMEM_LIMIT)."""
-    s, best = 256, 0
-    while s <= cap:
-        gate = (fwd_gate_bytes(rf, b=b, n=n, s=s, d=d) if pass_ == "fwd"
-                else bwd_gate_bytes(rf, s=s, d=d))
-        if gate > rf.vmem_budget:
-            break
-        best = s
-        s *= 2
-    return best
+# the ragged serving kernel's VMEM plan and decode HBM traffic
 
 
 def ragged_plan_bytes(*, d_head: int, page: int, group: int,
@@ -528,20 +343,16 @@ def predict_floors(pass_: str, *, b: int, n: int, n_kv: int, s: int, d: int,
                    opt_comm: bool = True,
                    itemsize: int = 4) -> Tuple[float, float]:
     """(t_comm_pred_s, t_compute_pred_s) — the static model's floors for
-    one measured ring config, what benchmarks/ring_overlap.py records
-    beside its measured floors so TPU rows calibrate HW for free.
-    generation=None resolves the running device's generation and falls
-    back to "v5e" off-TPU (the repo's measured hardware)."""
+    one ring config.  generation=None resolves the running device's
+    generation and falls back to "v5e" off-TPU (the repo's measured
+    hardware)."""
     if generation is None:
         generation = tuning.canonical_kind() or "v5e"
     r_live = None
     if window is not None and layout == "contig" and causal:
         rl = live_round_prefix(layout, s, world, causal=True, window=window)
         r_live = rl if rl < world else None
-    rf = tuning.resolve_fused(table=tuning.generation_row(
-        generation if generation in tuning.generations() else "default"),
-        wire_dtype=wire)
-    program = compile_program(pass_, topology, world, rf, r_live=r_live)
+    program = compile_program(pass_, topology, world, wire, r_live=r_live)
     est = roofline(pass_, generation if generation in HW else "default",
                    program, layout=layout, b=b, n=n, n_kv=n_kv, s=s, d=d,
                    causal=causal, window=window, opt_comm=opt_comm,
@@ -592,53 +403,26 @@ def predict_metric(metric: str) -> Optional[float]:
 
 def cost_table(world: int = DEFAULT_WORLD,
                shape: Optional[dict] = None) -> dict:
-    """The full tuning-table x topology x wire-dtype x pass matrix, one
-    machine-readable row per config: resolved knobs, static resource plan
-    (at the canonical shape AND the largest gate-admitted shard), roofline
-    estimates, and a `fits` verdict the autotuner prunes on.  Plus the
+    """The generation x topology x wire-dtype x pass matrix, one machine-
+    readable row per config with its roofline estimates.  Plus the
     ragged-paged serving plans and the per-pool-dtype decode HBM pricing
-    (`ragged_hbm`, new in v2).  Schema "burstcost-v2" is pinned by
-    tests/test_analysis.py."""
+    (`ragged_hbm`).  Schema "burstcost-v3" (v2 carried the VMEM plans of
+    ring kernels that are gone) is pinned by tests/test_analysis.py."""
     shp = dict(DEFAULT_SHAPE if shape is None else shape)
     b, n, n_kv, s, d = (shp[k] for k in ("b", "n", "n_kv", "s", "d"))
     rows: List[dict] = []
     for gen in tuning.generations():
-        table = tuning.generation_row(gen)
         for wire in sched.WIRE_DTYPES:
-            rf = tuning.resolve_fused(table=table, wire_dtype=wire)
             for topo in sched.TOPOLOGIES:
                 for pass_ in PASSES:
-                    program = compile_program(pass_, topo, world, rf)
-                    pl = plan(pass_, rf, program, b=b, n=n, n_kv=n_kv,
-                              s=s, d=d)
-                    s_max = max_admitted_shard(pass_, rf, b=b, n=n, d=d)
-                    prog_max = program
-                    pl_max = plan(pass_, rf, prog_max, b=b, n=n, n_kv=n_kv,
-                                  s=s_max, d=d)
+                    program = compile_program(pass_, topo, world, wire)
                     est = roofline(pass_, gen, program, layout="zigzag",
                                    b=b, n=n, n_kv=n_kv, s=s, d=d,
                                    causal=True)
                     rows.append({
                         "generation": gen, "topology": topo,
                         "wire": wire, "pass": pass_,
-                        "block_q": rf.block_q if pass_ == "fwd"
-                        else rf.block_q_bwd,
-                        "block_kv": rf.block_kv if pass_ == "fwd"
-                        else rf.block_kv_bwd,
-                        "slots": list(program.slots),
                         "n_rounds": program.n_rounds,
-                        "gate_bytes": pl.gate_bytes,
-                        "vmem_bytes": pl.vmem_bytes,
-                        "slot_bytes": pl.slot_bytes,
-                        "sem_dma": pl.sem_dma,
-                        "sem_regular": pl.sem_regular,
-                        "budget": rf.vmem_budget,
-                        "vmem_limit": VMEM_LIMIT,
-                        "max_shard_seq": s_max,
-                        "vmem_bytes_at_max": pl_max.vmem_bytes,
-                        "fits": bool(pl.gate_bytes <= rf.vmem_budget
-                                     and pl.vmem_bytes <= VMEM_LIMIT
-                                     and pl_max.vmem_bytes <= VMEM_LIMIT),
                         "flops": est.flops,
                         "hbm_bytes": est.hbm_bytes,
                         "ici_bytes": est.ici_bytes,
@@ -667,7 +451,7 @@ def cost_table(world: int = DEFAULT_WORLD,
                 "win_vs_fp32": base / hb,
             })
     return {
-        "schema": "burstcost-v2",
+        "schema": "burstcost-v3",
         "world": world,
         "shape": shp,
         "hw": {g: {"peak_flops": h.peak_flops, "hbm_bw": h.hbm_bw,

@@ -8,7 +8,10 @@ FlashAttention numerics contract (arXiv 2205.14135; PAPER.md):
   fp32-accum  every dot_general with a low-precision (bf16/f16) operand
               accumulates in float32 (preferred_element_type) — an MXU dot
               that keeps a bf16 accumulator loses ~8 bits of mantissa per
-              long-sequence softmax reduction.
+              long-sequence softmax reduction.  Also: an int8/fp8 ring
+              payload (cfg.wire_dtype) meets its per-block scale before it
+              is accumulated (check_wire_trace, which ringcheck runs on
+              the ring's shard programs at wire_dtype int8 and fp8).
   lse-fp32    the running-max / log-sum-exp statistics ([B, N, S] rank-3
               float32 tensors in every shard-level trace) are never
               downcast below fp32 mid-ring; only the final rank-4 output
@@ -22,7 +25,8 @@ from .core import Finding, rule
 from .jaxpr_tools import iter_eqns
 
 rule("fp32-accum", "jaxpr",
-     "dot_general on bf16/f16 operands must accumulate in float32")(None)
+     "dot_general on bf16/f16 operands must accumulate in float32; a "
+     "quantized ring payload meets its scale before any accumulation")(None)
 rule("lse-fp32", "jaxpr",
      "rank-3 softmax stats (m/lse/delta) must never downcast below fp32")(None)
 
@@ -68,12 +72,12 @@ def check_trace(closed_jaxpr, *, where: str, anchor,
 
 
 # ---------------------------------------------------------------------------
-# wire-precision scale handling (reported under fused-ring-fused)
+# wire-precision scale handling (reported under fp32-accum)
 
 _QUANT = ("int8", "float8_e4m3fn", "float8_e5m2")
 # prims a still-unscaled dequantized value may flow through: linear in the
 # value, so the deferred per-block scale can still be applied after them
-# (the fused fwd multiplies AFTER the QK/PV dot — distributivity)
+# (a scalar scale may multiply AFTER the QK/PV dot — distributivity)
 _WIRE_PASS = {
     "convert_element_type", "reshape", "transpose", "broadcast_in_dim",
     "squeeze", "expand_dims", "slice", "dynamic_slice", "rev", "copy",
@@ -127,7 +131,7 @@ def _walk_wire(jaxpr, tainted, findings, where, path, line, seen):
                    if hasattr(v.aval, "dtype")} & set(_QUANT)
             if qin:
                 findings.append(Finding(
-                    rule="fused-ring-fused", file=path, line=line,
+                    rule="fp32-accum", file=path, line=line,
                     message=f"{where}: dot_general consumes a raw "
                             f"{'/'.join(sorted(qin))} operand — quantized "
                             "payloads must convert to f32 (and rescale) "
@@ -166,7 +170,7 @@ def _walk_wire(jaxpr, tainted, findings, where, path, line, seen):
                 tainted.add(ov)
             continue
         findings.append(Finding(
-            rule="fused-ring-fused", file=path, line=line,
+            rule="fp32-accum", file=path, line=line,
             message=f"{where}: dequantized wire payload reaches `{name}` "
                     "without an in-tile rescale — every quantized send "
                     "needs a matching scale multiply before accumulation"))
@@ -174,8 +178,8 @@ def _walk_wire(jaxpr, tainted, findings, where, path, line, seen):
 
 
 def check_wire_trace(closed_jaxpr, *, where: str, anchor) -> List[Finding]:
-    """Scale-handling proof over one traced fused program (fused-ring-fused
-    family): every int8/fp8 -> float conversion must meet a `mul` (its
+    """Scale-handling proof over one traced program (reported under
+    fp32-accum): every int8/fp8 -> float conversion must meet a `mul` (its
     per-block scale) before the value is accumulated or leaves the trace,
     and no dot_general may consume a quantized dtype directly.  Vacuous on
     dense traces (no quantized converts), so it runs unconditionally."""
@@ -188,7 +192,7 @@ def check_wire_trace(closed_jaxpr, *, where: str, anchor) -> List[Finding]:
                if not hasattr(v, "val") and v in out_taint]
     if escaped:
         findings.append(Finding(
-            rule="fused-ring-fused", file=path, line=line,
+            rule="fp32-accum", file=path, line=line,
             message=f"{where}: {len(escaped)} output(s) carry a dequantized "
                     "payload that never met its scale multiply"))
     return findings

@@ -145,8 +145,8 @@ def check_off_identity(jaxpr_off, jaxpr_plain, *, anchor) -> List[Finding]:
 
 def check_all() -> List[Finding]:
     """Trace the burst forward AND backward shard programs on a simulated
-    flat ring and prove both are callback-free.  (The scan/fused dispatch,
-    tile kernels, and case-split branches are all inside these traces;
+    flat ring and prove both are callback-free.  (The tile kernels
+    and case-split branches are all inside these traces;
     ringcheck's topology matrix covers scheduling, this covers purity.)"""
     import jax
     import jax.numpy as jnp

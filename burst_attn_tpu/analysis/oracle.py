@@ -201,255 +201,6 @@ def verify_dq_returns_home(n_inter: int, n_intra: int, r_live=None) -> None:
 
 
 # ---------------------------------------------------------------------------
-# fused ring (in-kernel RDMA rotation, ops/fused_ring.py)
-
-
-def fused_slot_schedule(world: int, slots: int) -> List[int]:
-    """Independent derivation of the fused kernel's per-round KV-slot ids
-    (duplicated from parallel/ring.fused_slot_schedule on purpose — the
-    analyzer must not trust the code under test).  Round r consumes slot
-    r mod C with C = min(slots, world)."""
-    return [r % min(slots, world) for r in range(world)]
-
-
-def verify_fused_ring(world: int, slots: int, slot_sched=None) -> None:
-    """Prove by simulation that the fused ring's schedule + semaphore
-    protocol is correct, raising AssertionError otherwise:
-
-      delivery      with every device sending its round-r chunk from
-                    slot[r] to the RIGHT neighbor's slot[r+1], the chunk a
-                    device reads at round r is partition ring_schedule[d, r]
-                    — i.e. neighbor-only (+1) sends reproduce the exact
-                    schedule the scan ring realizes with ppermute.
-      hop count     every chunk travels exactly world - 1 hops (each of the
-                    world - 1 per-device sends moves one chunk one hop).
-      slot safety   under the capacity handshake (a sender at round
-                    r >= C-1 consumes a free credit the receiver grants
-                    only after finishing round r+1-C), a maximally-ahead
-                    sender can never overwrite a slot version the receiver
-                    has not consumed yet.  Simulated with the sender
-                    running unboundedly ahead of the receiver.
-    """
-    C = min(slots, world)
-    assert C >= 2, f"fused ring needs >= 2 slots, got {slots}"
-    if slot_sched is None:
-        slot_sched = fused_slot_schedule(world, slots)
-    slot_sched = [int(x) for x in slot_sched]
-    assert len(slot_sched) == world, (len(slot_sched), world)
-    assert all(0 <= s < C for s in slot_sched), slot_sched
-
-    # ---- delivery + hop count (lockstep rounds) ----
-    sched = ring_schedule(world, 1)
-    buf = [{slot_sched[0]: d} for d in range(world)]  # slot -> partition id
-    hops = {d: 0 for d in range(world)}  # partition -> hops traveled
-    for r in range(world):
-        sends = []
-        for d in range(world):
-            assert slot_sched[r] in buf[d], (
-                f"device {d} round {r}: slot {slot_sched[r]} never written")
-            part = buf[d][slot_sched[r]]
-            assert part == int(sched[d, r]), (
-                f"device {d} round {r}: holds partition {part}, schedule "
-                f"says {int(sched[d, r])}")
-            if r < world - 1:
-                sends.append(((d + 1) % world, slot_sched[r + 1], part))
-        for dst_dev, dst_slot, part in sends:  # all transfers in flight at once
-            buf[dst_dev][dst_slot] = part
-            hops[part] += 1
-    for part, h in hops.items():
-        assert h == world - 1, f"partition {part} made {h} hops, not {world - 1}"
-
-    # ---- slot safety: maximally-ahead sender vs slowest receiver ----
-    # Versions: the receiver must read version r of slot[r] at round r
-    # (version 0 = its own initial copy-in).  The sender may issue the
-    # round-r send as soon as its credits allow; each grant is emitted when
-    # the receiver FINISHES round t (t <= world-1-C).
-    consumed = 0          # receiver's completed rounds
-    credits = 0           # unconsumed free credits held by the sender
-    slot_version = {slot_sched[0]: 0}
-    pending = []          # writes the receiver has not yet read
-    for rs in range(world - 1):  # sender's rounds, run as early as possible
-        if rs >= C - 1:
-            # sender needs one credit: receiver must have finished rounds
-            # up to rs + 1 - C before this write may land
-            while credits == 0:
-                # receiver consumes its next round
-                t = consumed
-                got = slot_version.get(slot_sched[t])
-                assert got == t, (
-                    f"receiver reads slot {slot_sched[t]} at round {t} but "
-                    f"holds version {got} — overwritten before read")
-                consumed += 1
-                if t <= world - 1 - C:
-                    credits += 1
-            credits -= 1
-        assert consumed >= rs + 1 - C, (consumed, rs)
-        slot_version[slot_sched[rs + 1]] = rs + 1
-    while consumed < world:  # receiver drains the tail
-        t = consumed
-        got = slot_version.get(slot_sched[t])
-        assert got == t, (
-            f"receiver reads slot {slot_sched[t]} at round {t} but holds "
-            f"version {got} — overwritten before read")
-        consumed += 1
-
-
-def fused_bwd_slot_schedule(world: int, slots: int) -> List[int]:
-    """Independent derivation of the fused BACKWARD kernel's per-round slot
-    ids (duplicated from parallel/ring.fused_bwd_slot_schedule on purpose —
-    the analyzer must not trust the code under test).  Both concurrent
-    streams — the q-side bundle and the dq ring — consume slot r mod C at
-    round r, C = min(slots, world); the dq return-home hop targets the
-    dedicated HOME slot (index C) outside this cycle."""
-    return [r % min(slots, world) for r in range(world)]
-
-
-def verify_fused_ring_bwd(world: int, slots: int, slot_sched=None) -> None:
-    """Prove by simulation that the fused backward's schedule + semaphore
-    protocol is correct, raising AssertionError otherwise:
-
-      bundle delivery  with every device sending its round-r bundle from
-                    slot[r] to the RIGHT neighbor's slot[r+1], the q-side
-                    payload a device reads at round r is partition
-                    ring_schedule[d, r] — the same schedule the scan
-                    backward realizes with ppermute — and every bundle
-                    travels exactly world - 1 hops.
-      dq return-home   simulating the add-and-forward dq stream (each
-                    device folds its round-r contribution into the partial
-                    arriving one hop behind the bundle, then streams it
-                    onward; round world-1 sends into the right neighbor's
-                    HOME slot), every partition's gradient lands on its
-                    owner EXACTLY once, carrying all `world` per-device
-                    contributions.
-      slot safety   under the capacity handshake, a maximally-ahead sender
-                    can never overwrite a slot version its receiver has
-                    not consumed — proven for the bundle stream (sends at
-                    round r's first step, the forward's phase) AND the dq
-                    stream (sends streamed DURING round r, one hop behind).
-    """
-    C = min(slots, world)
-    assert C >= 2, f"fused bwd ring needs >= 2 slots, got {slots}"
-    if slot_sched is None:
-        slot_sched = fused_bwd_slot_schedule(world, slots)
-    slot_sched = [int(x) for x in slot_sched]
-    assert len(slot_sched) == world, (len(slot_sched), world)
-    assert all(0 <= s < C for s in slot_sched), slot_sched
-
-    # ---- bundle delivery + hop count: the rotation topology is identical
-    # to the forward KV ring, so the same lockstep simulation applies ----
-    sched = ring_schedule(world, 1)
-    buf = [{slot_sched[0]: d} for d in range(world)]  # slot -> q partition
-    hops = {d: 0 for d in range(world)}
-    for r in range(world):
-        sends = []
-        for d in range(world):
-            assert slot_sched[r] in buf[d], (
-                f"device {d} round {r}: bundle slot {slot_sched[r]} never "
-                "written")
-            part = buf[d][slot_sched[r]]
-            assert part == int(sched[d, r]), (
-                f"device {d} round {r}: holds bundle of partition {part}, "
-                f"schedule says {int(sched[d, r])}")
-            if r < world - 1:
-                sends.append(((d + 1) % world, slot_sched[r + 1], part))
-        for dst_dev, dst_slot, part in sends:
-            buf[dst_dev][dst_slot] = part
-            hops[part] += 1
-    for part, h in hops.items():
-        assert h == world - 1, (
-            f"bundle of partition {part} made {h} hops, not {world - 1}")
-
-    # ---- dq add-and-forward + return-home (lockstep rounds) ----
-    # register[d] = set of (contributing device, partition) pairs in the dq
-    # partial device d holds for its CURRENT round; home[d] = what landed in
-    # d's HOME slot.  A wrong hop order, a dropped fold, or a misdirected
-    # final hop all break the exactly-once-with-all-contributions assert.
-    reg = [set() for _ in range(world)]
-    home = [None] * world
-    for r in range(world):
-        for d in range(world):
-            part = int(sched[d, r])
-            reg[d] = reg[d] | {(d, part)}
-            parts = {p for (_, p) in reg[d]}
-            assert parts == {part}, (
-                f"device {d} round {r}: dq partial mixes partitions {parts}")
-        if r < world - 1:
-            reg = [reg[(d - 1) % world] for d in range(world)]  # one hop right
-        else:
-            for d in range(world):  # return-home hop into HOME slots
-                dst = (d + 1) % world
-                assert home[dst] is None, (
-                    f"device {dst}: HOME slot written twice")
-                home[dst] = reg[d]
-    for d in range(world):
-        assert home[d] is not None, f"device {d}: dq never arrived home"
-        want = {((d + t) % world, d) for t in range(world)}
-        assert home[d] == want, (
-            f"device {d}: home dq carries {home[d]}, expected every "
-            f"contribution of partition {d}: {want}")
-
-    # ---- slot safety: maximally-ahead sender vs slowest receiver ----
-    # Bundle stream: sends at round rs read slot[rs] and land version rs+1;
-    # the receiver reads version r of slot[r] at round r (version 0 = its
-    # own copy-in).  Identical protocol to the forward KV ring.
-    consumed, credits = 0, 0
-    slot_version = {slot_sched[0]: 0}
-    for rs in range(world - 1):
-        if rs >= C - 1:
-            while credits == 0:
-                t = consumed
-                got = slot_version.get(slot_sched[t])
-                assert got == t, (
-                    f"bundle: receiver reads slot {slot_sched[t]} at round "
-                    f"{t} but holds version {got} — overwritten before read")
-                consumed += 1
-                if t <= world - 1 - C:
-                    credits += 1
-            credits -= 1
-        assert consumed >= rs + 1 - C, (consumed, rs)
-        slot_version[slot_sched[rs + 1]] = rs + 1
-    while consumed < world:
-        t = consumed
-        got = slot_version.get(slot_sched[t])
-        assert got == t, (
-            f"bundle: receiver reads slot {slot_sched[t]} at round {t} but "
-            f"holds version {got} — overwritten before read")
-        consumed += 1
-
-    # Dq stream: phase-shifted — the round-(t) partial is STREAMED during
-    # the sender's round t-1 (after each block's fold), so sender round rs
-    # writes version rs+1; the receiver consumes no dq at round 0 and must
-    # find version t in slot[t] at rounds 1..world-1.  Credits follow the
-    # same grant/take schedule as the bundle (one per stream).
-    consumed, credits = 0, 0
-    dq_version = {}
-    for rs in range(world - 1):
-        if rs >= C - 1:
-            while credits == 0:
-                t = consumed
-                if t > 0:
-                    got = dq_version.get(slot_sched[t])
-                    assert got == t, (
-                        f"dq: receiver reads slot {slot_sched[t]} at round "
-                        f"{t} but holds version {got} — overwritten before "
-                        "read")
-                consumed += 1
-                if t <= world - 1 - C:
-                    credits += 1
-            credits -= 1
-        assert consumed >= rs + 1 - C, (consumed, rs)
-        dq_version[slot_sched[rs + 1]] = rs + 1
-    while consumed < world:
-        t = consumed
-        if t > 0:
-            got = dq_version.get(slot_sched[t])
-            assert got == t, (
-                f"dq: receiver reads slot {slot_sched[t]} at round {t} but "
-                f"holds version {got} — overwritten before read")
-        consumed += 1
-
-
-# ---------------------------------------------------------------------------
 # windowed truncation
 
 

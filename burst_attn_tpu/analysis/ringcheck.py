@@ -29,12 +29,13 @@ Tracing is abstract (jax.make_jaxpr on ShapeDtypeStructs): nothing
 executes, no TPU is needed, and the whole matrix runs in seconds on CPU.
 """
 
+import dataclasses
 import inspect
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
 from .core import Finding, rule
-from . import oracle
+from . import numerics, oracle
 from .jaxpr_tools import collect_collectives
 
 # registered rule docs (checkers live in verify_* below; the names must
@@ -54,13 +55,7 @@ rule("fused-ring-schedule", "jaxpr",
      "every schedule the compiler emits (uni, bidi, double; fwd AND bwd) "
      "is simulation-proven: delivery of the declared rotation, hop "
      "counts, per-slot overwrite-before-read safety per direction, "
-     "prefetch distance >= one intra cycle, dq exactly-once return-home; "
-     "the legacy uni slot views still match the independent derivation")(None)
-rule("fused-ring-fused", "jaxpr",
-     "fused fwd/bwd issue zero XLA collectives and exactly the compiled "
-     "program's remote-copy census (schedule.expected_remote_dma: per-"
-     "direction payload channels, dq rings, return-home hops), with "
-     "fp32-accum numerics — for uni, bidi, double and multi-axis meshes")(None)
+     "prefetch distance >= one intra cycle, dq exactly-once return-home")(None)
 
 
 @dataclass
@@ -297,115 +292,52 @@ def verify_ring_entry(entry: RingEntry) -> List[Finding]:
             return findings
         r_live = len(live)
 
-    # ---- forward ----
-    fwd = shard_map(lambda q, k, v: burst._fwd_impl(q, k, v, cfg),
-                    mesh=mesh, in_specs=(spec4,) * 3,
-                    out_specs=(spec4, spec3), check_vma=False)
+    def trace(cfg):
+        fwd = shard_map(lambda q, k, v: burst._fwd_impl(q, k, v, cfg),
+                        mesh=mesh, in_specs=(spec4,) * 3,
+                        out_specs=(spec4, spec3), check_vma=False)
+        bwd = shard_map(
+            lambda q, k, v, o, lse, do: burst._bwd_impl(cfg, q, k, v, o, lse,
+                                                        do),
+            mesh=mesh, in_specs=(spec4,) * 4 + (spec3, spec4),
+            out_specs=(spec4,) * 3, check_vma=False)
+        return (jax.make_jaxpr(fwd)(q, q, q),
+                jax.make_jaxpr(bwd)(q, q, q, q, lse, q))
+
+    jx_fwd, jx_bwd = trace(cfg)
     findings += verify_traced_ring(
-        jax.make_jaxpr(fwd)(q, q, q), kind="fwd", n_inter=n_inter,
+        jx_fwd, kind="fwd", n_inter=n_inter,
         n_intra=n_intra, r_live=r_live, leaves_pay=2, axis_map=axis_map,
         where=f"{entry.name} fwd", anchor=_anchor(burst._fwd_impl),
         window=truncating)
-
-    # ---- backward ----
-    bwd = shard_map(
-        lambda q, k, v, o, lse, do: burst._bwd_impl(cfg, q, k, v, o, lse, do),
-        mesh=mesh, in_specs=(spec4,) * 4 + (spec3, spec4),
-        out_specs=(spec4,) * 3, check_vma=False)
     findings += verify_traced_ring(
-        jax.make_jaxpr(bwd)(q, q, q, q, lse, q), kind="bwd", n_inter=n_inter,
+        jx_bwd, kind="bwd", n_inter=n_inter,
         n_intra=n_intra, r_live=r_live, leaves_pay=4, axis_map=axis_map,
         where=f"{entry.name} bwd", anchor=_anchor(burst._bwd_impl),
         window=truncating)
+
+    # ---- the same shard programs on a quantized wire: every K/V, bundle
+    # and dq payload meets its scale before it is accumulated ----
+    for wire in ("int8", "fp8"):
+        for kind, jx, impl in zip(
+                ("fwd", "bwd"),
+                trace(dataclasses.replace(cfg, wire_dtype=wire)),
+                (burst._fwd_impl, burst._bwd_impl)):
+            findings += numerics.check_wire_trace(
+                jx, where=f"{entry.name} {kind} wire={wire}",
+                anchor=_anchor(impl))
     return findings
 
 
 def _remote_dma_starts(closed_jaxpr):
+    """Cross-chip dma_start equations of a trace (servecheck's census: the
+    serving kernels must hold none)."""
     from .jaxpr_tools import iter_eqns
 
     return [e for e in iter_eqns(closed_jaxpr)
             if e.primitive.name == "dma_start"
             and e.params.get("device_id_type") is not None
             and "LOGICAL" in str(e.params["device_id_type"]).upper()]
-
-
-def verify_fused_fwd_trace(closed_jaxpr, *, where: str, anchor,
-                           expected_dma: int = 2) -> List[Finding]:
-    """fused-ring-fused checks on one traced fused FORWARD shard program.
-
-    The trace must contain ZERO XLA collectives (the ring lives entirely
-    inside the kernel) and exactly `expected_dma` remote dma_start call
-    sites — schedule.expected_remote_dma of the compiled program (the
-    classic uni ring's k+v pair is 2; a bidi ring doubles it, the double
-    ring adds the inter-prefetch channel); more would double-send, fewer
-    would starve a stream — the kernel's dots must pass the
-    fp32-accum/lse-fp32 contract, and any quantized wire payloads must
-    pass the scale-handling proof (numerics.check_wire_trace: every
-    int8/fp8 dequant meets its per-block scale multiply before
-    accumulation; vacuous on dense traces)."""
-    from . import numerics
-
-    findings: List[Finding] = []
-    path, line = anchor
-    colls = [e for e in collect_collectives(closed_jaxpr)
-             if e.prim in ("ppermute", "all_to_all")]
-    if colls:
-        findings.append(Finding(
-            rule="fused-ring-fused", file=path, line=line,
-            message=f"{where}: fused forward issues XLA collectives "
-                    f"{[(e.prim, e.axis) for e in colls]} — the ring "
-                    "must live entirely inside the kernel"))
-    remote = _remote_dma_starts(closed_jaxpr)
-    if len(remote) != expected_dma:
-        findings.append(Finding(
-            rule="fused-ring-fused", file=path, line=line,
-            message=f"{where}: expected exactly {expected_dma} remote "
-                    f"dma_starts (the compiled program's census), traced "
-                    f"{len(remote)}"))
-    findings += numerics.check_trace(closed_jaxpr, where=where, anchor=anchor)
-    findings += numerics.check_wire_trace(closed_jaxpr, where=where,
-                                          anchor=anchor)
-    return findings
-
-
-def verify_fused_bwd_trace(closed_jaxpr, *, where: str, anchor,
-                           expected_dma: int = 6) -> List[Finding]:
-    """fused-ring-fused checks on one traced fused BACKWARD shard program.
-
-    Shared by verify_fused_ring (tracing the real dispatch) and the
-    mutation tests (tracing seeded-bad programs): the trace must contain
-    ZERO XLA collectives (the two rotating streams live entirely inside
-    the kernel) and exactly `expected_dma` remote dma_starts — for the
-    classic uni ring 6: 4 for the q-side bundle (delta|o, do, q, lse),
-    1 for the streamed dq ring hop, 1 for the dq return-home hop; other
-    topologies derive theirs from schedule.expected_remote_dma of the
-    compiled program.  More would double-send, fewer would starve a
-    stream — the kernel's dots must pass the fp32-accum/lse-fp32
-    contract, and quantized wire payloads the scale-handling proof
-    (numerics.check_wire_trace; vacuous on dense traces)."""
-    from . import numerics
-
-    findings: List[Finding] = []
-    path, line = anchor
-    colls = [e for e in collect_collectives(closed_jaxpr)
-             if e.prim in ("ppermute", "all_to_all")]
-    if colls:
-        findings.append(Finding(
-            rule="fused-ring-fused", file=path, line=line,
-            message=f"{where}: fused backward issues XLA collectives "
-                    f"{[(e.prim, e.axis) for e in colls]} — both the "
-                    "bundle and the dq ring must live inside the kernel"))
-    remote = _remote_dma_starts(closed_jaxpr)
-    if len(remote) != expected_dma:
-        findings.append(Finding(
-            rule="fused-ring-fused", file=path, line=line,
-            message=f"{where}: expected exactly {expected_dma} remote "
-                    f"dma_starts (bundle operands + dq ring/boundary + "
-                    f"return-home), traced {len(remote)}"))
-    findings += numerics.check_trace(closed_jaxpr, where=where, anchor=anchor)
-    findings += numerics.check_wire_trace(closed_jaxpr, where=where,
-                                          anchor=anchor)
-    return findings
 
 
 # (topology, n_inter, n_intra, compile kwargs) matrix of compiler-emitted
@@ -581,317 +513,6 @@ def verify_ring_programs() -> List[Finding]:
     return findings
 
 
-def verify_fused_ring() -> List[Finding]:
-    """Fused ring (ops/fused_ring.py + ops/fused_ring_bwd.py) rules.
-
-    Schedule family: the slot schedule the kernel consumes (exported by
-    parallel/ring.fused_slot_schedule and delivered via scalar prefetch) is
-    matched against the oracle's independent derivation, and the oracle
-    PROVES — by simulating a maximally-ahead sender against the capacity
-    handshake — neighbor-only delivery of ring_schedule, exactly world-1
-    hops per chunk, and that no slot is overwritten before its last read.
-
-    Jaxpr family: the fused forward shard program is traced abstractly on a
-    simulated mesh and must contain ZERO XLA collectives (ppermute /
-    all_to_all / psum on the ring payload — the whole point of the fused
-    path) and exactly 2 remote dma_starts inside the kernel (one per
-    operand per hop; more would double-send, fewer would starve the ring);
-    the kernel's dots are also run through the fp32-accum/lse-fp32
-    numerics contract."""
-    import os
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    from ..ops import fused_ring as fr
-    from ..parallel import burst, ring
-    from jax import shard_map
-
-    findings: List[Finding] = []
-    anchor_plan = _anchor(ring.fused_slot_schedule)
-    for world, slots in ((2, 2), (4, 2), (8, 2), (8, 3), (8, 8)):
-        got = [int(x) for x in ring.fused_slot_schedule(world, slots)]
-        want = oracle.fused_slot_schedule(world, slots)
-        if got != want:
-            findings.append(Finding(
-                rule="fused-ring-schedule", file=anchor_plan[0],
-                line=anchor_plan[1],
-                message=f"world={world} slots={slots}: exported slot "
-                        f"schedule {got} != oracle derivation {want}"))
-            continue
-        try:
-            oracle.verify_fused_ring(world, slots, got)
-        except AssertionError as e:
-            findings.append(Finding(
-                rule="fused-ring-schedule", file=anchor_plan[0],
-                line=anchor_plan[1],
-                message=f"world={world} slots={slots}: schedule proof "
-                        f"failed: {e}"))
-
-    # ---- bwd schedule family: the bundle + dq twin streams ----
-    anchor_bwd_plan = _anchor(ring.fused_bwd_slot_schedule)
-    for world, slots in ((2, 2), (4, 2), (8, 2), (8, 3), (8, 8)):
-        got = [int(x) for x in ring.fused_bwd_slot_schedule(world, slots)]
-        want = oracle.fused_bwd_slot_schedule(world, slots)
-        if got != want:
-            findings.append(Finding(
-                rule="fused-ring-schedule", file=anchor_bwd_plan[0],
-                line=anchor_bwd_plan[1],
-                message=f"world={world} slots={slots}: exported bwd slot "
-                        f"schedule {got} != oracle derivation {want}"))
-            continue
-        try:
-            oracle.verify_fused_ring_bwd(world, slots, got)
-        except AssertionError as e:
-            findings.append(Finding(
-                rule="fused-ring-schedule", file=anchor_bwd_plan[0],
-                line=anchor_bwd_plan[1],
-                message=f"world={world} slots={slots}: bwd schedule proof "
-                        f"failed: {e}"))
-
-    # ---- traced structure of the fused forward ----
-    anchor = _anchor(fr.fused_ring_fwd)
-    devs = jax.devices()
-    world = 4
-    if len(devs) < world:
-        raise RuntimeError(
-            f"analysis needs {world} simulated devices "
-            f"(XLA_FLAGS=--xla_force_host_platform_device_count=8); "
-            f"have {len(devs)}")
-    mesh = Mesh(np.asarray(devs[:world]), ("sp",))
-    b, n, d, s_local = 1, 2, 8, 16
-    S = jax.ShapeDtypeStruct
-    q = S((b, n, s_local * world, d), jnp.bfloat16)
-    spec4 = P(None, None, "sp", None)
-    spec3 = P(None, None, "sp")
-    # make_jaxpr never executes, but the dispatch's supported() gate reads
-    # the interpret opt-in off-TPU — enable it for the trace only
-    prev = os.environ.get("BURST_FUSED_INTERPRET")
-    os.environ["BURST_FUSED_INTERPRET"] = "1"
-    try:
-        for layout, causal in (("zigzag", True), ("striped", True),
-                               ("contig", False)):
-            cfg = burst.BurstConfig(causal=causal, layout=layout,
-                                    intra_axis="sp", backend="fused_ring")
-            fwd = shard_map(lambda q, k, v: burst._fwd_impl(q, k, v, cfg),
-                            mesh=mesh, in_specs=(spec4,) * 3,
-                            out_specs=(spec4, spec3), check_vma=False)
-            jx = jax.make_jaxpr(fwd)(q, q, q)
-            where = f"fused-{layout}{'-causal' if causal else ''}"
-            findings += verify_fused_fwd_trace(jx, where=where,
-                                               anchor=anchor)
-
-        # ---- traced structure of the fused backward ----
-        from ..ops import fused_ring_bwd as frb
-
-        anchor_bwd = _anchor(frb.fused_ring_bwd)
-        lse = S((b, n, s_local * world), jnp.float32)
-        for layout, causal, opt in (("zigzag", True, True),
-                                    ("striped", True, False),
-                                    ("contig", False, True)):
-            cfg = burst.BurstConfig(causal=causal, layout=layout,
-                                    intra_axis="sp", backend="fused_ring",
-                                    optimize_bwd_comm=opt)
-            bwd = shard_map(
-                lambda q, k, v, o, l, do: burst._bwd_impl(
-                    cfg, q, k, v, o, l, do),
-                mesh=mesh, in_specs=(spec4,) * 4 + (spec3, spec4),
-                out_specs=(spec4,) * 3, check_vma=False)
-            jx = jax.make_jaxpr(bwd)(q, q, q, q, lse, q)
-            where = (f"fused-bwd-{layout}{'-causal' if causal else ''}"
-                     f"{'' if opt else '-rotate-o'}")
-            findings += verify_fused_bwd_trace(jx, where=where,
-                                               anchor=anchor_bwd)
-
-        # ---- end-to-end: value_and_grad through the fused backend keeps
-        # BOTH passes collective-free (the acceptance-criterion trace) ----
-        cfg = burst.BurstConfig(causal=True, layout="zigzag",
-                                intra_axis="sp", backend="fused_ring")
-
-        def loss(q, k, v):
-            o = burst._burst_attn_shard_plain(q, k, v, cfg)
-            return jnp.sum(o.astype(jnp.float32))
-
-        vg = shard_map(
-            lambda q, k, v: jax.value_and_grad(loss, (0, 1, 2))(q, k, v),
-            mesh=mesh, in_specs=(spec4,) * 3,
-            out_specs=(P(), (spec4,) * 3), check_vma=False)
-        jx = jax.make_jaxpr(vg)(q, q, q)
-        colls = [e for e in collect_collectives(jx)
-                 if e.prim in ("ppermute", "all_to_all")]
-        if colls:
-            findings.append(Finding(
-                rule="fused-ring-fused", file=anchor_bwd[0],
-                line=anchor_bwd[1],
-                message="value_and_grad(fused_ring) issues XLA collectives "
-                        f"{[(e.prim, e.axis) for e in colls]} — both passes "
-                        "must live inside their kernels"))
-    finally:
-        if prev is None:
-            os.environ.pop("BURST_FUSED_INTERPRET", None)
-        else:
-            os.environ["BURST_FUSED_INTERPRET"] = prev
-    return findings
-
-
-def verify_fused_topologies() -> List[Finding]:
-    """fused-ring-fused, schedule-IR topologies: the configs the hand-built
-    schedules could never express trace fused with ZERO XLA collectives and
-    exactly the compiled program's remote-DMA census
-    (schedule.expected_remote_dma) — fwd AND bwd each:
-
-      bidi         counter-rotating flat ring (both ICI directions)
-      double-flat  hierarchical double ring factored onto one ring axis
-      double-2ax   the real two-axis ("inter", "intra") double ring
-      multi-axis   pp x tp x sp training mesh, ring on "sp" with
-                   cfg.mesh_axes proving the extra axes never alias
-                   ring traffic
-
-    bidi and double-flat are single-named-axis programs, so they trace
-    under the interpret opt-in like the uni checks; the two-axis double
-    ring and the multi-axis mesh cannot be discharged by the interpreter
-    at all — BURST_FUSED_ASSUME_TPU forces the HARDWARE trace (full
-    semaphore choreography, never executed), which is exactly the program
-    a TPU would run, so the acceptance-criterion traces are checked
-    off-TPU on every burstlint run."""
-    import os
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    from ..ops import fused_ring as fr
-    from ..parallel import burst, schedule as sched
-    from jax import shard_map
-
-    findings: List[Finding] = []
-    anchor_fwd = _anchor(fr.fused_ring_fwd)
-    devs = jax.devices()
-    if len(devs) < 8:
-        raise RuntimeError(
-            "analysis needs 8 simulated devices "
-            "(XLA_FLAGS=--xla_force_host_platform_device_count=8); "
-            f"have {len(devs)}")
-    b, n, d, s_local = 1, 2, 8, 16
-    S = jax.ShapeDtypeStruct
-
-    # (name, env flag, mesh axes+sizes, ring axes, cfg extras, q specs).
-    # The windowed-* / segments-* rows are OCCUPANCY-ELIDED programs: the
-    # compiler truncates them to the live prefix, and the census assertion
-    # below proves the elided program's remote-DMA call-site count never
-    # exceeds — and for bidi strictly undercuts — the dense compile's.
-    CASES = (
-        ("bidi-4", "BURST_FUSED_INTERPRET", (("sp", 4),), ("sp", None),
-         {"fused_topology": "bidi"}),
-        ("double-flat-2x2", "BURST_FUSED_INTERPRET", (("sp", 4),),
-         ("sp", None), {"fused_seq_factor": (2, 2)}),
-        ("double-2ax-2x4", "BURST_FUSED_ASSUME_TPU",
-         (("inter", 2), ("intra", 4)), ("intra", "inter"), {}),
-        ("multiaxis-pp2-tp2-sp2", "BURST_FUSED_ASSUME_TPU",
-         (("pp", 2), ("tp", 2), ("sp", 2)), ("sp", None),
-         {"mesh_axes": (("pp", 2), ("tp", 2), ("sp", 2))}),
-        ("windowed-uni-8", "BURST_FUSED_INTERPRET", (("sp", 8),),
-         ("sp", None), {"layout": "contig", "window": 20}),
-        ("windowed-bidi-8", "BURST_FUSED_INTERPRET", (("sp", 8),),
-         ("sp", None), {"layout": "contig", "window": 20,
-                        "fused_topology": "bidi"}),
-        ("segments-uni-8", "BURST_FUSED_INTERPRET", (("sp", 8),),
-         ("sp", None), {"layout": "contig", "max_segment_len": 16}),
-        # wire-precision rows: the quantized traces must keep ZERO XLA
-        # collectives, hit the wire-aware census (expected_remote_dma
-        # counts the scale sub-payload call sites: fwd 2 -> 4 per channel,
-        # bwd bundle 4 -> 7 and dq sites x2), and discharge the
-        # scale-handling proof inside verify_fused_*_trace
-        ("wire-int8-uni-4", "BURST_FUSED_INTERPRET", (("sp", 4),),
-         ("sp", None), {"wire_dtype": "int8"}),
-        ("wire-fp8-bidi-4", "BURST_FUSED_INTERPRET", (("sp", 4),),
-         ("sp", None), {"wire_dtype": "fp8", "fused_topology": "bidi"}),
-        ("wire-int8-double-2ax", "BURST_FUSED_ASSUME_TPU",
-         (("inter", 2), ("intra", 4)), ("intra", "inter"),
-         {"wire_dtype": "int8"}),
-    )
-    for name, env, axes, (intra_axis, inter_axis), extras in CASES:
-        names = tuple(a for a, _ in axes)
-        sizes = tuple(sz for _, sz in axes)
-        mesh = Mesh(np.asarray(devs[:int(np.prod(sizes))]).reshape(sizes),
-                    names)
-        extras = dict(extras)
-        layout = extras.pop("layout", "zigzag")
-        cfg = burst.BurstConfig(
-            causal=True, layout=layout, intra_axis=intra_axis,
-            inter_axis=inter_axis, backend="fused_ring", **extras)
-        ring_names = tuple(a for a in (inter_axis, intra_axis) if a)
-        world = int(np.prod([dict(axes)[a] for a in ring_names]))
-        seq = world * s_local
-        q = S((b, n, seq, d), jnp.bfloat16)
-        lse = S((b, n, seq), jnp.float32)
-        seq_spec = ring_names if len(ring_names) > 1 else ring_names[0]
-        spec4 = P(None, None, seq_spec, None)
-        spec3 = P(None, None, seq_spec)
-        n_inter = dict(axes).get(inter_axis, 1) if inter_axis else 1
-        topo, t_i, t_s = fr.resolve_topology(cfg, world // n_inter, n_inter)
-        elided = fr.occupancy_r_live(cfg, world, s_local) is not None
-        prev = os.environ.get(env)
-        os.environ[env] = "1"
-        try:
-            prog_f = fr._compile_for(cfg, topo, t_i, t_s, "fwd", s=s_local)
-            fwd = shard_map(lambda q, k, v: burst._fwd_impl(q, k, v, cfg),
-                            mesh=mesh, in_specs=(spec4,) * 3,
-                            out_specs=(spec4, spec3), check_vma=False)
-            findings += verify_fused_fwd_trace(
-                jax.make_jaxpr(fwd)(q, q, q), where=f"fused-{name}-fwd",
-                anchor=anchor_fwd,
-                expected_dma=sched.expected_remote_dma(prog_f, 2))
-
-            from ..ops import fused_ring_bwd as frb
-
-            prog_b = fr._compile_for(cfg, topo, t_i, t_s, "bwd", s=s_local)
-            bwd = shard_map(
-                lambda q, k, v, o, l, do: burst._bwd_impl(
-                    cfg, q, k, v, o, l, do),
-                mesh=mesh, in_specs=(spec4,) * 4 + (spec3, spec4),
-                out_specs=(spec4,) * 3, check_vma=False)
-            findings += verify_fused_bwd_trace(
-                jax.make_jaxpr(bwd)(q, q, q, q, lse, q),
-                where=f"fused-{name}-bwd", anchor=_anchor(frb.fused_ring_bwd),
-                expected_dma=sched.expected_remote_dma(prog_b, 4))
-            if elided:
-                # elision census: the dense compile of the SAME topology
-                # must never undercut the elided program, and the bidi
-                # ring must strictly shrink (its dead ccw bank vanishes)
-                dense_f = fr._compile_for(cfg, topo, t_i, t_s, "fwd")
-                dense_b = fr._compile_for(cfg, topo, t_i, t_s, "bwd")
-                for pss, prog, dense, payload in (
-                        ("fwd", prog_f, dense_f, 2),
-                        ("bwd", prog_b, dense_b, 4)):
-                    got = sched.expected_remote_dma(prog, payload)
-                    ref = sched.expected_remote_dma(dense, payload)
-                    strict = topo == "bidi"
-                    if got > ref or (strict and got >= ref):
-                        findings.append(Finding(
-                            rule="fused-ring-fused", file=anchor_fwd[0],
-                            line=anchor_fwd[1],
-                            message=f"fused-{name}-{pss}: elided remote-"
-                                    f"DMA census {got} does not undercut "
-                                    f"the dense census {ref}"))
-                    if prog.n_rounds >= dense.n_rounds:
-                        findings.append(Finding(
-                            rule="fused-ring-fused", file=anchor_fwd[0],
-                            line=anchor_fwd[1],
-                            message=f"fused-{name}-{pss}: elided program "
-                                    f"keeps {prog.n_rounds} rounds, dense "
-                                    f"has {dense.n_rounds}"))
-        finally:
-            if prev is None:
-                os.environ.pop(env, None)
-            else:
-                os.environ[env] = prev
-    return findings
-
-
 def verify_ulysses() -> List[Finding]:
     """Ulysses a2a contract: exactly 4 all_to_alls (q, k, v in; o out) on
     the sequence axis, no ppermutes, none conditional."""
@@ -943,7 +564,5 @@ def check_all() -> List[Finding]:
     for entry in ENTRIES:
         findings += verify_ring_entry(entry)
     findings += verify_ring_programs()
-    findings += verify_fused_ring()
-    findings += verify_fused_topologies()
     findings += verify_ulysses()
     return findings

@@ -17,7 +17,7 @@ chunking never retrace.  This rule proves, from the traced jaxpr alone:
   remote-DMA=0   a census of cross-chip DMA starts in the kernel body
                  must be ZERO.  This kernel serves the single-host pool;
                  cross-device traffic belongs to the ring subsystem
-                 (parallel/fused_ring.py) and the sequence-parallel
+                 (parallel/burst.py) and the sequence-parallel
                  decode path (models/dist_decode.py) — a remote
                  `dma_start` appearing in THIS kernel means pool state
                  leaked into a collective.

@@ -11,10 +11,10 @@ report per policy.
 
 Replica cost function — provenance, not guesswork: replicas advance by
 three rates derived from burstcost's `--cost-json` table
-(analysis/costmodel.py, schema burstcost-v2, itself cross-validated
+(analysis/costmodel.py, schema burstcost-v3, itself cross-validated
 against devstats pair counters and wire-byte counters):
 
-  prefill tokens/s   the best fitting fwd row's ring pass: world*s
+  prefill tokens/s   the best fwd row's ring pass: world*s
                      tokens through max(t_compute_s, t_comm_s);
   decode steps/s     ragged-paged attention's per-step HBM traffic
                      (`ragged_hbm` rows) against the generation's HBM
@@ -123,7 +123,7 @@ def rates_from_cost_table(table: Optional[dict] = None, *,
     if table is None:
         from ..analysis import costmodel
         table = costmodel.cost_table()
-    if table.get("schema") != "burstcost-v2":
+    if table.get("schema") != "burstcost-v3":
         raise ValueError(f"unsupported cost table schema "
                          f"{table.get('schema')!r}")
     hw = table["hw"][generation]
@@ -131,9 +131,9 @@ def rates_from_cost_table(table: Optional[dict] = None, *,
     world = int(table["world"])
     rows = [r for r in table["rows"]
             if r["generation"] == generation and r["pass"] == "fwd"
-            and r["wire"] is None and r["fits"]]
+            and r["wire"] is None]
     if not rows:
-        raise ValueError(f"no fitting fwd rows for generation "
+        raise ValueError(f"no fwd rows for generation "
                          f"{generation!r} in cost table")
     t_pass = min(max(r["t_compute_s"], r["t_comm_s"]) for r in rows)
     prefill_tokens_per_s = world * shape["s"] / t_pass

@@ -3,10 +3,10 @@
                                           [--trace] [--waterfall TRACE_ID]
 
 Renders a report from a run's JSONL export (written by
-`obs.export_jsonl`, which bench.py, benchmarks/ring_overlap.py and the
-training runner call).  A file may hold several export snapshots (the
-exporter appends); the report shows each metric's LAST exported state —
-i.e. the final state of the run — and aggregates spans across snapshots.
+`obs.export_jsonl`, which bench.py and the training runner call).  A file
+may hold several export snapshots (the exporter appends); the report shows
+each metric's LAST exported state — i.e. the final state of the run — and
+aggregates spans across snapshots.
 
 `--merge GLOB` switches to the MULTI-PROCESS view: every matching file is
 one process's export, and the report is the job-level fold (counters sum,

@@ -4,8 +4,8 @@ Everything else in `burst_attn_tpu.obs` is host-only by contract (the
 burstlint `obs-jit-safe` rule proves no registry/span call is reachable
 under jit).  That contract makes the *inside* of a ring step invisible:
 per-round work distribution, mask occupancy under the causal layouts,
-softmax-stat health, fused-ring slot behavior — all of it lives in the
-compiled program, where host instrumentation must never go.
+softmax-stat health — all of it lives in the compiled program, where host
+instrumentation must never go.
 
 `DevStats` closes the gap without breaking the contract.  It is a NamedTuple
 of plain device arrays that the ring forward accumulates IN-GRAPH
@@ -20,9 +20,9 @@ the bargain: the stats-enabled forward/backward traces contain zero
 host-callback primitives, and the stats-OFF trace is bit-identical to the
 plain (pre-devstats) ring program.
 
-Per-shard, every field is a scalar (except `slot_use`); at the
-`burst_attn` boundary the shards are stacked over the ring axis, so the
-caller sees per-device arrays of leading length `world`:
+Per-shard, every field is a scalar; at the `burst_attn` boundary the shards
+are stacked over the ring axis, so the caller sees per-device arrays of
+leading length `world`:
 
   rounds         executed ring rounds (truncated rings count live schedule)
   rounds_live    rounds whose mask had ANY attending pair (ops/masks.spec_live)
@@ -33,27 +33,17 @@ caller sees per-device arrays of leading length `world`:
                  in closed form (analysis/costmodel.pass_flops), with the
                  cost-model-consistent lint rule pinning the closed-form
                  pair count to the per-round sum these counters integrate
-  m_max          max running row-max after the ring (scan ring only; the
-                 fused kernel keeps m internal — reported as -inf there)
+  m_max          max running row-max after the ring
   lse_min/max    finite range of the final log-sum-exp
   nonfinite_lse  count of nan/+inf lse entries (-inf is a legal fully-masked
                  row, not an error)
   nonfinite_acc  count of non-finite accumulator/output entries
-  fused_rounds   rounds executed inside the fused RDMA kernel (0 on scan)
+  fused_rounds   always 0: the ring kernel that counted here is gone, the
+                 field goes with the rest of this module (ROADMAP D13)
   rounds_elided  rounds the occupancy compiler removed from the schedule
                  entirely (windowed/segment-bounded contig rings); these
                  never launched, unlike (rounds - rounds_live) which ran
                  fully masked
-  slot_use       [MAX_SLOTS] per-KV-slot consume counts from the fused
-                 forward kernel's in-kernel scalar output (zeros on the
-                 scan path)
-  slot_use_bwd   [MAX_SLOTS] per-slot bundle consume counts from the fused
-                 BACKWARD kernel (ops/fused_ring_bwd.py), emitted through
-                 the same SMEM scalar-output channel.  Zeros on the scan
-                 path AND on the autodiff path: custom_vjp cotangents
-                 cannot carry telemetry forward in time, so these counters
-                 only populate via the direct `fused_ring_bwd(...,
-                 collect_stats=True)` call (tests, offline audits)
 
 The split of labor per causal layout is visible directly: zigzag/striped
 devices report near-equal `attn_pairs` (the load-balancing the layouts
@@ -66,12 +56,6 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-# Fixed width of the per-device slot_use vector so the pytree structure is
-# static across configs (a fused kernel with fewer slots zero-pads; the scan
-# path reports all zeros).  Matches the largest kv_slots in ops/tuning.py
-# with headroom.
-MAX_SLOTS = 8
 
 _NEG_INF = float("-inf")
 _POS_INF = float("inf")
@@ -98,20 +82,9 @@ class DevStats(NamedTuple):
     # length-bounded packed-segment contig rings): world minus the
     # compiled round count.  Executed-vs-live accounting: rounds +
     # rounds_elided == world on single-ring schedules, and an elided
-    # round never launched — no RDMA, no sweep, no slot traffic — which
-    # is what distinguishes this counter from (rounds - rounds_live),
-    # the rounds that RAN fully masked.
+    # round never launched, which is what distinguishes this counter from
+    # (rounds - rounds_live), the rounds that RAN fully masked.
     rounds_elided: jnp.ndarray   # i32
-    slot_use: jnp.ndarray        # i32[MAX_SLOTS]
-    slot_use_bwd: jnp.ndarray    # i32[MAX_SLOTS]
-    # second-direction banks of the schedule-IR kernels: the ccw ring of a
-    # counter-rotating (bidi) topology, or the double ring's inter
-    # prefetch bank.  Zeros for uni schedules and on the scan path; the
-    # published counter labels these rows dir="ccw" next to the primary
-    # banks' dir="cw" so the bidirectional traffic split is verifiable on
-    # device (docs/observability.md).
-    slot_use_ccw: jnp.ndarray      # i32[MAX_SLOTS]
-    slot_use_bwd_ccw: jnp.ndarray  # i32[MAX_SLOTS]
     # finite-range gauge of the wire quantizer (cfg.wire_dtype): the
     # largest |value| the symmetric per-block quantization mapped to its
     # top code this dispatch.  0.0 on the dense wire.  A growing gauge
@@ -126,7 +99,7 @@ class DevStats(NamedTuple):
         the step, never under a trace (the burstlint `obs-jit-safe` /
         `devstats-pure` pair keeps this honest).  Per-device gauges carry a
         `device` label (ring position); cross-device health extrema and the
-        slot/nonfinite counters are aggregated.  Returns the registry."""
+        nonfinite counters are aggregated.  Returns the registry."""
         import numpy as np
 
         from .registry import default_registry
@@ -166,7 +139,7 @@ class DevStats(NamedTuple):
                   "max/mean per-device attention flops (1.0 = balanced)"
                   ).set(float(fl.max()) / mean if mean > 0 else 0.0, **base)
         reg.gauge("devstats.m_max",
-                  "max running row-max across devices (scan ring)").set(
+                  "max running row-max across devices").set(
             float(leaves["m_max"].max()), **base)
         reg.gauge("devstats.lse_min").set(float(leaves["lse_min"].min()),
                                           **base)
@@ -178,21 +151,8 @@ class DevStats(NamedTuple):
         reg.counter("devstats.nonfinite").inc(
             float(leaves["nonfinite_acc"].sum()), which="acc", **base)
         reg.counter("devstats.fused_rounds",
-                    "ring rounds executed inside the fused RDMA kernel").inc(
+                    "always 0 (no kernel counts here any more)").inc(
             float(leaves["fused_rounds"].sum()), **base)
-        for field, pass_, dir_ in (("slot_use", "fwd", "cw"),
-                                   ("slot_use_bwd", "bwd", "cw"),
-                                   ("slot_use_ccw", "fwd", "ccw"),
-                                   ("slot_use_bwd_ccw", "bwd", "ccw")):
-            slot_tot = leaves[field].sum(axis=0)
-            for j in range(slot_tot.shape[0]):
-                if slot_tot[j]:
-                    reg.counter(
-                        "devstats.slot_use",
-                        "fused-ring chunk/bundle consumes per comm slot, "
-                        "by pass and ring direction").inc(
-                        float(slot_tot[j]), slot=j, dir=dir_, **base,
-                        **{"pass": pass_})
         reg.gauge("devstats.quant_absmax",
                   "largest |value| the wire quantizer mapped to its top "
                   "code (0 = dense wire; watch for saturation)").set(
@@ -202,29 +162,13 @@ class DevStats(NamedTuple):
         return reg
 
 
-def _slot_vec(slot_use):
-    """Zero-pad a [.., slots] counter vector to the static MAX_SLOTS width
-    (None = all zeros, the scan path's value)."""
-    if slot_use is None:
-        return jnp.zeros((MAX_SLOTS,), jnp.int32)
-    return jnp.zeros((MAX_SLOTS,), jnp.int32).at[:slot_use.shape[-1]].set(
-        jnp.asarray(slot_use, jnp.int32).reshape(-1))
-
-
 def ring_stats(rounds, rounds_live, attn_pairs, total_pairs, head_dim,
-               m, lse, acc, fused_rounds=0, rounds_elided=0, slot_use=None,
-               slot_use_bwd=None, slot_use_ccw=None,
-               slot_use_bwd_ccw=None, quant_absmax=0.0) -> DevStats:
+               m, lse, acc, rounds_elided=0, quant_absmax=0.0) -> DevStats:
     """Assemble a per-shard DevStats from ring results (traced context).
 
-    `m` may be None (fused kernel: the row max never leaves the kernel);
-    `acc` is the f32 accumulator on the scan path and the finalized output
-    on the fused path — either way, non-finite entries mean the softmax
-    went wrong.  `lse` -inf entries are legal (fully-masked rows) and are
-    excluded from the finite range but not counted as corruption.
-    `slot_use_bwd` carries the fused backward kernel's bundle slot-consume
-    counters when the caller ran it with collect_stats (see the field
-    docstring above)."""
+    `acc` is the f32 accumulator: non-finite entries mean the softmax went
+    wrong.  `lse` -inf entries are legal (fully-masked rows) and are
+    excluded from the finite range but not counted as corruption."""
     i32 = jnp.int32
     f32 = jnp.float32
     attn_pairs = jnp.asarray(attn_pairs, f32)
@@ -235,19 +179,14 @@ def ring_stats(rounds, rounds_live, attn_pairs, total_pairs, head_dim,
         attn_pairs=attn_pairs,
         total_pairs=jnp.asarray(total_pairs, f32),
         flops=attn_pairs * (4.0 * head_dim),
-        m_max=(jnp.asarray(_NEG_INF, f32) if m is None
-               else jnp.max(m).astype(f32)),
+        m_max=jnp.max(m).astype(f32),
         lse_min=jnp.min(jnp.where(finite, lse, _POS_INF)).astype(f32),
         lse_max=jnp.max(jnp.where(finite, lse, _NEG_INF)).astype(f32),
         nonfinite_lse=jnp.sum(
             jnp.isnan(lse) | (lse == _POS_INF)).astype(i32),
         nonfinite_acc=jnp.sum(~jnp.isfinite(acc)).astype(i32),
-        fused_rounds=jnp.asarray(fused_rounds, i32),
+        fused_rounds=jnp.asarray(0, i32),
         rounds_elided=jnp.asarray(rounds_elided, i32),
-        slot_use=_slot_vec(slot_use),
-        slot_use_bwd=_slot_vec(slot_use_bwd),
-        slot_use_ccw=_slot_vec(slot_use_ccw),
-        slot_use_bwd_ccw=_slot_vec(slot_use_bwd_ccw),
         quant_absmax=jnp.asarray(quant_absmax, f32),
     )
     # telemetry is non-differentiable by definition: zero the tangents here

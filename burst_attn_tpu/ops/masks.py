@@ -181,8 +181,8 @@ def _host_round_pairs(layout: str, q_part: int, kv_part: int, s: int,
                       causal: bool, window=None) -> int:
     """Host (numpy) twin of `spec_pair_count(round_spec(...))` for CONCRETE
     partition ids.  live_delta_table runs from inside traced callers
-    (fused_ring.supported under shard_map), where even constant jnp ops
-    become tracers — so the occupancy table needs an all-host evaluation.
+    (under shard_map), where even constant jnp ops become tracers — so the
+    occupancy table needs an all-host evaluation.
     Mirrors round_spec's spec algebra field by field; pinned equal to the
     traced closed form and the dense-mask sum in tests/test_masks.py."""
     if window is not None:

@@ -165,8 +165,8 @@ def ragged_supported(*, n_kv_heads, n_q_heads, q_tokens, d_head, page,
                      quantized=False, block_q=8, interpret=None):
     """Capability probe: None when ragged_paged_attention can serve this
     shape, else a human-readable reason whose PREFIX is a stable key (the
-    serving engine maps it to a bounded fallback-counter label).  Mirrors
-    fused_ring.supported's contract: probe first, fall back loudly."""
+    serving engine maps it to a bounded fallback-counter label): probe
+    first, fall back loudly."""
     if interpret is None:
         interpret = _interpret_default()
     if q_tokens < 1:

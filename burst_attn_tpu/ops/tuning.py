@@ -51,53 +51,6 @@ class BlockTable(NamedTuple):
     # on the v5e (benchmarks/sweep_tile_calls.py; the numbers are at
     # call_row); the other generations inherit it unswept.
     band_block: int = 512
-    # Fused ring kernel (ops/fused_ring.py): KV communication-slot count
-    # (2 = plain double buffering; more slots let the RDMA pipeline run
-    # deeper ahead of compute at the cost of one extra KV chunk of HBM per
-    # slot) and the q-row block of its grid.  The fused kernel's sweep
-    # reads KV from a VMEM-resident chunk, so — unlike the scan-path
-    # kernels — its row block does NOT gate KV streaming traffic; 512 rows
-    # keeps the per-step acc/stat state small while giving the MXU full
-    # [512, kv] tiles.  Estimated until swept on hardware
-    # (benchmarks/ring_overlap.py reports per-config timings to retune).
-    fused_kv_slots: int = 2
-    fused_block_q: int = 512
-    fused_block_kv: int = 512
-    # VMEM budget (bytes) the fused kernel may plan against for its
-    # resident KV chunk + stats; above it the dispatch falls back to the
-    # scan ring rather than risk a Mosaic allocation failure mid-ring.
-    fused_vmem_budget: int = 96 * 1024 * 1024
-    # Fused ring BACKWARD kernel (ops/fused_ring_bwd.py): bundle/dq slot
-    # count and its grid blocks.  The bwd grid step keeps ~5 [bq, D] tiles
-    # plus two [bq, bkv] intermediates live on top of the resident KV chunk
-    # and the fp32 dk/dv accumulators, so its q block defaults one power of
-    # two below the forward's — mirroring the scan kernels' fwd/bwd block
-    # asymmetry.  Estimated until swept on hardware
-    # (benchmarks/ring_overlap.py --pass bwd reports per-config timings).
-    fused_bwd_slots: int = 2
-    fused_block_q_bwd: int = 256
-    fused_block_kv_bwd: int = 512
-    # Counter-rotating (bidi) / double-ring second bank: slots of the ccw
-    # direction (bidi) or the inter prefetch bank (double ring; the
-    # compiler clamps it to the cycle count).  Per ISSUE 6 these are
-    # per-DIRECTION knobs: the two ICI directions can be tuned
-    # independently when one carries more traffic (e.g. a torus wraparound
-    # link shared with another ring).  Estimated until swept on hardware
-    # (benchmarks/ring_overlap.py --topology bidi reports per-direction
-    # comm floors to retune).
-    fused_ccw_slots: int = 2
-    fused_bwd_ccw_slots: int = 2
-    # Wire precision of the ROTATING ring payloads (parallel/schedule.py
-    # WIRE_DTYPES): None ships the caller's dtypes; "int8"/"fp8" quantize
-    # the fwd K/V chunks, the bwd q-side bundle (lse exempt) and the dq
-    # partials to 1 byte/element with per-block fp32 scales riding the same
-    # HBM slots — ÷4 ring bytes vs the fp32 scan payloads.  Per-generation
-    # because the win is a function of the ICI:FLOPs ratio: every row stays
-    # None (bit-exact payloads) until an on-chip sweep
-    # (benchmarks/ring_overlap.py --wire-dtype) shows the comm floor is the
-    # bottleneck for that generation's links, at which point the measured
-    # row may opt in.  burst_attn(..., wire_dtype=...) overrides per call.
-    fused_wire_dtype: Optional[str] = None
 
 
 class ResolvedBlocks(NamedTuple):
@@ -270,79 +223,6 @@ def _clamp_cliff(bq: int, bkv: int, area: int, which: str):
     return bq, new_bkv
 
 
-class ResolvedFused(NamedTuple):
-    """resolve_fused() result: the fused ring kernels' static plan knobs
-    (forward KV ring AND backward bundle/dq ring — one resolution so the
-    two passes can never read different generation rows)."""
-
-    block_q: int
-    block_kv: int
-    kv_slots: int
-    vmem_budget: int
-    block_q_bwd: int
-    block_kv_bwd: int
-    bwd_slots: int
-    ccw_slots: int
-    bwd_ccw_slots: int
-    wire_dtype: Optional[str] = None
-
-    @property
-    def wire_itemsize(self) -> int:
-        """Bytes/element of the rotating payload banks (slot byte budgets
-        in supported()'s VMEM plans price quantized banks at 1 B/elem; the
-        per-block fp32 scales are O(1) per chunk and priced separately)."""
-        return 4 if self.wire_dtype is None else 1
-
-
-def resolve_fused(block_q=None, block_kv=None, kv_slots=None,
-                  device=None, block_q_bwd=None, block_kv_bwd=None,
-                  bwd_slots=None, ccw_slots=None,
-                  bwd_ccw_slots=None, wire_dtype=None,
-                  table: Optional[BlockTable] = None) -> ResolvedFused:
-    """Fill the fused ring kernels' knobs from the per-generation table.
-
-    kv_slots / bwd_slots < 2 cannot double-buffer (the send target would
-    be the slot being computed on) and is rejected rather than silently
-    bumped — an explicit wrong config should fail loudly, only the table
-    default is implicit.  The bwd blocks never default LARGER than the
-    (resolved) fwd blocks, mirroring resolve_blocks: a caller who tunes
-    the fwd blocks down for VMEM keeps that budget in the backward.
-    ccw_slots / bwd_ccw_slots tune the SECOND slot bank (the ccw direction
-    of a bidi ring, or the double ring's inter prefetch bank) per pass.
-    wire_dtype=None means "use the generation's fused_wire_dtype default"
-    (itself None on every row today — the wire stays bit-exact unless the
-    caller opts in per call).  `table` bypasses the device probe with an
-    explicit BlockTable row — how the static cost verifier resolves every
-    generation's knobs through the SAME defaulting algebra the dispatch
-    runs, from a host with no TPU."""
-    t = block_defaults(device) if table is None else table
-    bq = t.fused_block_q if block_q is None else block_q
-    bkv = t.fused_block_kv if block_kv is None else block_kv
-    slots = t.fused_kv_slots if kv_slots is None else kv_slots
-    bqb = min(t.fused_block_q_bwd, bq) if block_q_bwd is None else block_q_bwd
-    bkvb = (min(t.fused_block_kv_bwd, bkv) if block_kv_bwd is None
-            else block_kv_bwd)
-    bslots = t.fused_bwd_slots if bwd_slots is None else bwd_slots
-    cslots = t.fused_ccw_slots if ccw_slots is None else ccw_slots
-    bcslots = (t.fused_bwd_ccw_slots if bwd_ccw_slots is None
-               else bwd_ccw_slots)
-    if slots < 2:
-        raise ValueError(f"fused ring needs kv_slots >= 2, got {slots}")
-    if bslots < 2:
-        raise ValueError(f"fused ring bwd needs bwd_slots >= 2, got {bslots}")
-    if cslots < 2:
-        raise ValueError(f"fused ring needs ccw_slots >= 2, got {cslots}")
-    if bcslots < 2:
-        raise ValueError(
-            f"fused ring bwd needs bwd_ccw_slots >= 2, got {bcslots}")
-    wire = t.fused_wire_dtype if wire_dtype is None else wire_dtype
-    if wire not in (None, "int8", "fp8"):
-        raise ValueError(
-            f"wire_dtype must be None, 'int8' or 'fp8', got {wire!r}")
-    return ResolvedFused(bq, bkv, slots, t.fused_vmem_budget,
-                         bqb, bkvb, bslots, cslots, bcslots, wire)
-
-
 def _pow2_ceil(x: int) -> int:
     return 1 << max(x - 1, 0).bit_length()
 
@@ -416,7 +296,8 @@ def resolve_blocks(block_q=None, block_kv=None, block_q_bwd=None,
     _clamp_cliff; budgets come from the device's BlockTable row).  Always
     returns a 5-field ResolvedBlocks; callers without a compute sub-block
     ignore the last field.  `table` bypasses the device probe with an
-    explicit BlockTable row (see resolve_fused).
+    explicit BlockTable row: how the static cost verifier resolves every
+    generation's blocks through the same defaulting from a host with no TPU.
     """
     t = block_defaults(device) if table is None else table
     fwd_q, fwd_kv, bwd_q, bwd_kv = call_row(t, s_q, s_kv, window)

@@ -37,10 +37,9 @@ chunked device-major over (inter, intra): partition id = inter_rank *
 intra_size + intra_rank.
 """
 
-import logging
 from dataclasses import dataclass
 from functools import partial
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -58,16 +57,12 @@ from .ring import (ppermute_by, ppermute_next, my_partition,
                    partition_at_round, ring_round_counts,
                    wire_dequantize, wire_quantize)
 
-logger = logging.getLogger("burst_attn_tpu")
-
 # -- obs dispatch instrumentation (host boundary only — see _note_dispatch).
 # These counters advance when a program is DISPATCHED (once per trace under
 # jit, once per call eagerly): the unit for "which path did the ring take",
 # not per-step execution counts (docs/observability.md, "per-trace").
 _M_DISPATCH = obs.counter(
-    "burst.dispatch", "ring dispatches by path (fused kernel vs scan ring)")
-_M_FALLBACK = obs.counter(
-    "burst.fused_fallback", "fused_ring dispatches declined, by reason")
+    "burst.dispatch", "ring dispatches by backend and tile")
 _M_ROUNDS = obs.counter(
     "burst.ring_rounds", "scheduled ring rounds (incl. the self round)")
 _M_INPLACE = obs.counter(
@@ -99,12 +94,8 @@ class BurstConfig:
     scale: Optional[float] = None  # default 1/sqrt(head_dim)
     intra_axis: str = "sp"
     inter_axis: Optional[str] = None  # set for the hierarchical double ring
-    # "jnp" | "pallas" | "fused_ring".  fused_ring runs the WHOLE forward
-    # ring inside one Pallas kernel with in-kernel RDMA KV rotation
-    # (ops/fused_ring.py); configs the fused kernel does not cover (double
-    # ring, window, segments, cross-attention, off-TPU without
-    # BURST_FUSED_INTERPRET) and the backward fall back to the scan ring
-    # with the pallas (TPU) / jnp (CPU) tile backend.
+    # the tile of a round: "jnp" (the float32 oracle) | "pallas" (the flash
+    # kernels, ops/pallas_flash.py)
     backend: str = "jnp"
     optimize_bwd_comm: bool = True  # rotate delta=sum(o*do) [B,N,S] f32, not o
     # kernel blocks; None = resolved per tile CALL by resolved_blocks() in
@@ -138,46 +129,13 @@ class BurstConfig:
     # ships the caller's dtypes bit-exactly; "int8"/"fp8" quantize the fwd
     # K/V blocks, the bwd q-side bundle (delta|o, do, q — lse stays fp32)
     # and the fp32 dq partials to 1 B/elem, with per-block fp32 SCALAR
-    # scales riding the same payload (scan ring: extra pytree leaves in the
-    # rotating tuple; fused kernels: parallel scale slot banks on the same
-    # semaphores/credits, ops/fused_ring*.py).  fp32 ACCUMULATION is never
-    # touched — every quantized tensor is rescaled before any dot/add, like
-    # ops/ragged_paged.py's int8 pool path — so the cost is a pinned
-    # quantization tolerance, not a different algorithm.  Resident tensors
-    # and the purely-local math never see the wire dtype.
+    # scales riding the same payload (extra pytree leaves in the rotating
+    # tuple).  fp32 ACCUMULATION is never touched — every quantized tensor
+    # is rescaled before any dot/add, like ops/ragged_paged.py's int8 pool
+    # path — so the cost is a pinned quantization tolerance, not a different
+    # algorithm.  Resident tensors and the purely-local math never see the
+    # wire dtype.
     wire_dtype: Optional[str] = None
-    # Fused ring kernel knobs (backend="fused_ring" only): KV communication
-    # slot count (>= 2) and the fused grid's q-row / kv-sweep blocks; None =
-    # the per-TPU-generation table (ops/tuning.py resolve_fused).  The
-    # *_bwd / bwd_slots trio tunes the fused BACKWARD kernel (bundle + dq
-    # ring, ops/fused_ring_bwd.py) independently.
-    fused_kv_slots: Optional[int] = None
-    fused_block_q: Optional[int] = None
-    fused_block_kv: Optional[int] = None
-    fused_bwd_slots: Optional[int] = None
-    fused_block_q_bwd: Optional[int] = None
-    fused_block_kv_bwd: Optional[int] = None
-    # Schedule-IR topology selection (parallel/schedule.py): "auto" = uni
-    # on a flat ring, double when an inter axis (or fused_seq_factor) is
-    # present; "bidi" opts the flat ring into the counter-rotating
-    # bidirectional schedule (both ICI directions, per-direction slot
-    # banks; worlds < 3 degrade to uni).  fused_seq_factor = (n_inter,
-    # n_intra) grids the DOUBLE-ring schedule onto a flat ring axis
-    # (inter-major device order) — the schedule the reference's
-    # hierarchical ring runs, without needing a second mesh axis.
-    fused_topology: str = "auto"
-    fused_seq_factor: Optional[Tuple[int, int]] = None
-    # per-direction slot knobs for the second bank (bidi ccw / double
-    # inter prefetch); None = the per-generation table (ops/tuning.py)
-    fused_ccw_slots: Optional[int] = None
-    fused_bwd_ccw_slots: Optional[int] = None
-    # Ordered ((axis name, size), ...) of ALL mesh axes, host-filled by
-    # burst_attn: the fused kernels compute full LOGICAL RDMA ids from it
-    # (parallel/ring.device_roles), which is what makes multi-axis
-    # (pp x tp x sp) meshes safe to fuse.  None = the ring axes are the
-    # only axes in scope (direct burst_attn_shard users on bigger meshes
-    # fall back to the scan ring unless they fill this in).
-    mesh_axes: Optional[Tuple[Tuple[str, int], ...]] = None
     # Structural causal scheduling (reference burst_attn_interface.py:221-235,
     # :303-367): zigzag rounds dispatch through a 3-way lax.cond whose
     # branches run statically-sliced dense tiles (full q x half kv / half q x
@@ -222,21 +180,11 @@ class BurstConfig:
             raise ValueError(
                 f"wire_dtype must be None, 'int8' or 'fp8', got "
                 f"{self.wire_dtype!r}")
-        if self.fused_topology not in ("auto", "uni", "bidi", "double"):
+        if self.backend not in ("jnp", "pallas"):
             raise ValueError(
-                f"fused_topology must be auto|uni|bidi|double, got "
-                f"{self.fused_topology!r}")
-        if self.fused_seq_factor is not None:
-            f = tuple(self.fused_seq_factor)
-            if len(f) != 2 or any(x < 1 for x in f):
-                raise ValueError(
-                    f"fused_seq_factor must be (n_inter, n_intra) positive "
-                    f"ints, got {self.fused_seq_factor!r}")
-            object.__setattr__(self, "fused_seq_factor", f)
-        if self.mesh_axes is not None:
-            object.__setattr__(self, "mesh_axes",
-                               tuple((str(a), int(sz))
-                                     for a, sz in self.mesh_axes))
+                "backend must be 'jnp' or 'pallas' (burst_attn also takes "
+                f"'auto': pallas on a TPU, jnp elsewhere), got "
+                f"{self.backend!r}")
 
     def resolved_blocks(self, s_q=None, s_kv=None, window=None):
         """ResolvedBlocks with None fields filled by ops/tuning.py — the one
@@ -252,16 +200,6 @@ class BurstConfig:
 
 # ---------------------------------------------------------------------------
 # tile dispatch
-
-
-def _tile_backend(cfg) -> str:
-    """Per-round tile backend.  "fused_ring" maps to the equivalent tile
-    backend for everything that stays on the scan ring — the backward pass
-    and any forward the fused kernel declines (see _fwd_impl) — so a
-    fused_ring config degrades to the best scan path instead of erroring."""
-    if cfg.backend != "fused_ring":
-        return cfg.backend
-    return "pallas" if jax.default_backend() == "tpu" else "jnp"
 
 
 def _call_blocks(cfg, s_q, s_kv, q_range, kv_range, window):
@@ -285,7 +223,7 @@ def _tile_fwd(cfg, q, k, v, m, lse, acc, scale, spec, triangular=False,
     static decision (_round_in_kernel reads the same one).  `window`: the
     tile's static window where it is not cfg.window (a masks.BlockUnits)."""
     window = cfg.window if window is None else window
-    if _tile_backend(cfg) == "pallas":
+    if cfg.backend == "pallas":
         from ..ops import pallas_flash
 
         rb = _call_blocks(cfg, q.shape[2], k.shape[2], q_range, kv_range,
@@ -315,7 +253,7 @@ def _tile_bwd(cfg, do, q, k, v, delta, lse, scale, spec, triangular=False,
     partial); dk, dv are `carry` = (dk, dv) plus this round's where a carry
     is given.  Ranges and `window` as in _tile_fwd."""
     window = cfg.window if window is None else window
-    if _tile_backend(cfg) == "pallas":
+    if cfg.backend == "pallas":
         from ..ops import pallas_flash
 
         rb = _call_blocks(cfg, q.shape[2], k.shape[2], q_range, kv_range,
@@ -353,7 +291,7 @@ def _round_in_kernel(cfg, pass_, q_shape, k_shape) -> bool:
     shapes, or takes the sliced / added form in XLA: the tile entry's own
     static gate (pallas_flash.fwd_covers_ranges / bwd_folds_carry), asked
     for every tile call the round can make."""
-    if _tile_backend(cfg) != "pallas":
+    if cfg.backend != "pallas":
         return False
     from ..ops import pallas_flash
 
@@ -494,20 +432,6 @@ def _fwd_impl(q, k, v, cfg: BurstConfig, seg=None, collect=False):
     """
     if cfg.block_diffusion is not None:
         return _bd_fwd(q, k, v, cfg)
-    if cfg.backend == "fused_ring":
-        # tentpole fast path: the whole W-round ring in one Pallas kernel
-        # with in-kernel RDMA KV rotation (ops/fused_ring.py).  Declined
-        # configs fall through to the scan ring below with the tile backend
-        # from _tile_backend (the returned reason says why — logged once
-        # per trace so a silently-degraded bench run is visible).
-        from ..ops import fused_ring
-
-        reason = fused_ring.supported(cfg, q.shape, k.shape, seg is not None)
-        if reason is None:
-            return fused_ring.fused_ring_fwd(q, k, v, cfg, seg=seg,
-                                             collect_stats=collect)
-        logger.info("fused_ring backend falling back to the scan ring: %s",
-                    reason)
 
     b, n, s, d = q.shape
     scale = cfg.scale if cfg.scale is not None else d**-0.5
@@ -722,25 +646,9 @@ def _bwd_impl(cfg: BurstConfig, q, k, v, o, lse, do, seg=None):
     returned home by one extra hop (burst_attn_interface.py:255-398).
     With packed sequences (`seg`), the q-side ids rotate with the payload
     while the resident kv side keeps the local ids.
-
-    This is the ONE backward dispatch point (all four custom_vjp twins
-    route here): with `backend="fused_ring"` both rotating streams run
-    inside a single Pallas kernel (ops/fused_ring_bwd.py) when the bwd
-    gate admits the config; declined configs fall through to the scan
-    ring below with the tile backend from _tile_backend.
     """
     if cfg.block_diffusion is not None:
         return _bd_bwd(cfg, q, k, v, o, lse, do)
-    if cfg.backend == "fused_ring":
-        from ..ops import fused_ring, fused_ring_bwd
-
-        reason = fused_ring.supported(cfg, q.shape, k.shape, seg is not None,
-                                      pass_="bwd")
-        if reason is None:
-            return fused_ring_bwd.fused_ring_bwd(cfg, q, k, v, o, lse, do,
-                                                 seg=seg)
-        logger.info("fused_ring backward falling back to the scan ring: %s",
-                    reason)
 
     b, n, s, d = q.shape
     scale = cfg.scale if cfg.scale is not None else d**-0.5
@@ -1076,100 +984,43 @@ _burst_attn_shard_stats_seg.defvjp(_stats_seg_vjp_fwd, _stats_seg_vjp_bwd)
 # global-array wrapper
 
 
-# (reason-string prefix -> bounded label) for burst.fused_fallback: the
-# supported() reasons embed shapes/budgets, which would explode counter
-# cardinality if used as labels verbatim.  Since the schedule-IR refactor
-# the "double ring" and generic multi-axis rows only exist as
-# interpret-mode emulation limits (labelled interpret-*); on hardware both
-# trace fused.
-_FALLBACK_LABELS = (
-    ("off-TPU", "off-tpu"),
-    ("interpret-mode remote DMA", "interpret-single-axis"),
-    ("double ring inter axis", "double-ring-axis-unbound"),
-    # "sliding window" / "packed segments" rows are GONE: since the
-    # occupancy compiler both run fused (window as a static band predicate,
-    # segments via a gathered id side table) with dead rounds elided from
-    # the program — there is no such decline reason left to label
-    ("cross-attention", "cross-attn"),
-    ("world < 2", "world-lt-2"),
-    ("ring axis", "multi-axis-no-mesh"),
-    ("axis env unavailable", "axis-env-unavailable"),
-    ("topology config invalid", "topology-invalid"),
-    ("schedule compiler declined", "schedule-compiler"),
-    ("VMEM plan", "vmem-budget"),
-)
-
-
-def _fallback_label(reason: str) -> str:
-    for prefix, label in _FALLBACK_LABELS:
-        if reason.startswith(prefix):
-            return label
-    return "other"
-
-
-def _note_dispatch(cfg: BurstConfig, mesh, q_shape, k_shape, has_seg: bool,
-                   batch_axes, head_axes) -> None:
+def _note_dispatch(cfg: BurstConfig, mesh, q_shape, k_shape, batch_axes,
+                   head_axes) -> None:
     """Record one ring dispatch in the obs registry (burst.dispatch /
-    burst.fused_fallback / burst.ring_rounds / burst.ring_hops).
+    burst.ring_rounds / burst.inplace_rounds / burst.ring_hops /
+    burst.wire_bytes).
 
     Host-boundary code: called from burst_attn BEFORE shard_map, never from
-    inside the traced shard program (burstlint `obs-jit-safe`).  The fused
-    gate is re-evaluated here with explicit world/extra_axes through the
-    SAME fused_ring.supported predicate the traced dispatch runs, on the
-    same per-shard shapes, so these counters cannot drift from _fwd_impl's
-    real decision."""
-    from ..ops import fused_ring
+    inside the traced shard program (burstlint `obs-jit-safe`)."""
 
-    def _sizes_of(axes):
+    def _size_of(axes):
         if axes is None:
-            return 1, []
+            return 1
         axes = axes if isinstance(axes, (tuple, list)) else (axes,)
-        names = [a for a in axes if a is not None]
         prod = 1
-        for a in names:
-            prod *= mesh.shape.get(a, 1)
-        return prod, [a for a in names if mesh.shape.get(a, 1) > 1]
+        for a in axes:
+            if a is not None:
+                prod *= mesh.shape.get(a, 1)
+        return prod
 
     n_intra = mesh.shape.get(cfg.intra_axis, 1)
     n_inter = (mesh.shape.get(cfg.inter_axis, 1)
                if cfg.inter_axis is not None else 1)
     world = n_inter * n_intra
-    b_div, extra_b = _sizes_of(batch_axes)
-    h_div, extra_h = _sizes_of(head_axes)
+    b_div, h_div = _size_of(batch_axes), _size_of(head_axes)
     q_local = (max(1, q_shape[0] // b_div), max(1, q_shape[1] // h_div),
                max(1, q_shape[2] // world), q_shape[3])
     k_local = (max(1, k_shape[0] // b_div), max(1, k_shape[1] // h_div),
                max(1, k_shape[2] // world), k_shape[3])
-    path, reason, reason_bwd = "scan", None, None
-    if cfg.backend == "fused_ring":
-        reason = fused_ring.supported(cfg, q_local, k_local, has_seg,
-                                      world=n_intra, n_inter=n_inter,
-                                      extra_axes=extra_b + extra_h)
-        path = "fused" if reason is None else "scan"
-        # the backward runs its own gate at _bwd_impl's dispatch point; a
-        # bwd-only decline (e.g. the bwd VMEM plan overflowing while the
-        # fwd fits) must be distinguishable in obs output, so the fallback
-        # counter is labeled by pass
-        reason_bwd = fused_ring.supported(cfg, q_local, k_local, has_seg,
-                                          world=n_intra, n_inter=n_inter,
-                                          extra_axes=extra_b + extra_h,
-                                          pass_="bwd")
-        if reason_bwd is not None:
-            _M_FALLBACK.inc(reason=_fallback_label(reason_bwd),
-                            **{"pass": "bwd"})
-    _M_DISPATCH.inc(path=path, backend=cfg.backend, tile=_tile_backend(cfg))
-    if reason is not None:
-        _M_FALLBACK.inc(reason=_fallback_label(reason), **{"pass": "fwd"})
+    _M_DISPATCH.inc(backend=cfg.backend, tile=cfg.backend)
     r_live = _r_live(cfg, q_local[2], k_local[2], n_inter, n_intra)
     rounds, intra_hops, inter_hops = ring_round_counts(n_inter, n_intra,
                                                        r_live)
     _M_ROUNDS.inc(rounds)
-    # of the scan ring's rounds after the self round, which fold into their
-    # carry inside the kernel: the tile entry's own static gate, per pass
-    scans = {"fwd": path == "scan",
-             "bwd": cfg.backend != "fused_ring" or reason_bwd is not None}
+    # of the rounds after the self round, which fold into their carry inside
+    # the kernel: the tile entry's own static gate, per pass
     for pass_ in ("fwd", "bwd"):
-        if rounds > 1 and scans[pass_]:
+        if rounds > 1:
             in_kernel = _round_in_kernel(cfg, pass_, q_local, k_local)
             _M_INPLACE.inc(rounds - 1, **{
                 "pass": pass_, "path": "kernel" if in_kernel else "xla"})
@@ -1178,8 +1029,8 @@ def _note_dispatch(cfg: BurstConfig, mesh, q_shape, k_shape, has_seg: bool,
     if inter_hops:
         _M_HOPS.inc(inter_hops, axis="inter")
     # per-round wire bytes from the ONE shared derivation
-    # (schedule.wire_round_bytes) — the same numbers ring_overlap records
-    # and tests/test_wire_quant.py replays against the compiled program
+    # (schedule.wire_round_bytes), which tests/test_wire_quant.py replays
+    # against the compiled program
     from . import schedule as sched_ir
 
     b_l, n_l, s_l, d_l = q_local
@@ -1221,16 +1072,6 @@ def burst_attn(
     window: Optional[int] = None,
     segment_ids=None,
     max_segment_len: Optional[int] = None,
-    fused_kv_slots: Optional[int] = None,
-    fused_block_q: Optional[int] = None,
-    fused_block_kv: Optional[int] = None,
-    fused_bwd_slots: Optional[int] = None,
-    fused_block_q_bwd: Optional[int] = None,
-    fused_block_kv_bwd: Optional[int] = None,
-    fused_topology: str = "auto",
-    fused_seq_factor: Optional[Tuple[int, int]] = None,
-    fused_ccw_slots: Optional[int] = None,
-    fused_bwd_ccw_slots: Optional[int] = None,
     wire_dtype: Optional[str] = None,
     collect_stats: bool = False,
     block_diffusion: Optional[int] = None,
@@ -1252,10 +1093,8 @@ def burst_attn(
     statically elide ring rounds no segment can reach.
     wire_dtype: "int8" | "fp8" | None — quantize the ROTATING ring payloads
     (fwd K/V, bwd bundle, dq partials; lse exempt) with per-block fp32
-    scales riding the same transport; None = the per-generation table
-    default (ops/tuning.py fused_wire_dtype, itself None = bit-exact wire).
-    fp32 accumulation is untouched; see docs/fused_ring.md for the pinned
-    tolerances.
+    scales riding the same transport; None = bit-exact wire.  fp32
+    accumulation is untouched; tests/test_wire_quant.py pins the tolerances.
     collect_stats: return `(o, obs.devstats.DevStats)` instead of `o` —
     in-graph ring telemetry with a leading per-device axis of length
     `world` (batch/head replica groups are pre-reduced in-graph).  Fold it
@@ -1273,8 +1112,6 @@ def burst_attn(
         inter_axis, intra_axis = seq_axes
     else:
         raise ValueError(f"seq_axes must have 1 or 2 names, got {seq_axes}")
-    from ..ops.tuning import block_defaults
-
     if block_diffusion is not None:
         world = 1
         for a in seq_axes:
@@ -1293,10 +1130,6 @@ def burst_attn(
     # window validation lives in BurstConfig.__post_init__ (constructed
     # below); the blocks stay as the caller gave them (None = unset): each
     # tile call resolves its own from its geometry (cfg.resolved_blocks)
-    if wire_dtype is None:
-        # per-generation wire default (every table row is None today — the
-        # wire stays bit-exact unless the caller opts in per call)
-        wire_dtype = block_defaults().fused_wire_dtype
     cfg = BurstConfig(
         causal=causal,
         layout=layout,
@@ -1312,24 +1145,10 @@ def burst_attn(
         case_split=case_split,
         window=window,
         max_segment_len=max_segment_len,
-        fused_kv_slots=fused_kv_slots,
-        fused_block_q=fused_block_q,
-        fused_block_kv=fused_block_kv,
-        fused_bwd_slots=fused_bwd_slots,
-        fused_block_q_bwd=fused_block_q_bwd,
-        fused_block_kv_bwd=fused_block_kv_bwd,
-        fused_topology=fused_topology,
-        fused_seq_factor=fused_seq_factor,
-        fused_ccw_slots=fused_ccw_slots,
-        fused_bwd_ccw_slots=fused_bwd_ccw_slots,
         wire_dtype=wire_dtype,
         block_diffusion=block_diffusion,
-        # the host knows the mesh's full axis order: the fused kernels
-        # compute multi-axis LOGICAL RDMA ids from it (ring.device_roles)
-        mesh_axes=tuple((str(a), int(sz)) for a, sz in mesh.shape.items()),
     )
-    _note_dispatch(cfg, mesh, q.shape, k.shape, segment_ids is not None,
-                   batch_axes, head_axes)
+    _note_dispatch(cfg, mesh, q.shape, k.shape, batch_axes, head_axes)
     seq_spec = seq_axes if len(seq_axes) > 1 else intra_axis
     spec = P(batch_axes, head_axes, seq_spec, None)
     if collect_stats:

@@ -1,19 +1,18 @@
 """Ring-schedule IR and compiler (ROADMAP item 1).
 
-The hand-built slot schedules this module replaces
-(`ring.fused_slot_schedule` / `ring.fused_bwd_slot_schedule`) encoded
-exactly one topology: a unidirectional single ring.  Here a ring schedule
-is a small compiled PROGRAM — per-round consume/send/recv/credit ops per
-stream — emitted once by `compile_fwd` / `compile_bwd` and lowered twice:
+A ring schedule is a small compiled PROGRAM — per-round
+consume/send/recv/credit ops per stream — emitted once by `compile_fwd` /
+`compile_bwd`.  It was written for two executors, of which one is left:
 
   * `scan_events(program)` flattens it to the ordered (cls, axis, hops)
     collective stream the scan ring issues (`parallel/ring.ring_round_counts`
     derives its hop accounting from this, and burstlint matches the traced
     scan program against the same stream via analysis/oracle.py);
-  * `to_table(program)` packs it into the int32 scalar-prefetch table the
-    fused Pallas kernels interpret (ops/fused_ring.py reads the payload
-    columns, ops/fused_ring_bwd.py additionally the dq columns) — the
-    kernels contain NO schedule logic of their own.
+  * `to_table(program)` packs it into an int32 scalar-prefetch table for a
+    ring kernel with in-kernel RDMA.  The kernels that read it never
+    compiled for hardware and are gone (git keeps them at PR 31's parent);
+    the table, the `bidi` programs and the slot / credit ops have no
+    executor until a new such kernel is written (ROADMAP D7c).
 
 Topologies the compiler emits (all simulation-proven by
 analysis/oracle.verify_ring_program before any kernel may consume them —
@@ -80,7 +79,7 @@ TOPOLOGIES = ("uni", "bidi", "double")
 WIRE_DTYPES = (None, "int8", "fp8")
 
 # ---------------------------------------------------------------------------
-# table column layout (shared by both fused kernels; bwd extends fwd).
+# table column layout (bwd extends fwd).
 # Columns 0..4 are reserved for the per-round mask-spec scalars
 # (ops/masks.round_spec via pallas_flash._spec_array) which the kernel
 # ENTRY fills in — they are traced values (they depend on the device's
@@ -824,8 +823,8 @@ def quantized_operands(program: RingProgram) -> int:
 
 
 def expected_remote_dma(program: RingProgram, operands_ch: int = 2) -> int:
-    """Remote dma_start CALL SITES the fused kernel lowered from this
-    program must contain — the fused-ring-fused census (burstlint).
+    """Remote dma_start CALL SITES a ring kernel lowered from this
+    program must contain.
 
     operands_ch: arrays per payload send (fwd: k+v = 2; bwd bundle: 4).
     Channel 0 contributes one site per (operand, src bank) it ever sources
@@ -867,10 +866,9 @@ def expected_remote_dma(program: RingProgram, operands_ch: int = 2) -> int:
 
 # ---------------------------------------------------------------------------
 # wire byte accounting — the ONE derivation of per-round ring bytes.  The
-# obs dispatch counters (parallel/burst._note_dispatch), the comm-floor
-# benchmark (benchmarks/ring_overlap.py) and the schedule-replay test
-# (tests/test_wire_quant.py) all call this helper, so they cannot drift
-# from each other by construction.
+# obs dispatch counters (parallel/burst._note_dispatch) and the
+# schedule-replay test (tests/test_wire_quant.py) both call this helper, so
+# they cannot drift from each other by construction.
 
 
 def wire_itemsize(wire: Optional[str], dense_itemsize: int = 4) -> int:
@@ -933,6 +931,5 @@ def partition_for_round(program: RingProgram, r: int, inter_rank, intra_rank):
 
 
 def bank_dirs(program: RingProgram) -> Tuple[str, ...]:
-    """Human/obs labels of the slot banks, in bank order: the devstats
-    `slot_use{dir=...}` label values (docs/observability.md)."""
+    """Human labels of the slot banks, in bank order."""
     return program.channels

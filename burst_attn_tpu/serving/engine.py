@@ -102,7 +102,9 @@ _M_RB_DECODE = obs.counter("serve.ragged_batch_decode_tokens",
 _M_RB_FILL = obs.gauge("serve.ragged_batch_fill",
                        "real-token fraction of the last launch's [slots, "
                        "chunk] token grid")
-_M_FALLBACK = obs.counter("burst.fused_fallback")
+_M_FALLBACK = obs.counter(
+    "burst.fused_fallback",
+    "ragged-kernel launches declined to the dense-gather path, by reason")
 # prefix-cache family: admission-time sharing and the write barrier
 _M_PREFIX_HITS = obs.counter("serve.prefix_hits",
                              "admissions that pinned >= 1 cached prefix page")
@@ -142,8 +144,7 @@ from .model import (
     ragged_model_step,
 )
 
-# reason-string prefix -> bounded counter label, mirroring
-# parallel/burst.py's _FALLBACK_LABELS contract (probe reasons embed
+# reason-string prefix -> bounded counter label (probe reasons embed
 # shapes, which would explode label cardinality verbatim)
 _FALLBACK_LABELS = (
     ("empty q chunk", "empty-chunk"),

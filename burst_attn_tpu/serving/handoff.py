@@ -4,8 +4,8 @@ DIRECTLY in pool pages, feeding sequence-parallel paged decode.
 The long-context serving story has three acts (ROADMAP items 3/4):
 
   1. PREFILL at ring scale: the training forward (burst ring attention
-     over the `sp` axes, fused_ring on hardware / scan ring elsewhere —
-     cfg.attn_backend picks, exactly as in training) absorbs the prompt.
+     over the `sp` axes; cfg.attn_backend picks the tile, exactly as in
+     training) absorbs the prompt.
   2. HANDOFF: each layer's rope'd K/V is scattered straight from the
      ring-sharded activations into pool pages — in LAYOUT order, with NO
      re-layout copy.  Page p simply holds layout positions

@@ -8,13 +8,12 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 args=("$@")
 filtered=()
-fast=0; tpu=0; fused=0; obs=0; schedule=0; serve=0; loadgen=0; fleet=0
+fast=0; tpu=0; obs=0; schedule=0; serve=0; loadgen=0; fleet=0
 quant=0; sim=0
 for a in "${args[@]}"; do
   case "$a" in
     --fast) fast=1 ;;
     --tpu) tpu=1 ;;
-    --fused) fused=1 ;;
     --obs) obs=1 ;;
     --schedule) schedule=1 ;;
     --serve) serve=1 ;;
@@ -27,8 +26,7 @@ for a in "${args[@]}"; do
 done
 # burstlint pre-test gate: CPU-only static verification (ring invariants,
 # numerics contract, AST hygiene, protocol model checking, and the
-# burstcost resource/roofline family — the full tuning-table x topology x
-# wire-dtype x pass VMEM-budget matrix, sub-second) in a few seconds —
+# burstcost roofline family, sub-second) in a few seconds —
 # tier-1 fails on new violations before any test runs.  The
 # SARIF copy feeds CI annotation uploaders; the gate itself keys off the
 # exit status.
@@ -223,44 +221,31 @@ elif [[ $sim == 1 ]]; then
     --strict-cache --summary-json results/sim_gate.json
 elif [[ $schedule == 1 ]]; then
   # focused lane for the ring-schedule IR + compiler (parallel/schedule.py):
-  # compiler/oracle unit tests, interpret-mode parity of the bidi and
-  # double-ring fused schedules vs the scan ring + dense oracle, and the
-  # schedule-proof mutation suite (flipped direction, shortened prefetch,
-  # aliased slot, broken elider — each must fire).  The burstlint gate above
-  # already simulation-proved the full emitted matrix (including the
-  # occupancy-elided r_live entries) + the hardware-trace census.
-  python -m pytest tests/test_schedule_ir.py tests/test_fused_topologies.py \
-    tests/test_schedule.py -q ${filtered[@]+"${filtered[@]}"}
-  python -m pytest tests/test_analysis.py -q -k "ring_program or fused or elision or elided" \
+  # compiler/oracle unit tests and the schedule-proof mutation suite
+  # (flipped direction, shortened prefetch, aliased slot, broken elider —
+  # each must fire).  The burstlint gate above already simulation-proved
+  # the full emitted matrix (including the occupancy-elided r_live entries).
+  python -m pytest tests/test_schedule_ir.py tests/test_schedule.py -q \
+    ${filtered[@]+"${filtered[@]}"}
+  python -m pytest tests/test_analysis.py -q -k "ring_program or elision or elided" \
     ${filtered[@]+"${filtered[@]}"}
   # occupancy compilation: closed-form/live-set unit tests, then the
-  # elided windowed + packed-segment fused parity sweeps (incl. slow)
+  # windowed + packed-segment round accounting on the ring (incl. slow)
   python -m pytest tests/test_masks.py -q \
     -k "pair_count or elided or elision or truncate or segment or prefix" \
     ${filtered[@]+"${filtered[@]}"}
-  python -m pytest tests/test_fused_ring.py tests/test_fused_ring_bwd.py \
-    tests/test_devstats.py -q \
-    -k "window or segment or elided or elision or supported" \
+  python -m pytest tests/test_devstats.py -q \
+    -k "window or segment or elided or elision" \
     ${filtered[@]+"${filtered[@]}"}
 elif [[ $quant == 1 ]]; then
   # focused lane for the wire-precision layer (cfg.wire_dtype): fwd/grad
-  # parity matrices vs the fp32 ring (slow-marked sweeps included here on
-  # purpose), wire_dtype=None bit-identity, byte-accounting replay against
-  # schedule.wire_round_bytes, and the scale-proof burstlint mutations
-  # (dropped rescale, escaped unscaled output, raw quantized dot, fp16
-  # accum behind quant, credit-neutral recompile) — the quick iteration
-  # loop while working on the quantizers + scale slot banks
+  # parity vs the fp32 ring, wire_dtype=None bit-identity, byte-accounting
+  # replay against schedule.wire_round_bytes, and the scale-proof burstlint
+  # mutations (dropped rescale, escaped unscaled output, raw quantized dot,
+  # fp16 accum behind quant, credit-neutral recompile)
   python -m pytest tests/test_wire_quant.py -q ${filtered[@]+"${filtered[@]}"}
   python -m pytest tests/test_analysis.py -q -k "wire" \
     ${filtered[@]+"${filtered[@]}"}
-elif [[ $fused == 1 ]]; then
-  # focused lane for the fused RDMA-ring kernels' interpret-mode parity
-  # tests — forward (tests/test_fused_ring.py), backward
-  # (tests/test_fused_ring_bwd.py), devstats bit-identity, and the
-  # fused-rule burstlint mutations in tests/test_analysis.py all carry the
-  # fused_ring marker.  The same tests also run in the default/fast lanes —
-  # this is the quick iteration loop while working on ops/fused_ring*.py
-  python -m pytest tests/ -q -m "fused_ring" ${filtered[@]+"${filtered[@]}"}
 elif [[ $fast == 1 ]]; then
   python -m pytest tests/ -q -m "not slow" ${filtered[@]+"${filtered[@]}"}
 else

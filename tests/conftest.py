@@ -25,59 +25,25 @@ if not os.environ.get("BURST_TESTS_TPU"):
 # ---------------------------------------------------------------------------
 # fast/slow split: tests measured >= ~19 s under contention (full-suite
 # --durations runs, latest 2026-08-05; ~12-19 s borderliners keep their
-# marker across runs — hysteresis, not churn) are marked slow here,
-# plus the >= ~10 s fused parity matrices whose coverage the focused
-# lanes (--fused / --schedule) re-run: the fast lane keeps one canary
-# per matrix.  The list lives in ONE place rather than as decorators in 15
-# files, so it can be regenerated mechanically from any fresh --durations
-# log.  `pytest -m "not slow"` = the fast lane (tier-1: about 4 minutes on
-# six workers, --dist loadfile, so the longest FILE bounds it); the full
-# suite is for releases.
+# marker across runs — hysteresis, not churn) are marked slow here.  The
+# ring's parity against the dense oracle (tests/test_burst.py, the ring
+# cases of tests/test_window.py and tests/test_wire_quant.py) is NOT in
+# the list: those cases run one jitted program a side and take 1-4 s each,
+# so tier-1 guards the ring the cells run.  The list lives in ONE place
+# rather than as decorators in 15 files, so it can be regenerated
+# mechanically from any fresh --durations log.  `pytest -m "not slow"` =
+# the fast lane (tier-1: about 4 minutes on six workers, --dist loadfile,
+# so the longest FILE bounds it); the full suite is for releases.
 
 _SLOW = {
-    ("test_burst.py", "test_causal_double_ring"),
-    ("test_burst.py", "test_ring_random_config_property_sweep"),
-    ("test_burst.py", "test_causal_single_ring"),
-    ("test_burst.py", "test_cross_attention_lengths"),
-    ("test_burst.py", "test_gqa"),
-    ("test_burst.py", "test_noncausal"),
-    ("test_burst.py", "test_pallas_backend_in_ring_interpret"),
-    ("test_burst.py", "test_pallas_striped_triangular_in_ring_interpret"),
-    ("test_burst.py", "test_segments_double_ring_gqa"),
-    ("test_burst.py", "test_segments_no_case_split"),
-    ("test_burst.py", "test_segments_noncausal"),
-    ("test_burst.py", "test_segments_single_ring"),
-    ("test_burst.py", "test_small_world_2"),
-    ("test_burst.py", "test_uniform_spec_path_no_case_split"),
-    ("test_burst.py", "test_unoptimized_bwd_comm"),
     ("test_checkpoint.py", "test_save_restore_roundtrip"),
     ("test_decode.py", "test_generate_greedy_matches_recompute"),
     ("test_decode.py", "test_moe_decode_chunked_prefill_matches_forward"),
     ("test_devstats.py", "test_double_ring_collect_matches_plain"),
-    ("test_devstats.py", "test_fused_ring_bit_identity_and_slot_counts"),
     ("test_devstats.py", "test_scan_ring_bit_identity_fwd_and_grads"),
     ("test_devstats.py", "test_segments_collect_matches_plain"),
     ("test_devstats.py", "test_windowed_contig_truncation_visible_in_stats"),
     ("test_dist_decode.py", "test_dist_prefill_matches_single_device"),
-    ("test_fused_topologies.py", "test_bidi_fwd_parity"),
-    ("test_fused_topologies.py", "test_bidi_fwd_noncausal_contig"),
-    ("test_fused_topologies.py", "test_bidi_slot_counters_split_by_direction"),
-    ("test_fused_topologies.py", "test_double_fwd_noncausal"),
-    ("test_fused_topologies.py", "test_bidi_deeper_cw_bank"),
-    ("test_fused_topologies.py", "test_bidi_grad_parity"),
-    ("test_fused_topologies.py", "test_double_fwd_parity"),
-    ("test_fused_topologies.py", "test_double_grad_parity"),
-    ("test_fused_ring.py", "test_causal_parity"),
-    ("test_fused_ring.py", "test_grad_through_fused_backend"),
-    ("test_fused_ring.py", "test_gqa_bf16_parity"),
-    ("test_fused_ring_bwd.py", "test_causal_bwd_parity"),
-    ("test_fused_ring_bwd.py", "test_causal_bwd_parity_zigzag"),
-    ("test_fused_ring_bwd.py", "test_noncausal_bwd_parity"),
-    ("test_fused_ring_bwd.py", "test_rotate_o_bwd_parity"),
-    ("test_fused_ring_bwd.py", "test_gqa_bf16_bwd_parity"),
-    ("test_fused_ring_bwd.py", "test_three_slots_and_rect_blocks"),
-    ("test_fused_ring_bwd.py", "test_grad_matches_dense_oracle"),
-    ("test_fused_ring_bwd.py", "test_bwd_slot_counters_replay_schedule"),
     ("test_pallas.py", "test_bwd_random_config_property_sweep"),
     ("test_pallas.py", "test_fwd_random_config_property_sweep"),
     ("test_model.py", "test_double_ring_model"),
@@ -148,13 +114,9 @@ _SLOW = {
     ("test_fleet.py", "test_fleet_prefill_kill_reruns_on_sibling"),
     ("test_fleet.py", "test_fleet_autoscale_up_on_pressure_down_on_idle"),
     ("test_fleet.py", "test_fleet_trace_tree_cross_process_breakdown"),
-    ("test_window.py", "test_burst_ring_contig_window"),
     ("test_window.py", "test_dist_decode_window_matches_single_chip"),
-    ("test_window.py", "test_burst_ring_window_grad"),
     ("test_window.py", "test_decode_window_matches_forward"),
     ("test_window.py", "test_model_trains_with_window"),
-    ("test_window.py", "test_ring_truncation_matches_dense"),
-    ("test_window.py", "test_window_double_ring_matches_dense"),
     # pagepool-cow-safe mutants each re-serve the full sharing schedule;
     # tier-1 keeps the rule's clean run (test_clean_run_on_real_package)
     # and registration canary
@@ -162,32 +124,12 @@ _SLOW = {
     ("test_analysis.py", "test_poolcheck_refcount_leak_fires"),
     # grouped-kernel parity: tier-1 keeps the fp32 canary
     ("test_prefix_cache.py", "test_grouped_matches_plain_variants"),
-    # 2026-08-05 re-trim: the heaviest elision/window accounting tests move
-    # out of tier-1 — the --schedule lane re-runs all three via its
-    # window/segment/elided -k selections, and the fast lane keeps
-    # test_window_and_segments_dispatch_fused as the dispatch canary
+    # 2026-08-05 re-trim: the heaviest elision accounting test
     ("test_devstats.py", "test_rounds_elided_live_vs_executed"),
-    ("test_fused_ring_bwd.py", "test_window_grad_dispatch_fused"),
-    ("test_fused_ring_bwd.py", "test_segments_elided_grad_dispatch_fused"),
-    # --fused lane coverage (marker fused_ring): the causal canaries and
-    # the bwd slot/rect variants stay fast, these parity/edge twins move
-    ("test_fused_ring.py", "test_noncausal_parity"),
-    ("test_fused_ring.py", "test_three_slots_and_custom_blocks"),
-    ("test_fused_ring_bwd.py", "test_world_two"),
-    ("test_fused_ring_bwd.py", "test_fallback_double_ring_grad"),
     # burstlint CLI subprocess duplicate of test_clean_run_on_real_package
     # (same rules in-process), and the ~15 s profiler-capture smoke
     ("test_analysis.py", "test_cli_exits_zero_on_repo"),
     ("test_utils.py", "test_trace_writes_profile"),
-    # wire-precision parity sweeps (scripts/test.sh --quant reruns them);
-    # tier-1 keeps the fwd/grad canaries, the byte-accounting replay and
-    # the wire_dtype=None jaxpr identity
-    ("test_wire_quant.py", "test_wire_fused_fwd_parity_matrix"),
-    ("test_wire_quant.py", "test_wire_fused_grad_parity_matrix"),
-    ("test_wire_quant.py", "test_wire_gqa_opt_comm_composition"),
-    ("test_wire_quant.py", "test_wire_scan_ring_parity"),
-    ("test_wire_quant.py", "test_wire_none_bit_identical"),
-    ("test_wire_quant.py", "test_wire_slot_counters_and_quant_absmax"),
 }
 
 

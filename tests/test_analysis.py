@@ -47,7 +47,7 @@ def test_at_least_8_rules_registered():
                      "traced-bool-branch", "ring-rotation", "ring-hops",
                      "ring-order", "dq-return-home", "window-truncation",
                      "fp32-accum", "lse-fp32",
-                     "fused-ring-schedule", "fused-ring-fused",
+                     "fused-ring-schedule",
                      "obs-jit-safe", "ckpt-jit-safe",
                      "pipe-fused-pure", "pipe-tick-identity",
                      "ragged-serve-safe", "pagepool-cow-safe",
@@ -731,105 +731,6 @@ def test_cli_exits_zero_on_repo():
 
 
 # ---------------------------------------------------------------------------
-# fused ring schedule rules
-
-
-def test_fused_oracle_proves_itself():
-    for world, slots in [(2, 2), (4, 2), (8, 2), (8, 3), (8, 8)]:
-        oracle.verify_fused_ring(world, slots)
-    # no double buffering: every round reads/writes slot 0, so a sender one
-    # round ahead overwrites the version the receiver has not consumed yet
-    with pytest.raises(AssertionError):
-        oracle.verify_fused_ring(8, 2, [0] * 8)
-    # consecutive rounds sharing a slot: the round-1 send targets the slot
-    # round 2 still has to read, and the capacity credit (granted after
-    # round 0) does not cover it — overwritten before read
-    with pytest.raises(AssertionError):
-        oracle.verify_fused_ring(8, 2, [0, 1, 1, 0, 0, 1, 1, 0])
-
-
-def test_fused_schedule_mutation_fires(monkeypatch):
-    from burst_attn_tpu.parallel import ring
-
-    healthy = ringcheck.verify_fused_ring()
-    assert healthy == [], "\n".join(f.format() for f in healthy)
-
-    monkeypatch.setattr(ring, "fused_slot_schedule",
-                        lambda world, slots: np.zeros(world, dtype=np.int64))
-    findings = ringcheck.verify_fused_ring()
-    assert "fused-ring-schedule" in _rules_of(findings), [
-        f.format() for f in findings]
-
-
-# ---------------------------------------------------------------------------
-# fused ring BACKWARD schedule/fusion rules (ISSUE 5): reordered dq hop,
-# extra collective, fp16 accum each fire
-
-
-@pytest.mark.fused_ring
-def test_fused_bwd_oracle_proves_itself():
-    for world, slots in [(2, 2), (4, 2), (8, 2), (8, 3), (8, 8)]:
-        oracle.verify_fused_ring_bwd(world, slots)
-    # no double buffering: every round's bundle AND dq stream share slot 0,
-    # so a sender one round ahead overwrites an unconsumed version
-    with pytest.raises(AssertionError):
-        oracle.verify_fused_ring_bwd(8, 2, [0] * 8)
-    # reordered dq hop: consecutive rounds sharing a slot mean the dq
-    # partial streamed during round 1 lands in the slot round 2 still has
-    # to read — overwritten before read under the capacity credits
-    with pytest.raises(AssertionError):
-        oracle.verify_fused_ring_bwd(8, 2, [0, 1, 1, 0, 0, 1, 1, 0])
-
-
-@pytest.mark.fused_ring
-def test_fused_bwd_schedule_mutation_fires(monkeypatch):
-    from burst_attn_tpu.parallel import ring
-
-    monkeypatch.setattr(ring, "fused_bwd_slot_schedule",
-                        lambda world, slots: np.zeros(world, dtype=np.int64))
-    findings = ringcheck.verify_fused_ring()
-    assert "fused-ring-schedule" in _rules_of(findings), [
-        f.format() for f in findings]
-    assert any("bwd" in f.message for f in findings
-               if f.rule == "fused-ring-schedule")
-
-
-@pytest.mark.fused_ring
-def test_fused_bwd_extra_collective_fires():
-    """A dq hop smuggled OUTSIDE the kernel (an XLA collective in a trace
-    claiming to be the fused backward) fires fused-ring-fused — as does the
-    starved remote-copy census of the same seeded program."""
-    mesh = _mesh4()
-    spec = P(None, None, "sp", None)
-    fn = shard_map(lambda dq: ppermute_by(dq, "sp", 1), mesh=mesh,
-                   in_specs=spec, out_specs=spec, check_vma=False)
-    jx = jax.make_jaxpr(fn)(
-        jax.ShapeDtypeStruct((1, 2, 64, 8), jnp.float32))
-    findings = ringcheck.verify_fused_bwd_trace(jx, where="seeded bwd",
-                                                anchor=ANCHOR)
-    msgs = [f.message for f in findings if f.rule == "fused-ring-fused"]
-    assert any("collectives" in m for m in msgs), msgs
-    assert any("6 remote dma_starts" in m for m in msgs), msgs
-    assert findings[0].file == "seeded.py" and findings[0].line == 7
-
-
-@pytest.mark.fused_ring
-def test_fused_bwd_fp16_accum_fires():
-    """A bf16 dot without the f32 accumulator inside a bwd-shaped trace is
-    reported through the same verifier the bwd rule family runs."""
-    S = jax.ShapeDtypeStruct
-    q = S((1, 2, 64, 16), jnp.bfloat16)
-
-    def bad(q, k):
-        return jax.lax.dot_general(q[0, 0], k[0, 0], (((1,), (1,)), ((), ())))
-
-    jx = jax.make_jaxpr(bad)(q, q)
-    findings = ringcheck.verify_fused_bwd_trace(jx, where="seeded bwd kernel",
-                                                anchor=ANCHOR)
-    assert "fp32-accum" in _rules_of(findings)
-
-
-# ---------------------------------------------------------------------------
 # schedule-IR program proofs (ISSUE 6): the compiler's emitted programs are
 # simulation-proven (ringcheck.verify_ring_programs); deliberately corrupted
 # programs — flipped direction, shortened prefetch distance, aliased slot —
@@ -840,13 +741,11 @@ def _export(prog):
     return prog.export()
 
 
-@pytest.mark.fused_ring
 def test_ring_program_matrix_proves_clean():
     findings = ringcheck.verify_ring_programs()
     assert findings == [], "\n".join(f.format() for f in findings)
 
 
-@pytest.mark.fused_ring
 def test_ring_program_flipped_direction_fires():
     """Swapping a channel's direction (cw -> ccw) delivers the mirror
     rotation: every consume after round 0 holds the wrong partition."""
@@ -864,7 +763,6 @@ def test_ring_program_flipped_direction_fires():
         oracle.verify_ring_program(prog)
 
 
-@pytest.mark.fused_ring
 def test_ring_program_shortened_prefetch_fires():
     """Moving the double ring's inter hop to the cycle's LAST round keeps
     delivery intact but shrinks the prefetch distance below one intra
@@ -883,7 +781,6 @@ def test_ring_program_shortened_prefetch_fires():
         oracle.verify_ring_program(prog)
 
 
-@pytest.mark.fused_ring
 def test_ring_program_aliased_slot_fires():
     """Aiming a send at the slot another round still has to read is the
     overwrite-before-read hazard the per-slot credits exist to prevent."""
@@ -897,7 +794,6 @@ def test_ring_program_aliased_slot_fires():
         oracle.verify_ring_program(prog)
 
 
-@pytest.mark.fused_ring
 def test_ring_program_dropped_home_hop_fires():
     """Turning a return-home hop into a plain ring hop strands the owner's
     gradient: the exactly-once home delivery proof must fire."""
@@ -941,7 +837,7 @@ def test_elided_ring_program_census_undercut():
                 assert got < ref, (topo, compiler.__name__, got, ref)
 
 
-def test_elision_mutation_fires_fused_ring_schedule():
+def test_elision_mutation_fires_on_ring_programs():
     """Seeded-bad eliders are caught by the shared verify_elided_program
     obligation: a compiler that fails to elide (ships the dense program)
     keeps DEAD offsets; one that over-truncates drops LIVE offsets."""
@@ -970,13 +866,14 @@ def test_elision_mutation_fires_fused_ring_schedule():
 
 
 # ---------------------------------------------------------------------------
-# wire-precision scale-handling proof (ISSUE 14): the fused-ring-fused
-# family now proves every quantized send has a matching in-tile rescale
-# before accumulation.  The mutations — a dropped rescale, a raw int8
-# MXU operand, a f16 accumulator smuggled behind the dequant, a bogus
+# wire-precision scale-handling proof (ISSUE 14): numerics.check_wire_trace
+# proves every quantized send has a matching rescale before accumulation
+# (reported under fp32-accum).  The mutations — a dropped rescale, a raw
+# int8 MXU operand, a f16 accumulator smuggled behind the dequant, a bogus
 # wire dtype in the schedule IR — must each fire, or the proof has no
-# teeth.  The clean direction rides the real wire traces via
-# test_clean_run_on_real_package (verify_fused_topologies' wire-* rows).
+# teeth.  The clean direction is ringcheck's run over the ring's forward
+# and backward shard programs at wire_dtype int8 and fp8
+# (verify_ring_entry, via test_clean_run_on_real_package).
 
 
 S4 = jax.ShapeDtypeStruct((64, 16), jnp.float32)
@@ -984,7 +881,6 @@ S8 = jax.ShapeDtypeStruct((64, 16), jnp.int8)
 SC = jax.ShapeDtypeStruct((), jnp.float32)
 
 
-@pytest.mark.fused_ring
 def test_wire_dropped_rescale_fires():
     """Dequantizing a wire payload and accumulating WITHOUT the per-block
     scale multiply is exactly the silent-corruption defect the proof
@@ -999,12 +895,27 @@ def test_wire_dropped_rescale_fires():
     jx = jax.make_jaxpr(bad)(S4, S8)
     findings = numerics.check_wire_trace(jx, where="seeded", anchor=ANCHOR)
     assert findings, "dropped rescale did not fire"
-    assert _rules_of(findings) == {"fused-ring-fused"}
+    assert _rules_of(findings) == {"fp32-accum"}
     assert any("rescale" in f.message for f in findings)
     assert findings[0].file == "seeded.py" and findings[0].line == 7
 
 
-@pytest.mark.fused_ring
+def test_wire_dropped_rescale_in_ring_fires(monkeypatch):
+    """The same defect seeded in the ring itself: a dequantize that casts
+    up and drops the scale must fire on the traced forward AND backward
+    shard programs, on both wire dtypes."""
+    from burst_attn_tpu.parallel import burst
+
+    assert ringcheck.verify_ring_entry(ringcheck.ENTRIES[0]) == []
+    monkeypatch.setattr(burst, "wire_dequantize",
+                        lambda x8, scale, dtype: x8.astype(dtype))
+    findings = ringcheck.verify_ring_entry(ringcheck.ENTRIES[0])
+    assert _rules_of(findings) == {"fp32-accum"}
+    for tag in ("fwd wire=int8", "bwd wire=int8", "fwd wire=fp8",
+                "bwd wire=fp8"):
+        assert any(tag in f.message for f in findings), tag
+
+
 def test_wire_escaped_unscaled_output_fires():
     """An unscaled dequantized value flowing straight to the trace output
     (through taint-transparent reshapes) is also a dropped rescale."""
@@ -1015,7 +926,6 @@ def test_wire_escaped_unscaled_output_fires():
         f.format() for f in findings]
 
 
-@pytest.mark.fused_ring
 def test_wire_raw_quant_dot_fires():
     """A raw int8 operand into dot_general bypasses the cast-up-then-
     rescale contract entirely."""
@@ -1030,7 +940,6 @@ def test_wire_raw_quant_dot_fires():
                for f in findings), [f.format() for f in findings]
 
 
-@pytest.mark.fused_ring
 def test_wire_fp16_accum_behind_quant_fires():
     """A f16 accumulator smuggled BEHIND the dequant+rescale: the scale
     proof is satisfied (the mul is there) but the fp32-accum census of the
@@ -1043,19 +952,17 @@ def test_wire_fp16_accum_behind_quant_fires():
                                    (((1,), (1,)), ((), ())))
 
     jx = jax.make_jaxpr(bad)(S4, S8, SC)
-    findings = ringcheck.verify_fused_bwd_trace(jx, where="seeded bwd",
-                                                anchor=ANCHOR)
+    findings = numerics.check_trace(jx, where="seeded bwd", anchor=ANCHOR)
     assert "fp32-accum" in _rules_of(findings), [
         f.format() for f in findings]
     # and the rescale itself kept the scale proof quiet
-    assert not any("rescale" in f.message for f in findings
-                   if f.rule == "fused-ring-fused")
+    assert numerics.check_wire_trace(jx, where="seeded bwd",
+                                     anchor=ANCHOR) == []
 
 
-@pytest.mark.fused_ring
 def test_wire_deferred_rescale_after_dot_is_quiet():
-    """The fused forward's idiom — cast up, dot, THEN fold the scalar
-    scale into the score (distributivity) — must stay quiet."""
+    """Cast up, dot, THEN fold the scalar scale into the score
+    (distributivity) must stay quiet."""
 
     def good(q, k8, sc):
         k = k8.astype(jnp.float32)
@@ -1067,7 +974,6 @@ def test_wire_deferred_rescale_after_dot_is_quiet():
     assert numerics.check_wire_trace(jx, where="seeded", anchor=ANCHOR) == []
 
 
-@pytest.mark.fused_ring
 def test_wire_program_bogus_dtype_fires():
     """The schedule-IR oracle validates the wire field: a program claiming
     an unknown wire dtype must not prove."""
@@ -1080,7 +986,6 @@ def test_wire_program_bogus_dtype_fires():
         oracle.verify_ring_program(prog)
 
 
-@pytest.mark.fused_ring
 def test_wire_recompile_credit_neutral():
     """The wire recompile of every topology keeps the op table, slot
     banks, and copy-in list bit-identical to the dense compile (scale
@@ -1603,10 +1508,9 @@ def test_changed_files_on_this_repo_answers_or_declines():
 
 
 # ---------------------------------------------------------------------------
-# cost-* family (burstcost, ISSUE 16): clean on the real tables, and each
-# rule killed by its mutation — an inflated slot plan / deflated budget
-# (kernel-vmem-budget), a window-blind pair function (cost-model-
-# consistent), and a fwd<bwd table inversion (tuning-table-sound).
+# cost-* family (burstcost, ISSUE 16): clean on the real tables, and a
+# window-blind pair function (cost-model-consistent) and a fwd<bwd table
+# inversion (tuning-table-sound) each fire their rule.
 
 
 def _v5e_row(**overrides):
@@ -1620,30 +1524,6 @@ def test_cost_family_clean_on_real_tables():
 
     findings = costcheck.check_all()
     assert findings == [], "\n".join(f.format() for f in findings)
-
-
-def test_kernel_vmem_budget_fires_on_deflated_budget():
-    """A row whose budget its OWN canonical-shape gate plan violates: the
-    dispatch gate would reject its own generation."""
-    from burst_attn_tpu.analysis import costcheck
-
-    row = _v5e_row(fused_vmem_budget=8 * 1024 * 1024)
-    findings = costcheck.check_vmem_budget(table=row)
-    assert findings
-    assert all(f.rule == "kernel-vmem-budget" for f in findings)
-    assert any("exceeds fused_vmem_budget" in f.message for f in findings)
-
-
-def test_kernel_vmem_budget_fires_on_inflated_slot_plan():
-    """Inflating the slot banks past the semaphore tripwires on a wide
-    ring: an unintended per-slot array growing the schedule is a lint
-    finding, not an on-device surprise."""
-    from burst_attn_tpu.analysis import costcheck
-
-    row = _v5e_row(fused_kv_slots=64, fused_bwd_slots=64)
-    findings = costcheck.check_vmem_budget(table=row, world=64)
-    assert any(f.rule == "kernel-vmem-budget"
-               and "semaphore census" in f.message for f in findings)
 
 
 def test_cost_model_consistent_fires_on_dropped_elision_term():
@@ -1662,50 +1542,44 @@ def test_cost_model_consistent_fires_on_dropped_elision_term():
 
 
 def test_tuning_table_sound_fires_on_fwd_bwd_inversion():
-    """A RAW bwd block larger than its fwd partner is dead weight
-    resolve_fused silently clamps away — the rule checks the raw fields
-    so the min() clamp cannot hide the inversion."""
+    """A RAW bwd cliff area above its fwd partner: the rule checks the raw
+    fields, so a clamp downstream cannot hide the inversion."""
     from burst_attn_tpu.analysis import costcheck
 
-    row = _v5e_row(fused_block_q_bwd=1024, fused_block_q=512)
+    row = _v5e_row(bwd_cliff_area=4096 * 2048, fwd_cliff_area=2048 * 2048)
     findings = costcheck.check_tuning_sound(table=row)
     assert any(f.rule == "tuning-table-sound"
-               and "fused_block_q_bwd" in f.message for f in findings)
+               and "bwd_cliff_area" in f.message for f in findings)
 
 
 def test_cost_json_cli_pinned_schema(capsys):
-    """--cost-json prints the burstcost-v2 table: the machine-readable
-    matrix the autotuner prunes on and fleet/sim.py prices with.  v2
-    adds `ragged_hbm` — per-pool-dtype decode bandwidth pricing.  Grow
-    the schema additively or change these asserts with intent."""
+    """--cost-json prints the burstcost-v3 table: the machine-readable
+    roofline matrix fleet/sim.py prices with, the ragged serving plans and
+    `ragged_hbm`, the per-pool-dtype decode bandwidth pricing (v3 dropped
+    the VMEM-plan columns of ring kernels that are gone).  Grow the schema
+    additively or change these asserts with intent."""
     import json
 
     from burst_attn_tpu.analysis.__main__ import main
 
     assert main(["--cost-json"]) == 0
     d = json.loads(capsys.readouterr().out)
-    assert d["schema"] == "burstcost-v2"
+    assert d["schema"] == "burstcost-v3"
     assert set(d) == {"schema", "world", "shape", "hw", "n_rows", "rows",
                       "ragged", "ragged_hbm"}
     assert d["world"] == 8
     assert set(d["shape"]) == {"b", "n", "n_kv", "s", "d"}
     # 5 generations (4 named + default) x 3 topologies x 3 wires x 2 passes
     assert d["n_rows"] == len(d["rows"]) == 90
-    row_keys = {"generation", "topology", "wire", "pass", "block_q",
-                "block_kv", "slots", "n_rounds", "gate_bytes", "vmem_bytes",
-                "slot_bytes", "sem_dma", "sem_regular", "budget",
-                "vmem_limit", "max_shard_seq", "vmem_bytes_at_max", "fits",
+    row_keys = {"generation", "topology", "wire", "pass", "n_rounds",
                 "flops", "hbm_bytes", "ici_bytes", "t_compute_s",
                 "t_comm_s"}
     for row in d["rows"]:
         assert set(row) == row_keys
-        # the acceptance bar: every tuning-table entry x topology x
-        # wire-dtype x pass statically proven within budget
-        assert row["fits"] is True, row
     assert d["ragged"]
     for row in d["ragged"]:
         assert row["fits"] is True, row
-    # v2: per-pool-dtype decode HBM pricing — 2 d_heads x 3 pool dtypes,
+    # per-pool-dtype decode HBM pricing — 2 d_heads x 3 pool dtypes,
     # and the 1 B/elem pools must show the analytic bandwidth win
     assert len(d["ragged_hbm"]) == 6
     hbm_keys = {"d_head", "n_kv", "kv_len", "pool_dtype", "kv_elem_bytes",

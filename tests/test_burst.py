@@ -10,7 +10,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 import pytest
 
-from burst_attn_tpu import burst_attn
+from burst_attn_tpu import BurstConfig, burst_attn
 from burst_attn_tpu.ops.reference import dense_attention
 from burst_attn_tpu.parallel import layouts
 from burst_attn_tpu.utils.testing import check_close, random_qkv
@@ -41,14 +41,17 @@ def run_case(mesh_shape, layout, causal, kv_heads=4, optimize_bwd_comm=True,
         seg = jnp.sum(jnp.arange(S)[None, :, None] >= cuts[:, None, :],
                       axis=-1).astype(jnp.int32)
 
+    # one program a side (value and gradients together): what these cases
+    # cost is compilation, not arithmetic
+
     # oracle on natural token order
     def ref_loss(q, k, v):
-        return jnp.sum(dense_attention(q, k, v, causal=causal, window=window,
-                                       segment_ids=seg).astype(jnp.float32) * do)
-
-    o_ref = dense_attention(q, k, v, causal=causal, window=window,
+        o = dense_attention(q, k, v, causal=causal, window=window,
                             segment_ids=seg)
-    dq_ref, dk_ref, dv_ref = jax.grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
+        return jnp.sum(o.astype(jnp.float32) * do), o
+
+    (_, o_ref), (dq_ref, dk_ref, dv_ref) = jax.jit(jax.value_and_grad(
+        ref_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
 
     # burst on layout order
     ql, kl, vl, dol = (layouts.to_layout(t, layout, W, 2) for t in (q, k, v, do))
@@ -60,14 +63,10 @@ def run_case(mesh_shape, layout, causal, kv_heads=4, optimize_bwd_comm=True,
             backend=backend, optimize_bwd_comm=optimize_bwd_comm,
             segment_ids=segl, window=window, **burst_kw,
         )
-        return jnp.sum(o.astype(jnp.float32) * dol)
+        return jnp.sum(o.astype(jnp.float32) * dol), o
 
-    o_l = burst_attn(
-        ql, kl, vl, mesh=mesh, seq_axes=names, causal=causal, layout=layout,
-        backend=backend, optimize_bwd_comm=optimize_bwd_comm,
-        segment_ids=segl, window=window, **burst_kw,
-    )
-    dq_l, dk_l, dv_l = jax.grad(burst_loss, argnums=(0, 1, 2))(ql, kl, vl)
+    (_, o_l), (dq_l, dk_l, dv_l) = jax.jit(jax.value_and_grad(
+        burst_loss, argnums=(0, 1, 2), has_aux=True))(ql, kl, vl)
 
     o = layouts.from_layout(o_l, layout, W, 2)
     dq = layouts.from_layout(dq_l, layout, W, 2)
@@ -108,6 +107,17 @@ def test_unoptimized_bwd_comm():
 
 def test_small_world_2():
     run_case((2,), "zigzag", causal=True)
+
+
+def test_unknown_backend_names_the_valid_ones():
+    """One ring, two tiles: any other backend string (the fused RDMA ring's
+    among them, which left with PR 31) is refused by name."""
+    mesh, names = make_mesh((2,))
+    q = jnp.zeros((1, 2, 32, 8), jnp.float32)
+    with pytest.raises(ValueError, match="'jnp' or 'pallas'"):
+        BurstConfig(backend="fused_ring")
+    with pytest.raises(ValueError, match="'jnp' or 'pallas'"):
+        burst_attn(q, q, q, mesh=mesh, seq_axes=names, backend="fused_ring")
 
 
 def test_pallas_backend_in_ring_interpret():
@@ -152,19 +162,19 @@ def test_cross_attention_lengths(mesh_shape):
     do = jax.random.normal(ks[3], (1, 4, sq, 16), jnp.float32)
 
     def ref_loss(q, k, v):
-        return jnp.sum(dense_attention(q, k, v).astype(jnp.float32) * do)
+        o = dense_attention(q, k, v)
+        return jnp.sum(o.astype(jnp.float32) * do), o
 
-    o_ref = dense_attention(q, k, v)
-    g_ref = jax.grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
+    (_, o_ref), g_ref = jax.jit(jax.value_and_grad(
+        ref_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
 
     def burst_loss(q, k, v):
         o = burst_attn(q, k, v, mesh=mesh, seq_axes=names, causal=False,
                        layout="contig", backend="jnp")
-        return jnp.sum(o.astype(jnp.float32) * do)
+        return jnp.sum(o.astype(jnp.float32) * do), o
 
-    o = burst_attn(q, k, v, mesh=mesh, seq_axes=names, causal=False,
-                   layout="contig", backend="jnp")
-    g = jax.grad(burst_loss, argnums=(0, 1, 2))(q, k, v)
+    (_, o), g = jax.jit(jax.value_and_grad(
+        burst_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
     check_close(o, o_ref, rtol=2e-4, atol=2e-4, msg="cross o")
     for got, want, nm in zip(g, g_ref, "qkv"):
         check_close(got, want, rtol=2e-4, atol=2e-4, msg=f"cross d{nm}")
@@ -213,7 +223,7 @@ def test_bf16_reference_tolerance():
     check_close(o, o_ref, rtol=4e-2, atol=4e-2, msg="bf16 o")
 
 
-def test_ring_random_config_property_sweep():
+def _sweep_cases():
     """Randomized ring-level interaction sweep: mesh topology x layout x
     causal x GQA x window x packed segments x backend x bwd-comm mode vs
     the dense oracle — the targeted tests each pin one dimension; this
@@ -253,6 +263,12 @@ def test_ring_random_config_property_sweep():
             seen["double_ring"] += 1
         if c["layout"] == "striped" and c["kv_heads"] < 4:
             seen["gqa_striped"] += 1
-        run_case(**c)
     assert (seen["wnd_seg"] >= 1 and seen["double_ring"] >= 2
             and seen["gqa_striped"] >= 1), seen
+    return cases
+
+
+@pytest.mark.parametrize("case", _sweep_cases(), ids=lambda c: "-".join(
+    f"{v}" for v in c.values()).replace(" ", ""))
+def test_ring_random_config_property_sweep(case):
+    run_case(**case)

@@ -1,12 +1,11 @@
-"""burstcost (analysis/costmodel.py): the static plans against the real
-gates, the closed-form algebra against brute force, and the roofline's
+"""burstcost (analysis/costmodel.py): the ragged plan against the real
+gate, the closed-form algebra against brute force, and the roofline's
 inputs against the production counters.
 
 The lint family (analysis/costcheck.py) runs the full-matrix versions of
 these identities in the gate; here the model is additionally proven
-against ground truth the gate can't afford — dense-mask pair counts,
-per-shape sweeps of the dispatch predicates, and the deep per-generation
-admitted-shard sweep (@slow, with a fast v5e canary).
+against ground truth the gate can't afford: dense-mask pair counts and a
+sweep of the ragged dispatch predicate.
 """
 
 import json
@@ -54,8 +53,7 @@ def test_devstats_sum_equals_closed_form(layout, topology):
     compiled program equals the global closed form — for every layout and
     topology."""
     s = 256
-    rf = tuning.resolve_fused(table=tuning.generation_row("v5e"))
-    program = cm.compile_program("fwd", topology, WORLD, rf)
+    program = cm.compile_program("fwd", topology, WORLD)
     closed = cm.pass_pairs(layout, s, WORLD, causal=True)
     assert cm.devstats_pass_pairs(program, layout, s, causal=True) == closed
 
@@ -70,8 +68,7 @@ def test_devstats_sum_exact_on_elided_program():
     r_live = live_round_prefix("contig", s, WORLD, causal=True,
                                window=window)
     assert r_live < WORLD  # the window genuinely elides rounds
-    rf = tuning.resolve_fused(table=tuning.generation_row("v5e"))
-    program = cm.compile_program("fwd", "uni", WORLD, rf, r_live=r_live)
+    program = cm.compile_program("fwd", "uni", WORLD, r_live=r_live)
     assert program.n_rounds < WORLD
     closed = cm.pass_pairs("contig", s, WORLD, causal=True, window=window)
     summed = cm.devstats_pass_pairs(program, "contig", s, causal=True,
@@ -115,9 +112,8 @@ def test_stream_bytes_matches_wire_round_bytes(pass_, wire, opt_comm,
 def test_send_census_matches_hop_totals_fwd():
     """Payload sends read off the op table agree with scan_events'
     hop census for every topology."""
-    rf = tuning.resolve_fused(table=tuning.generation_row("v5e"))
     for topo in sched.TOPOLOGIES:
-        program = cm.compile_program("fwd", topo, WORLD, rf)
+        program = cm.compile_program("fwd", topo, WORLD)
         census = cm.send_census(program)
         totals = sched.hop_totals(program)
         assert census["send0"] + census["send1"] == sum(totals.values())
@@ -125,48 +121,13 @@ def test_send_census_matches_hop_totals_fwd():
 
 def test_uni_bwd_dq_hops_are_world():
     """The dense uni bwd dq stream add-and-forwards W-1 ring hops plus
-    the final home hop — the chain ring_overlap's comm floor times."""
-    rf = tuning.resolve_fused(table=tuning.generation_row("v5e"))
-    program = cm.compile_program("bwd", "uni", WORLD, rf)
+    the final home hop — the chain the comm floor times."""
+    program = cm.compile_program("bwd", "uni", WORLD)
     assert cm.send_census(program)["dq"] == WORLD
 
 
 # ---------------------------------------------------------------------------
-# VMEM plans vs the dispatch gates
-
-
-def _host_supported(pass_, s, *, b=1, n=8, d=128, wire=None):
-    """fused_ring.supported as a host-callable predicate (per-shard
-    shapes, explicit world, interpret checks off)."""
-    from burst_attn_tpu.parallel import burst
-    from burst_attn_tpu.ops import fused_ring
-
-    cfg = burst.BurstConfig(causal=True, layout="zigzag", intra_axis="sp",
-                            backend="fused_ring", wire_dtype=wire)
-    shape = (b, n, s, d)
-    return fused_ring.supported(cfg, shape, shape, False, world=WORLD,
-                                extra_axes=[], interpret=False,
-                                pass_=pass_)
-
-
-@pytest.mark.parametrize("pass_", cm.PASSES)
-@pytest.mark.parametrize("wire", sched.WIRE_DTYPES)
-def test_gate_bytes_match_dispatch_gate(pass_, wire):
-    """The model's gate formula reproduces the dispatch gate's decision
-    AND its byte count, across shards spanning the admission cliff.  On
-    this host both resolve through the default tuning row — the same
-    algebra, one from the device probe, one from the table."""
-    rf = tuning.resolve_fused(table=tuning.generation_row("default"),
-                              wire_dtype=wire)
-    for s in (4096, 8192, 16384, 32768, 65536, 131072, 262144):
-        gate = (cm.fwd_gate_bytes(rf, b=1, n=8, s=s, d=128)
-                if pass_ == "fwd" else cm.bwd_gate_bytes(rf, s=s, d=128))
-        reason = _host_supported(pass_, s, wire=wire)
-        if gate <= rf.vmem_budget:
-            assert reason is None, (s, gate, reason)
-        else:
-            assert reason is not None and "VMEM plan" in reason, (s, gate)
-            assert f"VMEM plan {gate} bytes" in reason, (s, gate, reason)
+# the ragged VMEM plan vs its dispatch gate
 
 
 def test_ragged_plan_matches_ragged_supported():
@@ -192,61 +153,8 @@ def test_ragged_plan_matches_ragged_supported():
             assert reason is not None and "VMEM plan" in reason, c
 
 
-def test_full_plan_dominates_gate_plan():
-    """The full scratch inventory is a superset of the gate's coarse
-    plan — a full plan below the gate plan means the mirror dropped a
-    buffer."""
-    for gen in tuning.generations():
-        for wire in sched.WIRE_DTYPES:
-            rf = tuning.resolve_fused(table=tuning.generation_row(gen),
-                                      wire_dtype=wire)
-            for pass_ in cm.PASSES:
-                program = cm.compile_program(pass_, "uni", WORLD, rf)
-                pl = cm.plan(pass_, rf, program, b=1, n=32, n_kv=32,
-                             s=8192, d=128)
-                assert pl.vmem_bytes >= pl.gate_bytes, (gen, wire, pass_)
-                assert pl.slot_bytes > 0 and pl.sem_dma > 0
-
-
-def test_admitted_shard_compiles_v5e_canary():
-    """Fast canary of the budget-soundness theorem: the largest shard the
-    v5e gate admits keeps the FULL inventory under the Mosaic limit (the
-    @slow sweep proves every generation x wire x pass)."""
-    rf = tuning.resolve_fused(table=tuning.generation_row("v5e"))
-    for pass_ in cm.PASSES:
-        s_max = cm.max_admitted_shard(pass_, rf, b=1, n=32, d=128)
-        assert s_max >= 8192  # the headline shard must be admitted
-        program = cm.compile_program(pass_, "uni", WORLD, rf)
-        pl = cm.plan(pass_, rf, program, b=1, n=32, n_kv=32, s=s_max,
-                     d=128)
-        assert pl.vmem_bytes <= VMEM_LIMIT, (pass_, s_max, pl)
-
-
-@pytest.mark.slow
-def test_admitted_shard_compiles_every_config():
-    """Deep sweep: for EVERY generation x topology x wire x pass, every
-    power-of-two shard the gate admits keeps the full inventory within
-    the Mosaic limit — admitted implies compiles, with no shard gaps."""
-    for gen in tuning.generations():
-        row = tuning.generation_row(gen)
-        for wire in sched.WIRE_DTYPES:
-            rf = tuning.resolve_fused(table=row, wire_dtype=wire)
-            for topo in sched.TOPOLOGIES:
-                for pass_ in cm.PASSES:
-                    program = cm.compile_program(pass_, topo, WORLD, rf)
-                    s = 256
-                    while s <= cm.max_admitted_shard(pass_, rf, b=1, n=32,
-                                                     d=128):
-                        pl = cm.plan(pass_, rf, program, b=1, n=32,
-                                     n_kv=32, s=s, d=128)
-                        assert pl.vmem_bytes <= VMEM_LIMIT, \
-                            (gen, topo, wire, pass_, s, pl)
-                        assert pl.sem_dma <= cm.SEM_DMA_BUDGET
-                        s *= 2
-
-
 # ---------------------------------------------------------------------------
-# roofline + calibration hooks
+# roofline
 
 
 def test_hw_peaks_match_train_smoke_table():
@@ -313,36 +221,19 @@ def test_check_regression_predicted_field(tmp_path):
     assert rep["verdicts"][0]["predicted"] > 0
 
 
-def test_ring_overlap_pred_fields_on_smoke_row(tmp_path):
-    """A CPU smoke run of the benchmark lands the pred fields in its
-    JSONL row (satellite: every row carries the model's floors)."""
-    from benchmarks import ring_overlap
-
-    out = tmp_path / "ring_overlap.jsonl"
-    rec = ring_overlap.run_config(128, 4, "zigzag", 2, 64, True, str(out),
-                                  pass_="fwd")
-    assert "pred_error" not in rec, rec.get("pred_error")
-    assert rec["t_comm_pred_s"] > 0
-    assert rec["t_compute_pred_s"] > 0
-    assert rec["pred_ratio"] > 0
-    on_disk = json.loads(out.read_text().splitlines()[-1])
-    assert on_disk["t_comm_pred_s"] == rec["t_comm_pred_s"]
-
-
 # ---------------------------------------------------------------------------
 # cost table export
 
 
 def test_cost_table_covers_matrix_and_fits():
     t = cm.cost_table()
-    assert t["schema"] == "burstcost-v2"
+    assert t["schema"] == "burstcost-v3"
     combos = {(r["generation"], r["topology"], r["wire"], r["pass"])
               for r in t["rows"]}
     expected = {(g, topo, w, p) for g in tuning.generations()
                 for topo in sched.TOPOLOGIES for w in sched.WIRE_DTYPES
                 for p in cm.PASSES}
     assert combos == expected
-    assert all(r["fits"] for r in t["rows"])
     assert all(r["fits"] for r in t["ragged"])
     # roofline fields are populated and internally consistent
     for r in t["rows"]:

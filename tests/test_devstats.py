@@ -2,18 +2,13 @@
 
 The load-bearing contract is BIT-IDENTITY: `collect_stats=True` must change
 nothing about the computation — forward outputs AND gradients equal the
-plain path bit for bit, on the scan ring and on the interpret-mode fused
-ring (the stats custom_vjp twins reuse the plain backward; burstlint's
-`devstats-pure` rule proves the jaxpr side of the same story).  On top of
-that, the stats themselves must be RIGHT: mask occupancy equals the dense
-mask algebra, the causal layouts show their signature load balance, the
-fused kernel's in-kernel slot counters match the exported slot schedule,
-and publish() lands the documented catalog in a registry.
+plain path bit for bit (the stats custom_vjp twins reuse the plain
+backward; burstlint's `devstats-pure` rule proves the jaxpr side of the
+same story).  On top of that, the stats themselves must be RIGHT: mask
+occupancy equals the dense mask algebra, the causal layouts show their
+signature load balance, and publish() lands the documented catalog in a
+registry.
 """
-
-import os
-
-os.environ["BURST_FUSED_INTERPRET"] = "1"  # read at trace time, module-wide
 
 import numpy as np
 import jax
@@ -25,7 +20,7 @@ from burst_attn_tpu import burst_attn
 from burst_attn_tpu.obs import devstats
 from burst_attn_tpu.obs.registry import Registry
 from burst_attn_tpu.ops import masks
-from burst_attn_tpu.parallel import burst, layouts, ring
+from burst_attn_tpu.parallel import burst, layouts
 
 KEY = jax.random.PRNGKey(7)
 
@@ -142,7 +137,6 @@ def test_scan_ring_bit_identity_fwd_and_grads(layout):
     assert (np.asarray(st.nonfinite_lse) == 0).all()
     assert (np.asarray(st.nonfinite_acc) == 0).all()
     assert (np.asarray(st.fused_rounds) == 0).all()
-    assert (np.asarray(st.slot_use) == 0).all()
     # scan path reports a real running max
     assert np.isfinite(np.asarray(st.m_max)).all()
     lse_min, lse_max = np.asarray(st.lse_min), np.asarray(st.lse_max)
@@ -203,44 +197,6 @@ def test_segments_collect_matches_plain():
 
 
 # ---------------------------------------------------------------------------
-# fused interpret-mode parity
-
-
-@pytest.mark.fused_ring
-@pytest.mark.parametrize("layout", ["zigzag", "striped"])
-def test_fused_ring_bit_identity_and_slot_counts(layout):
-    world = 8
-    mesh = _mesh(world)
-    ql = _qkv(world, layout=layout)
-    kw = dict(causal=True, layout=layout, backend="fused_ring")
-    o0, _, g0 = _fwd_and_grads(ql, mesh, **kw)
-    o1, st, g1 = _fwd_and_grads(ql, mesh, collect_stats=True, **kw)
-    assert bool(jnp.all(o0 == o1)), "fused fwd diverged under collect"
-    assert bool(jnp.all(g0 == g1)), "fused grads diverged under collect"
-
-    assert (np.asarray(st.fused_rounds) == world).all()
-    # the kernel's in-kernel slot counters replay the exported schedule
-    from burst_attn_tpu.ops.tuning import resolve_fused
-
-    slots = min(resolve_fused(None, None, None).kv_slots, world)
-    sched = ring.fused_slot_schedule(world, slots)
-    want = np.bincount(sched, minlength=devstats.MAX_SLOTS)
-    assert (np.asarray(st.slot_use) == want[None, :]).all(), (
-        np.asarray(st.slot_use), want)
-    assert np.asarray(st.slot_use).sum(axis=1).tolist() == [world] * world
-    # occupancy equals the scan ring's for the same layout
-    o_scan, st_scan, _ = _fwd_and_grads(
-        ql, mesh, collect_stats=True,
-        causal=True, layout=layout, backend="jnp")
-    assert np.asarray(st.attn_pairs).sum() == \
-        np.asarray(st_scan.attn_pairs).sum()
-    # fused kernel keeps m internal: reported as -inf by contract
-    assert (np.asarray(st.m_max) == -np.inf).all()
-    assert (np.asarray(st.nonfinite_lse) == 0).all()
-    assert (np.asarray(st.nonfinite_acc) == 0).all()
-
-
-# ---------------------------------------------------------------------------
 # publish + merge/cross_reduce semantics
 
 
@@ -295,11 +251,10 @@ def test_nonfinite_detection():
 # occupancy elision: live-vs-executed round accounting
 
 
-@pytest.mark.fused_ring
 def test_rounds_elided_live_vs_executed():
     """Elided rounds never RAN: the in-shard round counters (incremented
     per executed round) stop at r_live, and rounds_elided makes the split
-    sum back to the full ring on both the scan and the fused path."""
+    sum back to the full ring."""
     world = 8
     mesh = _mesh(world)
     ql = _qkv(world, layout="contig")
@@ -312,17 +267,15 @@ def test_rounds_elided_live_vs_executed():
     r_live = masks.live_round_prefix("contig", 16, world, causal=True,
                                      window=20)
     assert r_live == 3  # the truncation bites: strictly fewer than world
-    for backend, field in (("jnp", "rounds"), ("fused_ring", "fused_rounds")):
-        st = stats(backend=backend, window=20)
-        executed = np.asarray(getattr(st, field))
-        assert (executed == r_live).all(), (backend, executed)
-        assert (np.asarray(st.rounds_elided) == world - r_live).all()
+    st = stats(backend="jnp", window=20)
+    assert (np.asarray(st.rounds) == r_live).all(), np.asarray(st.rounds)
+    assert (np.asarray(st.rounds_elided) == world - r_live).all()
 
     # packed segments under the max_segment_len contract: reach 15 < 17
     # kills every offset past delta 1
     seg = jnp.asarray(np.repeat(np.arange(world), 16)[None, :], jnp.int32)
-    st = stats(backend="fused_ring", segment_ids=seg, max_segment_len=16)
-    assert (np.asarray(st.fused_rounds) == 2).all()
+    st = stats(backend="jnp", segment_ids=seg, max_segment_len=16)
+    assert (np.asarray(st.rounds) == 2).all()
     assert (np.asarray(st.rounds_elided) == world - 2).all()
 
     # dense schedules report zero elision

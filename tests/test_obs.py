@@ -523,35 +523,6 @@ def test_inplace_round_counter_follows_the_tile_gate(backend, shard, block,
                          if want_fwd else {})
 
 
-def test_fused_dispatch_fallback_counter(monkeypatch):
-    """A fused_ring dispatch off-TPU without the interpret opt-in counts a
-    scan-path dispatch plus an off-tpu fallback reason."""
-    import burst_attn_tpu as bat
-
-    monkeypatch.delenv("BURST_FUSED_INTERPRET", raising=False)
-    world = 4
-    mesh = Mesh(np.asarray(jax.devices()[:world]), ("sp",))
-    q = jax.random.normal(jax.random.PRNGKey(1), (1, 2, world * 16, 8),
-                          jnp.float32)
-    ql = bat.layouts.to_layout(q, "zigzag", world, axis=2)
-    scan0 = obs.counter("burst.dispatch").get(path="scan",
-                                              backend="fused_ring",
-                                              tile="jnp")
-    fwd_lab = {"reason": "off-tpu", "pass": "fwd"}
-    bwd_lab = {"reason": "off-tpu", "pass": "bwd"}
-    fb0 = obs.counter("burst.fused_fallback").get(**fwd_lab)
-    fb0b = obs.counter("burst.fused_fallback").get(**bwd_lab)
-    o = bat.burst_attn(ql, ql, ql, mesh=mesh, causal=True, layout="zigzag",
-                       backend="fused_ring")
-    jax.block_until_ready(o)
-    assert obs.counter("burst.dispatch").get(
-        path="scan", backend="fused_ring", tile="jnp") == scan0 + 1
-    # fallback reasons are split by pass: this dispatch declined BOTH the
-    # fused forward and the fused backward (same off-TPU reason)
-    assert obs.counter("burst.fused_fallback").get(**fwd_lab) == fb0 + 1
-    assert obs.counter("burst.fused_fallback").get(**bwd_lab) == fb0b + 1
-
-
 def test_ring_round_counts_double_ring():
     from burst_attn_tpu.parallel.ring import ring_round_counts
 

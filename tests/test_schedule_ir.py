@@ -1,11 +1,9 @@
 """Schedule IR + compiler unit tests (parallel/schedule.py).
 
 Host-side only — no mesh, no kernels: the compiled programs' structure,
-the legacy schedule views, the oracle's simulation proofs across the
-topology matrix, and the lowering helpers the kernels and the scan ring
-consume.  The kernel-level parity of the same programs rides
-tests/test_fused_topologies.py; the proof-has-teeth mutations ride
-tests/test_analysis.py.
+the oracle's simulation proofs across the topology matrix, and the
+lowering helpers the scan ring consumes.  The proof-has-teeth mutations
+ride tests/test_analysis.py.
 """
 
 import numpy as np
@@ -19,16 +17,16 @@ from burst_attn_tpu.parallel import ring, schedule
 # compiler output structure
 
 
-def test_uni_reproduces_legacy_slot_schedules():
-    """The "uni" program is a superset of the hand-built schedules the IR
-    replaced: the exported consume-slot views must match the old closed
-    forms bit for bit (burstlint pins the same equivalence)."""
+def test_uni_consume_slots_cycle_round_mod_slots():
+    """The "uni" programs consume their slots in the closed form of the
+    hand-built schedules the IR replaced: round r reads slot r mod slots,
+    forward and backward."""
     for world, slots in ((2, 2), (4, 2), (8, 2), (8, 3), (8, 8)):
-        legacy = np.arange(world) % min(slots, world)
-        got = ring.fused_slot_schedule(world, slots)
-        assert got.tolist() == legacy.tolist(), (world, slots)
-        got_bwd = ring.fused_bwd_slot_schedule(world, slots)
-        assert got_bwd.tolist() == legacy.tolist(), (world, slots)
+        want = (np.arange(world) % min(slots, world)).tolist()
+        fwd = schedule.compile_fwd("uni", world, slots=slots)
+        assert list(fwd.col(schedule.CONSUME_SLOT)) == want, (world, slots)
+        bwd = schedule.compile_bwd("uni", world, slots=slots, dq_slots=slots)
+        assert list(bwd.col(schedule.CONSUME_SLOT)) == want, (world, slots)
 
 
 def test_table_shape_and_spec_columns():

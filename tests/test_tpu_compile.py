@@ -326,19 +326,6 @@ def test_train_step_at_the_chip_smoke_size(topo, on_chip):
     assert _device_bytes(c) < 15.75 * 2**30 - 2**30  # a GiB to spare
 
 
-@pytest.mark.xfail(
-    strict=True, raises=jax.errors.JaxRuntimeError,
-    reason="Mosaic refuses both fused ring kernels: 'Cannot infer the memory "
-           "space of main's argument 6' — their HBM slot banks are pl.ANY "
-           "scratch shapes; as pltpu.HBM scratch it answers 'Scratch memref "
-           "allocation only supported for vmem, smem and semaphore_mem', so "
-           "the banks have to become operands of the call (ROADMAP S2)")
-@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
-def test_fused_ring_sp4(topo, on_chip, grad):
-    _compile_attn_grad(_seq_mesh(topo, 4), seq=32768, backend="fused_ring",
-                       grad=grad)
-
-
 def test_ragged_paged_attention_compiles(topo, on_chip):
     """The serving kernel at d_head 128, page 128, 32 q / 4 kv heads, a
     mixed prefill chunk: the next bring-up's first fact."""

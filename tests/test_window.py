@@ -131,9 +131,9 @@ def test_burst_ring_contig_window(backend):
     # crosses shard boundaries (window 24 > local 16)
     q, k, v, _ = _inputs(128, seed=3)
     mesh = Mesh(np.array(jax.devices()[:8]), ("sp",))
-    o = bat.burst_attn(q, k, v, mesh=mesh, seq_axes=("sp",), causal=True,
-                       layout="contig", backend=backend, window=24,
-                       block_q=16, block_kv=16)
+    o = jax.jit(lambda q, k, v: bat.burst_attn(
+        q, k, v, mesh=mesh, seq_axes=("sp",), causal=True, layout="contig",
+        backend=backend, window=24, block_q=16, block_kv=16))(q, k, v)
     np.testing.assert_allclose(
         np.asarray(o, np.float32), np.asarray(banded_dense(q, k, v, 24)),
         rtol=2e-4, atol=2e-4)
@@ -147,11 +147,11 @@ def test_burst_ring_window_grad():
         return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32)
                                        * do.astype(jnp.float32))
 
-    got = jax.grad(loss(lambda q, k, v: bat.burst_attn(
+    got = jax.jit(jax.grad(loss(lambda q, k, v: bat.burst_attn(
         q, k, v, mesh=mesh, causal=True, layout="contig", backend="jnp",
-        window=24)), argnums=(0, 1, 2))(q, k, v)
-    ref = jax.grad(loss(lambda q, k, v: banded_dense(q, k, v, 24)),
-                   argnums=(0, 1, 2))(q, k, v)
+        window=24)), argnums=(0, 1, 2)))(q, k, v)
+    ref = jax.jit(jax.grad(loss(lambda q, k, v: banded_dense(q, k, v, 24)),
+                           argnums=(0, 1, 2)))(q, k, v)
     for name, x, y in zip(("dq", "dk", "dv"), ref, got):
         np.testing.assert_allclose(np.asarray(y, np.float32), x,
                                    rtol=2e-4, atol=2e-4, err_msg=name)
@@ -416,6 +416,26 @@ def test_fused_bwd_banded_schedule_coverage(window, bq, bkv, nqb, qp, kp,
     np.testing.assert_array_equal(computed, want)
 
 
+def _check_against_banded_dense(ring, q, k, v, do, window):
+    """Values and grads of `ring` against the dense banded oracle, one
+    program a side."""
+    def both(fn):
+        def loss(q, k, v):
+            o = fn(q, k, v)
+            return jnp.sum(o * do), o
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                          has_aux=True))(q, k, v)
+
+    (_, ref), gr = both(lambda q, k, v: banded_dense(q, k, v, window))
+    (_, got), g = both(ring)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    for name, a, b in zip(("dq", "dk", "dv"), gr, g):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                   rtol=2e-5, atol=2e-5, err_msg=name)
+
+
 @pytest.mark.parametrize("window", [1, 40, 100, 160, 1000])
 def test_ring_truncation_matches_dense(window):
     """Static round truncation (windowed single contig ring): r_live spans
@@ -431,17 +451,7 @@ def test_ring_truncation_matches_dense(window):
                               causal=True, layout="contig", backend="jnp",
                               window=window)
 
-    ref = banded_dense(q, k, v, window)
-    got = ring(q, k, v)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-    g = jax.grad(lambda q, k, v: jnp.sum(ring(q, k, v) * do),
-                 argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(lambda q, k, v: jnp.sum(banded_dense(q, k, v, window) * do),
-                  argnums=(0, 1, 2))(q, k, v)
-    for name, a, b in zip(("dq", "dk", "dv"), gr, g):
-        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
-                                   rtol=2e-5, atol=2e-5, err_msg=name)
+    _check_against_banded_dense(ring, q, k, v, do, window)
 
 
 def test_window_double_ring_matches_dense():
@@ -457,13 +467,4 @@ def test_window_double_ring_matches_dense():
                               seq_axes=("inter", "intra"), causal=True,
                               layout="contig", backend="jnp", window=window)
 
-    ref = banded_dense(q, k, v, window)
-    np.testing.assert_allclose(np.asarray(ring(q, k, v)), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-    g = jax.grad(lambda q, k, v: jnp.sum(ring(q, k, v) * do),
-                 argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(lambda q, k, v: jnp.sum(banded_dense(q, k, v, window) * do),
-                  argnums=(0, 1, 2))(q, k, v)
-    for name, a, b in zip(("dq", "dk", "dv"), gr, g):
-        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
-                                   rtol=2e-5, atol=2e-5, err_msg=name)
+    _check_against_banded_dense(ring, q, k, v, do, window)

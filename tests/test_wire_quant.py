@@ -4,29 +4,18 @@ The contract under test, end to end:
 
   parity        int8/fp8 wire payloads change only the RING TRAFFIC, never
                 the math structure: fwd outputs and grads stay within the
-                pinned tolerances of the fp32 ring across every layout x
-                topology x elided-window shape the fused dispatch serves,
-                on both the fused kernels and the scan ring.
+                pinned tolerances of the fp32 ring.
   bit-identity  wire_dtype=None is the pre-PR program: outputs AND the
                 traced jaxpr are bit-identical to a config that never
                 mentions wire_dtype.
   accounting    the burst.wire_bytes{pass,dir} counters advance by exactly
                 schedule.wire_round_bytes of the dispatched shard (the ONE
-                shared derivation), int8 ships <= 0.5x the fp32 bytes on
-                fwd AND bwd, and the fused kernel's in-kernel slot counters
-                replay the SAME exported slot schedule under wire — the
-                scale sub-payloads ride existing slot credits, they never
-                add slots.
+                shared derivation), and int8 ships <= 0.5x the fp32 bytes
+                on fwd AND bwd.
 
-Tolerances are pinned from measured interpret-mode maxima (~2x headroom;
-see docs/fused_ring.md's tolerance table): loosening one is a numerics
-regression, not a flake.  The full matrices are slow-marked; each keeps a
-fast canary (scripts/test.sh --quant runs everything here).
+Tolerances are pinned from measured maxima (~2x headroom): loosening one is
+a numerics regression, not a flake.
 """
-
-import os
-
-os.environ["BURST_FUSED_INTERPRET"] = "1"  # read at trace time, module-wide
 
 import numpy as np
 import jax
@@ -35,15 +24,15 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from burst_attn_tpu import burst_attn
-from burst_attn_tpu.parallel import burst, layouts, schedule as sched
+from burst_attn_tpu.parallel import layouts, schedule as sched
 
 KEY = jax.random.PRNGKey(11)
 
-# pinned max|err| vs the fp32 ring at ~2x the measured interpret-mode
-# maxima (int8 fwd 0.018 / grad 0.135; fp8 fwd 0.096 / grad 0.841 across
-# the matrices below).  Grad tolerances are looser because the loss
-# compounds fwd quantization error through do before the bwd wire adds
-# its own.  Loosening one of these is a numerics regression, not a flake.
+# pinned max|err| vs the fp32 ring at ~2x the measured maxima (int8 fwd
+# 0.018 / grad 0.135; fp8 fwd 0.096 / grad 0.841).  Grad tolerances are
+# looser because the loss compounds fwd quantization error through do before
+# the bwd wire adds its own.  Loosening one of these is a numerics
+# regression, not a flake.
 TOL_FWD = {"int8": 0.04, "fp8": 0.2}
 TOL_GRAD = {"int8": 0.25, "fp8": 1.5}
 
@@ -70,7 +59,8 @@ def _qkv(world=8, n=2, d=16, seq_per_dev=16, layout="zigzag", kv_heads=None):
 
 
 def _fwd(mesh, ql, kl, vl, **kw):
-    return burst_attn(ql, kl, vl, mesh=mesh, **kw)
+    return jax.jit(lambda q, k, v: burst_attn(q, k, v, mesh=mesh, **kw))(
+        ql, kl, vl)
 
 
 def _grads(mesh, ql, kl, vl, **kw):
@@ -78,7 +68,7 @@ def _grads(mesh, ql, kl, vl, **kw):
         o = burst_attn(q, k, v, mesh=mesh, **kw)
         return jnp.sum(o.astype(jnp.float32) ** 2)
 
-    return jax.grad(loss, (0, 1, 2))(ql, kl, vl)
+    return jax.jit(jax.grad(loss, (0, 1, 2)))(ql, kl, vl)
 
 
 def _max_err(a, b):
@@ -86,75 +76,10 @@ def _max_err(a, b):
                                  - jnp.asarray(b, jnp.float32))))
 
 
-# (layout, world, cfg extras) — uni / bidi / double / elided-window shapes;
-# every row runs fwd AND grad parity for both wire dtypes in the matrices
-_SHAPES = (
-    ("zigzag", 8, {}),                                   # uni
-    ("striped", 4, {"fused_topology": "bidi"}),          # bidi
-    ("zigzag", 8, {"fused_seq_factor": (2, 4)}),         # double (flat)
-    ("contig", 8, {"window": 20}),                       # occupancy-elided
-)
-
-
 # ---------------------------------------------------------------------------
-# fused parity — fast canaries + slow matrices
+# parity on the ring (backend="jnp": ppermute wire)
 
 
-@pytest.mark.fused_ring
-def test_wire_fused_fwd_canary():
-    """Fast-lane canary of the slow fwd matrix: zigzag uni, int8 (world 4
-    keeps it cheap; the slow matrix runs the full 8-device shapes)."""
-    mesh = _mesh(4)
-    ql, kl, vl = _qkv(4)
-    kw = dict(causal=True, layout="zigzag", backend="fused_ring")
-    o0 = _fwd(mesh, ql, kl, vl, **kw)
-    o1 = _fwd(mesh, ql, kl, vl, wire_dtype="int8", **kw)
-    assert _max_err(o0, o1) < TOL_FWD["int8"]
-
-
-@pytest.mark.fused_ring
-@pytest.mark.parametrize("wire", ["int8", "fp8"])
-@pytest.mark.parametrize("layout,world,extras", _SHAPES)
-def test_wire_fused_fwd_parity_matrix(layout, world, extras, wire):
-    mesh = _mesh(world)
-    ql, kl, vl = _qkv(world, layout=layout)
-    kw = dict(causal=True, layout=layout, backend="fused_ring", **extras)
-    o0 = _fwd(mesh, ql, kl, vl, **kw)
-    o1 = _fwd(mesh, ql, kl, vl, wire_dtype=wire, **kw)
-    err = _max_err(o0, o1)
-    assert err < TOL_FWD[wire], (layout, extras, wire, err)
-
-
-@pytest.mark.fused_ring
-def test_wire_fused_grad_canary():
-    """Fast-lane canary of the slow grad matrix: zigzag uni, int8,
-    quantization live through BOTH passes (fwd K/V + bwd bundle + dq).
-    World 4 keeps it cheap; the slow matrix runs the 8-device shapes."""
-    mesh = _mesh(4)
-    ql, kl, vl = _qkv(4)
-    kw = dict(causal=True, layout="zigzag", backend="fused_ring")
-    g0 = _grads(mesh, ql, kl, vl, **kw)
-    g1 = _grads(mesh, ql, kl, vl, wire_dtype="int8", **kw)
-    for name, a, b in zip(("dq", "dk", "dv"), g0, g1):
-        err = _max_err(a, b)
-        assert err < TOL_GRAD["int8"], (name, err)
-
-
-@pytest.mark.fused_ring
-@pytest.mark.parametrize("wire", ["int8", "fp8"])
-@pytest.mark.parametrize("layout,world,extras", _SHAPES)
-def test_wire_fused_grad_parity_matrix(layout, world, extras, wire):
-    mesh = _mesh(world)
-    ql, kl, vl = _qkv(world, layout=layout)
-    kw = dict(causal=True, layout=layout, backend="fused_ring", **extras)
-    g0 = _grads(mesh, ql, kl, vl, **kw)
-    g1 = _grads(mesh, ql, kl, vl, wire_dtype=wire, **kw)
-    for name, a, b in zip(("dq", "dk", "dv"), g0, g1):
-        err = _max_err(a, b)
-        assert err < TOL_GRAD[wire], (layout, extras, wire, name, err)
-
-
-@pytest.mark.fused_ring
 @pytest.mark.parametrize("opt_comm", [True, False])
 def test_wire_gqa_opt_comm_composition(opt_comm):
     """GQA (kv_heads < heads) x optimize_bwd_comm x wire: the per-(batch,
@@ -162,7 +87,7 @@ def test_wire_gqa_opt_comm_composition(opt_comm):
     with grouped heads and the packed-delta bundle layout."""
     mesh = _mesh(4)
     ql, kl, vl = _qkv(4, n=4, kv_heads=2)
-    kw = dict(causal=True, layout="zigzag", backend="fused_ring",
+    kw = dict(causal=True, layout="zigzag", backend="jnp",
               optimize_bwd_comm=opt_comm)
     g0 = _grads(mesh, ql, kl, vl, **kw)
     g1 = _grads(mesh, ql, kl, vl, wire_dtype="int8", **kw)
@@ -170,10 +95,6 @@ def test_wire_gqa_opt_comm_composition(opt_comm):
         err = _max_err(a, b)
         assert err < TOL_GRAD["int8"], (opt_comm, name, err)
         assert a.shape == b.shape
-
-
-# ---------------------------------------------------------------------------
-# scan ring parity (backend="jnp": ppermute wire, same quantizers)
 
 
 @pytest.mark.parametrize("wire", ["int8", "fp8"])
@@ -194,12 +115,10 @@ def test_wire_scan_ring_parity(wire):
 # wire_dtype=None bit-identity: outputs AND traced program
 
 
-@pytest.mark.fused_ring
-@pytest.mark.parametrize("backend", ["fused_ring", "jnp"])
-def test_wire_none_bit_identical(backend):
+def test_wire_none_bit_identical():
     mesh = _mesh(4)
     ql, kl, vl = _qkv(4)
-    kw = dict(causal=True, layout="zigzag", backend=backend)
+    kw = dict(causal=True, layout="zigzag", backend="jnp")
     o_default = _fwd(mesh, ql, kl, vl, **kw)
     o_none = _fwd(mesh, ql, kl, vl, wire_dtype=None, **kw)
     assert np.array_equal(np.asarray(o_default), np.asarray(o_none))
@@ -209,7 +128,6 @@ def test_wire_none_bit_identical(backend):
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.fused_ring
 def test_wire_none_trace_identical():
     """The wire_dtype=None JAXPR is the pre-PR program — not just close
     outputs, the identical traced computation (addresses canonicalized)."""
@@ -221,7 +139,7 @@ def test_wire_none_trace_identical():
     def trace(**kw):
         fn = lambda q, k, v: burst_attn(  # noqa: E731
             q, k, v, mesh=mesh, causal=True, layout="zigzag",
-            backend="fused_ring", **kw)
+            backend="jnp", **kw)
         return _canon_jaxpr(jax.make_jaxpr(fn)(S, S, S))
 
     assert trace() == trace(wire_dtype=None)
@@ -243,8 +161,8 @@ def test_wire_bytes_counters_replay_schedule():
               {"pass": "bwd", "dir": "bundle"},
               {"pass": "bwd", "dir": "dq"})
     before = [c.get(**lb) for lb in labels]
-    o = burst_attn(ql, kl, vl, mesh=mesh, causal=True, layout="zigzag",
-                   backend="fused_ring", wire_dtype="int8")
+    o = _fwd(mesh, ql, kl, vl, causal=True, layout="zigzag", backend="jnp",
+             wire_dtype="int8")
     jax.block_until_ready(o)
     after = [c.get(**lb) for lb in labels]
     b, n, S, d = ql.shape
@@ -269,32 +187,19 @@ def test_wire_int8_bytes_at_most_half_of_fp32(pass_, opt_comm):
 
 
 # ---------------------------------------------------------------------------
-# scale-slot schedule replay: the wire run's in-kernel slot counters match
-# the SAME exported slot schedule as the dense run — scale sub-payloads
-# ride existing slot credits (no new slots, no extra slot writes) — and
 # quant_absmax surfaces the quantizer's input range
 
 
-@pytest.mark.fused_ring
-def test_wire_slot_counters_and_quant_absmax():
-    from burst_attn_tpu.obs import devstats
+def test_wire_quant_absmax():
     from burst_attn_tpu.obs.registry import Registry
-    from burst_attn_tpu.ops.tuning import resolve_fused
-    from burst_attn_tpu.parallel import ring
 
     world = 8
     mesh = _mesh(world)
     ql, kl, vl = _qkv(world)
-    kw = dict(causal=True, layout="zigzag", backend="fused_ring",
+    kw = dict(causal=True, layout="zigzag", backend="jnp",
               collect_stats=True)
-    _, st_dense = burst_attn(ql, kl, vl, mesh=mesh, **kw)
-    _, st_wire = burst_attn(ql, kl, vl, mesh=mesh, wire_dtype="int8", **kw)
-    slots = min(resolve_fused(None, None, None).kv_slots, world)
-    want = np.bincount(ring.fused_slot_schedule(world, slots),
-                       minlength=devstats.MAX_SLOTS)
-    assert (np.asarray(st_wire.slot_use) == want[None, :]).all()
-    assert (np.asarray(st_wire.slot_use)
-            == np.asarray(st_dense.slot_use)).all()
+    _, st_dense = _fwd(mesh, ql, kl, vl, **kw)
+    _, st_wire = _fwd(mesh, ql, kl, vl, wire_dtype="int8", **kw)
     # quant_absmax: zero (disabled) on the dense run, the true k/v absmax
     # under wire — the gauge that says how much of the int8 range the
     # payloads actually use
