@@ -11,7 +11,11 @@ fused backward's in-place `dq` races shows as a difference, not as a time.
 
     python -m benchmarks.sweep_tile_calls --out chiprun_out/sweep_tile_calls.jsonl
 
-results/sweep_tile_calls.jsonl is PR 29's sweep on one v5e.
+results/sweep_tile_calls.jsonl is PR 29's sweep on one v5e, then PR 33's of
+the forward's diagonal sub-square edge (`--edges`: the rows with an `edge`,
+and beside each the host seconds its call site took to trace and to lower,
+`trace_s` / `lower_s`: a Python-unrolled body's other cost, which lands in a
+cell's `setup_s`).
 """
 
 import argparse
@@ -19,6 +23,7 @@ import json
 import shutil
 import sys
 import tempfile
+import time
 
 ITERS = 5
 
@@ -36,12 +41,17 @@ GEOMETRIES = {
 
 SQUARES = [(128, 128), (256, 256), (512, 512), (1024, 1024)]
 ROW_FWD, ROW_BWD = (2048, 2048, True), (1024, 2048)
+# the sub-square edge of the forward's diagonal sweep (PR 33) in the row's
+# tiles: 0 (the whole tile on the masked path, what every forward entry
+# without an edge runs: the tile sizes were swept on it), then the edges
+EDGES = [ROW_FWD + (e,) for e in (0, 1024, 512, 256, 128, 64)]
 # {(geometry, call, pass): [(block_q, block_kv[, ask for the all-live
-# grid]), ...]}; the first entry of each list is the reference the others
-# are compared with.  Calls: the three quadrants (`diagonal` folds into the
-# `below` call's state, as burst._bd_fwd chains them; `diagonal_empty` and
-# `below_carried` are the other order) and `banded`, a causal call under
-# the geometry's token window.
+# grid[, diagonal sub-square edge]]), ...]}; the first entry of each list is
+# the reference the others are compared with.  Calls: the three quadrants
+# (`diagonal` folds into the `below` call's state, as burst._bd_fwd chains
+# them; `diagonal_empty` and `below_carried` are the other order) and
+# `banded`, a causal call under the geometry's token window.  The `edge`
+# sweeps are `--edges`' (the forward rows of the cells' causal calls).
 SWEEPS = {
     # the block-diagonal call: the v5e row on the rectangular grid (what the
     # cell ran before), then the band grid over tile sizes
@@ -76,6 +86,12 @@ SWEEPS = {
     ("win1024_8k", "banded", "fwd"): [ROW_FWD, (512, 512, True),
                                       (1024, 1024, True)],
     ("win1024_8k", "banded", "bwd"): [ROW_BWD, (512, 512), (1024, 1024)],
+    ("bd8k", "clean", "edge"): EDGES,
+    ("bd8k", "below", "edge"): EDGES,
+    ("bd8k", "below_carried", "edge"): EDGES,
+    ("causal8k", "clean", "edge"): EDGES,
+    # one 1024 x 1024 tile a head on the rectangular grid: edge 1024 is 0
+    ("causal1k", "clean", "edge"): [EDGES[0]] + EDGES[2:],
 }
 
 
@@ -86,6 +102,8 @@ def main():
                    help="comma list of geometry names (default: all)")
     p.add_argument("--first", type=int, default=0,
                    help="only the first N configurations of each sweep")
+    p.add_argument("--edges", action="store_true",
+                   help="only the sweeps of the diagonal sub-square edge")
     args = p.parse_args()
 
     import os
@@ -145,8 +163,10 @@ def main():
 
     only = [g for g in args.only.split(",") if g]
     for (gname, call, pass_), configs in SWEEPS.items():
-        if only and gname not in only:
+        if (only and gname not in only) or args.edges != (pass_ == "edge"):
             continue
+        if pass_ == "edge":
+            pass_ = "fwd"
         g = GEOMETRIES[gname]
         s, b, n, n_kv, unit = g["s"], g["b"], g["n"], g["n_kv"], g["unit"]
         d, scale = 128, 128 ** -0.5
@@ -201,12 +221,22 @@ def main():
             try:
                 if pass_ == "fwd":
                     row["band_or_tri"] = cfg[2]
+                    row["edge"] = edge = cfg[3] if len(cfg) > 3 else 0
                     fn = jax.jit(lambda q, k, v, *st, bq=bq, bkv=bkv,
-                                 tri=cfg[2]: pf.flash_fwd(
+                                 tri=cfg[2], edge=edge: pf.flash_fwd(
                         q, k, v, *(st or (None,) * 3), scale, spec,
                         block_q=bq, block_kv=bkv, triangular=tri,
-                        window=window))
+                        window=window, diag_block=edge))
                     xs = (q, k, v) + (state if other is not None else ())
+                    if len(cfg) > 3:
+                        # what the call site costs before the compiler: a
+                        # first trace of this edge's body, and its lowering
+                        t0 = time.perf_counter()
+                        traced = fn.trace(*xs)
+                        t1 = time.perf_counter()
+                        traced.lower()
+                        row.update(trace_s=round(t1 - t0, 3), lower_s=round(
+                            time.perf_counter() - t1, 3))
                 else:
                     fn = jax.jit(lambda do, q, k, v, delta, lse, *c, bq=bq,
                                  bkv=bkv: pf.flash_bwd(

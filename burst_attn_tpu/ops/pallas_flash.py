@@ -39,12 +39,14 @@ packed back with a native lane-reducing reshape.
 import functools
 import logging
 import os
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import tuning
 from .masks import MaskSpec, unit_of
 
 logger = logging.getLogger("burst_attn_tpu")
@@ -401,6 +403,51 @@ def fwd_covers_ranges(s_q, s_kv, q_range, kv_range, *, block_q, block_kv,
             and _whole_blocks(kv_range, s_kv, block_kv))
 
 
+class DiagPath(NamedTuple):
+    """What serves the diagonal tiles of a forward call that promises
+    `triangular` (fwd_diag_path)."""
+
+    path: str  # "sub": _fwd_kernel's _sweep_diag; "whole": its sweep(True)
+    tiles: int  # diagonal tiles a (batch, head): the q blocks of the grid
+    edge: Optional[int]  # the sub-square edge where path == "sub"
+
+
+def fwd_diag_path(s_q, s_kv, *, block_q, block_kv, triangular, window=None,
+                  segments=False, q_range=None, kv_range=None,
+                  diag_block=None, loop_sweep=False) -> Optional[DiagPath]:
+    """Whether flash_fwd's diagonal tiles (q block i against kv block i of a
+    call whose caller promises `triangular`: statically full-window causal,
+    offset 0 or -1) compute their live sub-squares only, or their whole
+    area on the masked path.  None where no such promise is made.  Static:
+    flash_fwd chooses its kernel body by it, and the ring counts its
+    dispatches by it (parallel/burst.py, flash.diag_tiles).
+
+    The sub-square sweep needs what makes the dead, full and cut squares of
+    a diagonal tile known at trace time: no sliding window (a
+    masks.BlockUnits unit is fine), no `segments`, exact tiling with
+    block_q == block_kv, and an edge `diag_block` (None: the generation's,
+    ops/tuning.py; 0 turns the sweep off) that divides the tile, is smaller
+    than it and is whole mask units.  The fori_loop sweep (`loop_sweep`,
+    BURST_FWD_LOOP) keeps the whole tile.  A call with ranges is judged on
+    the rows they cover: that is what its sliced form runs on."""
+    s_q, s_kv = _range_len(q_range, s_q), _range_len(kv_range, s_kv)
+    if not triangular or s_q != s_kv:
+        return None
+    sq_pad = _padded_len(s_q, block_q)
+    bq = _pick_block(sq_pad, block_q)
+    bkv = _pick_block(_padded_len(s_kv, block_kv), block_kv)
+    nqb = sq_pad // bq
+    unit, win = unit_of(window)
+    if diag_block is None:
+        diag_block = tuning.block_defaults().diag_block
+    sub = (sq_pad == s_q and win is None and not segments
+           and not (loop_sweep or _fwd_loop_default()) and bq == bkv
+           and 0 < diag_block < bkv and bkv % diag_block == 0
+           and diag_block % unit == 0)
+    return DiagPath("sub", nqb, diag_block) if sub else DiagPath(
+        "whole", nqb, None)
+
+
 # ---------------------------------------------------------------------------
 # forward
 
@@ -489,11 +536,13 @@ def _fwd_kernel(
     *rest,
     scale, bq, bkv, bkv_compute, lp, n_kv_blocks, cast_p, tri, wnd=None,
     seg=False, emit_o=False, loop=False, ablate=None, band_nb=None,
-    carry=True, tri_r=1, q_off=0, keep_rows=False,
+    carry=True, tri_r=1, q_off=0, keep_rows=False, diag=None,
 ):
     # q_off: this call's first q-row block in the FULL state arrays (a
     # sub-range round, see flash_fwd); the mask arithmetic below stays local
     # to the range.  keep_rows: the range leaves some of a head's rows out.
+    # diag: the edge of the diagonal sweep's row chunks where tile i == j
+    # takes it (fwd_diag_path decides, statically), else None.
     if carry:
         m_in_ref, lse_in_ref, acc_in_ref = rest[:3]
         rest = rest[3:]
@@ -578,12 +627,14 @@ def _fwd_kernel(
     # [bq, bkv] score matrix — the kernel is VPU-bound, not MXU-bound
     q = q_ref[0, 0, :, :] * (scale * LOG2E)
 
-    def _score(u):
-        cs = pl.ds(u * bkv_compute, bkv_compute)
+    def _qk(q_rows, cs):
         return jax.lax.dot_general(
-            q, k_ref[0, 0, cs, :], (((1,), (1,)), ((), ())),
+            q_rows, k_ref[0, 0, cs, :], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+    def _score(u):
+        return _qk(q, pl.ds(u * bkv_compute, bkv_compute))
 
     def _softmax(s, mask, m_prev, l_prev):
         """VPU half of one sub-block fold: returns (m_new, l_new, alpha, p)."""
@@ -598,12 +649,14 @@ def _fwd_kernel(
         l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
         return m_new, l_new, alpha, p.astype(v_ref.dtype) if cast_p else p
 
-    def _pv(u, p):
-        cs = pl.ds(u * bkv_compute, bkv_compute)
+    def _p_v(p, cs):
         return jax.lax.dot_general(
             p, v_ref[0, 0, cs, :], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+    def _pv(u, p):
+        return _p_v(p, pl.ds(u * bkv_compute, bkv_compute))
 
     def _sweep_loop(masked):
         """lax.fori_loop variant of _sweep: the pend (alpha, p) rides the
@@ -722,15 +775,97 @@ def _fwd_kernel(
         acc = acc * pend[1] + _pv(pend[0], pend[2])
         m_scr[:], l_scr[:], acc_scr[:] = m, l, acc
 
+    def _sweep_diag():
+        """The tile the causal diagonal cuts (i == j, bq == bkv), in row
+        chunks of `diag` rows: under the caller's promise (full-window
+        causal, offset 0 or -1, see flash_fwd) row chunk r sees all of the
+        columns left of its own square, none right of it, and the square
+        itself through the diagonal.  So chunk r folds columns
+        [0, (r+1)*diag) in ONE fold: the columns left of the square without
+        a mask (no iota, no select), the square under _block_mask with the
+        real spec scalars (so offset -1 and block units stay exact), and
+        the dead squares not at all.  One running max a row for the whole
+        tile where _sweep takes one a compute sub-block, so the result
+        differs from sweep(True)'s by rounding.  The three-stage stagger is
+        _sweep's, over row chunks: the next chunk's scores are issued before
+        this chunk's softmax, and its pv one chunk late.  Measured against
+        the column-major form (column chunk u folded into rows [u*diag, bq),
+        _sweep's order): the 8,192-row call reads 4.07 / 4.32 / 4.22 ms that
+        way at 1024 / 512 / 256 and 4.19 / 3.76 / 3.53 this way (4.76 whole;
+        PERF.md section 6, PR 32): a narrow column chunk pays the [rows, 1]
+        state updates, which fill 1 lane of 128, once a chunk."""
+        e = diag
+
+        def scores(r):
+            qr = q[r * e:(r + 1) * e]
+            return (_qk(qr, pl.ds(0, r * e)) if r else None,
+                    _qk(qr, pl.ds(r * e, e)))
+
+        def softmax(r, s_full, s_cut):
+            rows = pl.ds(r * e, e)
+            mask = _block_mask(spec_ref, r0 + r * e, c0 + r * e, e, e, wnd)
+            m_prev, l_prev = m_scr[rows, :], l_scr[rows, :]
+            s_cut = jnp.where(mask, s_cut, NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s_cut, axis=1, keepdims=True))
+            if s_full is not None:
+                m_new = jnp.maximum(m_new,
+                                    jnp.max(s_full, axis=1, keepdims=True))
+            alpha = jnp.where(m_prev >= m_new, 1.0, jnp.exp2(m_prev - m_new))
+            # the select guards the all-masked-row nan (s = m_new = -inf)
+            p_cut = jnp.where(mask, jnp.exp2(s_cut - m_new), 0.0)
+            l_new = l_prev * alpha + jnp.sum(p_cut, axis=1, keepdims=True)
+            p_full = None
+            if s_full is not None:
+                p_full = jnp.exp2(s_full - m_new)
+                l_new = l_new + jnp.sum(p_full, axis=1, keepdims=True)
+            m_scr[rows, :], l_scr[rows, :] = m_new, l_new
+            if cast_p:
+                p_cut = p_cut.astype(v_ref.dtype)
+                if p_full is not None:
+                    p_full = p_full.astype(v_ref.dtype)
+            return alpha, p_full, p_cut
+
+        def fold_pv(r, alpha, p_full, p_cut):
+            rows = pl.ds(r * e, e)
+            pv = _p_v(p_cut, rows)
+            if p_full is not None:
+                pv = pv + _p_v(p_full, pl.ds(0, r * e))
+            acc_scr[rows, :] = acc_scr[rows, :] * alpha + pv
+
+        s_cur = scores(0)
+        pend = None  # (r, alpha, p_full, p_cut) awaiting its pv + acc fold
+        for r in range(bq // e):
+            s_next = scores(r + 1) if (r + 1) * e < bq else None
+            out = softmax(r, *s_cur)
+            if pend is not None:
+                fold_pv(*pend)
+            pend = (r, *out)
+            s_cur = s_next
+        fold_pv(*pend)
+
     sweep = _sweep_loop if loop else _sweep
 
     @pl.when(fast_cond)
     def _compute_fast():
         sweep(False)
 
-    @pl.when(masked_cond)
-    def _compute_masked():
-        sweep(True)
+    if diag is None:
+        @pl.when(masked_cond)
+        def _compute_masked():
+            sweep(True)
+    elif tri:
+        # tri_r == 1: the masked step IS the tile with i == j
+        @pl.when(masked_cond)
+        def _compute_diag():
+            _sweep_diag()
+    else:
+        @pl.when(masked_cond & (i != j))
+        def _compute_masked():
+            sweep(True)
+
+        @pl.when(masked_cond & (i == j))
+        def _compute_diag():
+            _sweep_diag()
 
     @pl.when(is_fin)
     def _finish():
@@ -753,7 +888,7 @@ def flash_fwd(q, k, v, m, lse, acc, scale, spec: MaskSpec, *,
               block_q=1024, block_kv=1024, block_kv_compute=None,
               interpret=None, cast_p=True, triangular=False, window=None,
               segments=None, emit_o=False, loop_sweep=False, _ablate=None,
-              q_range=None, kv_range=None):
+              q_range=None, kv_range=None, diag_block=None):
     """One online-softmax ring round on TPU.  Same contract as
     ops/tile.py:tile_fwd: returns updated (m, lse, acc).
 
@@ -792,6 +927,30 @@ def flash_fwd(q, k, v, m, lse, acc, scale, spec: MaskSpec, *,
     fwd_band_nb).  Do NOT tighten either grid to absolute offsets.
     Falls back to the rectangular grid when the square-tiling preconditions
     don't hold.
+
+    Under the same promise the tile the diagonal cuts (q block i against kv
+    block i; block_q == block_kv, no window, no segments: fwd_diag_path)
+    computes its live sub-squares only, in row chunks of `diag_block` rows
+    (_fwd_kernel._sweep_diag; None: the generation's, ops/tuning.py; 0: the
+    whole tile on the masked path), on the triangular grid and, where that
+    cannot be formed (one q block, an odd count), on the rectangular one.
+    Measured on the v5e (benchmarks/sweep_tile_calls.py --edges; kernel
+    device ms of one call, 32 heads x 128, bf16, the row's 2048 x 2048
+    tiles): the 8,192-row call (10 tiles a head, 4 diagonal) 4.76 whole,
+    4.19 / 3.76 / 3.53 / 3.51 / 3.59 at 1024 / 512 / 256 / 128 / 64 (32 / 8
+    and 32 / 4 heads, block units of 4, offset 0 and -1, empty and carried
+    state: the same to 0.01); the 1,024-row call x batch 8 (one 1024 x 1024
+    tile a head) 1.526 whole, 1.119 / 0.944 / 0.920 / 1.134 at 512 / 256 /
+    128 / 64.  A diagonal 2048 x 2048 tile of 32 heads, with its init and
+    finish: 0.61 ms whole, 0.30 at 256 and at 128; a full one 0.386.  The
+    generation's edge is 256, not 128: the sweep is unrolled in Python, and
+    its trace is part of a program's set-up (ops/tuning.py has both costs).
+
+    The defaults and switches are resolved here; the kernel launch on exact
+    tiles (_fwd_launch) is traced behind ONE jit (_fwd_launch_traced) whose
+    static keywords are everything but the arrays, so that the layers of a
+    model, and jax.checkpoint's recomputed forward, share one trace of the
+    kernel and one lowered function.  XLA inlines the call.
     """
     if interpret is None:
         interpret = _interpret_default()
@@ -800,6 +959,31 @@ def flash_fwd(q, k, v, m, lse, acc, scale, spec: MaskSpec, *,
     if _ablate is not None and loop_sweep:
         raise ValueError("_ablate has no loop_sweep variant — the ablation "
                          "would silently time the full softmax chain")
+    if diag_block is None:
+        diag_block = tuning.block_defaults().diag_block
+    # everything the kernel launch reads from the environment is resolved
+    # here and rides its static keywords: the cached trace answers for
+    # nothing else
+    return _flash_fwd_body(
+        q, k, v, m, lse, acc, spec, segments, scale=scale, block_q=block_q,
+        block_kv=block_kv, block_kv_compute=block_kv_compute,
+        interpret=interpret, cast_p=cast_p, triangular=triangular,
+        window=window, emit_o=emit_o, loop_sweep=loop_sweep, _ablate=_ablate,
+        q_range=q_range, kv_range=kv_range, diag_block=diag_block,
+        no_tri=_tri_disabled())
+
+
+def _flash_fwd_body(q, k, v, m, lse, acc, spec, segments, *, scale, block_q,
+                    block_kv, block_kv_compute, interpret, cast_p, triangular,
+                    window, emit_o, loop_sweep, _ablate, q_range, kv_range,
+                    diag_block, no_tri):
+    """flash_fwd with its defaults resolved: the sliced and the padded form
+    around the kernel, then the launch on exact tiles (_fwd_launch)."""
+    static = dict(
+        scale=scale, block_q=block_q, block_kv=block_kv,
+        block_kv_compute=block_kv_compute, interpret=interpret, cast_p=cast_p,
+        window=window, emit_o=emit_o, loop_sweep=loop_sweep, _ablate=_ablate,
+        diag_block=diag_block, no_tri=no_tri)
     carry = m is not None
     assert (lse is None) == (acc is None) == (not carry), \
         "m, lse, acc must be all None (empty carry) or all present"
@@ -809,13 +993,13 @@ def flash_fwd(q, k, v, m, lse, acc, scale, spec: MaskSpec, *,
             block_kv=block_kv, triangular=triangular):
         from .tile import fwd_on_ranges
 
+        def on_slices(q, k, v, m, lse, acc, scale, spec, segments):
+            return _flash_fwd_body(
+                q, k, v, m, lse, acc, spec, segments, triangular=triangular,
+                q_range=None, kv_range=None, **static)
+
         return fwd_on_ranges(
-            functools.partial(
-                flash_fwd, block_q=block_q, block_kv=block_kv,
-                block_kv_compute=block_kv_compute, interpret=interpret,
-                cast_p=cast_p, triangular=triangular, window=window,
-                emit_o=emit_o, loop_sweep=loop_sweep, _ablate=_ablate),
-            q, k, v, m, lse, acc, scale, spec, segments=segments,
+            on_slices, q, k, v, m, lse, acc, scale, spec, segments=segments,
             q_range=q_range, kv_range=kv_range)
     b, n, sq_full, d = q.shape
     n_kv, skv_full = k.shape[1], k.shape[2]
@@ -831,17 +1015,43 @@ def flash_fwd(q, k, v, m, lse, acc, scale, spec: MaskSpec, *,
             # pad ids never match each other or any real segment
             segments = (_pad_seg(segments[0], sq_pad, -1),
                         _pad_seg(segments[1], skv_pad, -2))
-        m2, lse2, acc2 = flash_fwd(
+        m2, lse2, acc2 = _flash_fwd_body(
             _pad_seq(q, sq_pad), _pad_seq(k, skv_pad), _pad_seq(v, skv_pad),
             _pad_seq(m, sq_pad, float("-inf")) if carry else None,
             (_pad_seq(lse, sq_pad, float("-inf")) if carry else None),
             _pad_seq(acc, sq_pad) if carry else None,
-            scale, spec, block_q=block_q, block_kv=block_kv,
-            block_kv_compute=block_kv_compute, interpret=interpret,
-            cast_p=cast_p, triangular=False, window=window, segments=segments,
-            emit_o=emit_o, loop_sweep=loop_sweep, _ablate=_ablate,
-        )
+            spec, segments, triangular=False, q_range=None, kv_range=None,
+            **static)
         return m2[:, :, :s_q], lse2[:, :, :s_q], acc2[:, :, :s_q]
+    lp = _pick_block(_pick_block(s_q, block_q), 128)
+    inputs = [_spec_array(spec), q, k, v]
+    if carry:
+        inputs += [_pack(m, lp), _pack(lse, lp), acc]
+    if segments is not None:
+        q_seg, kv_seg = segments
+        # ids as [B, S, 1] (q rows along sublanes) / [B, 1, S] (kv along
+        # lanes) so the in-kernel compare broadcasts without relayout
+        inputs.append(jnp.asarray(q_seg, jnp.int32)[:, :, None])
+        inputs.append(jnp.asarray(kv_seg, jnp.int32)[:, None, :])
+    m_new, lse_new, acc_new = _fwd_launch_traced(
+        *inputs, carry=carry, seg=segments is not None,
+        triangular=triangular, q_range=q_range, kv_range=kv_range, **static)
+    return _unpack(m_new), _unpack(lse_new), acc_new
+
+
+def _fwd_launch(spec_arr, q, k, v, *rest, carry, seg, scale, block_q,
+                block_kv, block_kv_compute, interpret, cast_p, triangular,
+                window, emit_o, loop_sweep, _ablate, q_range, kv_range,
+                diag_block, no_tri):
+    """The forward kernel on exact tiles: `rest` is the packed (m, lse) and
+    acc where `carry`, then the segment ids as [B, S, 1] / [B, 1, S] where
+    `seg`; every keyword static.  Returns the packed (m, lse) and acc (or o
+    under emit_o)."""
+    b, n, sq_full, d = q.shape
+    n_kv, skv_full = k.shape[1], k.shape[2]
+    # the lengths the grid covers; the arrays keep their full length
+    s_q, s_kv = _range_len(q_range, sq_full), _range_len(kv_range, skv_full)
+    group = _gqa_group(n, n_kv)
     bq = _pick_block(s_q, block_q)
     bkv = _pick_block(s_kv, block_kv)
     if block_kv_compute is None:
@@ -856,7 +1066,7 @@ def flash_fwd(q, k, v, m, lse, acc, scale, spec: MaskSpec, *,
     # tokens, and every tile (the compute sub-blocks too) is whole blocks
     unit, win = unit_of(window)
     _check_unit_tiles(window, bq, bkc)
-    tri = (bool(triangular) and win is None and not _tri_disabled()
+    tri = (bool(triangular) and win is None and not no_tri
            and bq % bkv == 0 and s_q == s_kv and nqb % 2 == 0 and nqb >= 2)
     tri_r = bq // bkv if tri else 1  # tall-q aspect (see _tri_coords)
     # band grid: the window analogue of the tri grid.  A q-block's band can
@@ -869,7 +1079,7 @@ def flash_fwd(q, k, v, m, lse, acc, scale, spec: MaskSpec, *,
     # tri (static full-window causal, offset 0/-1), which `triangular=True`
     # already promises.
     band_nb = None
-    if bool(triangular) and win is not None and not _tri_disabled():
+    if bool(triangular) and win is not None and not no_tri:
         nb = min(nkb, fwd_band_nb(bq // unit, bkv // unit, win))
         if nb < nkb:
             band_nb = nb
@@ -902,12 +1112,17 @@ def flash_fwd(q, k, v, m, lse, acc, scale, spec: MaskSpec, *,
         q_map, kv_map, state_map = _make_index_maps(
             bq, bkv, nqb, nkb, group, wnd=window, q_off=q_off, kv_off=kv_off)
         grid = (b, n, nqb, nkb)
+    path = None if _ablate is not None else fwd_diag_path(
+        s_q, s_kv, block_q=block_q, block_kv=block_kv, triangular=triangular,
+        window=window, segments=seg, diag_block=diag_block,
+        loop_sweep=loop_sweep)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, bq=bq, bkv=bkv, bkv_compute=bkc, lp=lp,
         n_kv_blocks=nkb, cast_p=cast_p, tri=tri, wnd=window,
-        seg=segments is not None, emit_o=emit_o, loop=loop_sweep,
+        seg=seg, emit_o=emit_o, loop=loop_sweep,
         ablate=_ablate, band_nb=band_nb, carry=carry, tri_r=tri_r,
         q_off=q_off, keep_rows=s_q != sq_full,
+        diag=None if path is None else path.edge,
     )
     state_block = pl.BlockSpec((1, 1, sq_full // lp, lp), state_map)
     in_specs = [
@@ -915,21 +1130,14 @@ def flash_fwd(q, k, v, m, lse, acc, scale, spec: MaskSpec, *,
         pl.BlockSpec((1, 1, bkv, d), kv_map),
         pl.BlockSpec((1, 1, bkv, d), kv_map),
     ]
-    inputs = [_spec_array(spec), q, k, v]
     if carry:
         in_specs += [state_block, state_block,
                      pl.BlockSpec((1, 1, bq, d), q_map)]
-        inputs += [_pack(m, lp), _pack(lse, lp), acc]
-    if segments is not None:
-        q_seg, kv_seg = segments
-        # ids as [B, S, 1] (q rows along sublanes) / [B, 1, S] (kv along
-        # lanes) so the in-kernel compare broadcasts without relayout
+    if seg:
         in_specs.append(pl.BlockSpec(
             (1, bq, 1), lambda b_, h, i, j, sp: (b_, q_map(b_, h, i, j, sp)[2], 0)))
         in_specs.append(pl.BlockSpec(
             (1, 1, bkv), lambda b_, h, i, j, sp: (b_, 0, kv_map(b_, h, i, j, sp)[2])))
-        inputs.append(jnp.asarray(q_seg, jnp.int32)[:, :, None])
-        inputs.append(jnp.asarray(kv_seg, jnp.int32)[:, None, :])
     out_shape = [
         jax.ShapeDtypeStruct((b, n, sq_full // lp, lp), jnp.float32),
         jax.ShapeDtypeStruct((b, n, sq_full // lp, lp), jnp.float32),
@@ -958,7 +1166,7 @@ def flash_fwd(q, k, v, m, lse, acc, scale, spec: MaskSpec, *,
             pltpu.VMEM((bq, d), jnp.float32),
         ],
     )
-    m_new, lse_new, acc_new = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         # a band grid runs under a name of its own (same prefix): a trace
         # shows a windowed call's time beside the other forward calls'
@@ -974,8 +1182,28 @@ def flash_fwd(q, k, v, m, lse, acc, scale, spec: MaskSpec, *,
             dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(*inputs)
-    return _unpack(m_new), _unpack(lse_new), acc_new
+    )(spec_arr, q, k, v, *rest)
+
+
+# One trace and one lowered body a DISTINCT forward call, not one a call site
+# (PR 33).  Pallas traces a kernel body anew at every pallas_call, a model
+# makes one a layer (and jax.checkpoint's forward again), and _sweep_diag is a
+# Python-unrolled loop: trace + lower of jax.grad over four
+# jax.checkpoint(burst_attn) blocks at 1 x 8,192 rows for a described v5e
+# (benchmarks/trace_cost.py, this repo's CPU host) reads 0.46-0.57 s with the
+# whole-tile body, 0.96 with the sub-square sweep at an edge of 128 traced a
+# site (PR 32), and 0.54-0.61 at 256 behind this jit (PERF.md section 6).
+# Every layer's call has the same static keywords and avals, so the later ones
+# hit jit's trace cache and lower to calls of one func.func; XLA inlines it.
+# The jit holds the kernel launch and nothing else: what XLA fuses around a
+# call (slices, pads, the state's packing, the ring's finalize) must meet the
+# graph it met before, or the program around the kernel moves (with the
+# state's unpacking inside, the four-chip ring compiled one copy and 32,256
+# bytes of temporaries away from the parent's).
+_fwd_launch_traced = jax.jit(_fwd_launch, static_argnames=(
+    "carry", "seg", "scale", "block_q", "block_kv", "block_kv_compute",
+    "interpret", "cast_p", "triangular", "window", "emit_o", "loop_sweep",
+    "_ablate", "q_range", "kv_range", "diag_block", "no_tri"))
 
 
 # ---------------------------------------------------------------------------

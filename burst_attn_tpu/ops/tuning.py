@@ -51,6 +51,25 @@ class BlockTable(NamedTuple):
     # on the v5e (benchmarks/sweep_tile_calls.py; the numbers are at
     # call_row); the other generations inherit it unswept.
     band_block: int = 512
+    # Sub-square edge of the forward's diagonal sweep (pallas_flash
+    # fwd_diag_path / _fwd_kernel._sweep_diag): a causal call's diagonal tile
+    # is computed in row chunks of this many rows, the squares above the
+    # diagonal skipped and only the ones it cuts masked.  0 = the whole tile
+    # on the masked path.  Chosen on the kernel AND on what its body costs to
+    # trace, which lands in a cell's setup_s (the body is a Python-unrolled
+    # loop of tile / edge chunks).  MEASURED on the v5e (benchmarks/
+    # sweep_tile_calls.py --edges, PRs 32 and 33; kernel ms of the 8,192-row
+    # call / of the 1,024-row call x 8, then host seconds to trace + lower
+    # one call site for a described v5e, benchmarks/trace_cost.py):
+    #   0 (whole) 4.76 / 1.526, 0.11 s    512  3.76 / 1.119, 0.16 s
+    #   256       3.53 / 0.944, 0.25 s    128  3.51 / 0.920, 0.37 s
+    #   1024      4.19           64   3.59 / 1.134
+    # 256 keeps 98 % of 128's gain at 8,192 rows and 96 % at 1,024 for two
+    # thirds of its trace (PR 32 shipped 128 traced once a layer and was
+    # refused on setup_s; since PR 33 the body is traced once a distinct
+    # call, so the edge is paid once a program length, not once a layer).
+    # The other generations inherit it unswept.
+    diag_block: int = 256
 
 
 class ResolvedBlocks(NamedTuple):

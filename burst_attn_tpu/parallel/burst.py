@@ -70,6 +70,13 @@ _M_INPLACE = obs.counter(
     "scheduled scan-ring rounds after the self round, by pass and by where "
     "the round folds into its carry: path=kernel (the tile writes into the "
     "carry in place) or path=xla (slice / run / add around the tile)")
+_M_DIAG = obs.counter(
+    "flash.diag_tiles",
+    "diagonal tiles (q block i against kv block i, a batch and head each) of "
+    "the dispatch's forward tile calls that promise a full-window causal "
+    "mask, by pass and by what serves them: path=sub (the kernel computes "
+    "the tile's live sub-squares only) or path=whole (its whole area on the "
+    "masked path)")
 _M_HOPS = obs.counter(
     "burst.ring_hops", "scheduled KV ring hops, by mesh axis role")
 _M_WIRE = obs.counter(
@@ -283,6 +290,44 @@ def _round_tiles(cfg, s, s_kv):
             return [(True, None, None)]
     band = cfg.layout == "contig" and cfg.causal and cfg.window is not None
     return [(band, None, None)]
+
+
+def _promised_fwd_calls(cfg, s, s_kv, rounds, seg):
+    """The forward tile calls of one dispatch that promise `triangular`, as
+    static (calls, rows, window, segments) of _tile_fwd's arguments: the
+    self round (_fwd_impl's tri0), the later rounds whose tile says so
+    (_round_tiles), or the three quadrants of a block-diffusion stream
+    (_bd_fwd)."""
+    if cfg.block_diffusion is not None:
+        from ..ops.masks import bd_quadrants
+
+        return [(1, quad.q_range[1] - quad.q_range[0], quad.window, False)
+                for quad in bd_quadrants(s, cfg.block_diffusion)]
+    if not (cfg.causal and s_kv == s):
+        return []
+    later = sum(tri for tri, _, _ in _round_tiles(cfg, s, s_kv))
+    return [(1 + later * (rounds - 1), s, cfg.window, seg)]
+
+
+def _diag_tiles(cfg, q_shape, k_shape, rounds, seg):
+    """{path: diagonal tiles} of one dispatch's forward calls, by the tile
+    entry's own static choice (pallas_flash.fwd_diag_path, on the blocks
+    _tile_fwd resolves)."""
+    if cfg.backend != "pallas":
+        return {}
+    from ..ops import pallas_flash
+
+    (b, n, s, _), s_kv = q_shape, k_shape[2]
+    tiles = {}
+    for calls, rows, window, segs in _promised_fwd_calls(
+            cfg, s, s_kv, rounds, seg):
+        rb = cfg.resolved_blocks(rows, rows, window)
+        path = pallas_flash.fwd_diag_path(
+            rows, rows, block_q=rb.block_q, block_kv=rb.block_kv,
+            triangular=True, window=window, segments=segs)
+        tiles[path.path] = (tiles.get(path.path, 0)
+                            + calls * b * n * path.tiles)
+    return tiles
 
 
 def _round_in_kernel(cfg, pass_, q_shape, k_shape) -> bool:
@@ -985,10 +1030,11 @@ _burst_attn_shard_stats_seg.defvjp(_stats_seg_vjp_fwd, _stats_seg_vjp_bwd)
 
 
 def _note_dispatch(cfg: BurstConfig, mesh, q_shape, k_shape, batch_axes,
-                   head_axes) -> None:
+                   head_axes, seg=False) -> None:
     """Record one ring dispatch in the obs registry (burst.dispatch /
-    burst.ring_rounds / burst.inplace_rounds / burst.ring_hops /
-    burst.wire_bytes).
+    burst.ring_rounds / burst.inplace_rounds / flash.diag_tiles /
+    burst.ring_hops / burst.wire_bytes).  `seg`: whether the call carries
+    segment ids.
 
     Host-boundary code: called from burst_attn BEFORE shard_map, never from
     inside the traced shard program (burstlint `obs-jit-safe`)."""
@@ -1024,6 +1070,9 @@ def _note_dispatch(cfg: BurstConfig, mesh, q_shape, k_shape, batch_axes,
             in_kernel = _round_in_kernel(cfg, pass_, q_local, k_local)
             _M_INPLACE.inc(rounds - 1, **{
                 "pass": pass_, "path": "kernel" if in_kernel else "xla"})
+    for path, tiles in _diag_tiles(cfg, q_local, k_local, rounds,
+                                   seg).items():
+        _M_DIAG.inc(tiles, **{"pass": "fwd", "path": path})
     if intra_hops:
         _M_HOPS.inc(intra_hops, axis="intra")
     if inter_hops:
@@ -1148,7 +1197,8 @@ def burst_attn(
         wire_dtype=wire_dtype,
         block_diffusion=block_diffusion,
     )
-    _note_dispatch(cfg, mesh, q.shape, k.shape, batch_axes, head_axes)
+    _note_dispatch(cfg, mesh, q.shape, k.shape, batch_axes, head_axes,
+                   seg=segment_ids is not None)
     seq_spec = seq_axes if len(seq_axes) > 1 else intra_axis
     spec = P(batch_axes, head_axes, seq_spec, None)
     if collect_stats:

@@ -14,8 +14,10 @@ import pytest
 from jax.sharding import Mesh
 
 import burst_attn_tpu as bat
+from burst_attn_tpu.analysis.jaxpr_tools import iter_eqns
 from burst_attn_tpu.ops import masks, pallas_flash as pf
 from burst_attn_tpu.ops.masks import BlockUnits, round_spec
+from burst_attn_tpu.ops.tile import finalize
 
 
 def _dense(q, k, v, mask):
@@ -262,6 +264,70 @@ def test_the_diagonal_call_s_fused_backward_equals_the_split_kernels_on_the_chip
     assert max(errs.values()) < 1e-6, errs
 
 
+@on_the_chip
+@pytest.mark.parametrize("rows,batch,kv_heads,unit", [
+    (8192, 1, 8, 1),   # train_mistral_1x8k, op_causal_64k's parity check
+    (1024, 8, 8, 1),   # train_mistral_8x1k: one tile a head
+    (8192, 1, 4, 4),   # train_sdar_bd_1x8k's `clean` and `below`
+])
+def test_the_compiled_forward_s_diagonal_sweep_against_dense_on_the_chip(
+        rows, batch, kv_heads, unit):
+    """The compiled forward at the cells' own geometries (32 query heads x
+    128, the tiles ops/tuning.py resolves, the generation's sub-square edge),
+    offset 0 and, in blocks, -1, against dense float32 softmax attention
+    under the rule's mask, one query head at a time: `o` and `lse`."""
+    heads, d = 32, 128
+    ks = jax.random.split(jax.random.PRNGKey(rows + unit), 3)
+    q = jax.random.normal(ks[0], (batch, heads, rows, d), jnp.bfloat16)
+    k, v = (jax.random.normal(k_, (batch, kv_heads, rows, d), jnp.bfloat16)
+            for k_ in ks[1:])
+    window = BlockUnits(unit) if unit != 1 else None
+    rb = pf.resolve_blocks(s_q=rows, s_kv=rows, window=window)
+    path = pf.fwd_diag_path(rows, rows, block_q=rb.block_q,
+                            block_kv=rb.block_kv, triangular=True,
+                            window=window)
+    nb = jnp.int32(rows // unit)
+    blk = np.arange(rows) // unit
+
+    @jax.jit
+    def head(q, k, v, mask):
+        s = jnp.einsum("bid,bjd->bij", q, k) * d ** -0.5
+        s = jnp.where(mask, s, -jnp.inf)
+        lse = jax.nn.logsumexp(s, -1)
+        return jnp.einsum("bij,bjd->bid", jnp.exp(s - lse[..., None]), v), lse
+
+    f32 = lambda x: x.astype(jnp.float32)
+    for offset in (0, -1) if unit != 1 else (0,):
+        spec = masks.MaskSpec(jnp.int32(0), nb, nb, jnp.int32(1),
+                              jnp.int32(offset))
+        m, lse, acc = jax.jit(lambda q, k, v: pf.flash_fwd(
+            q, k, v, None, None, None, d ** -0.5, spec, block_q=rb.block_q,
+            block_kv=rb.block_kv, triangular=True, window=window))(q, k, v)
+        o = finalize(m, lse, acc, jnp.float32)
+        mask = jnp.asarray(blk[None, :] <= blk[:, None] + offset)
+        errs, peak = np.zeros(2), np.zeros(2)
+        with jax.default_matmul_precision("highest"):
+            for h in range(heads):
+                g = h // (heads // kv_heads)
+                want = head(f32(q[:, h]), f32(k[:, g]), f32(v[:, g]), mask)
+                # offset -1: the first block's rows see nothing (lse -inf)
+                live = jnp.isfinite(want[1])
+                for i, (a, b) in enumerate(((o[:, h], want[0]),
+                                            (lse[:, h], want[1]))):
+                    w = live[..., None] if i == 0 else live
+                    a, b = jnp.where(w, a, 0.0), jnp.where(w, b, 0.0)
+                    errs[i] = max(errs[i], float(jnp.max(jnp.abs(a - b))))
+                    peak[i] = max(peak[i], float(jnp.max(jnp.abs(b))))
+                assert bool(jnp.all(jnp.isneginf(lse[:, h]) == ~live))
+        print(f"PARITY forward {batch} x {rows} rows 32 / {kv_heads} unit "
+              f"{unit} offset {offset} {path} vs dense f32, max abs err "
+              f"(max |ref|):", {n: (float(e), float(p)) for n, e, p in zip(
+                  ("o", "lse"), errs, peak)})
+        assert path.path == "sub"
+        for name, err, top in zip(("o", "lse"), errs, peak):
+            assert err < 4e-2 * max(1.0, top), (name, err)
+
+
 @pytest.mark.parametrize("length,block,bq,bkv", [(256, 4, 64, 64),
                                                  (256, 32, 64, 128),
                                                  (512, 4, 128, 64),
@@ -362,6 +428,9 @@ PARENT_JAXPRS = {
     "fwd_tri": "3ba8284980c741aa",
     "fwd_carry_range": "4cd52d0285b83415",
     "fwd_window": "880c777e2641587f",
+    # a segmented call that promises `triangular`: taken on the parent of
+    # PR 33 (9f85e4b), whose diagonal sweep must leave it the whole tile
+    "fwd_segments": "53615c08c18166d2",
     "bwd_split": "79e57846668dbb7d",
     "bwd_rect_carry": "1ad2e3725d9f4175",
     "bwd_rect_window": "cb17c00346ac39f2",
@@ -388,6 +457,10 @@ def _plain_calls(window=lambda w: w):
         "fwd_window": (lambda q, k, v: pf.flash_fwd(
             q, k, v, None, None, None, 0.2, spec(), triangular=True,
             window=window(48), **kw), (q, kv, kv)),
+        "fwd_segments": (lambda q, k, v, s: pf.flash_fwd(
+            q, k, v, None, None, None, 0.2, spec(), triangular=True,
+            segments=(s, s), window=window(None), **kw),
+            (q, kv, kv, jax.ShapeDtypeStruct((1, 256), jnp.int32))),
         "bwd_split": (lambda do, q, k, v, d, l: pf.flash_bwd(
             do, q, k, v, d, l, 0.2, spec(), window=window(None), **kw),
             (q, q, kv, kv, st, st)),
@@ -403,10 +476,22 @@ def _plain_calls(window=lambda w: w):
     }
 
 
+def _kernel_texts(fn, *args):
+    return [str(e.params["jaxpr"]) for e in iter_eqns(jax.make_jaxpr(fn)(*args))
+            if e.primitive.name == "pallas_call"]
+
+
 @pytest.mark.parametrize("name", sorted(PARENT_JAXPRS))
-def test_a_call_with_no_block_mask_traces_the_parent_s_jaxpr(name):
+def test_a_call_with_no_block_mask_traces_the_parent_s_jaxpr(name,
+                                                             monkeypatch):
+    """The forward's with flash_fwd's body traced in line; behind its one
+    jit (PR 33) the kernel is that one, text for text."""
     fn, args = _plain_calls()[name]
+    behind_the_jit = _kernel_texts(fn, *args)
+    monkeypatch.setattr(pf, "_fwd_launch_traced", pf._fwd_launch)
+    fn, args = _plain_calls()[name]  # make_jaxpr remembers a function
     assert _digest(fn, *args) == PARENT_JAXPRS[name]
+    assert behind_the_jit == _kernel_texts(fn, *args) != []
 
 
 @pytest.mark.parametrize("name,kernel", [

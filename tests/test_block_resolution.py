@@ -1,8 +1,11 @@
-"""Which blocks and which grid a tile call gets (ops/tuning.call_row through
-BurstConfig.resolved_blocks and burst._tile_fwd / _tile_bwd): the benchmark
-cells' calls under the v5e row, a caller's own blocks, and that the op cells'
-programs trace what they traced before the resolution saw a call's geometry."""
+"""Which blocks, which grid and which sweep of its diagonal tiles a tile call
+gets (ops/tuning.call_row through BurstConfig.resolved_blocks and
+burst._tile_fwd / _tile_bwd; pallas_flash.fwd_diag_path): the benchmark
+cells' calls under the v5e row, a caller's own blocks, that the op cells'
+programs trace what they traced before the resolution saw a call's geometry,
+and the forward's diagonal sweep against the oracle and the whole tile."""
 
+import functools
 import hashlib
 
 import jax
@@ -12,8 +15,10 @@ import pytest
 from jax.sharding import Mesh
 
 import burst_attn_tpu as bat
-from burst_attn_tpu.ops import pallas_flash as pf, tuning
-from burst_attn_tpu.ops.masks import BlockUnits
+from burst_attn_tpu import obs
+from burst_attn_tpu.analysis.jaxpr_tools import iter_eqns
+from burst_attn_tpu.ops import pallas_flash as pf, tile, tuning
+from burst_attn_tpu.ops.masks import BlockUnits, MaskSpec
 from burst_attn_tpu.parallel.burst import BurstConfig
 
 V5E = tuning.generation_row("v5e")
@@ -83,23 +88,17 @@ def test_blocks_the_caller_sets_win(v5e):
                                  table=V5E)[:2] == (2048, 2048)
 
 
+def _kernels(jaxpr):
+    """[(name, grid, kernel jaxpr's text)] of every pallas_call, in order,
+    through whatever call wraps it."""
+    return [(e.params["name"], tuple(e.params["grid_mapping"].grid),
+             str(e.params["jaxpr"])) for e in iter_eqns(jaxpr)
+            if e.primitive.name == "pallas_call"]
+
+
 def _pallas_calls(fn, *args):
     """[(kernel name, grid)] of every pallas_call in fn's jaxpr."""
-    found = []
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                found.append((eqn.params["name"],
-                              tuple(eqn.params["grid_mapping"].grid)))
-            for value in eqn.params.values():
-                for x in value if isinstance(value, (list, tuple)) else [value]:
-                    inner = getattr(x, "jaxpr", x)
-                    if hasattr(inner, "eqns"):
-                        walk(inner)
-
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return found
+    return [k[:2] for k in _kernels(jax.make_jaxpr(fn)(*args))]
 
 
 def test_the_block_diagonal_call_takes_the_band_grids(v5e, monkeypatch):
@@ -150,10 +149,10 @@ def test_flash_attention_resolves_from_its_window(v5e, monkeypatch):
                            ("burst_flash_bwd_rect", (1, 2, 4, 8 * 4))]
 
 
-def _op_cell_digest(world, seq):
-    """sha256[:16] of the jaxpr of an op cell's program (chipbench/runners/
-    op.py: forward + backward of burst_attn, causal, zigzag, 32 heads x 128,
-    bf16), with the Pallas tile named outright (on the CPU "auto" is jnp)."""
+def _op_cell_program(world, seq):
+    """The jaxpr of an op cell's program (chipbench/runners/op.py: forward +
+    backward of burst_attn, causal, zigzag, 32 heads x 128, bf16), with the
+    Pallas tile named outright (on the CPU "auto" is jnp)."""
     mesh = Mesh(np.array(jax.devices()[:world]), ("sp",))
     q = jax.ShapeDtypeStruct((1, 32, seq, 128), jnp.bfloat16)
 
@@ -167,11 +166,14 @@ def _op_cell_digest(world, seq):
             q, k, v)
         return (o, *grads)
 
-    return hashlib.sha256(
-        str(jax.make_jaxpr(run)(q, q, q, q)).encode()).hexdigest()[:16]
+    return jax.make_jaxpr(run)(q, q, q, q)
 
 
-# taken on the parent commit of PR 29 (759fd86) with this function, under
+def _digest(jaxpr):
+    return hashlib.sha256(str(jaxpr).encode()).hexdigest()[:16]
+
+
+# taken on the parent commit of PR 29 (759fd86) with these functions, under
 # conftest's CPU settings: the resolution now sees each call's rows, and at
 # the op cells' rows it must return what the parent's one row gave
 PARENT_OP_CELLS = {
@@ -180,7 +182,311 @@ PARENT_OP_CELLS = {
 }
 
 
+# the same programs' kernels (name, grid, kernel jaxpr of every pallas_call)
+# under the generation's diagonal edge, taken on PR 33's tree: the forward's
+# differ from the parent's, and a PR that changes a kernel pins them anew
+SWEPT_OP_CELLS = {
+    "op_causal_64k": "89f5bb09f0f7330f",
+    "ring4_causal_128k": "4513ed414bf3d8c9",
+}
+
+
 @pytest.mark.parametrize("cell", sorted(PARENT_OP_CELLS))
-def test_the_op_cells_trace_the_parent_s_jaxprs(cell):
+def test_the_op_cells_trace_the_parent_s_jaxprs(cell, monkeypatch):
+    """With the diagonal sweep off (an edge of 0 keeps the whole tile on the
+    masked path) and flash_fwd's body traced in line, not behind its jit
+    (PR 33): the program is the parent's, text for text.  Behind the jit
+    every kernel is still that program's; with the sweep on the forward's
+    differ, and no other."""
     world, seq, digest = PARENT_OP_CELLS[cell]
-    assert _op_cell_digest(world, seq) == digest
+    row = tuning.block_defaults()
+    swept = _kernels(_op_cell_program(world, seq))
+    assert _digest(swept) == SWEPT_OP_CELLS[cell]
+    monkeypatch.setattr(tuning, "block_defaults",
+                        lambda device=None: row._replace(diag_block=0))
+    whole = _kernels(_op_cell_program(world, seq))
+    monkeypatch.setattr(pf, "_fwd_launch_traced", pf._fwd_launch)
+    parent = _op_cell_program(world, seq)
+    assert _digest(parent) == digest
+    assert whole == _kernels(parent)
+    assert [k[:2] for k in swept] == [k[:2] for k in whole]
+    differ = {a[0] for a, b in zip(swept, whole) if a != b}
+    assert differ == {"burst_flash_fwd"}
+
+
+# ---------------------------------------------------------------------------
+# the diagonal sweep (flash_fwd's tile i == j in sub-squares, PR 32)
+
+DIAG_TILE = 64  # stands for the row's 2048; the edges for 1024 ... 128
+
+
+def _block_causal(s, unit, offset):
+    nb = jnp.int32(s // unit)
+    return MaskSpec(jnp.int32(0), nb, nb, jnp.int32(1), jnp.int32(offset))
+
+
+def _finite(x):
+    x = np.asarray(x, np.float32)
+    return np.where(np.isneginf(x), 0.0, x)
+
+
+# every grid (one tile on the rectangular grid; 2 and 4 q blocks on the
+# triangular one) with every edge under every mask (offset 0 / -1 in tokens
+# and in blocks of 4: train_sdar_bd_1x8k's `clean` and `below`), the cells'
+# two head groupings (4 and 8 query heads a kv head: 32 / 8 and 32 / 4) and
+# the three states (empty, carried, the fused finalize) dealt over them so
+# that each meets every grid, every edge and every mask
+DIAG_GRIDS = [(1, 32, 2), (1, 16, 1), (1, 8, 1), (1, 4, 2), (2, 32, 1),
+              (2, 16, 2), (2, 8, 2), (2, 4, 1), (4, 32, 1), (4, 16, 1),
+              (4, 8, 2), (4, 4, 2)]
+DIAG_MASKS = [(1, 0), (1, -1), (4, 0), (4, -1)]
+DIAG_CASES = [(*g, *m, ("empty", "carried", "emit_o")[(i + i // 4 + j) % 3])
+              for i, g in enumerate(DIAG_GRIDS)
+              for j, m in enumerate(DIAG_MASKS)]
+
+
+@pytest.mark.parametrize("nqb,edge,kv_heads,unit,offset,state", DIAG_CASES)
+def test_diag_sweep_against_the_oracle_and_the_whole_tile(
+        nqb, edge, kv_heads, unit, offset, state):
+    """flash_fwd's diagonal tiles in sub-squares of `edge` against ops/tile.py
+    and against the same call with the whole tile on the masked path (forced
+    by `triangular=False`, or by a single segment), over offset 0 / -1 in
+    tokens and in blocks of 4 (train_sdar_bd_1x8k's `clean` and `below`)."""
+    n, d, s = 8, 8, nqb * DIAG_TILE
+    ks = jax.random.split(jax.random.PRNGKey(nqb * 100 + edge + offset), 6)
+    q = jax.random.normal(ks[0], (1, n, s, d), jnp.float32)
+    k, v = (jax.random.normal(k_, (1, kv_heads, s, d), jnp.float32)
+            for k_ in ks[1:3])
+    spec = _block_causal(s, unit, offset)
+    window = BlockUnits(unit) if unit != 1 else None
+    st = (None,) * 3
+    if state == "carried":
+        m0 = jax.random.normal(ks[3], (1, n, s), jnp.float32)
+        st = (m0, m0 + jnp.abs(jax.random.normal(ks[4], (1, n, s))),
+              jax.random.normal(ks[5], (1, n, s, d), jnp.float32))
+    run = functools.partial(
+        pf.flash_fwd, q, k, v, *st, d**-0.5, spec, block_q=DIAG_TILE,
+        block_kv=DIAG_TILE, window=window, emit_o=state == "emit_o",
+        interpret=True, cast_p=False, diag_block=edge)
+    assert pf.fwd_diag_path(
+        s, s, block_q=DIAG_TILE, block_kv=DIAG_TILE, triangular=True,
+        window=window, diag_block=edge) == ("sub", nqb, edge)
+    got = run(triangular=True)
+    seg = jnp.zeros((1, s), jnp.int32)
+    whole = (run(triangular=True, segments=(seg, seg)) if offset else
+             run(triangular=False))
+    for name, a, b in zip(("m", "lse", "acc"), got, whole):
+        np.testing.assert_allclose(_finite(a), _finite(b), rtol=2e-5,
+                                   atol=2e-5, err_msg=name)
+    ref = tile.tile_fwd(q, k, v, *(st if state == "carried" else
+                                   tile.init_state(1, n, s, d)),
+                        d**-0.5, spec, window=window)
+    # m depends on the fold order where a row's carry exceeds its scores
+    o = got[2] if state == "emit_o" else tile.finalize(*got, jnp.float32)
+    for name, a, b in (("lse", got[1], ref[1]),
+                       ("o", o, tile.finalize(*ref, jnp.float32))):
+        np.testing.assert_allclose(_finite(a), _finite(b), rtol=1e-4,
+                                   atol=1e-4, err_msg=name)
+
+
+def test_the_diagonal_sweep_runs_under_the_forward_s_name():
+    """The benchmark's readers join on `burst_flash_fwd`."""
+    q = jax.ShapeDtypeStruct((1, 2, 256, 16), jnp.float32)
+    assert _pallas_calls(lambda q, k, v: pf.flash_fwd(
+        q, k, v, None, None, None, 0.25, _block_causal(256, 1, 0),
+        block_q=64, block_kv=64, triangular=True, diag_block=16,
+        interpret=True), q, q, q) == [("burst_flash_fwd", (1, 2, 2, 5))]
+
+
+def test_diag_path_is_static_and_keeps_the_whole_tile_elsewhere(monkeypatch):
+    path = functools.partial(pf.fwd_diag_path, 256, 256, block_q=64,
+                             block_kv=64, diag_block=16)
+    assert path(triangular=False) is None
+    assert path(triangular=True) == ("sub", 4, 16)
+    assert path(triangular=True, q_range=(128, 256), kv_range=(0, 128)) == (
+        "sub", 2, 16)  # the sliced form of a promised sub-range
+    assert path(triangular=True, kv_range=(0, 128)) is None  # s_q != s_kv
+    assert path(triangular=True, window=BlockUnits(4)).path == "sub"
+    for kw in (dict(window=48), dict(window=BlockUnits(4, 1)),
+               dict(segments=True), dict(loop_sweep=True),
+               dict(window=BlockUnits(32)),  # the edge is half a mask unit
+               dict(diag_block=0), dict(diag_block=64), dict(diag_block=24)):
+        assert path(**{"triangular": True, **kw}) == ("whole", 4, None), kw
+    assert pf.fwd_diag_path(256, 256, block_q=128, block_kv=64,
+                            triangular=True, diag_block=16) == (
+        "whole", 2, None)
+    # a ragged length is padded and runs on the rectangular masked path
+    assert pf.fwd_diag_path(200, 200, block_q=64, block_kv=64,
+                            triangular=True, diag_block=16) == (
+        "whole", 4, None)
+    monkeypatch.setenv("BURST_FWD_LOOP", "1")
+    assert path(triangular=True) == ("whole", 4, None)
+    monkeypatch.delenv("BURST_FWD_LOOP")
+    monkeypatch.setattr(tuning, "block_defaults", lambda device=None: V5E)
+    assert pf.fwd_diag_path(8192, 8192, block_q=2048, block_kv=2048,
+                            triangular=True) == ("sub", 4, V5E.diag_block)
+
+
+def _diag_counted(fn, *args):
+    """(sub, whole) that flash.diag_tiles advances by when `fn` is traced."""
+    c = obs.counter("flash.diag_tiles")
+    read = lambda: [c.get(**{"pass": "fwd", "path": p})
+                    for p in ("sub", "whole")]
+    before = read()
+    jax.make_jaxpr(fn)(*args)
+    return tuple(a - b for a, b in zip(read(), before))
+
+
+# the five cells' causal forward calls at a sixteenth of their rows, the row
+# cut by as much (tiles of 128, the edge of 128 is 8): (batch, heads, kv
+# heads, rows a shard, ring, block diffusion)
+CELL_DISPATCHES = {
+    "op_causal_64k": (1, 32, 32, 4096, 1, None),
+    "ring4_causal_128k": (1, 32, 32, 2048, 4, None),
+    "train_mistral_1x8k": (1, 32, 8, 512, 1, None),
+    "train_mistral_8x1k": (8, 32, 8, 64, 1, None),
+    "train_sdar_bd_1x8k": (1, 32, 4, 512, 1, 4),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_DISPATCHES))
+def test_every_diagonal_tile_of_the_cells_is_swept_in_sub_squares(
+        cell, monkeypatch):
+    b, n, n_kv, rows, world, bd = CELL_DISPATCHES[cell]
+    row = V5E._replace(fwd_block_q=128, fwd_block_kv=128, bwd_block_q=64,
+                       bwd_block_kv=128, band_block=32, diag_block=8)
+    monkeypatch.setattr(tuning, "block_defaults", lambda device=None: row)
+    mesh = Mesh(np.array(jax.devices()[:world]), ("sp",))
+    stream = rows * world * (2 if bd else 1)
+    q = jax.ShapeDtypeStruct((b, n, stream, 16), jnp.float32)
+    kv = jax.ShapeDtypeStruct((b, n_kv, stream, 16), jnp.float32)
+    sub, whole = _diag_counted(lambda q, k, v: bat.burst_attn(
+        q, k, v, mesh=mesh, causal=bd is None, backend="pallas",
+        block_diffusion=bd), q, kv, kv)
+    tiles = b * n * max(1, rows // 128)
+    if bd:
+        # `clean` and `below`; the block-diagonal call has a window (a band
+        # grid in tiles of 32), so its diagonal tiles stay whole
+        assert (sub, whole) == (2 * tiles, b * n * rows // 32)
+    else:
+        # the self round; a zigzag ring's later rounds promise nothing
+        assert (sub, whole) == (tiles, 0)
+
+
+@pytest.mark.parametrize("case", ["segments", "token_window", "bq_ne_bkv",
+                                  "q_range_round", "striped_round",
+                                  "jnp_tile"])
+def test_diag_counter_reads_whole_where_a_condition_fails(case, monkeypatch):
+    n, s, d = 4, 256, 16
+    q = jax.ShapeDtypeStruct((1, n, s, d), jnp.float32)
+    kw = dict(causal=True, backend="pallas", block_q=64, block_kv=64)
+    world = 1
+    if case == "segments":
+        kw["segment_ids"] = jnp.zeros((1, s), jnp.int32)
+    elif case == "token_window":
+        kw.update(layout="contig", window=300)
+    elif case == "bq_ne_bkv":
+        kw.update(block_q=128)
+    elif case in ("q_range_round", "striped_round"):
+        world = 2
+        kw["layout"] = "zigzag" if case == "q_range_round" else "striped"
+    elif case == "jnp_tile":
+        kw["backend"] = "jnp"
+    mesh = Mesh(np.array(jax.devices()[:world]), ("sp",))
+    blocks = n * (s // world) // kw["block_q"]
+    # an edge that divides the tiles: the conditions decide.  A zigzag ring's
+    # later rounds (q_range / kv_range rounds) promise nothing and count
+    # under neither path; a striped ring's are full-window causal too
+    monkeypatch.setattr(tuning, "block_defaults",
+                        lambda device=None: V5E._replace(diag_block=16))
+    assert _diag_counted(
+        lambda q, k, v: bat.burst_attn(q, k, v, mesh=mesh, **kw),
+        q, q, q) == {
+        "segments": (0, blocks), "token_window": (0, blocks),
+        "bq_ne_bkv": (0, blocks), "q_range_round": (blocks, 0),
+        "striped_round": (2 * blocks, 0), "jnp_tile": (0, 0)}[case]
+
+
+# ---------------------------------------------------------------------------
+# one trace of the forward's body a distinct call (flash_fwd's jit, PR 33)
+
+
+def _fwd_bodies_traced(monkeypatch, world, blocks):
+    """How often Pallas traces _fwd_kernel for jax.grad over `blocks`
+    chained jax.checkpoint(burst_attn) blocks on a ring of `world`."""
+    traced = []
+    kernel = pf._fwd_kernel
+
+    def counted(*args, **kw):
+        traced.append(1)
+        return kernel(*args, **kw)
+
+    monkeypatch.setattr(pf, "_fwd_kernel", counted)
+    mesh = Mesh(np.array(jax.devices()[:world]), ("sp",))
+    q = jax.ShapeDtypeStruct((1, 8, 256 * world, 16), jnp.float32)
+    kv = jax.ShapeDtypeStruct((1, 2, 256 * world, 16), jnp.float32)
+
+    def model(q, k, v):
+        block = jax.checkpoint(lambda x: bat.burst_attn(
+            x, k, v, mesh=mesh, causal=True, layout="zigzag",
+            backend="pallas", block_q=64, block_kv=64))
+        for _ in range(blocks):
+            q = block(q)
+        return jnp.sum(q)
+
+    jax.clear_caches()
+    jaxpr = jax.make_jaxpr(jax.grad(model))(q, kv, kv)
+    sites = sum(e.primitive.name == "pallas_call"
+                and e.params["name"].startswith("burst_flash_fwd")
+                for e in iter_eqns(jaxpr))
+    return len(traced), sites
+
+
+@pytest.mark.parametrize("world", [1, 4])
+def test_the_forward_s_body_is_traced_once_a_distinct_call_not_once_a_layer(
+        world, monkeypatch):
+    """PR 32's sub-square sweep cost half a second of trace a call site and
+    a model has one a layer (and jax.checkpoint's forward again): behind
+    flash_fwd's jit every layer's call hits the trace of the first.  On a
+    ring the distinct calls are the self round and the two half-shard
+    rounds of the zigzag split."""
+    one, sites_one = _fwd_bodies_traced(monkeypatch, world, 1)
+    four, sites_four = _fwd_bodies_traced(monkeypatch, world, 4)
+    assert one == four == {1: 1, 4: 3}[world]
+    # the call sites are all still there: the primal and the recomputed
+    # forward of every block (the last block's primal feeds nothing)
+    assert sites_four > 4 * one and sites_four > sites_one
+
+
+def test_what_the_forward_s_trace_answers_for_is_in_its_static_keywords(
+        monkeypatch):
+    """The switches and the table flash_fwd reads are resolved before its
+    jit: a second call of the same shapes under another switch is another
+    trace, with no cache cleared between."""
+    q = jax.ShapeDtypeStruct((1, 2, 256, 16), jnp.float32)
+
+    def grid():
+        return _pallas_calls(lambda q, k, v: pf.flash_fwd(
+            q, k, v, None, None, None, 0.25, _block_causal(256, 1, 0),
+            block_q=64, block_kv=64, triangular=True, interpret=True),
+            q, q, q)
+
+    def kernel():
+        return _kernels(jax.make_jaxpr(lambda q, k, v: pf.flash_fwd(
+            q, k, v, None, None, None, 0.25, _block_causal(256, 1, 0),
+            block_q=64, block_kv=64, triangular=True, interpret=True))(
+                q, q, q))
+
+    monkeypatch.setattr(tuning, "block_defaults",
+                        lambda device=None: V5E._replace(diag_block=16))
+    assert grid() == [("burst_flash_fwd", (1, 2, 2, 5))]
+    swept = kernel()
+    monkeypatch.setenv("BURST_NO_TRI", "1")
+    assert grid() == [("burst_flash_fwd", (1, 2, 4, 4))]
+    monkeypatch.delenv("BURST_NO_TRI")
+    monkeypatch.setattr(tuning, "block_defaults",
+                        lambda device=None: V5E._replace(diag_block=0))
+    whole = kernel()
+    assert whole != swept
+    monkeypatch.setenv("BURST_FWD_LOOP", "1")
+    assert kernel() not in (whole, swept)
