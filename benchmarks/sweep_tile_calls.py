@@ -15,7 +15,12 @@ results/sweep_tile_calls.jsonl is PR 29's sweep on one v5e, then PR 33's of
 the forward's diagonal sub-square edge (`--edges`: the rows with an `edge`,
 and beside each the host seconds its call site took to trace and to lower,
 `trace_s` / `lower_s`: a Python-unrolled body's other cost, which lands in a
-cell's `setup_s`).
+cell's `setup_s`), then PR 34's of the 16,384-row call at 192 / 128 (`--only
+mla16k,causal16k`: the rows with `d_qk`).  Its four rows with `qk_feed` were
+taken with a trace-time hook that did not stay, so this script cannot take
+them again (each row says so: `reproducible` false): the 192-deep score
+product as it is (`whole`), or as `dot(q[:, :128], k[:, :128]) + dot(q[:,
+128:], k[:, 128:])` (`128+64`: 6.6 % slower forward, the same backward).
 """
 
 import argparse
@@ -37,6 +42,10 @@ GEOMETRIES = {
     "causal1k": dict(s=1024, b=8, n=32, n_kv=8, unit=1),
     "win256_8k": dict(s=8192, b=1, n=32, n_kv=8, unit=1, win=256),
     "win1024_8k": dict(s=8192, b=1, n=32, n_kv=8, unit=1, win=1024),
+    # PR 34: train_kanana2_mla_1x16k's call (latent attention: q, k 192 wide,
+    # v, o 128), and the same rows at 128 / 128 beside it
+    "mla16k": dict(s=16384, b=1, n=32, n_kv=32, unit=1, d_qk=192, d_v=128),
+    "causal16k": dict(s=16384, b=1, n=32, n_kv=32, unit=1),
 }
 
 SQUARES = [(128, 128), (256, 256), (512, 512), (1024, 1024)]
@@ -86,6 +95,12 @@ SWEEPS = {
     ("win1024_8k", "banded", "fwd"): [ROW_FWD, (512, 512, True),
                                       (1024, 1024, True)],
     ("win1024_8k", "banded", "bwd"): [ROW_BWD, (512, 512), (1024, 1024)],
+    ("mla16k", "clean", "fwd"): [ROW_FWD, (1024, 1024, True),
+                                 (2048, 1024, True), (1024, 2048, True)],
+    ("mla16k", "clean", "bwd"): [ROW_BWD, (1024, 1024), (512, 2048),
+                                 (2048, 1024)],
+    ("causal16k", "clean", "fwd"): [ROW_FWD],
+    ("causal16k", "clean", "bwd"): [ROW_BWD, (1024, 1024)],
     ("bd8k", "clean", "edge"): EDGES,
     ("bd8k", "below", "edge"): EDGES,
     ("bd8k", "below_carried", "edge"): EDGES,
@@ -169,12 +184,13 @@ def main():
             pass_ = "fwd"
         g = GEOMETRIES[gname]
         s, b, n, n_kv, unit = g["s"], g["b"], g["n"], g["n_kv"], g["unit"]
-        d, scale = 128, 128 ** -0.5
+        d, d_v = g.get("d_qk", 128), g.get("d_v", 128)
+        scale = d ** -0.5
         ks = jax.random.split(jax.random.PRNGKey(0), 4)
-        q, do = (jax.random.normal(k_, (b, n, s, d), jnp.bfloat16)
-                 for k_ in ks[:2])
-        k, v = (jax.random.normal(k_, (b, n_kv, s, d), jnp.bfloat16)
-                for k_ in ks[2:])
+        q = jax.random.normal(ks[0], (b, n, s, d), jnp.bfloat16)
+        do = jax.random.normal(ks[1], (b, n, s, d_v), jnp.bfloat16)
+        k = jax.random.normal(ks[2], (b, n_kv, s, d), jnp.bfloat16)
+        v = jax.random.normal(ks[3], (b, n_kv, s, d_v), jnp.bfloat16)
         nb = s // unit
         i32 = lambda x: jnp.asarray(x, jnp.int32)
         kind = call.split("_")[0]
@@ -217,7 +233,8 @@ def main():
         for cfg in configs[:args.first or None]:
             bq, bkv = cfg[0], cfg[1]
             row = dict(geometry=gname, call=call, **{"pass": pass_}, bq=bq,
-                       bkv=bkv, rows=s, batch=b, heads=n, kv_heads=n_kv)
+                       bkv=bkv, rows=s, batch=b, heads=n, kv_heads=n_kv,
+                       d_qk=d, d_v=d_v)
             try:
                 if pass_ == "fwd":
                     row["band_or_tri"] = cfg[2]

@@ -72,7 +72,9 @@ try:
 except ImportError:  # no such module off POSIX: the attr is then absent
     resource = None
 
-from .transformer import ModelConfig, forward, forward_with_aux, init_params, param_specs
+from .transformer import (STATE_LEAVES, ModelConfig, forward,
+                          forward_with_aux, has_experts, init_params,
+                          param_specs)
 from ..parallel import layouts
 
 
@@ -185,16 +187,23 @@ def _loss_parts(params, tokens, positions, labels, cfg: ModelConfig, mesh,
         logits, aux, stats = out
     else:
         logits, aux = out
+    nll_sum = masked_nll_sum(logits, labels)
+    if collect_stats:
+        return nll_sum, aux, stats
+    return nll_sum, aux
+
+
+def masked_nll_sum(logits, labels):
+    """Sum of the next-token cross entropy of fp32 `logits` [B, S, V] at the
+    positions whose `labels` [B, S] are not negative (the objective's
+    numerator: _loss_parts', and a caller's that holds its own logits)."""
     with jax.named_scope("obs.train.loss"):
         valid = labels >= 0
         labels_safe = jnp.where(valid, labels, 0)
         logp = jax.nn.log_softmax(logits, axis=-1)
         nll = -jnp.take_along_axis(logp, labels_safe[..., None],
                                    axis=-1)[..., 0]
-        nll_sum = jnp.sum(jnp.where(valid, nll, 0.0))
-    if collect_stats:
-        return nll_sum, aux, stats
-    return nll_sum, aux
+        return jnp.sum(jnp.where(valid, nll, 0.0))
 
 
 def loss_fn(params, tokens, positions, labels, cfg: ModelConfig, mesh,
@@ -320,6 +329,19 @@ def packed_fields_np(tokens, eos_id: int):
     return seg, positions, labels
 
 
+def _hold_state_leaves(new, old):
+    """`new` with every transformer.STATE_LEAVES leaf taken from `old`: model
+    state rides the parameter tree (placement, checkpoints) and is no
+    trained leaf.  No gradient reaches one (the forward reads it into a
+    choice), and this keeps the weight decay off it too.  A tree without
+    such leaves comes back as it is."""
+    def hold(path, n, o):
+        name = getattr(path[-1], "key", None)
+        return o if name in STATE_LEAVES else n
+
+    return jax.tree_util.tree_map_with_path(hold, new, old)
+
+
 def jit_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh):
     """The jitted step((params, opt_state), batch) -> (state, metrics)
     itself (state donated), for callers that lower or compile it.
@@ -336,7 +358,7 @@ def jit_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh):
     (bd_loss_parts).
     """
     opt = _optimizer(tcfg)
-    aux_w = tcfg.moe_aux_weight if cfg.n_experts else 0.0
+    aux_w = tcfg.moe_aux_weight if has_experts(cfg) else 0.0
     accum = tcfg.grad_accum
     collect = tcfg.collect_devstats
     if collect and accum != 1:
@@ -374,7 +396,7 @@ def jit_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh):
     # The drop-free expert layer's stats ride out of the plain step only:
     # under grad_accum > 1 or collect_devstats the step trains as before
     # and its metrics have no moe_* entries.
-    held_moe = bool(cfg.n_experts) and cfg.expert_axis is None \
+    held_moe = has_experts(cfg) and cfg.expert_axis is None \
         and cfg.pp_axis is None
     with_extras = bd is not None or (held_moe and accum == 1 and not collect)
 
@@ -460,7 +482,8 @@ def jit_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh):
             grads = jax.tree.map(lambda g: g / v_total, grads)
         with jax.named_scope("obs.train.optimizer"):
             updates, opt_state = opt.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            params = _hold_state_leaves(
+                optax.apply_updates(params, updates), params)
             gnorm = optax.global_norm(grads)
         metrics = {"loss": loss, "grad_norm": gnorm, **extras}
         if collect:

@@ -24,7 +24,7 @@ Design choices (TPU-first):
 
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +32,71 @@ from jax.sharding import PartitionSpec as P
 from jax import shard_map
 
 from ..parallel.burst import burst_attn
+
+
+# ---------------------------------------------------------------------------
+# the layer pattern (ROADMAP D11, its first step): what ONE layer is, for the
+# kinds the scalar knobs below cannot say.  Readers: init_params, param_specs,
+# forward_with_aux (and a reference of its own, outside this package).  The
+# pipeline path, decode and serving read the scalar knobs and refuse a pattern.
+
+
+@dataclass(frozen=True)
+class LatentAttn:
+    """Latent attention's head geometry (the deepseek_v3 block; q at full
+    rank).  Per head, q and k are `qk_nope + qk_rope` wide and v, o `v_head`:
+    k_nope and v come up from one `kv_latent`-wide RMS-normed latent a token,
+    and the `qk_rope` rotary channels of k are ONE key a token, shared by all
+    heads.  The rotary pairing is the interleaved one: channels (2i, 2i+1)
+    rotate together (not the half-split pairing of the GQA block).  Heads,
+    theta and dtype are the model's (ModelConfig.n_heads, rope_theta)."""
+
+    kv_latent: int
+    qk_nope: int
+    qk_rope: int
+    v_head: int
+
+
+@dataclass(frozen=True)
+class DenseMLP:
+    """One SwiGLU of width `d_ff` every token takes."""
+
+    d_ff: int
+
+
+@dataclass(frozen=True)
+class ExpertMLP:
+    """Routed experts of width `d_ff` (parallel/moe.py): `n_experts` router
+    outputs, `top_k` choices a token, weights for the experts `held` = (lo,
+    hi) here (None: all).  `score`, `choice_bias` (a float32 [n_experts]
+    leaf `router_bias` that enters the choice and not the gate: model STATE,
+    no gradient, no weight decay, see STATE_LEAVES) and `gate_scale` are
+    moe.route's; `shared_ff` > 0 adds the shared experts, one SwiGLU of that
+    width every token takes (moe.moe_held's `shared`)."""
+
+    d_ff: int
+    n_experts: int
+    top_k: int
+    held: Optional[Tuple[int, int]] = None
+    score: str = "softmax"
+    choice_bias: bool = False
+    gate_scale: float = 1.0
+    shared_ff: int = 0
+
+
+@dataclass(frozen=True)
+class LayerSpec:
+    """One layer: its MLP kind, and its attention kind where that is not the
+    GQA block of ModelConfig's scalar knobs (None)."""
+
+    mlp: Union[DenseMLP, ExpertMLP]
+    attn: Optional[LatentAttn] = None
+
+
+# Parameter leaves that are model state, not trained: the forward reads them,
+# no gradient reaches them, and the train step hands them on as they came
+# (models/train.py: no update, no weight decay).
+STATE_LEAVES = ("router_bias",)
 
 
 @dataclass(frozen=True)
@@ -91,9 +156,50 @@ class ModelConfig:
     # pp_microbatches must divide the per-dp-shard batch.
     pp_axis: Optional[str] = None
     pp_microbatches: int = 1
+    # One LayerSpec a layer (len == n_layers), for stacks whose layers differ
+    # or whose kinds the knobs above cannot say (latent attention, a leading
+    # dense layer before sparse ones, a sigmoid router, shared experts).
+    # None: every layer is the block the scalar knobs describe (layer_specs).
+    # With a pattern, d_head / n_kv_heads / d_ff / n_experts / moe_top_k /
+    # experts_held are not read for the kinds it names.  The trainer's
+    # forward only: pp_axis, decode and serving refuse it.
+    pattern: Optional[Tuple[LayerSpec, ...]] = None
 
 
 Params = Dict[str, Any]
+
+
+def _knob_mlp(cfg: ModelConfig):
+    """The MLP kind the scalar knobs describe."""
+    if cfg.n_experts:
+        return ExpertMLP(cfg.d_ff, cfg.n_experts, cfg.moe_top_k,
+                         cfg.experts_held)
+    return DenseMLP(cfg.d_ff)
+
+
+def layer_specs(cfg: ModelConfig) -> Tuple[LayerSpec, ...]:
+    """One LayerSpec a layer: the pattern, or the scalar knobs' block
+    n_layers times."""
+    if cfg.pattern is None:
+        return (LayerSpec(_knob_mlp(cfg)),) * cfg.n_layers
+    if len(cfg.pattern) != cfg.n_layers:
+        raise ValueError(f"pattern describes {len(cfg.pattern)} layers, "
+                         f"n_layers is {cfg.n_layers}")
+    return tuple(cfg.pattern)
+
+
+def has_experts(cfg: ModelConfig) -> bool:
+    """Whether any layer routes over experts."""
+    return any(isinstance(sp.mlp, ExpertMLP) for sp in layer_specs(cfg))
+
+
+def _refuse_pattern(cfg, who):
+    if cfg is not None and cfg.pattern is not None:
+        raise ValueError(
+            f"{who} reads ModelConfig's scalar knobs (one block for the "
+            "whole stack); a layer pattern (ModelConfig.pattern: latent "
+            "attention, per-layer MLP kinds) runs through the trainer's "
+            "forward_with_aux only")
 
 
 def _split(key, n):
@@ -102,7 +208,7 @@ def _split(key, n):
 
 def init_params(key, cfg: ModelConfig) -> Params:
     """Initialize the parameter pytree (all leaves cfg.dtype except norms)."""
-    d, nh, nkv, hd, f = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head, cfg.d_ff
+    d, nh, nkv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     init = jax.nn.initializers.normal(stddev=0.02)
 
     def dense(k, shape):
@@ -110,37 +216,62 @@ def init_params(key, cfg: ModelConfig) -> Params:
 
     keys = _split(key, cfg.n_layers + 2)
     layers = []
-    for lk in keys[: cfg.n_layers]:
+    for lk, spec in zip(keys[: cfg.n_layers], layer_specs(cfg)):
         ks = _split(lk, 6)
-        layer = {
-            "attn_norm": jnp.ones((d,), jnp.float32),
-            "wq": dense(ks[0], (d, nh, hd)),
-            "wk": dense(ks[1], (d, nkv, hd)),
-            "wv": dense(ks[2], (d, nkv, hd)),
-            "wo": dense(ks[3], (nh, hd, d)),
-            "mlp_norm": jnp.ones((d,), jnp.float32),
-        }
-        if cfg.qk_norm:
-            layer.update(q_norm=jnp.ones((hd,), jnp.float32),
-                         k_norm=jnp.ones((hd,), jnp.float32))
-        if cfg.n_experts:
+        layer = {"attn_norm": jnp.ones((d,), jnp.float32),
+                 "mlp_norm": jnp.ones((d,), jnp.float32)}
+        if spec.attn is None:
+            layer.update(
+                wq=dense(ks[0], (d, nh, hd)),
+                wk=dense(ks[1], (d, nkv, hd)),
+                wv=dense(ks[2], (d, nkv, hd)),
+                wo=dense(ks[3], (nh, hd, d)),
+            )
+            if cfg.qk_norm:
+                layer.update(q_norm=jnp.ones((hd,), jnp.float32),
+                             k_norm=jnp.ones((hd,), jnp.float32))
+        else:
+            a = spec.attn
+            layer.update(
+                wq=dense(ks[0], (d, nh, a.qk_nope + a.qk_rope)),
+                wkv_a=dense(ks[1], (d, a.kv_latent + a.qk_rope)),
+                kv_norm=jnp.ones((a.kv_latent,), jnp.float32),
+                wkv_b=dense(ks[2], (a.kv_latent, nh, a.qk_nope + a.v_head)),
+                wo=dense(ks[3], (nh, a.v_head, d)),
+            )
+        mlp = spec.mlp
+        if isinstance(mlp, ExpertMLP):
             from ..parallel.moe import init_moe_params
 
-            held = cfg.experts_held
+            held = mlp.held
             layer.update(
                 **init_moe_params(
-                    ks[4], d, f, cfg.n_experts, dtype=cfg.dtype,
+                    ks[4], d, mlp.d_ff, mlp.n_experts, dtype=cfg.dtype,
                     n_held=None if held is None else held[1] - held[0],
                 )._asdict()
             )
+            kb, *kshared = _split(ks[5], 4)
+            if mlp.choice_bias:
+                # drawn, not zero: a zero bias would let "bias in the gate"
+                # pass for "bias in the choice" (no load-driven update here:
+                # the leaf is state, STATE_LEAVES)
+                layer["router_bias"] = 0.1 * jax.random.normal(
+                    kb, (mlp.n_experts,), jnp.float32)
+            if mlp.shared_ff:
+                layer.update(
+                    shared_gate=dense(kshared[0], (d, mlp.shared_ff)),
+                    shared_up=dense(kshared[1], (d, mlp.shared_ff)),
+                    shared_down=dense(kshared[2], (mlp.shared_ff, d)),
+                )
         else:
             layer.update(
-                w_gate=dense(ks[4], (d, f)),
-                w_up=dense(ks[5], (d, f)),
-                w_down=dense(_split(ks[5], 2)[1], (f, d)),
+                w_gate=dense(ks[4], (d, mlp.d_ff)),
+                w_up=dense(ks[5], (d, mlp.d_ff)),
+                w_down=dense(_split(ks[5], 2)[1], (mlp.d_ff, d)),
             )
         layers.append(layer)
     if cfg.pp_axis is not None:
+        _refuse_pattern(cfg, "the pipeline-parallel stack")
         from .pipeline_lm import stack_layers
 
         layers = stack_layers(layers)
@@ -160,35 +291,49 @@ def param_specs(cfg: ModelConfig) -> Params:
     embeddings/lm_head shard the vocab dim.  Norm scales are replicated.
     """
     tp = cfg.head_axis
-    layer = {
-        "attn_norm": P(None),
-        "wq": P(None, tp, None),
-        "wk": P(None, tp, None),
-        "wv": P(None, tp, None),
-        "wo": P(tp, None, None),
-        "mlp_norm": P(None),
-    }
-    if cfg.qk_norm:
-        layer.update(q_norm=P(None), k_norm=P(None))
-    if cfg.n_experts:
-        # experts shard over expert_axis ONLY (the _mlp shard_map slices the
-        # same way); sharding their ffn dim over tp as well would need a
-        # row-parallel psum inside the expert MLP — replication across tp is
-        # the simpler trade at these expert sizes
-        ep = cfg.expert_axis
-        layer.update(
-            router=P(None, None),
-            w_gate=P(ep, None, None),
-            w_up=P(ep, None, None),
-            w_down=P(ep, None, None),
-        )
-    else:
-        layer.update(
-            w_gate=P(None, tp),
-            w_up=P(None, tp),
-            w_down=P(tp, None),
-        )
+
+    def layer_of(spec: LayerSpec):
+        layer = {"attn_norm": P(None), "mlp_norm": P(None),
+                 "wq": P(None, tp, None), "wo": P(tp, None, None)}
+        if spec.attn is None:
+            layer.update(wk=P(None, tp, None), wv=P(None, tp, None))
+            if cfg.qk_norm:
+                layer.update(q_norm=P(None), k_norm=P(None))
+        else:
+            # the down-projection and its norm serve every head: replicated
+            layer.update(wkv_a=P(None, None), kv_norm=P(None),
+                         wkv_b=P(None, tp, None))
+        if isinstance(spec.mlp, ExpertMLP):
+            # experts shard over expert_axis ONLY (the _mlp shard_map slices
+            # the same way); sharding their ffn dim over tp as well would
+            # need a row-parallel psum inside the expert MLP — replication
+            # across tp is the simpler trade at these expert sizes
+            ep = cfg.expert_axis
+            layer.update(
+                router=P(None, None),
+                w_gate=P(ep, None, None),
+                w_up=P(ep, None, None),
+                w_down=P(ep, None, None),
+            )
+            if spec.mlp.choice_bias:
+                layer["router_bias"] = P(None)
+            if spec.mlp.shared_ff:
+                # inside the expert layer's shard_map, which has no tp psum
+                layer.update(shared_gate=P(None, None),
+                             shared_up=P(None, None),
+                             shared_down=P(None, None))
+        else:
+            layer.update(
+                w_gate=P(None, tp),
+                w_up=P(None, tp),
+                w_down=P(tp, None),
+            )
+        return layer
+
+    specs = layer_specs(cfg)
+    layer = layer_of(specs[0])
     if cfg.pp_axis is not None:
+        _refuse_pattern(cfg, "the pipeline-parallel stack")
         # stacked layout: leading stage/layer dim sharded over pp, with the
         # per-leaf tp axes PRESERVED in the trailing dims — pipeline_lm
         # passes these specs as shard_map in_specs, and its hand-written
@@ -203,7 +348,8 @@ def param_specs(cfg: ModelConfig) -> Params:
         }
     return {
         "embed": P(tp, None),
-        "layers": [layer] * cfg.n_layers,
+        "layers": ([layer] * cfg.n_layers if cfg.pattern is None
+                   else [layer_of(spec) for spec in specs]),
         "final_norm": P(None),
         "lm_head": P(tp, None),
     }
@@ -226,6 +372,51 @@ def _rope(x, positions, theta):
     return out.astype(x.dtype)
 
 
+def _rope_interleaved(x, positions, theta):
+    """Rotary embedding with the INTERLEAVED pairing: channels (2i, 2i+1) of
+    x [B, N, S, H] are one complex number, rotated by positions * theta^(-2i/H)
+    (positions [B, S]); the pairs stay where they are."""
+    h = x.shape[-1]
+    freqs = 1.0 / (theta ** (jnp.arange(0, h, 2, dtype=jnp.float32) / h))
+    angles = positions[:, None, :, None].astype(jnp.float32) * freqs  # [B,1,S,H/2]
+    sin, cos = jnp.sin(angles), jnp.cos(angles)
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], h // 2, 2)
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _latent_qkv(p, x, positions, cfg: ModelConfig, a: LatentAttn):
+    """Latent attention's q, k [B, N, S, qk_nope + qk_rope] and v [B, N, S,
+    v_head] (LatentAttn).  k is materialised at full width a head, as the
+    published code does.  Scopes obs.model.mla.q / .kv_down / .kv_up
+    (docs/observability.md)."""
+    h = _rms_norm(x, p["attn_norm"])
+    # Each projection is two products over column ranges of its weight, not
+    # one product cut in two afterwards: the cut is then of the (small)
+    # weight, and no [B, N, S, 256] or second [B, N, S, 192] array of
+    # activations exists to be sliced.
+    proj = partial(jnp.einsum, "bsd,dnh->bnsh")
+    with jax.named_scope("obs.model.mla.q"):
+        q = jnp.concatenate(
+            [proj(h, p["wq"][..., :a.qk_nope]),
+             _rope_interleaved(proj(h, p["wq"][..., a.qk_nope:]), positions,
+                               cfg.rope_theta)], axis=-1)
+    with jax.named_scope("obs.model.mla.kv_down"):
+        down = jnp.einsum("bsd,dc->bsc", h, p["wkv_a"])
+        latent = _rms_norm(down[..., :a.kv_latent], p["kv_norm"])
+        # the one rotary key a token, every head's
+        k_rope = _rope_interleaved(down[:, None, :, a.kv_latent:], positions,
+                                   cfg.rope_theta)
+    with jax.named_scope("obs.model.mla.kv_up"):
+        k_nope = proj(latent, p["wkv_b"][..., :a.qk_nope])
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope, (*k_nope.shape[:3],
+                                               a.qk_rope))], axis=-1)
+        v = proj(latent, p["wkv_b"][..., a.qk_nope:])
+    return q, k, v
+
+
 def _qkv_proj(p, x, positions, cfg: ModelConfig):
     """Norm + qkv projections + rotary — shared by the regular and
     pipeline-parallel paths (a numerics change here must hit both, or the
@@ -246,11 +437,15 @@ def _attn_out(p, o):
 
 
 def _attention(p, x, positions, cfg: ModelConfig, mesh, segment_ids=None,
-               collect_stats=False):
+               collect_stats=False, kind: Optional[LatentAttn] = None):
     """One attention sublayer.  `collect_stats` (static) additionally
     returns the ring's in-graph DevStats (burst strategy only — ulysses has
-    no ring to instrument): `(out, DevStats)` instead of `out`."""
-    q, k, v = _qkv_proj(p, x, positions, cfg)
+    no ring to instrument): `(out, DevStats)` instead of `out`.  `kind`: the
+    layer's LayerSpec.attn (None: the GQA block of the scalar knobs)."""
+    if kind is None:
+        q, k, v = _qkv_proj(p, x, positions, cfg)
+    else:
+        q, k, v = _latent_qkv(p, x, positions, cfg, kind)
     if collect_stats and cfg.attn_strategy != "burst":
         raise ValueError(
             "collect_stats requires attn_strategy='burst' (devstats "
@@ -307,8 +502,12 @@ def _attention(p, x, positions, cfg: ModelConfig, mesh, segment_ids=None,
     return _attn_out(p, o)
 
 
-def _moe_group(mp, h2, cfg: ModelConfig, ep_axis, inference=False):
+def _moe_group(mp, h2, cfg: ModelConfig, ep_axis, inference=False,
+               kind: Optional[ExpertMLP] = None, extra=None):
     """One routing group's MoE: [tokens, d] -> (y, aux, MoEStats or None).
+    `kind`: the layer's ExpertMLP (None: the scalar knobs'); `extra`: its
+    leaves beside MoEParams ({"router_bias", "shared_gate", "shared_up",
+    "shared_down"}, those it has).
     The ONE place that picks the layer (see ModelConfig.n_experts): the
     regular path's _mlp and the pipeline's _moe_block both call it, inside
     their shard_maps.
@@ -322,9 +521,23 @@ def _moe_group(mp, h2, cfg: ModelConfig, ep_axis, inference=False):
     exact when nothing drops, since routing is per-token."""
     from ..parallel.moe import capacity_for, moe_held, moe_shard
 
+    kind = _knob_mlp(cfg) if kind is None else kind
+    extra = extra or {}
     if ep_axis is None and not inference:
-        return moe_held(mp, h2, top_k=cfg.moe_top_k, held=cfg.experts_held)
-    if cfg.experts_held is not None:
+        return moe_held(
+            mp, h2, top_k=kind.top_k, held=kind.held, score=kind.score,
+            bias=extra.get("router_bias"), gate_scale=kind.gate_scale,
+            shared=(tuple(extra[k] for k in ("shared_gate", "shared_up",
+                                             "shared_down"))
+                    if kind.shared_ff else None))
+    if (kind.score, kind.choice_bias, kind.gate_scale, kind.shared_ff) != (
+            "softmax", False, 1.0, 0):
+        raise ValueError(
+            "a sigmoid router, a choice bias, a gate scale and shared "
+            "experts are the drop-free trainer layer's (moe.moe_held); "
+            "inference and the expert_axis exchange run the dense-dispatch "
+            "layer, which has none of them")
+    if kind.held is not None:
         raise ValueError(
             "experts_held is the drop-free trainer layer's (moe.moe_held); "
             "inference and the expert_axis exchange run the dense-dispatch "
@@ -332,12 +545,12 @@ def _moe_group(mp, h2, cfg: ModelConfig, ep_axis, inference=False):
     tokens, dd = h2.shape
 
     def route(hc, cap):
-        y, aux, _ = moe_shard(mp, hc, top_k=cfg.moe_top_k, capacity=cap,
+        y, aux, _ = moe_shard(mp, hc, top_k=kind.top_k, capacity=cap,
                               axis=ep_axis)
         return y, aux
 
     if not inference:
-        y, aux = route(h2, capacity_for(tokens, cfg.n_experts, cfg.moe_top_k,
+        y, aux = route(h2, capacity_for(tokens, kind.n_experts, kind.top_k,
                                         cfg.moe_capacity_factor))
         return y, aux, None
     c = min(512, tokens)
@@ -355,20 +568,28 @@ def _mlp(p, x, cfg: Optional[ModelConfig] = None, mesh=None, inference=False):
     """Dense SwiGLU, or (cfg.n_experts > 0) a routed MoE (_moe_group says
     which).  Returns (out, aux_loss) — aux is 0 for the dense path so
     callers are uniform."""
+    _refuse_pattern(cfg, "this caller of _mlp (decode, serving, pipeline)")
     return _mlp_stats(p, x, cfg, mesh, inference)[:2]
 
 
+_MOE_EXTRA = ("router_bias", "shared_gate", "shared_up", "shared_down")
+
+
 def _mlp_stats(p, x, cfg: Optional[ModelConfig] = None, mesh=None,
-               inference=False):
+               inference=False, kind=None):
     """_mlp's (out, aux_loss) and, third, what the drop-free expert layer
     did: its moe.MoEStats over the token shards (slots summed, the load
     ratio's worst shard, the choices [B, S, k] sharded like the tokens);
-    None for the dense MLP and the dense-dispatch layer."""
+    None for the dense MLP and the dense-dispatch layer.  `kind`: the
+    layer's LayerSpec.mlp (None: the scalar knobs')."""
     h = _rms_norm(x, p["mlp_norm"])
-    if cfg is not None and cfg.n_experts:
+    if kind is None and cfg is not None:
+        kind = _knob_mlp(cfg)
+    if isinstance(kind, ExpertMLP):
         from ..parallel.moe import MoEParams, MoEStats
 
         mp = MoEParams(p["router"], p["w_gate"], p["w_up"], p["w_down"])
+        extra = {k: p[k] for k in _MOE_EXTRA if k in p}
         token_axes = tuple(
             a for a in (cfg.batch_axis, *cfg.seq_axes) if a is not None
         )
@@ -376,10 +597,10 @@ def _mlp_stats(p, x, cfg: Optional[ModelConfig] = None, mesh=None,
         # cross-shard aux reduction
         ep_axis = cfg.expert_axis if mesh is not None else None
 
-        def group(mp, h):
+        def group(mp, h, extra):
             bb, ss, dd = h.shape
             y, aux, stats = _moe_group(mp, h.reshape(bb * ss, dd), cfg,
-                                       ep_axis, inference)
+                                       ep_axis, inference, kind, extra)
             # moe_shard pmeans over the expert axis; average the remaining
             # token-sharding axes so aux is replicated
             rest = tuple(a for a in token_axes if a != ep_axis)
@@ -394,15 +615,15 @@ def _mlp_stats(p, x, cfg: Optional[ModelConfig] = None, mesh=None,
             return y.reshape(bb, ss, dd), aux, stats
 
         if mesh is None:  # single-program path (e.g. decode off-mesh)
-            return group(mp, h)
+            return group(mp, h, extra)
 
         seq_spec = cfg.seq_axes if len(cfg.seq_axes) > 1 else cfg.seq_axes[0]
         ep = cfg.expert_axis
         if ep is not None:
             ep_size = mesh.shape.get(ep, 1)
-            if cfg.n_experts % ep_size:
+            if kind.n_experts % ep_size:
                 raise ValueError(
-                    f"n_experts {cfg.n_experts} not divisible by "
+                    f"n_experts {kind.n_experts} not divisible by "
                     f"expert_axis {ep!r} size {ep_size}")
         pspec = MoEParams(P(None, None), P(ep, None, None),
                           P(ep, None, None), P(ep, None, None))
@@ -413,10 +634,10 @@ def _mlp_stats(p, x, cfg: Optional[ModelConfig] = None, mesh=None,
                       if ep is None and not inference else None)
         return shard_map(
             group, mesh=mesh,
-            in_specs=(pspec, tokens_spec),
+            in_specs=(pspec, tokens_spec, {k: P() for k in extra}),
             out_specs=(tokens_spec, P(), stats_spec),
             check_vma=False,
-        )(mp, h)
+        )(mp, h, extra)
     gate = jnp.einsum("bsd,df->bsf", h, p["w_gate"])
     up = jnp.einsum("bsd,df->bsf", h, p["w_up"])
     out = jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up, p["w_down"])
@@ -454,6 +675,7 @@ def forward_with_aux(params: Params, tokens, positions, cfg: ModelConfig, mesh,
     [B, hi - lo, vocab] (block diffusion reads the noised half of its
     stream)."""
     if cfg.pp_axis is not None:
+        _refuse_pattern(cfg, "the pipeline-parallel path")
         if moe_stats or head_rows is not None or cfg.block_diffusion:
             raise ValueError(
                 "moe_stats, head_rows and block_diffusion are not threaded "
@@ -480,7 +702,8 @@ def forward_with_aux(params: Params, tokens, positions, cfg: ModelConfig, mesh,
         x = params["embed"].astype(cfg.dtype)[tokens]
         x = jax.lax.with_sharding_constraint(x, act_spec)
 
-    def block(carry, p):
+    def block(carry, p, spec=None):
+        spec = LayerSpec(_knob_mlp(cfg)) if spec is None else spec
         if collect_stats:
             from ..obs import devstats
 
@@ -488,16 +711,16 @@ def forward_with_aux(params: Params, tokens, positions, cfg: ModelConfig, mesh,
             with jax.named_scope("obs.model.attn"):
                 a, st = _attention(p, x, positions, cfg, mesh,
                                    segment_ids=segment_ids,
-                                   collect_stats=True)
+                                   collect_stats=True, kind=spec.attn)
                 x = x + a
             stats = st if stats is None else devstats.merge(stats, st)
         else:
             x, aux = carry
             with jax.named_scope("obs.model.attn"):
                 x = x + _attention(p, x, positions, cfg, mesh,
-                                   segment_ids=segment_ids)
+                                   segment_ids=segment_ids, kind=spec.attn)
         with jax.named_scope("obs.model.mlp"):
-            m, aux_l, moe_l = _mlp_stats(p, x, cfg, mesh)
+            m, aux_l, moe_l = _mlp_stats(p, x, cfg, mesh, kind=spec.mlp)
             x = jax.lax.with_sharding_constraint(x + m, act_spec)
         if collect_stats:
             return (x, aux + aux_l, stats), moe_l
@@ -506,8 +729,13 @@ def forward_with_aux(params: Params, tokens, positions, cfg: ModelConfig, mesh,
     carry = ((x, jnp.float32(0.0), None) if collect_stats
              else (x, jnp.float32(0.0)))
     moe_layers = []  # each layer's MoEStats (None: no drop-free layer)
-    for p in params["layers"]:
-        carry, moe_l = (jax.checkpoint(block) if cfg.remat else block)(
+    # one function object a DISTINCT spec (a stack without a pattern: the one
+    # it always ran), so that layers of one kind share jax.checkpoint's trace
+    kinds = {None: block}
+    for p, spec in zip(params["layers"], layer_specs(cfg)):
+        key = None if cfg.pattern is None else spec
+        layer = kinds.setdefault(key, partial(block, spec=spec))
+        carry, moe_l = (jax.checkpoint(layer) if cfg.remat else layer)(
             carry, p)
         moe_layers.append(moe_l)
     if collect_stats:

@@ -905,8 +905,12 @@ def flash_fwd(q, k, v, m, lse, acc, scale, spec: MaskSpec, *,
     per-row-visit acc-in DMA — ~2 GB of dead HBM traffic per 64K-seq
     single-device forward.
 
-    q [B,N,S,D]; k, v [B,Nk,Skv,D] (GQA when Nk < N); m, lse [B,N,S] f32;
-    acc [B,N,S,D] f32.  `spec` scalars may be traced values.
+    q [B,N,S,D]; k [B,Nk,Skv,D], v [B,Nk,Skv,Dv] (GQA when Nk < N); m, lse
+    [B,N,S] f32; acc [B,N,S,Dv] f32.  Dv is v's own last axis (latent
+    attention: q, k 192 wide, v, acc and o 128): the kernel body is
+    specialised on the static shapes and Dv == D is the program it always
+    was; no operand is padded to the other's width.  `spec` scalars may be
+    traced values.
     `block_kv_compute` (<= block_kv) sets the in-kernel compute sub-block
     width (see _fwd_kernel._sweep); the default min(block_kv, 1024) is the
     measured v5e optimum (two pipelined sub-blocks per 2048 memory block:
@@ -1046,8 +1050,9 @@ def _fwd_launch(spec_arr, q, k, v, *rest, carry, seg, scale, block_q,
     """The forward kernel on exact tiles: `rest` is the packed (m, lse) and
     acc where `carry`, then the segment ids as [B, S, 1] / [B, 1, S] where
     `seg`; every keyword static.  Returns the packed (m, lse) and acc (or o
-    under emit_o)."""
+    under emit_o).  q and k are `d` wide, v, acc and o `d_v` wide."""
     b, n, sq_full, d = q.shape
+    d_v = v.shape[-1]
     n_kv, skv_full = k.shape[1], k.shape[2]
     # the lengths the grid covers; the arrays keep their full length
     s_q, s_kv = _range_len(q_range, sq_full), _range_len(kv_range, skv_full)
@@ -1128,11 +1133,11 @@ def _fwd_launch(spec_arr, q, k, v, *rest, carry, seg, scale, block_q,
     in_specs = [
         pl.BlockSpec((1, 1, bq, d), q_map),
         pl.BlockSpec((1, 1, bkv, d), kv_map),
-        pl.BlockSpec((1, 1, bkv, d), kv_map),
+        pl.BlockSpec((1, 1, bkv, d_v), kv_map),
     ]
     if carry:
         in_specs += [state_block, state_block,
-                     pl.BlockSpec((1, 1, bq, d), q_map)]
+                     pl.BlockSpec((1, 1, bq, d_v), q_map)]
     if seg:
         in_specs.append(pl.BlockSpec(
             (1, bq, 1), lambda b_, h, i, j, sp: (b_, q_map(b_, h, i, j, sp)[2], 0)))
@@ -1143,7 +1148,7 @@ def _fwd_launch(spec_arr, q, k, v, *rest, carry, seg, scale, block_q,
         jax.ShapeDtypeStruct((b, n, sq_full // lp, lp), jnp.float32),
         # emit_o: the third output is the NORMALIZED o in q's dtype (fused
         # finalize, see _finish) instead of the raw f32 accumulator
-        jax.ShapeDtypeStruct((b, n, sq_full, d),
+        jax.ShapeDtypeStruct((b, n, sq_full, d_v),
                              q.dtype if emit_o else jnp.float32),
     ]
     # the carried state is updated where it lies (flattened inputs: spec, q,
@@ -1158,12 +1163,12 @@ def _fwd_launch(spec_arr, q, k, v, *rest, carry, seg, scale, block_q,
         out_specs=[
             state_block,
             state_block,
-            pl.BlockSpec((1, 1, bq, d), q_map),
+            pl.BlockSpec((1, 1, bq, d_v), q_map),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, d_v), jnp.float32),
         ],
     )
     return pl.pallas_call(
@@ -1866,7 +1871,7 @@ def _flash_bwd_fused_tri(do, q, k, v, delta, lse, scale, spec, *,
                          block_q, block_kv, interpret, block_kv_compute=None,
                          segments=None, loop_sweep=False):
     b, n, s_q, d = q.shape
-    s_kv = k.shape[2]
+    s_kv, d_v = k.shape[2], v.shape[-1]
     bq = _pick_block(s_q, block_q)
     bkv = _pick_block(s_kv, block_kv)
     if block_kv_compute is None:
@@ -1905,10 +1910,10 @@ def _flash_bwd_fused_tri(do, q, k, v, delta, lse, scale, spec, *,
 
     state_block = pl.BlockSpec((1, 1, s_q // lp, lp), state_map)
     in_specs = [
-        pl.BlockSpec((1, 1, bq, d), q_map),
+        pl.BlockSpec((1, 1, bq, d_v), q_map),
         pl.BlockSpec((1, 1, bq, d), q_map),
         pl.BlockSpec((1, 1, bkv, d), kv_map),
-        pl.BlockSpec((1, 1, bkv, d), kv_map),
+        pl.BlockSpec((1, 1, bkv, d_v), kv_map),
         state_block,
         state_block,
     ]
@@ -1937,11 +1942,11 @@ def _flash_bwd_fused_tri(do, q, k, v, delta, lse, scale, spec, *,
             out_specs=[
                 pl.BlockSpec((1, 1, s_q, d), dq_map),
                 pl.BlockSpec((1, 1, bkv, d), kv_out_map),
-                pl.BlockSpec((1, 1, bkv, d), kv_out_map),
+                pl.BlockSpec((1, 1, bkv, d_v), kv_out_map),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bkv, d), jnp.float32),
-                pltpu.VMEM((bkv, d), jnp.float32),
+                pltpu.VMEM((bkv, d_v), jnp.float32),
                 pltpu.VMEM((bq, bkvc), q.dtype),
                 pltpu.VMEM((bq, d), q.dtype),
                 pltpu.SMEM((2,), jnp.int32),
@@ -1950,7 +1955,7 @@ def _flash_bwd_fused_tri(do, q, k, v, delta, lse, scale, spec, *,
         out_shape=[
             jax.ShapeDtypeStruct((b, n, s_q, d), jnp.float32),
             jax.ShapeDtypeStruct((b, n, s_kv, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, n, s_kv, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, n, s_kv, d_v), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT,
@@ -1982,7 +1987,7 @@ def _flash_bwd_fused(do, q, k, v, delta, lse, scale, spec, *,
                      block_q, block_kv, interpret, window=None,
                      segments=None, q_range=None, kv_range=None, carry=None):
     b, n, sq_full, d = q.shape
-    n_kv, skv_full = k.shape[1], k.shape[2]
+    n_kv, skv_full, d_v = k.shape[1], k.shape[2], v.shape[-1]
     # the lengths the grid covers; the arrays keep their full length
     s_q, s_kv = _range_len(q_range, sq_full), _range_len(kv_range, skv_full)
     group = _gqa_group(n, n_kv)
@@ -2014,10 +2019,10 @@ def _flash_bwd_fused(do, q, k, v, delta, lse, scale, spec, *,
     # full-size: a q_range round visits only its rows and the rest stay zero
     dq0 = jnp.zeros((b, n, sq_full, d), jnp.float32)
     in_specs = [
-        pl.BlockSpec((1, 1, bq, d), bq_map),
+        pl.BlockSpec((1, 1, bq, d_v), bq_map),
         pl.BlockSpec((1, 1, bq, d), bq_map),
         pl.BlockSpec((1, 1, bkv, d), bkv_map),
-        pl.BlockSpec((1, 1, bkv, d), bkv_map),
+        pl.BlockSpec((1, 1, bkv, d_v), bkv_map),
         bstate_block,
         bstate_block,
         pl.BlockSpec((1, 1, bq, d), bq_map),
@@ -2028,11 +2033,13 @@ def _flash_bwd_fused(do, q, k, v, delta, lse, scale, spec, *,
     aliases = {7: 0}
     if carry is None and s_kv != skv_full:
         # the kv blocks outside the range are never written: they are zeros
-        carry = (jnp.zeros((b, n_kv, skv_full, d), jnp.float32),) * 2
+        dk0 = jnp.zeros((b, n_kv, skv_full, d), jnp.float32)
+        carry = (dk0, dk0 if d_v == d else jnp.zeros(
+            (b, n_kv, skv_full, d_v), jnp.float32))
     if carry is not None:
         # each dk/dv block is read before its sweep and written after it,
         # once: the alias needs no separation argument (unlike dq's)
-        in_specs += [pl.BlockSpec((1, 1, bkv, d), bkv_map)] * 2
+        in_specs += [pl.BlockSpec((1, 1, bkv, w), bkv_map) for w in (d, d_v)]
         inputs += list(carry)
         aliases.update({8: 1, 9: 2})
     if segments is not None:
@@ -2059,11 +2066,11 @@ def _flash_bwd_fused(do, q, k, v, delta, lse, scale, spec, *,
             out_specs=[
                 pl.BlockSpec((1, 1, bq, d), bq_map),
                 pl.BlockSpec((1, 1, bkv, d), bkv_map),
-                pl.BlockSpec((1, 1, bkv, d), bkv_map),
+                pl.BlockSpec((1, 1, bkv, d_v), bkv_map),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bkv, d), jnp.float32),
-                pltpu.VMEM((bkv, d), jnp.float32),
+                pltpu.VMEM((bkv, d_v), jnp.float32),
                 # deferred-flush pend tiles (see _flush_dk);
                 # q.dtype matches the casts the stash performs
                 pltpu.VMEM((bq, bkv), q.dtype),
@@ -2074,7 +2081,7 @@ def _flash_bwd_fused(do, q, k, v, delta, lse, scale, spec, *,
         out_shape=[
             jax.ShapeDtypeStruct((b, n, sq_full, d), jnp.float32),
             jax.ShapeDtypeStruct((b, n_kv, skv_full, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_kv, skv_full, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, n_kv, skv_full, d_v), jnp.float32),
         ],
         input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
@@ -2086,23 +2093,25 @@ def _flash_bwd_fused(do, q, k, v, delta, lse, scale, spec, *,
     return dq, dk, dv
 
 
-def _tri_bwd_other_residents(bq, bkv, d, itemsize=2, bkvc=None):
+def _tri_bwd_other_residents(bq, bkv, d, itemsize=2, bkvc=None, d_v=None):
     """Estimated VMEM held by everything EXCEPT the whole-head dq output in
     the triangular fused bwd kernel: double-buffered input blocks (do, q,
     k, v) and dk/dv f32 output blocks, plus the ds/q deferral stashes
     (sub-block width when block_kv_compute is set).  Packed delta/lse
-    blocks are negligible next to these."""
+    blocks are negligible next to these.  q, k, dq, dk are `d` wide; do, v,
+    dv `d_v` (None: d)."""
     if bkvc is None:
         bkvc = bkv
-    blocks = 2 * (2 * bq * d * itemsize      # do, q
-                  + 2 * bkv * d * itemsize   # k, v
-                  + 2 * bkv * d * 4)         # dk, dv out (f32)
+    d_v = d if d_v is None else d_v
+    blocks = 2 * (bq * (d + d_v) * itemsize      # do, q
+                  + bkv * (d + d_v) * itemsize   # k, v
+                  + bkv * (d + d_v) * 4)         # dk, dv out (f32)
     scratch = bq * bkvc * itemsize + bq * d * itemsize  # ds stash, q stash
     return blocks + scratch
 
 
 def tri_bwd_supported(s_q, s_kv, n, n_kv, d, *, block_q, block_kv,
-                      block_kv_compute=None) -> bool:
+                      block_kv_compute=None, d_v=None) -> bool:
     """Whether flash_bwd(triangular=True) will actually use the
     wrapped-diagonal kernel (vs silently falling back to the rectangular
     fused kernel): group=1 only, square even block tiling, and the
@@ -2121,7 +2130,7 @@ def tri_bwd_supported(s_q, s_kv, n, n_kv, d, *, block_q, block_kv,
     # estimate charges the scratch the kernel actually allocates
     bkvc = None if block_kv_compute is None else _pick_block(bkv, block_kv_compute)
     dq_budget = VMEM_LIMIT // 2 - _tri_bwd_other_residents(
-        bq, bkv, d, bkvc=bkvc)
+        bq, bkv, d, bkvc=bkvc, d_v=d_v)
     return (
         n == n_kv and s_q == s_kv and bkv % bq == 0
         and nkb % 2 == 0 and nkb >= 2
@@ -2131,9 +2140,10 @@ def tri_bwd_supported(s_q, s_kv, n, n_kv, d, *, block_q, block_kv,
 
 def _bwd_kernel_of(n, n_kv, s_q, s_kv, d, *, block_q, block_kv, interpret,
                    fused=None, triangular=False, window=None,
-                   block_kv_compute=None) -> str:
+                   block_kv_compute=None, d_v=None) -> str:
     """The kernel flash_bwd runs a round of these (whole-block) lengths on:
-    "tri", "rect" (the fused rectangular one) or "split"."""
+    "tri", "rect" (the fused rectangular one) or "split".  `d`: the width of
+    q and k; `d_v`: of v (None: d)."""
     bq = _pick_block(s_q, block_q)
     bkv = _pick_block(s_kv, block_kv)
     explicit_split = fused is False
@@ -2150,14 +2160,15 @@ def _bwd_kernel_of(n, n_kv, s_q, s_kv, d, *, block_q, block_kv, interpret,
     if (bool(triangular) and not explicit_split and not _tri_disabled()
             and tri_bwd_supported(s_q, s_kv, n, n_kv, d, block_q=bq,
                                   block_kv=bkv,
-                                  block_kv_compute=block_kv_compute)):
+                                  block_kv_compute=block_kv_compute,
+                                  d_v=d_v)):
         return "tri"
     return "rect" if fused else "split"
 
 
 def bwd_folds_carry(n, n_kv, s_q, s_kv, d, q_range, kv_range, *, block_q,
                     block_kv, interpret=None, fused=None, triangular=False,
-                    window=None, block_kv_compute=None) -> bool:
+                    window=None, block_kv_compute=None, d_v=None) -> bool:
     """Whether flash_bwd takes a round's `q_range` / `kv_range` / `carry` in
     the kernel: exactly where it takes the fused rectangular kernel, decided
     on the RANGE's own lengths (the in-place dq argument needs the range's
@@ -2173,7 +2184,7 @@ def bwd_folds_carry(n, n_kv, s_q, s_kv, d, q_range, kv_range, *, block_q,
         n, n_kv, _range_len(q_range, s_q), _range_len(kv_range, s_kv), d,
         block_q=block_q, block_kv=block_kv, interpret=interpret, fused=fused,
         triangular=triangular, window=window,
-        block_kv_compute=block_kv_compute) == "rect"
+        block_kv_compute=block_kv_compute, d_v=d_v) == "rect"
 
 
 def flash_bwd(do, q, k, v, delta, lse, scale, spec: MaskSpec, *,
@@ -2182,7 +2193,10 @@ def flash_bwd(do, q, k, v, delta, lse, scale, spec: MaskSpec, *,
               block_kv_compute=None, loop_sweep=False,
               q_range=None, kv_range=None, carry=None):
     """One backward ring round on TPU.  Same contract as ops/tile.py:tile_bwd:
-    returns (dq [B,N,S,D], dk [B,Nk,Skv,D], dv [B,Nk,Skv,D]) in float32.
+    returns (dq [B,N,S,D], dk [B,Nk,Skv,D], dv [B,Nk,Skv,Dv]) in float32.
+    q and k are D wide; v and do are Dv wide (their own last axes: the
+    kernels are specialised on the static shapes, Dv == D is the program it
+    always was).
 
     `carry` = (dk, dv) float32 of the rounds before: the returned dk, dv are
     the carry plus this round's.  `q_range` / `kv_range` (see "sub-range
@@ -2211,13 +2225,14 @@ def flash_bwd(do, q, k, v, delta, lse, scale, spec: MaskSpec, *,
     if not loop_sweep and _bwd_loop_default():
         loop_sweep = True  # BURST_BWD_LOOP promotion (see _bwd_loop_default)
     b, n, s_q, d = q.shape
-    n_kv, s_kv = k.shape[1], k.shape[2]
+    n_kv, s_kv, d_v = k.shape[1], k.shape[2], v.shape[-1]
     group = _gqa_group(n, n_kv)
     if q_range is not None or kv_range is not None or carry is not None:
         kw = dict(block_q=block_q, block_kv=block_kv, interpret=interpret,
                   fused=fused, triangular=triangular, window=window,
                   block_kv_compute=block_kv_compute)
-        if bwd_folds_carry(n, n_kv, s_q, s_kv, d, q_range, kv_range, **kw):
+        if bwd_folds_carry(n, n_kv, s_q, s_kv, d, q_range, kv_range, d_v=d_v,
+                           **kw):
             return _flash_bwd_fused(
                 do, q, k, v, delta, lse, scale, spec, block_q=block_q,
                 block_kv=block_kv, interpret=interpret, window=window,
@@ -2255,7 +2270,7 @@ def flash_bwd(do, q, k, v, delta, lse, scale, spec: MaskSpec, *,
     kernel = _bwd_kernel_of(
         n, n_kv, s_q, s_kv, d, block_q=block_q, block_kv=block_kv,
         interpret=interpret, fused=fused, triangular=triangular,
-        window=window, block_kv_compute=block_kv_compute)
+        window=window, block_kv_compute=block_kv_compute, d_v=d_v)
     if kernel == "tri":
         return _flash_bwd_fused_tri(
             do, q, k, v, delta, lse, scale, spec,
@@ -2275,10 +2290,10 @@ def flash_bwd(do, q, k, v, delta, lse, scale, spec: MaskSpec, *,
                                                 wnd=window)
     state_block = pl.BlockSpec((1, 1, s_q // lp, lp), state_map)
     dq_in_specs = [
-        pl.BlockSpec((1, 1, bq, d), q_map),
+        pl.BlockSpec((1, 1, bq, d_v), q_map),
         pl.BlockSpec((1, 1, bq, d), q_map),
         pl.BlockSpec((1, 1, bkv, d), kv_map),
-        pl.BlockSpec((1, 1, bkv, d), kv_map),
+        pl.BlockSpec((1, 1, bkv, d_v), kv_map),
         state_block,
         state_block,
     ]
@@ -2335,10 +2350,10 @@ def flash_bwd(do, q, k, v, delta, lse, scale, spec: MaskSpec, *,
 
     bstate_block = pl.BlockSpec((1, 1, s_q // lp, lp), bstate_map)
     dkdv_in_specs = [
-        pl.BlockSpec((1, 1, bq, d), bq_map),
+        pl.BlockSpec((1, 1, bq, d_v), bq_map),
         pl.BlockSpec((1, 1, bq, d), bq_map),
         pl.BlockSpec((1, 1, bkv, d), bkv_map),
-        pl.BlockSpec((1, 1, bkv, d), bkv_map),
+        pl.BlockSpec((1, 1, bkv, d_v), bkv_map),
         bstate_block,
         bstate_block,
     ]
@@ -2364,16 +2379,16 @@ def flash_bwd(do, q, k, v, delta, lse, scale, spec: MaskSpec, *,
             in_specs=dkdv_in_specs,
             out_specs=[
                 pl.BlockSpec((1, 1, bkv, d), bkv_map),
-                pl.BlockSpec((1, 1, bkv, d), bkv_map),
+                pl.BlockSpec((1, 1, bkv, d_v), bkv_map),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bkv, d), jnp.float32),
-                pltpu.VMEM((bkv, d), jnp.float32),
+                pltpu.VMEM((bkv, d_v), jnp.float32),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((b, n_kv, s_kv, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_kv, s_kv, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, n_kv, s_kv, d_v), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT,

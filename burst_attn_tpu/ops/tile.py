@@ -11,9 +11,11 @@ carry-in Triton kernel (burst_attn/lao.py:67-213).  It runs on any backend
 default backend for simulated-mesh tests.
 
 Conventions (all differ deliberately from the reference's torch layout mix):
-  q, k, v : [B, N, S, D]  ("bnsd"; contiguous [S, D] per head — TPU friendly)
+  q, k    : [B, N, S, D]  ("bnsd"; contiguous [S, D] per head — TPU friendly)
+  v       : [B, N, S, Dv] Dv = D, or v's own width (latent attention: q, k
+                          192 wide, v 128); acc, o, do and dv are Dv wide
   m, lse  : [B, N, S]     float32, initialized to -inf
-  acc     : [B, N, S, D]  float32, initialized to 0, unnormalized
+  acc     : [B, N, S, Dv] float32, initialized to 0, unnormalized
   final   : o = acc * exp(m - lse)   (guarded for fully-masked rows)
 
 GQA: N query heads, Nk kv heads with N % Nk == 0; kv head g serves query
@@ -214,7 +216,7 @@ def single_device_attention(q, k, v, scale=None, causal=False, window=None,
         raise ValueError("window attention requires causal=True")
     b, n, s, d = q.shape
     spec = round_spec(jnp.int32(0), jnp.int32(0), s, k.shape[2], causal, "contig")
-    m, lse, acc = init_state(b, n, s, d)
+    m, lse, acc = init_state(b, n, s, v.shape[-1])
     segs = None if segment_ids is None else (segment_ids, segment_ids)
     m, lse, acc = tile_fwd(q, k, v, m, lse, acc, scale, spec, window=window,
                            segments=segs)

@@ -288,6 +288,22 @@ def call_row(t: BlockTable, s_q=None, s_kv=None, window=None):
     2.15 against 2.13 in 512 x 512, 2.32 in 512 x 1024, 2.88 in 256 x 512.
     So `s_q` and `s_kv` change nothing today; they are what the next
     measured departure keys on.
+
+    Head widths (PR 34: q and k `d_qk` wide, v `d_v`; the kernels read them
+    off the arrays).  The row stands at 192 / 128 too, so the widths are not
+    arguments of this rule.  At 16,384 rows x 32 / 32 heads (the last rows
+    of results/sweep_tile_calls.jsonl; the forward with the diagonal tiles
+    whole, as the tile sizes were always swept): forward 22.33 ms in
+    2048 x 2048 against 23.50 in 1024 x 1024, 24.32 in 1024 x 2048, 25.84
+    in 2048 x 1024 (128 / 128 beside it: 15.68); the triangular backward
+    54.28 in 1024 x 2048 against 52.44 in 1024 x 1024 (-3.4 %), 54.49 in
+    512 x 2048 and 58.93 in 2048 x 1024, which takes the rectangular kernel
+    (128 / 128: 35.12 against 34.29, -2.4 %).  The backward's 1024 x 1024 is
+    the 2.4-3.4 % it has been at every length and width measured: a property
+    of that kernel (ROADMAP S9), not of a width, and no row of its own.  The
+    192-deep score product goes to the MXU as ONE operand: fed as a 128 + 64
+    pair of products summed, the forward read 20.66 ms against 19.39 (edge
+    256) and the backward 54.41 against 54.28.
     """
     del s_q, s_kv  # see Rows
     row = (t.fwd_block_q, t.fwd_block_kv, t.bwd_block_q, t.bwd_block_kv)

@@ -245,8 +245,8 @@ def _tile_fwd(cfg, q, k, v, m, lse, acc, scale, spec, triangular=False,
     if m is None:
         # jnp oracle has no None-carry fast path; materialize the empty
         # state it stands for (CPU-only — XLA folds the constants anyway)
-        b, n, s, d = q.shape
-        m, lse, acc = jnp_tile.init_state(b, n, s, d)
+        b, n, s, _ = q.shape
+        m, lse, acc = jnp_tile.init_state(b, n, s, v.shape[-1])
     return jnp_tile.tile_fwd(q, k, v, m, lse, acc, scale, spec,
                              window=window, segments=segments,
                              q_range=q_range, kv_range=kv_range)
@@ -330,7 +330,7 @@ def _diag_tiles(cfg, q_shape, k_shape, rounds, seg):
     return tiles
 
 
-def _round_in_kernel(cfg, pass_, q_shape, k_shape) -> bool:
+def _round_in_kernel(cfg, pass_, q_shape, k_shape, d_v) -> bool:
     """Whether a round after the self round folds into its carry INSIDE the
     kernel (the forward's state, the backward's dk / dv) on these per-shard
     shapes, or takes the sliced / added form in XLA: the tile entry's own
@@ -353,7 +353,8 @@ def _round_in_kernel(cfg, pass_, q_shape, k_shape) -> bool:
             n, n_kv, s, s_kv, d, q_rng, kv_rng, block_q=rb.block_q_bwd,
             block_kv=rb.block_kv_bwd,
             # the forward's band grid has no backward twin to ask for
-            triangular=tri and cfg.window is None, window=cfg.window)
+            triangular=tri and cfg.window is None, window=cfg.window,
+            d_v=d_v)
 
     return all(folds(*tile) for tile in _round_tiles(cfg, s, s_kv))
 
@@ -1030,11 +1031,11 @@ _burst_attn_shard_stats_seg.defvjp(_stats_seg_vjp_fwd, _stats_seg_vjp_bwd)
 
 
 def _note_dispatch(cfg: BurstConfig, mesh, q_shape, k_shape, batch_axes,
-                   head_axes, seg=False) -> None:
+                   head_axes, *, seg, d_v) -> None:
     """Record one ring dispatch in the obs registry (burst.dispatch /
     burst.ring_rounds / burst.inplace_rounds / flash.diag_tiles /
     burst.ring_hops / burst.wire_bytes).  `seg`: whether the call carries
-    segment ids.
+    segment ids; `d_v`: v's width.
 
     Host-boundary code: called from burst_attn BEFORE shard_map, never from
     inside the traced shard program (burstlint `obs-jit-safe`)."""
@@ -1058,7 +1059,8 @@ def _note_dispatch(cfg: BurstConfig, mesh, q_shape, k_shape, batch_axes,
                max(1, q_shape[2] // world), q_shape[3])
     k_local = (max(1, k_shape[0] // b_div), max(1, k_shape[1] // h_div),
                max(1, k_shape[2] // world), k_shape[3])
-    _M_DISPATCH.inc(backend=cfg.backend, tile=cfg.backend)
+    _M_DISPATCH.inc(backend=cfg.backend, tile=cfg.backend,
+                    d_qk=str(q_shape[3]), d_v=str(d_v))
     r_live = _r_live(cfg, q_local[2], k_local[2], n_inter, n_intra)
     rounds, intra_hops, inter_hops = ring_round_counts(n_inter, n_intra,
                                                        r_live)
@@ -1067,7 +1069,7 @@ def _note_dispatch(cfg: BurstConfig, mesh, q_shape, k_shape, batch_axes,
     # the kernel: the tile entry's own static gate, per pass
     for pass_ in ("fwd", "bwd"):
         if rounds > 1:
-            in_kernel = _round_in_kernel(cfg, pass_, q_local, k_local)
+            in_kernel = _round_in_kernel(cfg, pass_, q_local, k_local, d_v)
             _M_INPLACE.inc(rounds - 1, **{
                 "pass": pass_, "path": "kernel" if in_kernel else "xla"})
     for path, tiles in _diag_tiles(cfg, q_local, k_local, rounds,
@@ -1084,9 +1086,9 @@ def _note_dispatch(cfg: BurstConfig, mesh, q_shape, k_shape, batch_axes,
 
     b_l, n_l, s_l, d_l = q_local
     fwd_b = sched_ir.wire_round_bytes("fwd", cfg.wire_dtype, b=b_l, n=n_l,
-                                      n_kv=k_local[1], s=s_l, d=d_l)
+                                      n_kv=k_local[1], s=s_l, d=d_l, d_v=d_v)
     bwd_b = sched_ir.wire_round_bytes("bwd", cfg.wire_dtype, b=b_l, n=n_l,
-                                      n_kv=k_local[1], s=s_l, d=d_l,
+                                      n_kv=k_local[1], s=s_l, d=d_l, d_v=d_v,
                                       opt_comm=cfg.optimize_bwd_comm)
     _M_WIRE.inc(fwd_b["kv"], **{"pass": "fwd", "dir": "kv"})
     _M_WIRE.inc(bwd_b["bundle"], **{"pass": "bwd", "dir": "bundle"})
@@ -1126,7 +1128,9 @@ def burst_attn(
     block_diffusion: Optional[int] = None,
 ) -> jax.Array:
     """Burst attention on global arrays [B, N, S, D]; S must already be in
-    layout order (parallel/layouts.to_layout) for causal runs.
+    layout order (parallel/layouts.to_layout) for causal runs.  v may have a
+    width of its own (q, k [.., D], v and the result [.., Dv]: latent
+    attention's 192 / 128); the default scale is D ** -0.5.
 
     seq_axes: mesh axis name(s) the sequence is sharded over — ("sp",) for a
     single ring or ("inter", "intra") for the hierarchical double ring.
@@ -1198,7 +1202,7 @@ def burst_attn(
         block_diffusion=block_diffusion,
     )
     _note_dispatch(cfg, mesh, q.shape, k.shape, batch_axes, head_axes,
-                   seg=segment_ids is not None)
+                   seg=segment_ids is not None, d_v=v.shape[3])
     seq_spec = seq_axes if len(seq_axes) > 1 else intra_axis
     spec = P(batch_axes, head_axes, seq_spec, None)
     if collect_stats:

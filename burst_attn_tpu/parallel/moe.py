@@ -2,10 +2,12 @@
 
 `moe_held` (further down) is the TRAINER's layer wherever no `ep` mesh axis
 is named (models/transformer._mlp, the pipeline stages alike): drop-free,
-over the experts this chip HOLDS.  The router scores all E experts and takes
+over the experts this chip HOLDS.  The router scores all E experts (softmax,
+or sigmoid with a bias that enters the choice and not the gate) and takes
 the top k; the (token, choice) pairs that fall on held experts are sorted by
 expert and go through a grouped matrix product; what the absent experts
-would add is left out.  No capacity, no dropped token, no [T, E, C] tensor.
+would add is left out; shared experts, where a model has them, are one dense
+SwiGLU beside that.  No capacity, no dropped token, no [T, E, C] tensor.
 With every expert held it is the whole layer.
 
 `moe_apply` / `moe_shard` (below) are the dense-dispatch layer with capacity
@@ -322,36 +324,68 @@ def _combine_bwd(top_k, res, dy):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def route(router, x, top_k: int):
+def route(router, x, top_k: int, *, score: str = "softmax", bias=None,
+          gate_scale: float = 1.0):
     """The drop-free layer's router on [T, d] tokens: float32 logits over all
     E experts at the highest matmul precision (a choice must not turn on the
-    accelerator's default bf16 passes), softmax, the top k renormalised to
-    sum 1.  Returns (gates [T, k], choice [T, k], probs [T, E])."""
+    accelerator's default bf16 passes), scored by `score`:
+
+      "softmax": the top k probabilities, renormalised to sum 1;
+      "sigmoid": s = sigmoid(logits); the CHOICE is the top k of s + `bias`
+        ([E] float32, or None), the GATES are s at the chosen experts,
+        without the bias, over their sum + 1e-20 (the deepseek_v3 router:
+        the bias steers the load and is no part of the output).
+
+    Either way the gates are multiplied by `gate_scale`.  Returns (gates
+    [T, k], choice [T, k], probs [T, E]); probs are the scores normalised
+    over the experts (what the load-balancing loss reads)."""
     logits = jnp.dot(x.astype(jnp.float32), router,
                      precision=lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, choice = lax.top_k(probs, top_k)
-    return gates / jnp.sum(gates, axis=-1, keepdims=True), choice, probs
+    if score == "softmax":
+        if bias is not None:
+            raise ValueError("a choice-only bias belongs to score='sigmoid'")
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, choice = lax.top_k(probs, top_k)
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    elif score == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        _, choice = lax.top_k(s if bias is None else s + bias, top_k)
+        gates = jnp.take_along_axis(s, choice, axis=-1)
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+        probs = s / jnp.sum(s, axis=-1, keepdims=True)
+    else:
+        raise ValueError(f"score {score!r}: expected 'softmax' or 'sigmoid'")
+    if gate_scale != 1.0:
+        gates = gates * gate_scale
+    return gates, choice, probs
 
 
-def moe_held(p: MoEParams, x, *, top_k: int, held=None):
+def moe_held(p: MoEParams, x, *, top_k: int, held=None,
+             score: str = "softmax", bias=None, gate_scale: float = 1.0,
+             shared=None):
     """Drop-free MoE on [T, d] tokens over the experts held here.
 
     p.router is [d, E] (all E experts), p.w_* hold experts [lo, hi) = `held`
-    (None: all E).  Softmax over all E in float32, the top k renormalised to
-    sum 1; every (token, choice) pair whose expert is held is computed,
-    whatever the load, and nothing else is: the pairs are sorted by expert,
-    the tokens gathered in that order, three grouped matrix products
-    (SwiGLU) run over the held groups, and the gated results are summed
-    back per token.  Choices on absent experts add nothing here (on the
-    chips that hold them, in a deployment).
+    (None: all E).  The router (`route`: `score`, `bias`, `gate_scale`)
+    scores all E in float32 and chooses k; every (token, choice) pair whose
+    expert is held is computed, whatever the load, and nothing else is: the
+    pairs are sorted by expert, the tokens gathered in that order, three
+    grouped matrix products (SwiGLU) run over the held groups, and the gated
+    results are summed back per token.  Choices on absent experts add
+    nothing here (on the chips that hold them, in a deployment).
+
+    `shared` = (w_gate [d, f], w_up [d, f], w_down [f, d]): the shared
+    experts, one SwiGLU every local token takes, ungated, added once beside
+    the held experts' part (every chip of an expert-parallel group computes
+    it for its own tokens: it is not a share).
 
     Returns (y [T, d], aux, MoEStats): aux is the load-balancing loss over
     ALL E experts (E * sum_e fraction of the T*k choices on e * mean router
     probability of e), which every chip of an expert-parallel group
     computes alike.
 
-    Scopes: obs.model.moe.router / .dispatch / .experts (docs/observability.md).
+    Scopes: obs.model.moe.router / .dispatch / .experts / .shared
+    (docs/observability.md).
     """
     t, d = x.shape
     e = p.router.shape[1]
@@ -361,7 +395,8 @@ def moe_held(p: MoEParams, x, *, top_k: int, held=None):
         raise ValueError(
             f"held experts [{lo}, {hi}) but weights for {p.w_gate.shape[0]}")
     with jax.named_scope("obs.model.moe.router"):
-        gates, choice, probs = route(p.router, x, top_k)   # [T, k]
+        gates, choice, probs = route(p.router, x, top_k, score=score,
+                                     bias=bias, gate_scale=gate_scale)
         chosen = jnp.sum(jax.nn.one_hot(choice, e, dtype=jnp.float32),
                          axis=(0, 1)) / (t * top_k)
         aux = e * jnp.sum(chosen * jnp.mean(probs, axis=0))
@@ -389,6 +424,10 @@ def moe_held(p: MoEParams, x, *, top_k: int, held=None):
         ys = _grouped_matmul(jax.nn.silu(g) * u, p.w_down, sizes)
     with jax.named_scope("obs.model.moe.dispatch"):
         y = _combine(ys, gates, order, inv, live, top_k)
+    if shared is not None:
+        with jax.named_scope("obs.model.moe.shared"):
+            w_gate, w_up, w_down = shared
+            y = y + (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
     load = sizes.astype(jnp.float32)
     stats = MoEStats(
         slots_here=jnp.sum(load),

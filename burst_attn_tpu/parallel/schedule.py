@@ -882,7 +882,8 @@ def wire_itemsize(wire: Optional[str], dense_itemsize: int = 4) -> int:
 
 def wire_round_bytes(pass_: str, wire: Optional[str], *, b: int, n: int,
                      n_kv: int, s: int, d: int, opt_comm: bool = True,
-                     itemsize: int = 4) -> Dict[str, int]:
+                     itemsize: int = 4, d_v: Optional[int] = None
+                     ) -> Dict[str, int]:
     """Per-ROUND per-DEVICE payload bytes each rotating stream ships over
     one ring hop, by stream name:
 
@@ -895,7 +896,8 @@ def wire_round_bytes(pass_: str, wire: Optional[str], *, b: int, n: int,
     streams ship 1 byte/element plus one fp32 scale per quantized block at
     the scan ring's granularity (fwd: per (batch, kv head); bundle: per
     (batch, head) per operand; dq: per (batch, head)); lse always ships
-    b*n*s fp32.  Shapes are PER-SHARD.
+    b*n*s fp32.  Shapes are PER-SHARD.  `d` is the width of q and k (and
+    dq), `d_v` of v, o and do (None: d).
 
     This is THE byte derivation: the burst.wire_bytes counters integrate
     it per dispatch, and the burstcost roofline re-derives it
@@ -904,15 +906,16 @@ def wire_round_bytes(pass_: str, wire: Optional[str], *, b: int, n: int,
     here that the model doesn't mirror fails the gate."""
     wi = wire_itemsize(wire, itemsize)
     scale_b = 0 if wire is None else 4
+    d_v = d if d_v is None else d_v
     if pass_ == "fwd":
-        kv = 2 * b * n_kv * s * d * wi + 2 * b * n_kv * scale_b
+        kv = b * n_kv * s * (d + d_v) * wi + 2 * b * n_kv * scale_b
         return {"kv": kv}
     if pass_ != "bwd":
         raise ValueError(f"pass_ must be 'fwd' or 'bwd', got {pass_!r}")
     # bundle: (delta | o), do, q quantize; lse stays fp32
     first = b * n * s * (4 if wire is None else 1) if opt_comm \
-        else b * n * s * d * wi
-    bundle = (first + 2 * b * n * s * d * wi      # do + q
+        else b * n * s * d_v * wi
+    bundle = (first + b * n * s * (d + d_v) * wi  # do + q
               + b * n * s * 4                      # lse (fp32, exempt)
               + 3 * b * n * scale_b)               # delta|o, do, q scales
     dq = b * n * s * d * (4 if wire is None else 1) + b * n * scale_b

@@ -26,12 +26,13 @@ def make_mesh(shape):
 
 def run_case(mesh_shape, layout, causal, kv_heads=4, optimize_bwd_comm=True,
              seq_per_dev=16, backend="jnp", n=4, d=16, n_segments=None,
-             window=None, **burst_kw):
+             window=None, d_v=None, **burst_kw):
     W = int(np.prod(mesh_shape))
     b = 1
     S = seq_per_dev * W
     mesh, names = make_mesh(mesh_shape)
-    q, k, v, do = random_qkv(KEY, b, n, S, d, kv_heads=kv_heads, dtype=jnp.float32)
+    q, k, v, do = random_qkv(KEY, b, n, S, d, kv_heads=kv_heads,
+                             dtype=jnp.float32, d_v=d_v)
 
     seg = None
     if n_segments:

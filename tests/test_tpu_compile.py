@@ -89,12 +89,15 @@ def _inplace_rounds():
 
 
 def _compile_attn_grad(mesh, *, seq, heads=32, kv_heads=32, backend="auto",
-                       layout="zigzag", window=None, grad=True):
+                       layout="zigzag", window=None, grad=True, d_qk=128,
+                       d_v=128):
     sharding = NamedSharding(mesh, P(None, None, "sp", None))
-    q = jax.ShapeDtypeStruct((1, heads, seq, 128), jnp.bfloat16,
+    q = jax.ShapeDtypeStruct((1, heads, seq, d_qk), jnp.bfloat16,
                              sharding=sharding)
-    kv = jax.ShapeDtypeStruct((1, kv_heads, seq, 128), jnp.bfloat16,
-                              sharding=sharding)
+    k = jax.ShapeDtypeStruct((1, kv_heads, seq, d_qk), jnp.bfloat16,
+                             sharding=sharding)
+    v = jax.ShapeDtypeStruct((1, kv_heads, seq, d_v), jnp.bfloat16,
+                             sharding=sharding)
 
     def fwd(q, k, v):
         return bat.burst_attn(q, k, v, mesh=mesh, causal=True, layout=layout,
@@ -104,7 +107,7 @@ def _compile_attn_grad(mesh, *, seq, heads=32, kv_heads=32, backend="auto",
         return jnp.sum(fwd(q, k, v).astype(jnp.float32) ** 2)
 
     fn = jax.grad(loss, (0, 1, 2)) if grad else fwd
-    return jax.jit(fn).lower(q, kv, kv).compile()
+    return jax.jit(fn).lower(q, k, v).compile()
 
 
 def test_grad_burst_attn_one_chip_64k(topo, on_chip):
@@ -132,6 +135,29 @@ def test_grad_burst_attn_sp4_at_the_multichip_length(topo, on_chip):
     text = c.as_text()
     assert text.count("collective-permute-start") > 0
     assert _mosaic_calls(text) > 2
+    assert _device_bytes(c) < HBM_BYTES
+
+
+@pytest.mark.parametrize("world,seq", [(1, 16384), (4, 131072)],
+                         ids=["the_cell_s_call", "sp4"])
+def test_grad_burst_attn_at_192_128(topo, on_chip, world, seq):
+    """Latent attention's widths (q, k 192; v 128) through the kernels and,
+    over four chips, through the ring's carries and payloads, at
+    `train_kanana2_mla_1x16k`'s call and at the multichip length: Mosaic
+    takes a 192-deep contraction and 192-wide dq / dk beside 128-wide
+    o / dv, nothing is padded to 256, and a chip's share fits it.  No cell
+    measures the ring at these widths (PERF.md section 7)."""
+    c = _compile_attn_grad(_seq_mesh(topo, world), seq=seq, d_qk=192,
+                           d_v=128)
+    text = c.as_text()
+    if world == 1:
+        assert _kernels(text) == ["burst_flash_bwd_tri", "burst_flash_fwd"]
+        assert _mosaic_calls(text) == 2
+    else:
+        assert text.count("collective-permute-start") > 0
+        assert _kernels(text) == ["burst_flash_bwd_rect",
+                                  "burst_flash_bwd_tri", "burst_flash_fwd"]
+    assert not re.findall(r"bf16\[1,32,\d+,256\]", text)
     assert _device_bytes(c) < HBM_BYTES
 
 
