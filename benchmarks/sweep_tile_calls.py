@@ -16,7 +16,15 @@ the forward's diagonal sub-square edge (`--edges`: the rows with an `edge`,
 and beside each the host seconds its call site took to trace and to lower,
 `trace_s` / `lower_s`: a Python-unrolled body's other cost, which lands in a
 cell's `setup_s`), then PR 34's of the 16,384-row call at 192 / 128 (`--only
-mla16k,causal16k`: the rows with `d_qk`).  Its four rows with `qk_feed` were
+mla16k,causal16k`: the rows with `d_qk`), then PR 35's of the backward's
+cut-block edge (`--edges --bwd`: the backward rows with an `edge`, and
+`compile_s` beside the other two).  Those rows carry `body` and `order`: what
+the tree holds is `body: "one traced position"`, `order: "cols"`; the rows
+of the forms that lost (`order: "rows"`: the square in row chunks; `body: "a
+position each"`: a Python-unrolled body for every position of a cut block
+under its kv block, beside a whole-tile masked fallback, which ran into the
+VMEM cliff at some geometries: 27 and 95 ms) were taken with trees that did
+not stay, and say so (`reproducible` false).  Its four rows with `qk_feed` were
 taken with a trace-time hook that did not stay, so this script cannot take
 them again (each row says so: `reproducible` false): the 192-deep score
 product as it is (`whole`), or as `dot(q[:, :128], k[:, :128]) + dot(q[:,
@@ -46,6 +54,11 @@ GEOMETRIES = {
     # v, o 128), and the same rows at 128 / 128 beside it
     "mla16k": dict(s=16384, b=1, n=32, n_kv=32, unit=1, d_qk=192, d_v=128),
     "causal16k": dict(s=16384, b=1, n=32, n_kv=32, unit=1),
+    # PR 35: the triangular backward at 8,192 rows and at op_causal_64k's
+    "mha8k": dict(s=8192, b=1, n=32, n_kv=32, unit=1),
+    "causal64k": dict(s=65536, b=1, n=32, n_kv=32, unit=1),
+    # ring4_causal_128k's self round: a shard of 32,768 rows
+    "mha32k": dict(s=32768, b=1, n=32, n_kv=32, unit=1),
 }
 
 SQUARES = [(128, 128), (256, 256), (512, 512), (1024, 1024)]
@@ -54,6 +67,11 @@ ROW_FWD, ROW_BWD = (2048, 2048, True), (1024, 2048)
 # tiles: 0 (the whole tile on the masked path, what every forward entry
 # without an edge runs: the tile sizes were swept on it), then the edges
 EDGES = [ROW_FWD + (e,) for e in (0, 1024, 512, 256, 128, 64)]
+# the sub-square edge of the backward's cut blocks (PR 35) in the row's
+# blocks: 0 (the whole block on the masked path, what every backward entry
+# without an edge ran), then the edges
+BWD_EDGES = [ROW_BWD + (e,) for e in (0, 256)]
+BWD_EDGES_8K = BWD_EDGES + [ROW_BWD + (e,) for e in (512, 128)]
 # {(geometry, call, pass): [(block_q, block_kv[, ask for the all-live
 # grid[, diagonal sub-square edge]]), ...]}; the first entry of each list is
 # the reference the others are compared with.  Calls: the three quadrants
@@ -107,6 +125,18 @@ SWEEPS = {
     ("causal8k", "clean", "edge"): EDGES,
     # one 1024 x 1024 tile a head on the rectangular grid: edge 1024 is 0
     ("causal1k", "clean", "edge"): [EDGES[0]] + EDGES[2:],
+    # the backward by edge (`--edges --bwd`): the rectangular kernel at 32 / 8
+    # and 32 / 4 heads (the latter in block units, with and without a carry),
+    # the triangular one at 32 / 32 and at 192 / 128
+    ("causal8k", "clean", "bwd_edge"): BWD_EDGES_8K,
+    ("mha8k", "clean", "bwd_edge"): BWD_EDGES_8K,
+    ("bd8k", "clean", "bwd_edge"): BWD_EDGES,
+    ("bd8k", "below", "bwd_edge"): BWD_EDGES,
+    ("causal1k", "clean", "bwd_edge"): BWD_EDGES,
+    ("causal16k", "clean", "bwd_edge"): BWD_EDGES,
+    ("mla16k", "clean", "bwd_edge"): BWD_EDGES,
+    ("mha32k", "clean", "bwd_edge"): BWD_EDGES,
+    ("causal64k", "clean", "bwd_edge"): BWD_EDGES,
 }
 
 
@@ -119,6 +149,9 @@ def main():
                    help="only the first N configurations of each sweep")
     p.add_argument("--edges", action="store_true",
                    help="only the sweeps of the diagonal sub-square edge")
+    p.add_argument("--bwd", action="store_true",
+                   help="with --edges: the backward's sweeps, not the "
+                        "forward's")
     args = p.parse_args()
 
     import os
@@ -178,10 +211,11 @@ def main():
 
     only = [g for g in args.only.split(",") if g]
     for (gname, call, pass_), configs in SWEEPS.items():
-        if (only and gname not in only) or args.edges != (pass_ == "edge"):
+        wanted = ({"bwd_edge" if args.bwd else "edge"} if args.edges
+                  else {"fwd", "bwd"})
+        if (only and gname not in only) or pass_ not in wanted:
             continue
-        if pass_ == "edge":
-            pass_ = "fwd"
+        pass_ = {"edge": "fwd", "bwd_edge": "bwd"}.get(pass_, pass_)
         g = GEOMETRIES[gname]
         s, b, n, n_kv, unit = g["s"], g["b"], g["n"], g["n_kv"], g["unit"]
         d, d_v = g.get("d_qk", 128), g.get("d_v", 128)
@@ -255,12 +289,27 @@ def main():
                         row.update(trace_s=round(t1 - t0, 3), lower_s=round(
                             time.perf_counter() - t1, 3))
                 else:
+                    more = {}
+                    if len(cfg) > 2:
+                        row["edge"] = cfg[2]
+                        more = dict(diag_block=cfg[2])
                     fn = jax.jit(lambda do, q, k, v, delta, lse, *c, bq=bq,
-                                 bkv=bkv: pf.flash_bwd(
+                                 bkv=bkv, more=more: pf.flash_bwd(
                         do, q, k, v, delta, lse, scale, spec, block_q=bq,
                         block_kv=bkv, triangular=True,
-                        window=window, carry=c or None))
+                        window=window, carry=c or None, **more))
                     xs = (do, q, k, v, delta, lse) + (carry or ())
+                    if more:
+                        t0 = time.perf_counter()
+                        traced = fn.trace(*xs)
+                        t1 = time.perf_counter()
+                        lowered = traced.lower()
+                        t2 = time.perf_counter()
+                        fn = lowered.compile()
+                        row.update(trace_s=round(t1 - t0, 3),
+                                   lower_s=round(t2 - t1, 3),
+                                   compile_s=round(
+                                       time.perf_counter() - t2, 3))
                 flash, other, names, out = device_ms(fn, *xs)
                 row.update(flash_ms=round(flash, 4), other_ms=round(other, 4),
                            ops={k_: round(t, 4) for k_, t in names.items()})

@@ -404,11 +404,13 @@ def fwd_covers_ranges(s_q, s_kv, q_range, kv_range, *, block_q, block_kv,
 
 
 class DiagPath(NamedTuple):
-    """What serves the diagonal tiles of a forward call that promises
-    `triangular` (fwd_diag_path)."""
+    """What serves the tiles the causal diagonal cuts in a forward or a
+    backward call that promises `triangular` (fwd_diag_path, bwd_diag_path)."""
 
-    path: str  # "sub": _fwd_kernel's _sweep_diag; "whole": its sweep(True)
-    tiles: int  # diagonal tiles a (batch, head): the q blocks of the grid
+    # "sub": the tile's live sub-squares only (_fwd_kernel._sweep_diag,
+    # _bwd_cut_tile); "whole": its whole area on the masked path
+    path: str
+    tiles: int  # such tiles a (batch, head): the q blocks of the pass's grid
     edge: Optional[int]  # the sub-square edge where path == "sub"
 
 
@@ -446,6 +448,66 @@ def fwd_diag_path(s_q, s_kv, *, block_q, block_kv, triangular, window=None,
            and diag_block % unit == 0)
     return DiagPath("sub", nqb, diag_block) if sub else DiagPath(
         "whole", nqb, None)
+
+
+def _bwd_diag_edge(kernel, s_q, s_kv, *, block_q, block_kv, triangular,
+                   window, segments, diag_block, loop_sweep):
+    """The sub-square edge of the cut blocks of ONE fused backward launch on
+    exact tiles (`kernel` "tri" or "rect"), or None where the blocks the
+    diagonal cuts keep the whole tile (bwd_diag_path has the conditions)."""
+    bq, bkv = _pick_block(s_q, block_q), _pick_block(s_kv, block_kv)
+    unit, win = unit_of(window)
+    sub = (bool(triangular) and kernel in ("tri", "rect") and s_q == s_kv
+           and win is None and not segments and not loop_sweep
+           and bkv % bq == 0 and 0 < diag_block < bkv
+           and bq % diag_block == 0 and diag_block % unit == 0)
+    return diag_block if sub else None
+
+
+def bwd_diag_path(n, n_kv, s_q, s_kv, d, *, block_q, block_kv, triangular,
+                  window=None, segments=False, q_range=None, kv_range=None,
+                  d_v=None, interpret=None, fused=None, block_kv_compute=None,
+                  diag_block=None, loop_sweep=False) -> Optional[DiagPath]:
+    """fwd_diag_path's twin for flash_bwd: whether the q blocks the causal
+    diagonal cuts (with the backward's block_kv = ratio * block_q, `ratio` of
+    them a kv block) compute their live sub-squares only (_bwd_cut_tile), or
+    their whole area on the masked path.  None where the caller makes no
+    `triangular` promise.  `tiles` counts the cut q blocks a (batch, head).
+    Static: flash_bwd chooses its kernel body by it, and the ring counts its
+    dispatches by it (parallel/burst.py, flash.diag_tiles{pass=bwd}).
+
+    The sub-square sweep lives in the two fused kernels (the wrapped-diagonal
+    one and the rectangular one with its in-place dq; the split kernels keep
+    the whole tile: _bwd_kernel_of on these shapes decides, so off the chip
+    the default is "whole") and needs what fwd_diag_path's needs: no sliding
+    window (a masks.BlockUnits unit is fine), no `segments`, exact tiling
+    with block_kv a multiple of block_q, and an edge `diag_block` (None: the
+    generation's, ops/tuning.py; 0 turns the sweep off) that divides
+    block_q, is smaller than block_kv and is whole mask units.  The
+    fori_loop sweep (`loop_sweep`, BURST_BWD_LOOP) keeps the whole tile.  A
+    call with ranges or a carry is judged on the rows it covers: that is
+    what the kernel it reaches runs on, in place or sliced."""
+    s_q, s_kv = _range_len(q_range, s_q), _range_len(kv_range, s_kv)
+    if not triangular or s_q != s_kv:
+        return None
+    if interpret is None:
+        interpret = _interpret_default()
+    sq_pad = _padded_len(s_q, block_q)
+    nqb = sq_pad // _pick_block(sq_pad, block_q)
+    if diag_block is None:
+        diag_block = tuning.block_defaults().diag_block
+    edge = None
+    if sq_pad == s_q and _padded_len(s_kv, block_kv) == s_kv:
+        kernel = _bwd_kernel_of(
+            n, n_kv, s_q, s_kv, d, block_q=block_q, block_kv=block_kv,
+            interpret=interpret, fused=fused, triangular=triangular,
+            window=window, block_kv_compute=block_kv_compute, d_v=d_v)
+        edge = _bwd_diag_edge(
+            kernel, s_q, s_kv, block_q=block_q, block_kv=block_kv,
+            triangular=triangular, window=window, segments=segments,
+            diag_block=diag_block,
+            loop_sweep=loop_sweep or _bwd_loop_default())
+    return DiagPath("sub" if edge else "whole", nqb, edge)
 
 
 # ---------------------------------------------------------------------------
@@ -1436,6 +1498,127 @@ def _bwd_accum_tile(
     pend_flag[0] = 1
 
 
+def _bwd_cut_tile(
+    do_ref, q_ref, k_ref, v_ref, delta_ref, lse_ref, dv_scr, dk_scr,
+    iq, pos, mask_of, *, scale, bq, edge, lp, dq_update,
+):
+    """One fused-backward block pair that the causal diagonal cuts, in its
+    live sub-squares only (bwd_diag_path decides, statically).  `pos`
+    (traced) is how many q blocks this one lies below the kv block's first
+    row: under the caller's promise (full-window causal, offset 0 or -1, in
+    tokens or in whole mask units) its rows see all of the kv block's
+    columns [0, pos * bq), the square [pos * bq, (pos + 1) * bq) through the
+    diagonal and nothing right of it.  ONE body serves every position:
+
+      * the square, at the traced column offset pos * bq, in column chunks
+        of `edge` columns (_bwd_accum_tile_sub's order): chunk u meets the
+        rows of its own sub-square under `mask_of(u)` (_block_mask with the
+        real spec scalars, so offset -1 and block units stay exact), all the
+        rows below it at once without a mask (no iota, no select) and the
+        rows above it not at all.  dv / dk of a chunk are summed as values
+        and added to the scratch once; dq's rows are summed as values too;
+      * the columns left of the square one block_q-wide piece at a time,
+        each whole and without a mask under its own pl.when (piece `at`
+        exists where pos >= at).
+
+    The sums are the whole tile's in another order (float32 accumulation,
+    bf16 operands where they are bf16 there).  dk is folded HERE and
+    pend_flag left alone (0: the step's flush ran first): a cut block is the
+    last live step of its sweep, and its pairs are too few to hide a stash's
+    write and read behind.  dq leaves through `dq_update(rows, first)`:
+    first the square's assembled rows (a store, where the kernel adds dq in
+    place: the visit's one read of the aliased input), then each left
+    piece's on top of it, all inside the one grid step: a visit stays a
+    visit.
+
+    Measured on the v5e (benchmarks/sweep_tile_calls.py --edges --bwd; kernel
+    device ms of one call in the row's 1024 x 2048 blocks, 32 query heads x
+    128, bf16; PERF.md section 6, PR 35).  The 8,192-row call at 32 / 8
+    heads (the rectangular kernel; 6 full and 4 cut tile-units of
+    2048 x 2048): 10.27 whole, then by edge 512 / 256 / 128: 8.57 / 8.43 /
+    8.55 this way and 8.61 / 8.49 / 8.62 in row chunks (the forward's order:
+    chunk r against the r sub-squares left of its own at once; each chunk
+    then reads, adds to and writes back its columns' dv / dk scratch rows);
+    at 32 / 32 heads (the triangular kernel) 9.91 whole, 8.47 / 8.33 / 8.45
+    and 8.51 / 8.39 / 8.52; the 1,024-row call x batch 8 2.147 whole, 1.320
+    at 256 (rows: 1.374); 16,384 rows at 192 / 128 54.28 whole, 49.46
+    (49.72); 65,536 rows 506.9 whole, 494.2 (494.7).  A cut tile-unit:
+    1.05-1.08 ms whole, 0.62-0.65 at 256 (a full one 0.95-0.99).
+
+    Why one traced position and not a body a position: Mosaic gives every
+    pl.when body its own intermediates, and past the scoped-VMEM budget a
+    kernel runs three times slower (the block-area cliff of ops/tuning.py).
+    A body a position, beside a whole-tile masked body kept for blocks that
+    cannot occur, sat on that brink at an edge of 256: the 8,192-row call
+    read 8.46 ms at 32 / 8 heads but 27.3 in block units and 27.5 at an edge
+    of 512, and the 16,384-row call at 192 / 128 95.2.  This form reads the
+    same to 0.01 ms with VMEM_LIMIT at 88 MiB as at 100."""
+    e = edge
+    n_chunks = bq // e
+    base = pl.multiple_of(pos * bq, bq)
+    qs_scale = scale * LOG2E
+    lse_row = _read_rows(lse_ref, iq, bq, lp)
+    lse_row = jnp.where(lse_row == NEG_INF, BIG_LSE, lse_row * LOG2E)
+    delta_row = _read_rows(delta_ref, iq, bq, lp)
+
+    def chain(rows, cols, mask):
+        """p and ds of the rows x cols piece, dp issued beside the scores."""
+        s = jax.lax.dot_general(
+            q_ref[0, 0, rows, :] * qs_scale, k_ref[0, 0, cols, :],
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(
+            do_ref[0, 0, rows, :], v_ref[0, 0, cols, :],
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        p = jnp.exp2(s - lse_row[rows])
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
+        return p, p * (dp - delta_row[rows])
+
+    def grads(rows, cols, mask):
+        """(dv, dk) of the columns and dq of the rows from one piece."""
+        p, ds = chain(rows, cols, mask)
+        do, q, k_c = (do_ref[0, 0, rows, :], q_ref[0, 0, rows, :],
+                      k_ref[0, 0, cols, :])
+        t_dims = (((0,), (0,)), ((), ()))  # a^T @ b
+        return (
+            jax.lax.dot_general(p.astype(do.dtype), do, t_dims,
+                                preferred_element_type=jnp.float32),
+            jax.lax.dot_general(ds.astype(q.dtype), q, t_dims,
+                                preferred_element_type=jnp.float32),
+            jax.lax.dot_general(ds.astype(k_c.dtype), k_c,
+                                (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32))
+
+    def add_to(scr, cols, x):
+        scr[cols, :] = scr[cols, :] + x
+
+    dq_rows = [None] * n_chunks
+
+    def add_dq(r, x):
+        dq_rows[r] = x if dq_rows[r] is None else dq_rows[r] + x
+
+    for u in range(n_chunks):
+        cols = pl.ds(base + u * e, e)
+        dv_u, dk_u, dq_u = grads(slice(u * e, (u + 1) * e), cols, mask_of(u))
+        add_dq(u, dq_u)
+        if u + 1 < n_chunks:
+            dv_b, dk_b, dq_b = grads(slice((u + 1) * e, bq), cols, None)
+            dv_u, dk_u = dv_u + dv_b, dk_u + dk_b
+            for r in range(u + 1, n_chunks):
+                add_dq(r, dq_b[(r - u - 1) * e:(r - u) * e])
+        add_to(dv_scr, cols, dv_u)
+        add_to(dk_scr, cols, dk_u)
+    dq_update(jnp.concatenate(dq_rows, axis=0), True)
+    for at in range(1, k_ref.shape[2] // bq):
+        @pl.when(pos >= at)
+        def _left(at=at):
+            cols = slice((at - 1) * bq, at * bq)
+            dv_l, dk_l, dq_l = grads(slice(0, bq), cols, None)
+            add_to(dv_scr, cols, dv_l)
+            add_to(dk_scr, cols, dk_l)
+            dq_update(dq_l, False)
+
+
 # ---------------------------------------------------------------------------
 # backward: fused kernel (dq + dk + dv in one pass)
 #
@@ -1485,12 +1668,14 @@ def _bwd_fused_kernel(
     do_ref, q_ref, k_ref, v_ref, delta_ref, lse_ref, dq_in_ref,
     *rest,
     scale, bq, bkv, lp, n_q_blocks, group, nbq, wnd=None, seg=False,
-    carry=False, q_off=0,
+    carry=False, q_off=0, diag=None,
 ):
     # carry: dk, dv of the rounds before arrive as inputs aliased to the
     # outputs, and _finish adds this round's to them.  q_off: the sweep's
     # first q block in the full delta / lse arrays (a sub-range round, see
     # flash_bwd); iq, r0, c0 and the masks stay local to the range.
+    # diag: the sub-square edge where the blocks the diagonal cuts take
+    # _bwd_cut_tile (bwd_diag_path decides, statically), else None.
     if carry:
         dk_in_ref, dv_in_ref = rest[0], rest[1]
         rest = rest[2:]
@@ -1554,10 +1739,32 @@ def _bwd_fused_kernel(
     def _compute_fast():
         _accum(None)
 
-    @pl.when(masked_cond)
-    def _compute_masked():
-        _accum(_block_mask(spec_ref, r0, c0, bq, bkv, wnd,
-                           seg=(qs_tile, ks_tile) if seg else None))
+    if diag is None:
+        @pl.when(masked_cond)
+        def _compute_masked():
+            _accum(_block_mask(spec_ref, r0, c0, bq, bkv, wnd,
+                               seg=(qs_tile, ks_tile) if seg else None))
+    else:
+        # under the caller's promise the masked blocks are exactly the
+        # bkv // bq that the diagonal cuts, `pos` q blocks below kv block
+        # j's first row; no whole-tile masked body is kept beside the cut
+        # one (its intermediates would count against the same VMEM, see
+        # _bwd_cut_tile).  The visit of the dq block is the whole tile's:
+        # one read of the aliased input, one block written back
+        def _dq_store(dq_rows, first):
+            prev = dq_in_ref if first else dq_out_ref
+            dq_out_ref[0, 0, :, :] = prev[0, 0, :, :] + scale * dq_rows
+
+        @pl.when(masked_cond)
+        def _compute_cut():
+            pos = iq - j * (bkv // bq)
+            _bwd_cut_tile(
+                do_ref, q_ref, k_ref, v_ref, delta_ref, lse_ref, dv_scr,
+                dk_scr, _shift(iq, q_off), pos,
+                lambda r: _block_mask(
+                    spec_ref, r0 + r * diag, c0 + pos * bq + r * diag,
+                    diag, diag, wnd),
+                scale=scale, bq=bq, edge=diag, lp=lp, dq_update=_dq_store)
 
     @pl.when(~live & ~clamped)
     def _passthrough():
@@ -1759,6 +1966,7 @@ def _bwd_fused_tri_kernel(
     do_ref, q_ref, k_ref, v_ref, delta_ref, lse_ref,
     *rest,
     scale, bq, bkv, bkvc, lp, nqb, nkb, ratio, seg=False, loop=False,
+    diag=None,
 ):
     """Wrapped-diagonal causal backward (static full-window causal with
     offset 0 or -1 — see the flash_fwd docstring's triangular contract —
@@ -1774,6 +1982,10 @@ def _bwd_fused_tri_kernel(
     separation constraint.  dk/dv write at segment ends through an output
     index map lagged one step (jsel(c-1)), with one trailing no-compute step
     (c == C) to flush the final dk pend and write segment B's dk/dv.
+
+    `diag`: the sub-square edge where the `ratio` blocks the diagonal cuts
+    at each segment's end take _bwd_cut_tile (bwd_diag_path decides,
+    statically), else None: the whole tile on the masked path.
     """
     if seg:
         qseg_ref, kvseg_ref = rest[0], rest[1]
@@ -1862,14 +2074,31 @@ def _bwd_fused_tri_kernel(
     def _compute_fast():
         _accum(False)
 
-    @pl.when(compute & ~full)
-    def _compute_masked():
-        _accum(True)
+    if diag is None:
+        @pl.when(compute & ~full)
+        def _compute_masked():
+            _accum(True)
+    else:
+        @pl.when(compute & ~full)
+        def _compute_cut():
+            # `pos` q blocks below its kv block's first row
+            pos = iq - jk * ratio
+            _bwd_cut_tile(
+                do_ref, q_ref, k_ref, v_ref, delta_ref, lse_ref, dv_scr,
+                dk_scr, iq, pos,
+                lambda r: _block_mask(
+                    spec_ref, r0 + r * diag, c0 + pos * bq + r * diag,
+                    diag, diag),
+                scale=scale, bq=bq, edge=diag, lp=lp,
+                dq_update=lambda dq_rows, first: _dq_update(dq_rows))
 
 
-def _flash_bwd_fused_tri(do, q, k, v, delta, lse, scale, spec, *,
-                         block_q, block_kv, interpret, block_kv_compute=None,
-                         segments=None, loop_sweep=False):
+def _bwd_tri_launch(spec_arr, do, q, k, v, delta_p, lse_p, *seg_ids, scale,
+                    block_q, block_kv, interpret, block_kv_compute,
+                    loop_sweep, diag):
+    """The wrapped-diagonal backward kernel on exact tiles: delta and lse
+    packed, then the segment ids as [B, S, 1] / [B, 1, S] where the call has
+    them; every keyword static."""
     b, n, s_q, d = q.shape
     s_kv, d_v = k.shape[2], v.shape[-1]
     bq = _pick_block(s_q, block_q)
@@ -1917,22 +2146,18 @@ def _flash_bwd_fused_tri(do, q, k, v, delta, lse, scale, spec, *,
         state_block,
         state_block,
     ]
-    inputs = [_spec_array(spec), do, q, k, v, _pack(delta, lp),
-              _pack(lse, lp)]
-    if segments is not None:
+    if seg_ids:
         in_specs.append(pl.BlockSpec(
             (1, bq, 1),
             lambda b_, h, p, c, sp: (b_, q_map(b_, h, p, c, sp)[2], 0)))
         in_specs.append(pl.BlockSpec(
             (1, 1, bkv),
             lambda b_, h, p, c, sp: (b_, 0, kv_map(b_, h, p, c, sp)[2])))
-        inputs.append(jnp.asarray(segments[0], jnp.int32)[:, :, None])
-        inputs.append(jnp.asarray(segments[1], jnp.int32)[:, None, :])
-    dq, dk, dv = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(
             _bwd_fused_tri_kernel, scale=scale, bq=bq, bkv=bkv, bkvc=bkvc,
-            lp=lp, nqb=nqb, nkb=nkb, ratio=ratio, seg=segments is not None,
-            loop=loop_sweep,
+            lp=lp, nqb=nqb, nkb=nkb, ratio=ratio, seg=bool(seg_ids),
+            loop=loop_sweep, diag=diag,
         ),
         name="burst_flash_bwd_tri",
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1962,8 +2187,7 @@ def _flash_bwd_fused_tri(do, q, k, v, delta, lse, scale, spec, *,
             dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(*inputs)
-    return dq, dk, dv
+    )(spec_arr, do, q, k, v, delta_p, lse_p, *seg_ids)
 
 
 def _check_unit_tiles(window, bq, bkv):
@@ -1983,9 +2207,13 @@ def bwd_band_nbq(bq, bkv, nqb, window):
     return min(nqb, bwd_band_nb(bq // unit, bkv // unit, win))
 
 
-def _flash_bwd_fused(do, q, k, v, delta, lse, scale, spec, *,
-                     block_q, block_kv, interpret, window=None,
-                     segments=None, q_range=None, kv_range=None, carry=None):
+def _bwd_rect_launch(spec_arr, do, q, k, v, delta_p, lse_p, dq0, *rest,
+                     scale, block_q, block_kv, interpret, window, q_range,
+                     kv_range, carry, seg, diag):
+    """The fused rectangular backward kernel on exact tiles: delta and lse
+    packed, the zeros dq accumulates into, then (dk, dv) of the rounds
+    before where `carry`, then the segment ids as [B, S, 1] / [B, 1, S]
+    where `seg`; every keyword static."""
     b, n, sq_full, d = q.shape
     n_kv, skv_full, d_v = k.shape[1], k.shape[2], v.shape[-1]
     # the lengths the grid covers; the arrays keep their full length
@@ -2016,8 +2244,6 @@ def _flash_bwd_fused(do, q, k, v, delta, lse, scale, spec, *,
         return (b_, h, _shift(j, kv_off), 0)
 
     bstate_block = pl.BlockSpec((1, 1, sq_full // lp, lp), bstate_map)
-    # full-size: a q_range round visits only its rows and the rest stay zero
-    dq0 = jnp.zeros((b, n, sq_full, d), jnp.float32)
     in_specs = [
         pl.BlockSpec((1, 1, bq, d_v), bq_map),
         pl.BlockSpec((1, 1, bq, d), bq_map),
@@ -2027,35 +2253,25 @@ def _flash_bwd_fused(do, q, k, v, delta, lse, scale, spec, *,
         bstate_block,
         pl.BlockSpec((1, 1, bq, d), bq_map),
     ]
-    inputs = [_spec_array(spec), do, q, k, v, _pack(delta, lp),
-              _pack(lse, lp), dq0]
     # flattened input index 7 = dq0 (after the scalar-prefetch spec array)
     aliases = {7: 0}
-    if carry is None and s_kv != skv_full:
-        # the kv blocks outside the range are never written: they are zeros
-        dk0 = jnp.zeros((b, n_kv, skv_full, d), jnp.float32)
-        carry = (dk0, dk0 if d_v == d else jnp.zeros(
-            (b, n_kv, skv_full, d_v), jnp.float32))
-    if carry is not None:
+    if carry:
         # each dk/dv block is read before its sweep and written after it,
         # once: the alias needs no separation argument (unlike dq's)
         in_specs += [pl.BlockSpec((1, 1, bkv, w), bkv_map) for w in (d, d_v)]
-        inputs += list(carry)
         aliases.update({8: 1, 9: 2})
-    if segments is not None:
-        # seg ids appended LAST so the alias indices above stay stable
+    if seg:
+        # seg ids come LAST so the alias indices above stay stable
         in_specs.append(pl.BlockSpec(
             (1, bq, 1),
             lambda b_, h, j, t, sp: (b_, bq_map(b_, h, j, t, sp)[2], 0)))
         in_specs.append(pl.BlockSpec(
             (1, 1, bkv), lambda b_, h, j, t, sp: (b_, 0, _shift(j, kv_off))))
-        inputs.append(jnp.asarray(segments[0], jnp.int32)[:, :, None])
-        inputs.append(jnp.asarray(segments[1], jnp.int32)[:, None, :])
-    dq, dk, dv = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(
             _bwd_fused_kernel, scale=scale, bq=bq, bkv=bkv, lp=lp,
             n_q_blocks=nqb, group=group, nbq=nbq, wnd=window,
-            seg=segments is not None, carry=carry is not None, q_off=q_off,
+            seg=seg, carry=carry, q_off=q_off, diag=diag,
         ),
         # the banded sweep under its own name, as the forward's band grid
         name="burst_flash_bwd_" + ("band" if nbq < nqb else "rect"),
@@ -2089,8 +2305,74 @@ def _flash_bwd_fused(do, q, k, v, delta, lse, scale, spec, *,
             dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(*inputs)
-    return dq, dk, dv
+    )(spec_arr, do, q, k, v, delta_p, lse_p, dq0, *rest)
+
+
+def _bwd_launch(*arrays, kernel, **static):
+    """One fused backward kernel ("tri" or "rect") on exact tiles."""
+    if kernel == "tri":
+        return _bwd_tri_launch(*arrays, **static)
+    return _bwd_rect_launch(*arrays, **static)
+
+
+# flash_fwd's arrangement (see _fwd_launch_traced) for the two fused backward
+# kernels: one trace and one lowered body a DISTINCT backward call, not one a
+# layer.  _bwd_cut_tile is a Python-unrolled loop a position, and a model
+# makes one backward call a layer: trace + lower of jax.grad over four
+# jax.checkpoint(burst_attn) blocks at 1 x 8,192 rows for a described v5e
+# (benchmarks/trace_cost.py, this repo's CPU host; ops/tuning.py has the
+# numbers by edge).  The jit holds the kernel launch and nothing else: the
+# spec's stacking, the state's packing, the zeros dq accumulates into and a
+# range round's zero carry stay outside it, in the order the kernels' callers
+# always made them.  The split kernels (two launches, bodies that did not
+# grow) stay in line in flash_bwd.
+_bwd_launch_traced = jax.jit(_bwd_launch, static_argnames=(
+    "kernel", "scale", "block_q", "block_kv", "interpret", "window",
+    "q_range", "kv_range", "carry", "seg", "block_kv_compute", "loop_sweep",
+    "diag"))
+
+
+def _flash_bwd_fused_tri(do, q, k, v, delta, lse, scale, spec, *,
+                         block_q, block_kv, interpret, block_kv_compute=None,
+                         segments=None, loop_sweep=False, diag=None):
+    lp = _pick_block(_pick_block(q.shape[2], block_q), 128)
+    arrays = [_spec_array(spec), do, q, k, v, _pack(delta, lp),
+              _pack(lse, lp)]
+    if segments is not None:
+        arrays.append(jnp.asarray(segments[0], jnp.int32)[:, :, None])
+        arrays.append(jnp.asarray(segments[1], jnp.int32)[:, None, :])
+    return _bwd_launch_traced(
+        *arrays, kernel="tri", scale=scale, block_q=block_q,
+        block_kv=block_kv, interpret=interpret,
+        block_kv_compute=block_kv_compute, loop_sweep=loop_sweep, diag=diag)
+
+
+def _flash_bwd_fused(do, q, k, v, delta, lse, scale, spec, *,
+                     block_q, block_kv, interpret, window=None,
+                     segments=None, q_range=None, kv_range=None, carry=None,
+                     diag=None):
+    b, n, sq_full, d = q.shape
+    n_kv, skv_full, d_v = k.shape[1], k.shape[2], v.shape[-1]
+    lp = _pick_block(_pick_block(_range_len(q_range, sq_full), block_q), 128)
+    # full-size: a q_range round visits only its rows and the rest stay zero
+    dq0 = jnp.zeros((b, n, sq_full, d), jnp.float32)
+    arrays = [_spec_array(spec), do, q, k, v, _pack(delta, lp),
+              _pack(lse, lp), dq0]
+    if carry is None and _range_len(kv_range, skv_full) != skv_full:
+        # the kv blocks outside the range are never written: they are zeros
+        dk0 = jnp.zeros((b, n_kv, skv_full, d), jnp.float32)
+        carry = (dk0, dk0 if d_v == d else jnp.zeros(
+            (b, n_kv, skv_full, d_v), jnp.float32))
+    if carry is not None:
+        arrays += list(carry)
+    if segments is not None:
+        arrays.append(jnp.asarray(segments[0], jnp.int32)[:, :, None])
+        arrays.append(jnp.asarray(segments[1], jnp.int32)[:, None, :])
+    return _bwd_launch_traced(
+        *arrays, kernel="rect", scale=scale, block_q=block_q,
+        block_kv=block_kv, interpret=interpret, window=window,
+        q_range=q_range, kv_range=kv_range, carry=carry is not None,
+        seg=segments is not None, diag=diag)
 
 
 def _tri_bwd_other_residents(bq, bkv, d, itemsize=2, bkvc=None, d_v=None):
@@ -2191,7 +2473,7 @@ def flash_bwd(do, q, k, v, delta, lse, scale, spec: MaskSpec, *,
               block_q=1024, block_kv=1024, interpret=None, fused=None,
               triangular=False, window=None, segments=None,
               block_kv_compute=None, loop_sweep=False,
-              q_range=None, kv_range=None, carry=None):
+              q_range=None, kv_range=None, carry=None, diag_block=None):
     """One backward ring round on TPU.  Same contract as ops/tile.py:tile_bwd:
     returns (dq [B,N,S,D], dk [B,Nk,Skv,D], dv [B,Nk,Skv,Dv]) in float32.
     q and k are D wide; v and do are Dv wide (their own last axes: the
@@ -2219,14 +2501,33 @@ def flash_bwd(do, q, k, v, delta, lse, scale, spec: MaskSpec, *,
     fused=False takes precedence so the split kernels can always be
     A/B-compared.  `block_kv_compute` (tri path only) splits the kv block
     into compute sub-blocks — see _bwd_accum_tile_sub.
+
+    Under the `triangular` promise the q blocks the diagonal cuts (with
+    block_kv = ratio * block_q, `ratio` of them a kv block) compute their
+    live sub-squares only, in both fused kernels (bwd_diag_path has the
+    conditions, _bwd_cut_tile the body; `diag_block`: the sub-square edge,
+    None: the generation's `diag_block`, ops/tuning.py; 0: the whole
+    tile on the masked path).  The defaults and switches are resolved here;
+    a fused kernel's launch on exact tiles is traced behind ONE jit
+    (_bwd_launch_traced), as flash_fwd's is.
     """
     if interpret is None:
         interpret = _interpret_default()
     if not loop_sweep and _bwd_loop_default():
         loop_sweep = True  # BURST_BWD_LOOP promotion (see _bwd_loop_default)
+    if diag_block is None:
+        diag_block = tuning.block_defaults().diag_block
     b, n, s_q, d = q.shape
     n_kv, s_kv, d_v = k.shape[1], k.shape[2], v.shape[-1]
     group = _gqa_group(n, n_kv)
+
+    def diag_of(kernel, rows_q, rows_kv):
+        return _bwd_diag_edge(
+            kernel, rows_q, rows_kv, block_q=block_q, block_kv=block_kv,
+            triangular=triangular, window=window,
+            segments=segments is not None, diag_block=diag_block,
+            loop_sweep=loop_sweep)
+
     if q_range is not None or kv_range is not None or carry is not None:
         kw = dict(block_q=block_q, block_kv=block_kv, interpret=interpret,
                   fused=fused, triangular=triangular, window=window,
@@ -2237,11 +2538,14 @@ def flash_bwd(do, q, k, v, delta, lse, scale, spec: MaskSpec, *,
                 do, q, k, v, delta, lse, scale, spec, block_q=block_q,
                 block_kv=block_kv, interpret=interpret, window=window,
                 segments=segments, q_range=q_range, kv_range=kv_range,
-                carry=carry)
+                carry=carry, diag=diag_of(
+                    "rect", _range_len(q_range, s_q),
+                    _range_len(kv_range, s_kv)))
         from .tile import bwd_on_ranges
 
         return bwd_on_ranges(
-            functools.partial(flash_bwd, loop_sweep=loop_sweep, **kw),
+            functools.partial(flash_bwd, loop_sweep=loop_sweep,
+                              diag_block=diag_block, **kw),
             do, q, k, v, delta, lse, scale, spec, segments=segments,
             q_range=q_range, kv_range=kv_range, carry=carry)
     sq_pad, skv_pad = _padded_len(s_q, block_q), _padded_len(s_kv, block_kv)
@@ -2276,13 +2580,14 @@ def flash_bwd(do, q, k, v, delta, lse, scale, spec: MaskSpec, *,
             do, q, k, v, delta, lse, scale, spec,
             block_q=block_q, block_kv=block_kv, interpret=interpret,
             block_kv_compute=block_kv_compute, segments=segments,
-            loop_sweep=loop_sweep,
+            loop_sweep=loop_sweep, diag=diag_of("tri", s_q, s_kv),
         )
     if kernel == "rect":
         return _flash_bwd_fused(
             do, q, k, v, delta, lse, scale, spec,
             block_q=block_q, block_kv=block_kv, interpret=interpret,
             window=window, segments=segments,
+            diag=diag_of("rect", s_q, s_kv),
         )
 
     # ---- dq ----

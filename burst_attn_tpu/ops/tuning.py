@@ -51,7 +51,7 @@ class BlockTable(NamedTuple):
     # on the v5e (benchmarks/sweep_tile_calls.py; the numbers are at
     # call_row); the other generations inherit it unswept.
     band_block: int = 512
-    # Sub-square edge of the forward's diagonal sweep (pallas_flash
+    # Sub-square edge of the diagonal sweeps.  The forward's (pallas_flash
     # fwd_diag_path / _fwd_kernel._sweep_diag): a causal call's diagonal tile
     # is computed in row chunks of this many rows, the squares above the
     # diagonal skipped and only the ones it cuts masked.  0 = the whole tile
@@ -68,6 +68,21 @@ class BlockTable(NamedTuple):
     # thirds of its trace (PR 32 shipped 128 traced once a layer and was
     # refused on setup_s; since PR 33 the body is traced once a distinct
     # call, so the edge is paid once a program length, not once a layer).
+    # The same edge serves the BACKWARD's cut blocks (pallas_flash
+    # bwd_diag_path / _bwd_cut_tile: the q blocks the diagonal cuts, the
+    # square it passes through in column chunks of this many columns).
+    # MEASURED on the v5e (sweep_tile_calls.py --edges --bwd, PR 35; kernel
+    # ms of the 8,192-row call at 32 / 8 heads, rectangular kernel / at
+    # 32 / 32, triangular kernel / of the 1,024-row call x 8):
+    #   0 (whole) 10.27 / 9.91 / 2.147    512  8.57 / 8.47
+    #   256        8.43 / 8.33 / 1.320    128  8.55 / 8.45
+    # Host seconds to trace + lower jax.grad over four jax.checkpoint(
+    # burst_attn) blocks at 1 x 8,192 rows, 32 / 8 heads, for a described v5e
+    # (trace_cost.py, this repo's CPU host, least of three to five): the
+    # parent of PR 35 0.53-0.57; the backward's launch behind flash_bwd's one
+    # jit with the whole tile 0.48; with the cut blocks at 256 0.55-0.60 (at
+    # 8 x 1,024 rows 0.49-0.51 against 0.50-0.54): the one trace takes back
+    # what the body adds.
     # The other generations inherit it unswept.
     diag_block: int = 256
 

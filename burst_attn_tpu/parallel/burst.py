@@ -72,11 +72,11 @@ _M_INPLACE = obs.counter(
     "carry in place) or path=xla (slice / run / add around the tile)")
 _M_DIAG = obs.counter(
     "flash.diag_tiles",
-    "diagonal tiles (q block i against kv block i, a batch and head each) of "
-    "the dispatch's forward tile calls that promise a full-window causal "
-    "mask, by pass and by what serves them: path=sub (the kernel computes "
-    "the tile's live sub-squares only) or path=whole (its whole area on the "
-    "masked path)")
+    "tiles the causal diagonal cuts (a batch and head each; pass=fwd: q "
+    "block i against kv block i, pass=bwd: the backward's q blocks) of the "
+    "dispatch's tile calls that promise a full-window causal mask, by pass "
+    "and by what serves them: path=sub (the kernel computes the tile's live "
+    "sub-squares only) or path=whole (its whole area on the masked path)")
 _M_HOPS = obs.counter(
     "burst.ring_hops", "scheduled KV ring hops, by mesh axis role")
 _M_WIRE = obs.counter(
@@ -309,24 +309,46 @@ def _promised_fwd_calls(cfg, s, s_kv, rounds, seg):
     return [(1 + later * (rounds - 1), s, cfg.window, seg)]
 
 
-def _diag_tiles(cfg, q_shape, k_shape, rounds, seg):
-    """{path: diagonal tiles} of one dispatch's forward calls, by the tile
-    entry's own static choice (pallas_flash.fwd_diag_path, on the blocks
-    _tile_fwd resolves)."""
+def _promised_bwd_calls(cfg, s, s_kv, rounds, seg):
+    """_promised_fwd_calls for the backward tile calls: the quadrants of a
+    block-diffusion stream (_bd_bwd), the zigzag split's own round and
+    every round of the striped one (_bwd_impl's compute; a contig ring's
+    backward promises nothing, its forward's self round does)."""
+    if cfg.block_diffusion is not None:
+        return _promised_fwd_calls(cfg, s, s_kv, rounds, seg)
+    if not (cfg.causal and cfg.case_split and s_kv == s
+            and cfg.layout in ("zigzag", "striped")):
+        return []
+    return [(rounds if cfg.layout == "striped" else 1, s, cfg.window, seg)]
+
+
+def _diag_tiles(cfg, q_shape, k_shape, rounds, seg, d_v):
+    """{(pass, path): diagonal tiles} of one dispatch's tile calls that
+    promise `triangular`, by the tile entry's own static choice
+    (pallas_flash.fwd_diag_path / bwd_diag_path, on the blocks _tile_fwd /
+    _tile_bwd resolve).  A backward tile is a q block of the backward's own
+    tiling that the diagonal cuts."""
     if cfg.backend != "pallas":
         return {}
     from ..ops import pallas_flash
 
-    (b, n, s, _), s_kv = q_shape, k_shape[2]
+    (b, n, s, d), (_, n_kv, s_kv, _) = q_shape, k_shape
     tiles = {}
-    for calls, rows, window, segs in _promised_fwd_calls(
-            cfg, s, s_kv, rounds, seg):
-        rb = cfg.resolved_blocks(rows, rows, window)
-        path = pallas_flash.fwd_diag_path(
-            rows, rows, block_q=rb.block_q, block_kv=rb.block_kv,
-            triangular=True, window=window, segments=segs)
-        tiles[path.path] = (tiles.get(path.path, 0)
-                            + calls * b * n * path.tiles)
+    for pass_, promised in (("fwd", _promised_fwd_calls),
+                            ("bwd", _promised_bwd_calls)):
+        for calls, rows, window, segs in promised(cfg, s, s_kv, rounds, seg):
+            rb = cfg.resolved_blocks(rows, rows, window)
+            if pass_ == "fwd":
+                path = pallas_flash.fwd_diag_path(
+                    rows, rows, block_q=rb.block_q, block_kv=rb.block_kv,
+                    triangular=True, window=window, segments=segs)
+            else:
+                path = pallas_flash.bwd_diag_path(
+                    n, n_kv, rows, rows, d, block_q=rb.block_q_bwd,
+                    block_kv=rb.block_kv_bwd, triangular=True, window=window,
+                    segments=segs, d_v=d_v)
+            key = (pass_, path.path)
+            tiles[key] = tiles.get(key, 0) + calls * b * n * path.tiles
     return tiles
 
 
@@ -1072,9 +1094,9 @@ def _note_dispatch(cfg: BurstConfig, mesh, q_shape, k_shape, batch_axes,
             in_kernel = _round_in_kernel(cfg, pass_, q_local, k_local, d_v)
             _M_INPLACE.inc(rounds - 1, **{
                 "pass": pass_, "path": "kernel" if in_kernel else "xla"})
-    for path, tiles in _diag_tiles(cfg, q_local, k_local, rounds,
-                                   seg).items():
-        _M_DIAG.inc(tiles, **{"pass": "fwd", "path": path})
+    for (pass_, path), tiles in _diag_tiles(cfg, q_local, k_local, rounds,
+                                            seg, d_v).items():
+        _M_DIAG.inc(tiles, **{"pass": pass_, "path": path})
     if intra_hops:
         _M_HOPS.inc(intra_hops, axis="intra")
     if inter_hops:

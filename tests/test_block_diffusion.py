@@ -185,6 +185,15 @@ def test_the_cell_s_attention_against_the_dense_mask_on_the_chip():
         "burst_flash_fwd", "burst_flash_fwd", "burst_flash_fwd_band",
         "burst_flash_bwd_rect", "burst_flash_bwd_rect",
         "burst_flash_bwd_band"]
+    # `clean` and `below` (the latter with the former's dk / dv as its
+    # carry) compute their cut blocks in sub-squares (PR 35)
+    from burst_attn_tpu.parallel.burst import BurstConfig
+    for quad in masks.bd_quadrants(2 * length, block)[:2]:
+        rb = BurstConfig().resolved_blocks(length, length, quad.window)
+        assert pf.bwd_diag_path(
+            heads, kv_heads, length, length, 128, block_q=rb.block_q_bwd,
+            block_kv=rb.block_kv_bwd, triangular=True,
+            window=quad.window).path == "sub"
     got, vjp = jax.vjp(attn, q, k, v)
     got = (got, *vjp(do))
     mask = jnp.asarray(masks.bd_dense_mask(2 * length, block))
@@ -326,6 +335,84 @@ def test_the_compiled_forward_s_diagonal_sweep_against_dense_on_the_chip(
         assert path.path == "sub"
         for name, err, top in zip(("o", "lse"), errs, peak):
             assert err < 4e-2 * max(1.0, top), (name, err)
+
+
+@on_the_chip
+@pytest.mark.parametrize("rows,batch,kv_heads", [
+    (8192, 1, 8),    # train_mistral_1x8k: the rectangular kernel, 32 / 8
+    (1024, 8, 8),    # train_mistral_8x1k: one 1024 x 1024 block a head
+    (8192, 1, 32),   # op_causal_64k's parity check: the triangular kernel
+])
+def test_the_compiled_backward_s_cut_blocks_against_dense_on_the_chip(
+        rows, batch, kv_heads):
+    """The compiled fused backward at the cells' own geometries (32 query
+    heads x 128, the tiles ops/tuning.py resolves, the generation's
+    sub-square edge) against the gradients of dense float32 softmax
+    attention, one query head at a time: dq, dk, dv.  In the rectangular
+    kernel dq is added in place: a race would show here."""
+    heads, d = 32, 128
+    ks = jax.random.split(jax.random.PRNGKey(rows + kv_heads), 4)
+    q, do = (jax.random.normal(k_, (batch, heads, rows, d), jnp.bfloat16)
+             for k_ in ks[:2])
+    k, v = (jax.random.normal(k_, (batch, kv_heads, rows, d), jnp.bfloat16)
+            for k_ in ks[2:])
+    rb = pf.resolve_blocks(s_q=rows, s_kv=rows)
+    path = pf.bwd_diag_path(heads, kv_heads, rows, rows, d,
+                            block_q=rb.block_q_bwd, block_kv=rb.block_kv_bwd,
+                            triangular=True)
+    assert path.path == "sub"
+    spec = masks.round_spec(jnp.int32(0), jnp.int32(0), rows, rows, True,
+                            "contig")
+    scale = d ** -0.5
+
+    @jax.jit
+    def mine(q, k, v, do):
+        m, lse, acc = pf.flash_fwd(q, k, v, None, None, None, scale, spec,
+                                   block_q=rb.block_q, block_kv=rb.block_kv,
+                                   triangular=True)
+        o = finalize(m, lse, acc, jnp.float32)
+        delta = jnp.sum(o * do.astype(jnp.float32), -1)
+        return pf.flash_bwd(do, q, k, v, delta, lse, scale, spec,
+                            block_q=rb.block_q_bwd, block_kv=rb.block_kv_bwd,
+                            triangular=True)
+
+    name = "burst_flash_bwd_" + ("tri" if kv_heads == heads else "rect")
+    assert name in str(jax.make_jaxpr(mine)(q, k, v, do))
+    got = mine(q, k, v, do)
+
+    @jax.jit
+    def head(q, k, v, do):
+        def dense(q, k, v):
+            s = jnp.einsum("bid,bjd->bij", q, k) * scale
+            s = jnp.where(jnp.tril(jnp.ones((rows, rows), bool)), s, -jnp.inf)
+            return jnp.einsum("bij,bjd->bid", jax.nn.softmax(s, -1), v)
+
+        return jax.vjp(dense, q, k, v)[1](do)
+
+    f32 = lambda x: x.astype(jnp.float32)
+    group = heads // kv_heads
+    errs, peak = np.zeros(3), np.zeros(3)
+
+    def hold(i, a, b):
+        errs[i] = max(errs[i], float(jnp.max(jnp.abs(a - b))))
+        peak[i] = max(peak[i], float(jnp.max(jnp.abs(b))))
+
+    with jax.default_matmul_precision("highest"):
+        for g in range(kv_heads):
+            dkv = [0.0, 0.0]
+            for h in range(g * group, (g + 1) * group):
+                dq, dk, dv = head(f32(q[:, h]), f32(k[:, g]), f32(v[:, g]),
+                                  f32(do[:, h]))
+                hold(0, got[0][:, h], dq)
+                dkv = [dkv[0] + dk, dkv[1] + dv]
+            hold(1, got[1][:, g], dkv[0])
+            hold(2, got[2][:, g], dkv[1])
+    print(f"PARITY backward {batch} x {rows} rows 32 / {kv_heads} {name} "
+          f"{path} vs dense f32, max abs err (max |ref|):",
+          {n: (float(e), float(p)) for n, e, p in zip(
+              ("dq", "dk", "dv"), errs, peak)})
+    for n_, err, top in zip(("dq", "dk", "dv"), errs, peak):
+        assert err < 5e-2 * max(1.0, top), (n_, err)
 
 
 @pytest.mark.parametrize("length,block,bq,bkv", [(256, 4, 64, 64),
@@ -489,6 +576,7 @@ def test_a_call_with_no_block_mask_traces_the_parent_s_jaxpr(name,
     fn, args = _plain_calls()[name]
     behind_the_jit = _kernel_texts(fn, *args)
     monkeypatch.setattr(pf, "_fwd_launch_traced", pf._fwd_launch)
+    monkeypatch.setattr(pf, "_bwd_launch_traced", pf._bwd_launch)
     fn, args = _plain_calls()[name]  # make_jaxpr remembers a function
     assert _digest(fn, *args) == PARENT_JAXPRS[name]
     assert behind_the_jit == _kernel_texts(fn, *args) != []

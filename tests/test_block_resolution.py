@@ -183,21 +183,24 @@ PARENT_OP_CELLS = {
 
 
 # the same programs' kernels (name, grid, kernel jaxpr of every pallas_call)
-# under the generation's diagonal edge, taken on PR 33's tree: the forward's
-# differ from the parent's, and a PR that changes a kernel pins them anew
+# under the generation's diagonal edges, taken on PR 35's tree: the forward's
+# (PR 33) and the triangular backward's (PR 35) differ from the parent's, and
+# a PR that changes a kernel pins them anew
 SWEPT_OP_CELLS = {
-    "op_causal_64k": "89f5bb09f0f7330f",
-    "ring4_causal_128k": "4513ed414bf3d8c9",
+    "op_causal_64k": "9f4cdf83a2f729fe",
+    "ring4_causal_128k": "a0041017195b3a98",
 }
 
 
 @pytest.mark.parametrize("cell", sorted(PARENT_OP_CELLS))
 def test_the_op_cells_trace_the_parent_s_jaxprs(cell, monkeypatch):
-    """With the diagonal sweep off (an edge of 0 keeps the whole tile on the
-    masked path) and flash_fwd's body traced in line, not behind its jit
-    (PR 33): the program is the parent's, text for text.  Behind the jit
-    every kernel is still that program's; with the sweep on the forward's
-    differ, and no other."""
+    """With the diagonal sweeps off (an edge of 0 keeps the whole tile on
+    the masked path, in either pass) and the kernel launches traced in line,
+    not behind flash_fwd's and flash_bwd's jits (PRs 33, 35): the program is
+    the parent's, text for text.  Behind the jits every kernel is still that
+    program's; with the sweeps on the forward's and the triangular
+    backward's differ (the self round's; the ring's off-diagonal rounds run
+    the rectangular kernel on full tiles), and no other."""
     world, seq, digest = PARENT_OP_CELLS[cell]
     row = tuning.block_defaults()
     swept = _kernels(_op_cell_program(world, seq))
@@ -206,12 +209,13 @@ def test_the_op_cells_trace_the_parent_s_jaxprs(cell, monkeypatch):
                         lambda device=None: row._replace(diag_block=0))
     whole = _kernels(_op_cell_program(world, seq))
     monkeypatch.setattr(pf, "_fwd_launch_traced", pf._fwd_launch)
+    monkeypatch.setattr(pf, "_bwd_launch_traced", pf._bwd_launch)
     parent = _op_cell_program(world, seq)
     assert _digest(parent) == digest
     assert whole == _kernels(parent)
     assert [k[:2] for k in swept] == [k[:2] for k in whole]
     differ = {a[0] for a, b in zip(swept, whole) if a != b}
-    assert differ == {"burst_flash_fwd"}
+    assert differ == {"burst_flash_fwd", "burst_flash_bwd_tri"}
 
 
 # ---------------------------------------------------------------------------
@@ -327,10 +331,70 @@ def test_diag_path_is_static_and_keeps_the_whole_tile_elsewhere(monkeypatch):
                             triangular=True) == ("sub", 4, V5E.diag_block)
 
 
-def _diag_counted(fn, *args):
+# bwd_diag_path beside fwd_diag_path: 256 rows, 4 query heads x 16, the
+# backward's tall kv block (64 = 2 x 32), an edge of 16, a fused kernel
+# forced (off the chip flash_bwd's own gate takes the split kernels)
+BWD_PATH = dict(block_q=32, block_kv=64, triangular=True, diag_block=16,
+                interpret=True, fused=True)
+BWD_PATH_CASES = {
+    # case: (keywords over BWD_PATH, kv heads, the answer)
+    "tri_kernel": ({}, 4, ("sub", 8, 16)),
+    "rect_kernel": ({}, 1, ("sub", 8, 16)),
+    "block_units": (dict(window=BlockUnits(4)), 1, ("sub", 8, 16)),
+    "square_blocks": (dict(block_kv=32), 4, ("sub", 8, 16)),
+    "edge_is_block_q": (dict(diag_block=32), 1, ("sub", 8, 32)),
+    "sliced_sub_range": (dict(q_range=(128, 256), kv_range=(0, 128)), 1,
+                         ("sub", 4, 16)),
+    "no_promise": (dict(triangular=False), 1, None),
+    "s_q_ne_s_kv": (dict(kv_range=(0, 128)), 1, None),
+    "token_window": (dict(window=48), 1, ("whole", 8, None)),
+    "block_diagonal_band": (dict(window=BlockUnits(4, 1)), 1,
+                            ("whole", 8, None)),
+    "segments": (dict(segments=True), 1, ("whole", 8, None)),
+    "loop_sweep": (dict(loop_sweep=True), 4, ("whole", 8, None)),
+    "edge_half_a_mask_unit": (dict(window=BlockUnits(32)), 1,
+                              ("whole", 8, None)),
+    "edge_off": (dict(diag_block=0), 1, ("whole", 8, None)),
+    "edge_is_block_kv": (dict(diag_block=64), 1, ("whole", 8, None)),
+    "edge_does_not_divide": (dict(diag_block=24), 1, ("whole", 8, None)),
+    "block_kv_not_a_multiple": (dict(block_q=64, block_kv=32), 1,
+                                ("whole", 4, None)),
+    "split_kernels": (dict(fused=False), 1, ("whole", 8, None)),
+    "the_gate_off_the_chip": (dict(fused=None), 1, ("whole", 8, None)),
+    "ragged_length": (dict(), 1, ("whole", 8, None)),  # padded to 256
+}
+
+
+@pytest.mark.parametrize("case", sorted(BWD_PATH_CASES))
+def test_bwd_diag_path_is_static_and_keeps_the_whole_tile_elsewhere(case):
+    """Every condition of the backward's sub-square sweep, one a case: the
+    answer is read off what the call states, as fwd_diag_path's is."""
+    kw, n_kv, want = BWD_PATH_CASES[case]
+    rows = 200 if case == "ragged_length" else 256
+    assert pf.bwd_diag_path(4, n_kv, rows, rows, 16,
+                            **{**BWD_PATH, **kw}) == want
+
+
+def test_bwd_diag_path_reads_the_environment_and_the_table(monkeypatch):
+    path = functools.partial(pf.bwd_diag_path, 4, 4, 256, 256, 16)
+    monkeypatch.setenv("BURST_BWD_LOOP", "1")
+    assert path(**BWD_PATH) == ("whole", 8, None)
+    monkeypatch.delenv("BURST_BWD_LOOP")
+    monkeypatch.setenv("BURST_NO_TRI", "1")  # the rectangular kernel instead
+    assert path(**BWD_PATH) == ("sub", 8, 16)
+    monkeypatch.delenv("BURST_NO_TRI")
+    monkeypatch.setattr(tuning, "block_defaults", lambda device=None: V5E)
+    monkeypatch.setattr(pf, "_interpret_default", lambda: False)
+    for n_kv in (32, 8):  # op_causal_64k's kernel, train_mistral_1x8k's
+        assert pf.bwd_diag_path(
+            32, n_kv, 8192, 8192, 128, block_q=1024, block_kv=2048,
+            triangular=True) == ("sub", 8, V5E.diag_block)
+
+
+def _diag_counted(fn, *args, pass_="fwd"):
     """(sub, whole) that flash.diag_tiles advances by when `fn` is traced."""
     c = obs.counter("flash.diag_tiles")
-    read = lambda: [c.get(**{"pass": "fwd", "path": p})
+    read = lambda: [c.get(**{"pass": pass_, "path": p})
                     for p in ("sub", "whole")]
     before = read()
     jax.make_jaxpr(fn)(*args)
@@ -371,6 +435,66 @@ def test_every_diagonal_tile_of_the_cells_is_swept_in_sub_squares(
     else:
         # the self round; a zigzag ring's later rounds promise nothing
         assert (sub, whole) == (tiles, 0)
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_DISPATCHES))
+def test_every_cut_block_of_the_cells_backward_is_counted_once_a_dispatch(
+        cell, monkeypatch):
+    """flash.diag_tiles{pass=bwd}: the q blocks of the backward's own tiling
+    (half the forward's tile) that the diagonal cuts, on the kernels the chip
+    takes (the gate is asked as the chip would answer: a trace, never a
+    run)."""
+    b, n, n_kv, rows, world, bd = CELL_DISPATCHES[cell]
+    row = V5E._replace(fwd_block_q=128, fwd_block_kv=128, bwd_block_q=64,
+                       bwd_block_kv=128, band_block=32, diag_block=8)
+    monkeypatch.setattr(tuning, "block_defaults", lambda device=None: row)
+    monkeypatch.setattr(pf, "_interpret_default", lambda: False)
+    mesh = Mesh(np.array(jax.devices()[:world]), ("sp",))
+    stream = rows * world * (2 if bd else 1)
+    q = jax.ShapeDtypeStruct((b, n, stream, 16), jnp.float32)
+    kv = jax.ShapeDtypeStruct((b, n_kv, stream, 16), jnp.float32)
+    sub, whole = _diag_counted(lambda q, k, v: bat.burst_attn(
+        q, k, v, mesh=mesh, causal=bd is None, backend="pallas",
+        block_diffusion=bd), q, kv, kv, pass_="bwd")
+    blocks = b * n * max(1, rows // 64)
+    if bd:
+        # `clean` and `below`; the block-diagonal call sweeps a band
+        assert (sub, whole) == (2 * blocks, b * n * rows // 32)
+    else:
+        # the own round; a zigzag ring's later rounds promise nothing
+        assert (sub, whole) == (blocks, 0)
+
+
+@pytest.mark.parametrize("case", ["segments", "contig", "split_kernels",
+                                  "striped_round", "jnp_tile", "forward"])
+def test_bwd_diag_counter_by_what_the_call_states(case, monkeypatch):
+    n, s, d = 4, 256, 16
+    q = jax.ShapeDtypeStruct((1, n, s, d), jnp.float32)
+    kw = dict(causal=True, backend="pallas", block_q=64, block_kv=64,
+              block_q_bwd=32, block_kv_bwd=64)
+    world = 1
+    if case == "segments":
+        kw["segment_ids"] = jnp.zeros((1, s), jnp.int32)
+    elif case == "contig":  # its backward promises nothing
+        kw["layout"] = "contig"
+    elif case == "striped_round":  # every round is full-window causal
+        world = 2
+        kw["layout"] = "striped"
+    elif case == "jnp_tile":
+        kw["backend"] = "jnp"
+    if case != "split_kernels":  # off the chip the gate takes them
+        monkeypatch.setattr(pf, "_interpret_default", lambda: False)
+    mesh = Mesh(np.array(jax.devices()[:world]), ("sp",))
+    blocks = n * (s // world) // 32
+    monkeypatch.setattr(tuning, "block_defaults",
+                        lambda device=None: V5E._replace(diag_block=16))
+    kv = jax.ShapeDtypeStruct((1, 2, s, d), jnp.float32)
+    assert _diag_counted(
+        lambda q, k, v: bat.burst_attn(q, k, v, mesh=mesh, **kw),
+        q, kv, kv, pass_="bwd") == {
+        "segments": (0, blocks), "contig": (0, 0),
+        "split_kernels": (0, blocks), "striped_round": (2 * blocks, 0),
+        "jnp_tile": (0, 0), "forward": (blocks, 0)}[case]
 
 
 @pytest.mark.parametrize("case", ["segments", "token_window", "bq_ne_bkv",

@@ -21,7 +21,8 @@ import pytest
 
 from burst_attn_tpu.ops import pallas_flash as pf
 from burst_attn_tpu.ops import tile as T
-from burst_attn_tpu.ops.masks import full_spec, round_spec
+from burst_attn_tpu.ops.masks import (BlockUnits, MaskSpec, full_spec,
+                                      round_spec)
 
 on_tpu = pytest.mark.skipif(
     jax.default_backend() != "tpu", reason="fused bwd kernel is TPU-only"
@@ -452,6 +453,132 @@ def _max_err(a, b):
                                  - b.astype(jnp.float32))))
 
 
+# ---------------------------------------------------------------------------
+# the blocks the causal diagonal cuts, in their live sub-squares (PR 35)
+
+# (kernel, query heads, kv heads, d_qk, d_v, block_q, block_kv, edge, mask
+# unit, offset, carry): the triangular kernel (one head a kv head), the
+# rectangular one at the cells' head groupings (4 and 8 query heads a kv
+# head) with and without the ring's carry; offset 0 and -1 in tokens and in
+# blocks of 4 (train_sdar_bd_1x8k's `clean` and `below`); block_kv = block_q
+# and 2 x block_q; q and k wider than v (the kanana cell's 192 / 128)
+CUT_CASES = [
+    ("tri", 2, 2, 16, 16, 32, 64, 16, 1, 0, False),
+    ("tri", 2, 2, 16, 16, 32, 64, 8, 1, -1, False),
+    ("tri", 2, 2, 16, 16, 64, 64, 16, 1, 0, False),
+    ("tri", 2, 2, 16, 16, 64, 64, 32, 1, -1, False),
+    ("tri", 2, 2, 192, 128, 32, 64, 16, 1, 0, False),
+    ("tri", 2, 2, 192, 128, 64, 64, 32, 1, -1, False),
+    # a carry with one head a kv head: the sliced form around the kernel
+    ("tri", 2, 2, 16, 16, 32, 64, 16, 1, 0, True),
+    ("rect", 4, 1, 16, 16, 32, 64, 16, 1, 0, False),
+    ("rect", 4, 1, 16, 16, 32, 64, 16, 4, 0, False),
+    ("rect", 4, 1, 16, 16, 32, 64, 8, 4, -1, True),
+    ("rect", 8, 1, 16, 16, 32, 64, 32, 1, -1, True),
+    ("rect", 8, 1, 16, 16, 64, 64, 16, 4, 0, True),
+    ("rect", 8, 2, 16, 16, 64, 64, 32, 1, 0, False),
+    ("rect", 4, 1, 24, 16, 32, 64, 16, 1, -1, True),
+    ("rect", 8, 1, 192, 128, 32, 64, 16, 4, 0, True),
+]
+
+
+def _cut_inputs(n, n_kv, d, d_v, s, unit, offset, carry):
+    ks = jax.random.split(jax.random.PRNGKey(n * 7 + d + unit - offset), 6)
+    q = jax.random.normal(ks[0], (1, n, s, d), jnp.float32)
+    k = jax.random.normal(ks[1], (1, n_kv, s, d), jnp.float32)
+    v = jax.random.normal(ks[2], (1, n_kv, s, d_v), jnp.float32)
+    do = jax.random.normal(ks[3], (1, n, s, d_v), jnp.float32)
+    nb = jnp.int32(s // unit)
+    spec = MaskSpec(jnp.int32(0), nb, nb, jnp.int32(1), jnp.int32(offset))
+    window = BlockUnits(unit) if unit != 1 else None
+    m, lse, acc = T.tile_fwd(q, k, v, *T.init_state(1, n, s, d_v), d ** -0.5,
+                             spec, window=window)
+    delta = jnp.sum(T.finalize(m, lse, acc, jnp.float32) * do, -1)
+    # offset -1: the first rows see nothing; any finite lse does for them
+    lse = jnp.where(jnp.isneginf(lse), 0.0, lse)
+    dkv = None
+    if carry:
+        dkv = (jax.random.normal(ks[4], k.shape), jax.random.normal(
+            ks[5], v.shape))
+    return (do, q, k, v, delta, lse, d ** -0.5, spec), window, dkv
+
+
+@pytest.mark.parametrize(
+    "kernel,n,n_kv,d,d_v,bq,bkv,edge,unit,offset,carry", CUT_CASES)
+def test_cut_blocks_in_sub_squares_against_the_oracle_and_the_whole_tile(
+        kernel, n, n_kv, d, d_v, bq, bkv, edge, unit, offset, carry):
+    """dq, dk, dv of both fused kernels with the blocks the diagonal cuts
+    computed in sub-squares of `edge` (pallas_flash._bwd_cut_tile) against
+    the same call with the whole tile on the masked path (`diag_block=0`)
+    and against ops/tile.py.  Interpret mode reads the rectangular kernel's
+    aliased dq input as the zeros it was handed at EVERY visit, so its dq
+    holds each q block's LAST visit alone, which is the block's cut one:
+    against the whole tile that is a comparison of the cut blocks' dq rows,
+    and no comparison with the oracle (the chip-gated tests hold that)."""
+    s = 256
+    args, window, dkv = _cut_inputs(n, n_kv, d, d_v, s, unit, offset, carry)
+    kw = dict(block_q=bq, block_kv=bkv, interpret=True, fused=True,
+              triangular=True, window=window)
+    assert pf._bwd_kernel_of(n, n_kv, s, s, d, d_v=d_v, **kw) == kernel
+    assert pf.bwd_diag_path(
+        n, n_kv, s, s, d, d_v=d_v, diag_block=edge, **kw) == (
+        "sub", s // bq, edge)
+    got = pf.flash_bwd(*args, carry=dkv, diag_block=edge, **kw)
+    whole = pf.flash_bwd(*args, carry=dkv, diag_block=0, **kw)
+    want = T.tile_bwd(*args, window=window, carry=dkv)
+    for name, a, b, c in zip(("dq", "dk", "dv"), got, whole, want):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5, err_msg=name)
+        if name != "dq" or kernel == "tri":
+            np.testing.assert_allclose(a, c, rtol=1e-4, atol=1e-4,
+                                       err_msg=name + " against the oracle")
+
+
+@pytest.mark.parametrize("ranges", [((128, 256), (0, 128)),
+                                    ((128, 256), (128, 256)), (None, None)])
+def test_cut_blocks_of_a_round_on_ranges_are_local_to_the_ranges(ranges):
+    """No caller promises `triangular` on a sub-range round, but flash_bwd
+    takes one: the rectangular kernel runs it in place (block offsets in the
+    index maps, the spec local to the ranges), and which blocks the diagonal
+    cuts is local to the ranges too.  dk, dv against the sliced form."""
+    q_range, kv_range = ranges
+    args, _, dkv = _cut_inputs(4, 1, 16, 16, 256, 1, 0, True)
+    nb = jnp.int32(128 if q_range else 256)
+    spec = MaskSpec(jnp.int32(0), nb, nb, jnp.int32(1), jnp.int32(0))
+    args = (*args[:7], spec)
+    kw = dict(block_q=32, block_kv=64, interpret=True, fused=True,
+              triangular=True)
+    rng = dict(q_range=q_range, kv_range=kv_range)
+    assert pf.bwd_folds_carry(4, 1, 256, 256, 16, q_range, kv_range, **kw)
+    assert pf.bwd_diag_path(4, 1, 256, 256, 16, diag_block=16, **rng,
+                            **kw).path == "sub"
+    got = pf.flash_bwd(*args, diag_block=16, carry=dkv, **rng, **kw)
+    want = T.tile_bwd(*args, q_range=q_range, kv_range=kv_range, carry=dkv)
+    for name, a, b in zip(("dk", "dv"), got[1:], want[1:]):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4, err_msg=name)
+
+
+def test_the_cut_blocks_run_under_the_kernels_own_names_and_grids():
+    """The benchmark's readers join on `burst_flash_bwd_tri` / `_rect`, and
+    the in-place dq's argument rests on the grid and the index maps: a call
+    on the sub path has the whole tile's name, grid and block mappings."""
+    from burst_attn_tpu.analysis.jaxpr_tools import iter_eqns
+
+    def calls(n_kv, edge):
+        args, window, _ = _cut_inputs(4, n_kv, 16, 16, 256, 1, 0, False)
+        jaxpr = jax.make_jaxpr(lambda *xs: pf.flash_bwd(
+            *xs, *args[6:], block_q=32, block_kv=64, interpret=True,
+            fused=True, triangular=True, diag_block=edge))(*args[:6])
+        return [(e.params["name"], e.params["grid_mapping"].grid,
+                 str(e.params["grid_mapping"].block_mappings),
+                 e.params["input_output_aliases"])
+                for e in iter_eqns(jaxpr) if e.primitive.name == "pallas_call"]
+
+    for n_kv, name in ((4, "burst_flash_bwd_tri"), (1, "burst_flash_bwd_rect")):
+        sub, whole = calls(n_kv, 16), calls(n_kv, 0)
+        assert [c[0] for c in sub] == [name]
+        assert sub == whole
+
+
 @on_tpu
 @pytest.mark.parametrize("half", ["kv_first_half", "q_second_half"])
 def test_round_in_place_matches_sliced_on_tpu(half):
@@ -509,6 +636,60 @@ def test_round_in_place_matches_sliced_on_tpu(half):
             assert bool(jnp.all(a[:, :, h:] == c[:, :, h:]))
     else:
         assert not bool(jnp.any(got[0][:, :, :h]))
+
+
+@on_tpu
+@pytest.mark.parametrize("rows,heads,kv_heads,unit,offset,carry,bq,bkv", [
+    (8192, 8, 2, 1, 0, False, 1024, 2048),  # train_mistral's grouping
+    (8192, 8, 1, 4, -1, True, 1024, 2048),  # train_sdar_bd_1x8k's `below`
+    # the gate's edge, a sweep of 2 q blocks x 2 heads: q block 1 is
+    # written in kv block 0's sweep and read again, cut, four steps later
+    (2048, 2, 1, 1, 0, False, 1024, 1024),
+    (4096, 2, 1, 1, -1, False, 1024, 2048),  # 4 x 2 steps, two cut a sweep
+])
+def test_cut_blocks_in_place_dq_matches_split_on_tpu(
+        rows, heads, kv_heads, unit, offset, carry, bq, bkv):
+    """The rectangular kernel with its cut blocks in sub-squares (PR 35)
+    adds dq in place exactly as before: one read and one store a visit, the
+    parent's grid, index maps and order.  Against the split kernels (no
+    alias), at a shard of 8,192 rows in the row's tiles and at the gate's
+    edge, where the separation between a block's write and its next read is
+    the shortest the gate admits."""
+    d = 128
+    ks = jax.random.split(jax.random.PRNGKey(35), 6)
+    dt = jnp.bfloat16
+    q, do = (jax.random.normal(k_, (1, heads, rows, d), dt) for k_ in ks[:2])
+    k, v = (jax.random.normal(k_, (1, kv_heads, rows, d), dt)
+            for k_ in ks[2:4])
+    nb = jnp.int32(rows // unit)
+    spec = MaskSpec(jnp.int32(0), nb, nb, jnp.int32(1), jnp.int32(offset))
+    window = BlockUnits(unit) if unit != 1 else None
+    scale = d ** -0.5
+    dkv = None
+    if carry:
+        dkv = tuple(jax.random.normal(k_, (1, kv_heads, rows, d), jnp.float32)
+                    for k_ in ks[4:])
+    blocks = dict(block_q=bq, block_kv=bkv)
+    m, lse, acc = pf.flash_fwd(q, k, v, None, None, None, scale, spec,
+                               block_q=1024, block_kv=1024, window=window)
+    delta = jnp.sum(T.finalize(m, lse, acc, jnp.float32)
+                    * do.astype(jnp.float32), axis=-1)
+    lse = jnp.where(jnp.isneginf(lse), 0.0, lse)
+    args = (do, q, k, v, delta, lse, scale, spec)
+    kw = dict(triangular=True, window=window, carry=dkv, **blocks)
+    assert pf.bwd_diag_path(heads, kv_heads, rows, rows, d, triangular=True,
+                            window=window, **blocks).path == "sub"
+    assert "burst_flash_bwd_rect" in str(jax.make_jaxpr(
+        lambda *a: pf.flash_bwd(*a, scale, spec, **kw))(*args[:6]))
+    got = pf.flash_bwd(*args, **kw)
+    split = pf.flash_bwd(*args, fused=False, **kw)
+    whole = pf.flash_bwd(*args, diag_block=0, **kw)
+    errs = {}
+    for name, a, b_, c in zip(("dq", "dk", "dv"), got, split, whole):
+        errs[name] = (_max_err(a, b_), _max_err(a, c))
+    print("\nPARITY cut blocks in place,", rows, heads, kv_heads, unit,
+          offset, carry, "(vs split, vs whole tile):", errs)
+    assert max(e for pair in errs.values() for e in pair) < 2e-3, errs
 
 
 @on_tpu

@@ -243,7 +243,9 @@ def test_the_dispatch_counter_carries_both_widths():
 # chip's kernel choice, under conftest's settings with the matmul precision
 # set back to "default": taken on the parent of PR 34 (ffd1666), whose
 # kernels knew one width.  A PR that changes these
-# kernels on purpose pins them anew.
+# kernels on purpose pins them anew.  PR 35 changed the fused backward's cut
+# blocks on purpose: with their sub-square sweep declined (the whole tile on
+# the masked path) every kernel is still the one taken then.
 PARENT_KERNELS = {
     "op_causal_64k": ("39d296814919625c", 2, 1, (1, 32, 32, 65536), {}),
     "op_causal_64k.parity_8k": ("d3eb7f2a22bca9bc", 2, 1,
@@ -262,6 +264,7 @@ def test_equal_widths_trace_the_parent_s_kernels(cell, monkeypatch):
     digest, calls, world, (b, n, n_kv, s), kw = PARENT_KERNELS[cell]
     monkeypatch.setattr(tuning, "block_defaults",
                         lambda device=None: tuning.generation_row("v5e"))
+    monkeypatch.setattr(pf, "_bwd_diag_edge", lambda *a, **kw: None)
     monkeypatch.setattr(pf, "_interpret_default", lambda: False)
     mesh = Mesh(np.array(jax.devices()[:world]), ("sp",))
     q = jax.ShapeDtypeStruct((b, n, s, 128), jnp.bfloat16)
@@ -506,6 +509,10 @@ def test_the_cell_s_attention_at_192_128_against_dense_on_the_chip():
     assert [g.shape[-1] for g in got] == [d_v, d_qk, d_qk, d_v]
     # lse from the kernel the call runs (burst_attn keeps it to itself)
     rb = pf.resolve_blocks(s_q=rows, s_kv=rows)
+    # the backward's cut blocks in their live sub-squares (PR 35)
+    assert pf.bwd_diag_path(
+        heads, heads, rows, rows, d_qk, d_v=d_v, block_q=rb.block_q_bwd,
+        block_kv=rb.block_kv_bwd, triangular=True).path == "sub"
     _, lse, _ = jax.jit(lambda q, k, v: pf.flash_fwd(
         q, k, v, None, None, None, d_qk ** -0.5, _causal_spec(rows),
         block_q=rb.block_q, block_kv=rb.block_kv, triangular=True))(q, k, v)
