@@ -6,26 +6,14 @@ included; a model's FLOP utilization counts no recomputation).
 The attention's heads are two widths wide: q and k `qk_head_dim` (192 = 128
 without position + 64 rotary), v and the output `v_head_dim` (128).  A visible
 (query, key) pair costs 2 x qk_head_dim FLOPs for its score and 2 x v_head_dim
-for its share of the output: the USEFUL count.  A kernel that pads either
-width pays for the padding in its time and not here.
+for its share of the output: the USEFUL count (flops.attention_calls).  A
+kernel that pads either width pays for the padding in its time and not here.
 """
 
-# one routed expert's parameters (gate, up, down), the router's, and a share
-# of the bf16 peak: the block-diffusion MoE cells' own
-from .flops_bd_moe import expert_params, router_params, share_of_peak  # noqa: F401
-
-
-def attention_fwd_flops(model, mix):
-    """Forward FLOPs of ONE layer's causal attention over the batch."""
-    pair = 2.0 * model["qk_head_dim"] + 2.0 * model["v_head_dim"]
-    return (pair * mix["batch"] * mix["seq"] * mix["seq"] / 2
-            * model["num_attention_heads"])
-
-
-def flash_kernel_flops(model, mix):
-    """Forward + backward of every layer's attention call in one step, the
-    reference's 3.5 x forward (flops.attention_kernel_flops's convention)."""
-    return 3.5 * model["num_hidden_layers"] * attention_fwd_flops(model, mix)
+from .flops import attention_fwd_flops
+# one routed expert's parameters (gate, up, down) and the router's: the
+# block-diffusion MoE cells' own
+from .flops_bd_moe import expert_params, router_params
 
 
 def attention_params(model):
@@ -67,10 +55,9 @@ def token_params(model):
 def step_model_flops(model, mix, slots_here):
     """Model FLOPs of one step, no recomputation counted: 6 x the matrix
     parameters each token meets (the routed experts on the (token, expert)
-    pairs computed here, `slots_here`, all layers) + 3 x the causal
-    attention forward."""
+    pairs computed here, `slots_here`, all layers) + 3 x the attention
+    forward (flops.attention_fwd_flops: the causal pairs at d_qk and d_v)."""
     tokens = mix["batch"] * mix["seq"]
     return (6.0 * (token_params(model) * tokens
                    + expert_params(model) * slots_here)
-            + 3.0 * model["num_hidden_layers"]
-            * attention_fwd_flops(model, mix))
+            + 3.0 * attention_fwd_flops(model, mix))

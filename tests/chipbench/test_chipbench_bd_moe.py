@@ -14,7 +14,8 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from chipbench import flops_bd_moe, moe_readings, peaks, run  # noqa: E402
+from chipbench import (flops, flops_bd_moe, moe_readings, peaks,  # noqa: E402
+                       run)
 from chipbench.runners import train_bd_moe  # noqa: E402
 
 TINY_SDAR = {
@@ -24,7 +25,8 @@ TINY_SDAR = {
     "vocab_size": 512, "rope_theta": 1e6, "rms_norm_eps": 1e-6,
     "num_experts": 4, "router_outputs": 8, "experts_held": [0, 4],
     "num_experts_per_tok": 2, "block_length": 4, "mask_token_id": 511,
-    "qk_norm": True, "router_aux_loss_coef": 0.001}
+    "qk_norm": True, "router_aux_loss_coef": 0.001,
+    "flop_count": "flops_bd_moe"}
 TINY = {
     "train_sdar_bd_1x8k": (TINY_SDAR, {"batch": 1, "seq": 256, "sp": 1,
                                        "check_seq": 128, "file_windows": 8,
@@ -99,8 +101,8 @@ def test_the_cell_reports_what_the_issue_lists():
         "step_ms", "hbm_gib", "setup_s"}
     assert {m["name"] for m in cell["per_layer"]} >= {
         "moe_router_ms_per_step", "moe_dispatch_ms_per_step",
-        "moe_experts_ms_per_step", "moe_gmm_roofline", "bd_flash_roofline",
-        "step_mfu", "flash_ms_per_step", "flash_roofline",
+        "moe_experts_ms_per_step", "moe_gmm_roofline", "step_mfu",
+        "flash_ms_per_step", "flash_roofline",
         "device_idle_share", "compile_s"}
     model = cell["config"]
     assert model["experts_held"] == [0, model["num_experts"]]
@@ -116,12 +118,14 @@ def test_the_cell_reports_what_the_issue_lists():
 def test_flop_counts_are_the_issue_s_arithmetic():
     cell = run.load_cell("train_sdar_bd_1x8k")
     model, mix = cell["config"], cell["traffic"]
-    assert flops_bd_moe.visible_pairs(8192, 4) == 8192 ** 2 + 8192 * 4
+    assert flops.attention_pairs(model, 8192) == [8192 ** 2 + 8192 * 4] * 8
     assert flops_bd_moe.expert_params(model) == 4_718_592
     assert flops_bd_moe.attention_params(model) == 18_874_368
-    fwd = flops_bd_moe.bd_attention_fwd_flops(model, mix)
+    fwd = flops.attention_fwd_flops(model, mix) / 8  # a layer's
     assert fwd == 4.0 * (8192 ** 2 + 8192 * 4) * 32 * 128
-    assert flops_bd_moe.bd_flash_kernel_flops(model, mix) == 8 * 3.5 * fwd
+    # the stream of 2L, 16,384 rows a call, at 128 + 128 wide
+    assert flops.attention_calls(model, mix) == [
+        (8, fwd, 16384 * (32 + 4) * 6 * 128 * 2)]
     # a causal sweep over the stream of 2L would be 2L^2 pairs: twice this
     assert 1.99 < 4.0 * 16384 ** 2 / 2 * 32 * 128 / fwd < 2.0
     slots = 8 * 16384.0  # the mean: 2L * 8 choices / 8 chips, 8 layers
@@ -131,7 +135,19 @@ def test_flop_counts_are_the_issue_s_arithmetic():
     # compute binds the grouped products at the mean load
     assert flops_bd_moe.gmm_least_seconds(model, slots, peak) == \
         pytest.approx(6.0 * 4_718_592 * slots / 197e12)
-    assert flops_bd_moe.share_of_peak(197e12, 2.0, peak) == 50.0
+    assert flops.share_of_peak(197e12, 2.0, peak) == 50.0
+
+
+def test_the_runner_ignores_the_flop_count_key():
+    """`flop_count` names the benchmark's count for step_mfu; the program
+    is built from the same keys with it or without it."""
+    bare = {k: v for k, v in TINY_SDAR.items() if k != "flop_count"}
+    assert train_bd_moe.model_config(TINY_SDAR) == \
+        train_bd_moe.model_config(bare)
+    assert train_bd_moe.train_config(TINY_SDAR, 7) == \
+        train_bd_moe.train_config(bare, 7)
+    assert train_bd_moe.reference_keywords(TINY_SDAR) == \
+        train_bd_moe.reference_keywords(bare)
 
 
 def test_readers_find_nothing_in_a_program_without_their_scopes():
@@ -141,7 +157,7 @@ def test_readers_find_nothing_in_a_program_without_their_scopes():
     reading = {"cell": cell, "steps": [], "trace": None}
     for metric in ("moe_router_ms_per_step", "moe_dispatch_ms_per_step",
                    "moe_experts_ms_per_step", "moe_gmm_roofline",
-                   "bd_flash_roofline", "step_mfu"):
+                   "flash_roofline", "step_mfu"):
         assert run.read_layer_metric(cell, metric, dict(reading)) is None
     trace = {"devices": {"/device:TPU:0": [("%fusion.1 = f32[] fusion()",
                                             0, 10)]}, "steps": 1}

@@ -16,12 +16,12 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from chipbench import flops_mla_moe, moe_readings, peaks, run  # noqa: E402
+from chipbench import (flops, flops_mla_moe, moe_readings, peaks,  # noqa: E402
+                       run)
 from chipbench.runners import train_mla_moe  # noqa: E402
 
 CELL = "train_kanana2_mla_1x16k"
-NEW_METRICS = ("mla_flash_roofline", "mla_proj_ms_per_step",
-               "moe_shared_ms_per_step", "mla_step_mfu")
+NEW_METRICS = ("mla_proj_ms_per_step", "moe_shared_ms_per_step")
 TINY_KANANA = {
     "name": "tiny_kanana", "runner": "train_mla_moe",
     "reference": "mla_moe_lm", "hidden_size": 64, "intermediate_size": 96,
@@ -35,7 +35,7 @@ TINY_KANANA = {
     "num_experts_per_tok": 3, "n_shared_experts": 2, "n_group": 1,
     "topk_group": 1, "norm_topk_prob": True, "scoring_func": "sigmoid",
     "topk_method": "noaux_tc", "routed_scaling_factor": 2.448,
-    "router_aux_loss_coef": 0.0}
+    "router_aux_loss_coef": 0.0, "flop_count": "flops_mla_moe"}
 TINY_MIX = {"batch": 1, "seq": 128, "sp": 1, "check_seq": 64,
             "file_windows": 8, "token_ids": 64}
 
@@ -74,8 +74,8 @@ def test_new_cell_runs_end_to_end_at_a_tiny_size(trace, tmp_path,
     listed = cell["per_layer"] if trace else cell["end_to_end"]
     if trace:
         host = {m["name"] for m in listed if m["source"] != "device_trace"}
-        assert set(result["metrics"]) == host >= {"mla_step_mfu", "compile_s"}
-        assert 0 < result["metrics"]["mla_step_mfu"]["value"] < 100
+        assert set(result["metrics"]) == host >= {"step_mfu", "compile_s"}
+        assert 0 < result["metrics"]["step_mfu"]["value"] < 100
     else:
         assert set(result["metrics"]) == {"step_ms", "hbm_gib", "setup_s"}
     json.dumps(result), json.dumps(record)
@@ -110,7 +110,7 @@ def test_the_cells_report_what_the_issue_lists():
     assert {m["name"] for m in cell["end_to_end"]} == {
         "step_ms", "hbm_gib", "setup_s"}
     assert {m["name"] for m in cell["per_layer"]} == {
-        *NEW_METRICS, "flash_ms_per_step", "flash_roofline",
+        *NEW_METRICS, "step_mfu", "flash_ms_per_step", "flash_roofline",
         "device_idle_share", "compile_s"}
     assert cell["chips"] == 1 and cell["traffic"]["seq"] == 16384
     assert cell["traffic"]["check_seq"] == 4096
@@ -164,17 +164,14 @@ def test_flop_counts_are_the_issue_s_arithmetic():
     assert flops_mla_moe.router_params(model) == 262_144
     assert flops_mla_moe.expert_params(model) == 4_718_592
     assert flops_mla_moe.dense_mlp_params(model) == 37_748_736
-    fwd = flops_mla_moe.attention_fwd_flops(model, mix)
+    fwd = flops.attention_fwd_flops(model, mix) / 8  # a layer's
     assert fwd == (2 * 192 + 2 * 128) * 32 * 16384 ** 2 / 2
     assert fwd == pytest.approx(2.75e12, rel=2e-3)
-    assert flops_mla_moe.flash_kernel_flops(model, mix) == pytest.approx(
-        77e12, rel=2e-3)
-    # `flash_roofline` reads the cell at head_dim 64: 4 x 64 FLOPs a pair
-    # where the kernels do 640, 0.4 of mla_flash_roofline by construction
-    from chipbench import flops
-
-    assert 8 * flops.attention_kernel_flops(1, 16384, 32, 64) == \
-        pytest.approx(0.4 * flops_mla_moe.flash_kernel_flops(model, mix))
+    assert 3.5 * 8 * fwd == pytest.approx(77e12, rel=2e-3)
+    # `flash_roofline` counts the two widths the kernels compute, 640 FLOPs
+    # a pair, and not `head_dim` 64 (the rotary width) for all three
+    assert flops.attention_calls(model, mix) == [
+        (8, fwd, 16384 * (32 + 32) * (3 * 192 + 3 * 128) * 2)]
     # the matrix parameters every token meets, and the step at the mean
     # load: 16,384 x 6 / 8 pairs a sparse layer
     assert flops_mla_moe.token_params(model) == (
@@ -185,8 +182,19 @@ def test_flop_counts_are_the_issue_s_arithmetic():
     assert step == 6.0 * (flops_mla_moe.token_params(model) * 16384
                           + 4_718_592 * slots) + 3.0 * 8 * fwd
     assert step == pytest.approx(102.74e12, rel=1e-3)
-    assert flops_mla_moe.share_of_peak(197e12, 2.0,
-                                       peaks.peak("TPU v5 lite")) == 50.0
+    assert flops.share_of_peak(197e12, 2.0, peaks.peak("TPU v5 lite")) == 50.0
+
+
+def test_the_runner_ignores_the_flop_count_key():
+    """`flop_count` names the benchmark's count for step_mfu; the program
+    is built from the same keys with it or without it."""
+    bare = {k: v for k, v in TINY_KANANA.items() if k != "flop_count"}
+    assert train_mla_moe.model_config(TINY_KANANA) == \
+        train_mla_moe.model_config(bare)
+    assert train_mla_moe.train_config(TINY_KANANA) == \
+        train_mla_moe.train_config(bare)
+    assert train_mla_moe.reference_keywords(TINY_KANANA) == \
+        train_mla_moe.reference_keywords(bare)
 
 
 @pytest.mark.parametrize("cell_name", ["train_mistral_1x8k", CELL])
@@ -196,7 +204,7 @@ def test_readers_find_nothing_in_a_program_without_their_scopes(cell_name):
     records without the attr; none raises."""
     cell = run.load_cell(cell_name)
     reading = {"cell": cell, "steps": [], "trace": None}
-    for metric in NEW_METRICS:
+    for metric in NEW_METRICS + ("step_mfu", "flash_roofline"):
         assert run.read_layer_metric(cell, metric, dict(reading)) is None
     trace = {"devices": {"/device:TPU:0": [("%fusion.1 = f32[] fusion()",
                                             0, 10)]}, "steps": 1}
