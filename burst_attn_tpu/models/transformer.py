@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from jax import shard_map
 
+from ..ops.polynorm import PolyNorm, init_weights as _poly_init, poly_norm
 from ..parallel.burst import burst_attn
 
 
@@ -55,6 +56,24 @@ class LatentAttn:
     qk_nope: int
     qk_rope: int
     v_head: int
+
+
+@dataclass(frozen=True)
+class GDLAttn(LatentAttn):
+    """Grouped differential latent attention: LatentAttn's geometry with q
+    from a `q_latent`-wide RMS-normed latent a token, and k_nope, v for
+    `kv_heads` KV heads (not one a query head).  The ModelConfig.n_heads
+    query heads fall in `kv_heads` groups of n_heads / kv_heads, group g
+    reading KV head g; the LAST head of each group is its noise head, so
+    `noise_heads` == `kv_heads`, and the others are signal heads.  Each
+    signal head i outputs o_i - lambda_i * o_noise(group of i), lambda =
+    sigmoid(h W_lambda) one a signal head and token, times an elementwise
+    gate sigmoid(h W_gate) of the same width (h: the layer's normed input),
+    and the output projection reads the signal heads only."""
+
+    q_latent: int
+    kv_heads: int
+    noise_heads: int
 
 
 @dataclass(frozen=True)
@@ -86,17 +105,37 @@ class ExpertMLP:
 
 @dataclass(frozen=True)
 class LayerSpec:
-    """One layer: its MLP kind, and its attention kind where that is not the
-    GQA block of ModelConfig's scalar knobs (None)."""
+    """One layer: its MLP kind, its attention kind where that is not the GQA
+    block of ModelConfig's scalar knobs (None), its attention window where
+    that is not ModelConfig.window (None: the model's; needs layout
+    "contig", as any window does), and its MLPs' activation of the gate
+    product (None: SiLU; a PolyNorm: that, with the layer's own weights,
+    in the dense MLP, the shared and every routed expert)."""
 
     mlp: Union[DenseMLP, ExpertMLP]
     attn: Optional[LatentAttn] = None
+    window: Optional[int] = None
+    act: Optional[PolyNorm] = None
 
 
 # Parameter leaves that are model state, not trained: the forward reads them,
 # no gradient reaches them, and the train step hands them on as they came
 # (models/train.py: no update, no weight decay).
 STATE_LEAVES = ("router_bias",)
+
+
+@dataclass(frozen=True)
+class MHC:
+    """The residual path as `streams` streams [B, S, n, d] (mHC, arXiv
+    2512.24880): _mhc_pre / _mhc_post around each sublayer, the maps' mix
+    doubly stochastic by `sinkhorn_iters` Sinkhorn-Knopp rounds; the
+    embedding copied to every stream, the streams summed before the final
+    norm; the streams clipped to +-`clamp` after each sublayer (None:
+    not)."""
+
+    streams: int
+    sinkhorn_iters: int
+    clamp: Optional[float] = None
 
 
 @dataclass(frozen=True)
@@ -164,6 +203,11 @@ class ModelConfig:
     # experts_held are not read for the kinds it names.  The trainer's
     # forward only: pp_axis, decode and serving refuse it.
     pattern: Optional[Tuple[LayerSpec, ...]] = None
+    # The residual path as mHC's streams (MHC; None: the one stream x).
+    # The trainer's forward only, as a pattern.
+    mhc: Optional["MHC"] = None
+    # epsilon of every RMSNorm of the trainer's forward
+    norm_eps: float = 1e-6
 
 
 Params = Dict[str, Any]
@@ -193,17 +237,81 @@ def has_experts(cfg: ModelConfig) -> bool:
     return any(isinstance(sp.mlp, ExpertMLP) for sp in layer_specs(cfg))
 
 
+def _trainer_only_kinds(cfg: ModelConfig):
+    """What of `cfg` the trainer's forward_with_aux alone computes, by name
+    (empty where nothing)."""
+    met = []
+    if cfg.pattern is not None:
+        specs = cfg.pattern
+        attns = {type(sp.attn) for sp in specs if sp.attn is not None}
+        met += [name for kind, name in (
+            (LatentAttn, "latent attention"),
+            (GDLAttn, "grouped differential latent attention")) if kind in attns]
+        if any(sp.window is not None for sp in specs):
+            met.append("a window a layer")
+        if any(sp.act is not None for sp in specs):
+            met.append("PolyNorm MLPs")
+        if len({type(sp.mlp) for sp in specs}) > 1:
+            met.append("per-layer MLP kinds")
+        if not met:
+            met.append("a layer pattern")
+    if cfg.mhc is not None:
+        met.append(f"the mHC residual of {cfg.mhc.streams} streams")
+    if cfg.norm_eps != ModelConfig.norm_eps:
+        met.append(f"a norm epsilon of {cfg.norm_eps}")
+    return met
+
+
 def _refuse_pattern(cfg, who):
-    if cfg is not None and cfg.pattern is not None:
+    met = [] if cfg is None else _trainer_only_kinds(cfg)
+    if met:
         raise ValueError(
             f"{who} reads ModelConfig's scalar knobs (one block for the "
-            "whole stack); a layer pattern (ModelConfig.pattern: latent "
-            "attention, per-layer MLP kinds) runs through the trainer's "
-            "forward_with_aux only")
+            "whole stack); a layer pattern and the knobs beside it "
+            "(ModelConfig.pattern: latent or grouped differential latent "
+            "attention, a window a layer, PolyNorm MLPs, per-layer MLP "
+            "kinds; mhc, norm_eps) run through the trainer's "
+            f"forward_with_aux only, and this configuration has "
+            f"{', '.join(met)}")
 
 
 def _split(key, n):
     return list(jax.random.split(key, n))
+
+
+MHC_SUBLAYERS = ("attn", "mlp")
+
+
+def _init_mhc(key, cfg: ModelConfig):
+    """A layer's mHC leaves, for each sublayer s: `mhc_<s>_phi` [n d, 2n +
+    n^2] float32 (the three maps' projections of the normed streams, side by
+    side: pre, post, res), `mhc_<s>_alpha` [3] (their gains, 0.01 at
+    first) and `mhc_<s>_bias` [2n + n^2] (seeded, not the identity)."""
+    n, d = cfg.mhc.streams, cfg.d_model
+    width = 2 * n + n * n
+    out = {}
+    for s, k in zip(MHC_SUBLAYERS, _split(key, len(MHC_SUBLAYERS))):
+        kp, kb = _split(k, 2)
+        out[f"mhc_{s}_phi"] = 0.02 * jax.random.normal(kp, (n * d, width),
+                                                       jnp.float32)
+        out[f"mhc_{s}_alpha"] = jnp.full((3,), 0.01, jnp.float32)
+        out[f"mhc_{s}_bias"] = 0.1 * jax.random.normal(kb, (width,),
+                                                       jnp.float32)
+    return out
+
+
+def _init_poly(key, spec: LayerSpec):
+    """A layer's PolyNorm weights: `poly` [4] (the dense MLP), or
+    `expert_poly` [held, 4] and, with shared experts, `shared_poly` [4]."""
+    mlp = spec.mlp
+    if not isinstance(mlp, ExpertMLP):
+        return {"poly": _poly_init(key)}
+    held = mlp.n_experts if mlp.held is None else mlp.held[1] - mlp.held[0]
+    ke, ks = _split(key, 2)
+    out = {"expert_poly": _poly_init(ke, (held,))}
+    if mlp.shared_ff:
+        out["shared_poly"] = _poly_init(ks)
+    return out
 
 
 def init_params(key, cfg: ModelConfig) -> Params:
@@ -230,6 +338,22 @@ def init_params(key, cfg: ModelConfig) -> Params:
             if cfg.qk_norm:
                 layer.update(q_norm=jnp.ones((hd,), jnp.float32),
                              k_norm=jnp.ones((hd,), jnp.float32))
+        elif isinstance(spec.attn, GDLAttn):
+            a = spec.attn
+            ka = _split(ks[3], 4)
+            signal = nh - a.noise_heads
+            layer.update(
+                wq_a=dense(ks[0], (d, a.q_latent)),
+                q_a_norm=jnp.ones((a.q_latent,), jnp.float32),
+                wq_b=dense(ka[0], (a.q_latent, nh, a.qk_nope + a.qk_rope)),
+                wkv_a=dense(ks[1], (d, a.kv_latent + a.qk_rope)),
+                kv_norm=jnp.ones((a.kv_latent,), jnp.float32),
+                wkv_b=dense(ks[2], (a.kv_latent, a.kv_heads,
+                                    a.qk_nope + a.v_head)),
+                w_lambda=dense(ka[1], (d, signal)),
+                w_attn_gate=dense(ka[2], (d, signal, a.v_head)),
+                wo=dense(ka[3], (signal, a.v_head, d)),
+            )
         else:
             a = spec.attn
             layer.update(
@@ -239,6 +363,10 @@ def init_params(key, cfg: ModelConfig) -> Params:
                 wkv_b=dense(ks[2], (a.kv_latent, nh, a.qk_nope + a.v_head)),
                 wo=dense(ks[3], (nh, a.v_head, d)),
             )
+        if cfg.mhc is not None:
+            layer.update(_init_mhc(jax.random.fold_in(lk, 1), cfg))
+        if spec.act is not None:
+            layer.update(_init_poly(jax.random.fold_in(lk, 2), spec))
         mlp = spec.mlp
         if isinstance(mlp, ExpertMLP):
             from ..parallel.moe import init_moe_params
@@ -294,7 +422,15 @@ def param_specs(cfg: ModelConfig) -> Params:
 
     def layer_of(spec: LayerSpec):
         layer = {"attn_norm": P(None), "mlp_norm": P(None),
-                 "wq": P(None, tp, None), "wo": P(tp, None, None)}
+                 "wo": P(tp, None, None)}
+        if isinstance(spec.attn, GDLAttn):
+            # the latents and their norms serve every head: replicated; a
+            # head group's signal heads sit on the chip of its KV head
+            layer.update(wq_a=P(None, None), q_a_norm=P(None),
+                         wq_b=P(None, tp, None), w_lambda=P(None, tp),
+                         w_attn_gate=P(None, tp, None))
+        else:
+            layer["wq"] = P(None, tp, None)
         if spec.attn is None:
             layer.update(wk=P(None, tp, None), wv=P(None, tp, None))
             if cfg.qk_norm:
@@ -303,6 +439,18 @@ def param_specs(cfg: ModelConfig) -> Params:
             # the down-projection and its norm serve every head: replicated
             layer.update(wkv_a=P(None, None), kv_norm=P(None),
                          wkv_b=P(None, tp, None))
+        if cfg.mhc is not None:
+            layer.update({f"mhc_{s}_{leaf}": P(*(None,) * rank)
+                          for s in MHC_SUBLAYERS
+                          for leaf, rank in (("phi", 2), ("alpha", 1),
+                                             ("bias", 1))})
+        if spec.act is not None:
+            if isinstance(spec.mlp, ExpertMLP):
+                layer["expert_poly"] = P(cfg.expert_axis, None)
+                if spec.mlp.shared_ff:
+                    layer["shared_poly"] = P(None)
+            else:
+                layer["poly"] = P(None)
         if isinstance(spec.mlp, ExpertMLP):
             # experts shard over expert_axis ONLY (the _mlp shard_map slices
             # the same way); sharding their ffn dim over tp as well would
@@ -386,25 +534,52 @@ def _rope_interleaved(x, positions, theta):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def _latent_qkv(p, x, positions, cfg: ModelConfig, a: LatentAttn):
-    """Latent attention's q, k [B, N, S, qk_nope + qk_rope] and v [B, N, S,
-    v_head] (LatentAttn).  k is materialised at full width a head, as the
-    published code does.  Scopes obs.model.mla.q / .kv_down / .kv_up
-    (docs/observability.md)."""
-    h = _rms_norm(x, p["attn_norm"])
-    # Each projection is two products over column ranges of its weight, not
-    # one product cut in two afterwards: the cut is then of the (small)
-    # weight, and no [B, N, S, 256] or second [B, N, S, 192] array of
-    # activations exists to be sliced.
+def _latent_qkv(p, h, positions, cfg: ModelConfig, a: LatentAttn):
+    """Latent attention's q [B, N, S, qk_nope + qk_rope], k of the same
+    width and v [B, N, S, v_head] of the layer's normed input `h`.  q is two
+    products over column ranges of `wq`, not one product cut in two
+    afterwards: the cut is then of the (small) weight, and no second [B, N,
+    S, 192] array of activations exists to be sliced.  Scopes
+    obs.model.mla.q / .kv_down / .kv_up (docs/observability.md)."""
     proj = partial(jnp.einsum, "bsd,dnh->bnsh")
     with jax.named_scope("obs.model.mla.q"):
         q = jnp.concatenate(
             [proj(h, p["wq"][..., :a.qk_nope]),
              _rope_interleaved(proj(h, p["wq"][..., a.qk_nope:]), positions,
                                cfg.rope_theta)], axis=-1)
+    return (q, *_latent_kv(p, h, positions, cfg, a))
+
+
+def _gdla_qkv(p, h, positions, cfg: ModelConfig, a: GDLAttn):
+    """GDLA's q [B, N, S, qk_nope + qk_rope] from its normed q latent, and
+    _latent_kv's k, v for its `kv_heads`.  q is ONE product cut afterwards:
+    two column-range products of `wq_b` compile the rotary range's weight
+    gradient to a convolution windowed over all 192 columns (68 ms a layer
+    at 4,096 tokens on a v5e: PERF.md section 6).  The scopes are
+    _latent_qkv's."""
+    with jax.named_scope("obs.model.mla.q"):
+        q = jnp.einsum("bsc,cnh->bnsh", _rms_norm(
+            jnp.einsum("bsd,dc->bsc", h, p["wq_a"]), p["q_a_norm"],
+            cfg.norm_eps), p["wq_b"])
+        q = jnp.concatenate(
+            [q[..., :a.qk_nope],
+             _rope_interleaved(q[..., a.qk_nope:], positions,
+                               cfg.rope_theta)], axis=-1)
+    return (q, *_latent_kv(p, h, positions, cfg, a))
+
+
+def _latent_kv(p, h, positions, cfg: ModelConfig, a: LatentAttn):
+    """Latent attention's k [B, N_kv, S, qk_nope + qk_rope] and v [B, N_kv,
+    S, v_head] of the layer's normed input `h` (N_kv: a LatentAttn's n_heads,
+    a GDLAttn's kv_heads, read off `wkv_b`).  k is materialised at full
+    width a head, as the published code does, its rotary part the one key a
+    token; each product is over a column range of `wkv_b`, as _latent_qkv's
+    q.  Scopes obs.model.mla.kv_down / .kv_up."""
+    proj = partial(jnp.einsum, "bsd,dnh->bnsh")
     with jax.named_scope("obs.model.mla.kv_down"):
         down = jnp.einsum("bsd,dc->bsc", h, p["wkv_a"])
-        latent = _rms_norm(down[..., :a.kv_latent], p["kv_norm"])
+        latent = _rms_norm(down[..., :a.kv_latent], p["kv_norm"],
+                           cfg.norm_eps)
         # the one rotary key a token, every head's
         k_rope = _rope_interleaved(down[:, None, :, a.kv_latent:], positions,
                                    cfg.rope_theta)
@@ -414,19 +589,20 @@ def _latent_qkv(p, x, positions, cfg: ModelConfig, a: LatentAttn):
             [k_nope, jnp.broadcast_to(k_rope, (*k_nope.shape[:3],
                                                a.qk_rope))], axis=-1)
         v = proj(latent, p["wkv_b"][..., a.qk_nope:])
-    return q, k, v
+    return k, v
 
 
 def _qkv_proj(p, x, positions, cfg: ModelConfig):
     """Norm + qkv projections + rotary — shared by the regular and
     pipeline-parallel paths (a numerics change here must hit both, or the
     pp-vs-regular parity tests break)."""
-    h = _rms_norm(x, p["attn_norm"])
+    h = _rms_norm(x, p["attn_norm"], cfg.norm_eps)
     q = jnp.einsum("bsd,dnh->bnsh", h, p["wq"])
     k = jnp.einsum("bsd,dnh->bnsh", h, p["wk"])
     v = jnp.einsum("bsd,dnh->bnsh", h, p["wv"])
     if cfg.qk_norm:
-        q, k = _rms_norm(q, p["q_norm"]), _rms_norm(k, p["k_norm"])
+        q, k = (_rms_norm(q, p["q_norm"], cfg.norm_eps),
+                _rms_norm(k, p["k_norm"], cfg.norm_eps))
     return (_rope(q, positions, cfg.rope_theta),
             _rope(k, positions, cfg.rope_theta), v)
 
@@ -436,16 +612,45 @@ def _attn_out(p, o):
     return jnp.einsum("bnsh,nhd->bsd", o, p["wo"])
 
 
+def _gdla_out(p, h, o, a: GDLAttn):
+    """GDLA's output [B, S, D] from the attention's `o` [B, N, S, v_head]
+    (heads in KV-group order, each group's last the noise head) and the
+    layer's normed input `h`: each signal head minus lambda times its
+    group's noise head, times the elementwise gate, through wo.  The
+    difference and the gate in float32; scope obs.model.gdla.diff."""
+    b, n, s, dv = o.shape
+    per = n // a.kv_heads
+    with jax.named_scope("obs.model.gdla.diff"):
+        lam = jax.nn.sigmoid(jnp.einsum(
+            "bsd,dn->bns", h, p["w_lambda"]).astype(jnp.float32))
+        gate = jax.nn.sigmoid(jnp.einsum(
+            "bsd,dnh->bnsh", h, p["w_attn_gate"]).astype(jnp.float32))
+        o = o.astype(jnp.float32).reshape(b, a.kv_heads, per, s, dv)
+        lam = lam.reshape(b, a.kv_heads, per - 1, s, 1)
+        diff = (o[:, :, :-1] - lam * o[:, :, -1:]).reshape(b, -1, s, dv)
+        out = (gate * diff).astype(h.dtype)
+    return _attn_out(p, out)
+
+
 def _attention(p, x, positions, cfg: ModelConfig, mesh, segment_ids=None,
-               collect_stats=False, kind: Optional[LatentAttn] = None):
+               collect_stats=False, kind: Optional[LatentAttn] = None,
+               window: Optional[int] = None):
     """One attention sublayer.  `collect_stats` (static) additionally
     returns the ring's in-graph DevStats (burst strategy only — ulysses has
     no ring to instrument): `(out, DevStats)` instead of `out`.  `kind`: the
-    layer's LayerSpec.attn (None: the GQA block of the scalar knobs)."""
+    layer's LayerSpec.attn (None: the GQA block of the scalar knobs);
+    `window`: the layer's LayerSpec.window (None: cfg.window)."""
+    window = cfg.window if window is None else window
     if kind is None:
         q, k, v = _qkv_proj(p, x, positions, cfg)
     else:
-        q, k, v = _latent_qkv(p, x, positions, cfg, kind)
+        h = _rms_norm(x, p["attn_norm"], cfg.norm_eps)
+        q, k, v = (_gdla_qkv if isinstance(kind, GDLAttn) else _latent_qkv)(
+            p, h, positions, cfg, kind)
+    if isinstance(kind, GDLAttn):
+        out = partial(_gdla_out, p, h, a=kind)
+    else:
+        out = partial(_attn_out, p)
     if collect_stats and cfg.attn_strategy != "burst":
         raise ValueError(
             "collect_stats requires attn_strategy='burst' (devstats "
@@ -469,7 +674,7 @@ def _attention(p, x, positions, cfg: ModelConfig, mesh, segment_ids=None,
             q, k, v, mesh=mesh, seq_axis=cfg.seq_axes[0], causal=cfg.causal,
             backend=cfg.attn_backend, block_q=cfg.block_q,
             block_kv=cfg.block_kv, batch_axes=cfg.batch_axis,
-            head_axes=cfg.head_axis, window=cfg.window,
+            head_axes=cfg.head_axis, window=window,
             segment_ids=segment_ids,
         )
     elif cfg.attn_strategy == "burst":
@@ -486,28 +691,78 @@ def _attention(p, x, positions, cfg: ModelConfig, mesh, segment_ids=None,
             block_kv=cfg.block_kv,
             batch_axes=cfg.batch_axis,
             head_axes=cfg.head_axis,
-            window=cfg.window,
+            window=window,
             segment_ids=segment_ids,
             collect_stats=collect_stats,
             block_diffusion=cfg.block_diffusion,
         )
         if collect_stats:
             o, stats = o
-            return _attn_out(p, o), stats
+            return out(o), stats
     else:
         raise ValueError(
             f"unknown attn_strategy {cfg.attn_strategy!r}; "
             "expected 'burst' or 'ulysses'"
         )
-    return _attn_out(p, o)
+    return out(o)
+
+
+def _sinkhorn(logits, iters: int):
+    """Sinkhorn-Knopp on exp(logits) [..., n, n], float32: `iters` rounds
+    of a row then a column normalisation (doubly stochastic in the limit;
+    exactly column-stochastic after the last round).  A loop, not `iters`
+    copies: unrolled, the rounds were a quarter of the train step's
+    executable."""
+    def normalise(_, m):
+        m = m / jnp.sum(m, axis=-1, keepdims=True)
+        return m / jnp.sum(m, axis=-2, keepdims=True)
+
+    m = jnp.exp(logits - jnp.max(logits, axis=(-2, -1), keepdims=True))
+    return jax.lax.fori_loop(0, iters, normalise, m)
+
+
+def mhc_maps(p, x, sub: str, cfg: ModelConfig):
+    """mHC's three maps of the streams x [B, S, n, D] for sublayer `sub`
+    (MHC_SUBLAYERS), float32: pre [B, S, n] = sigmoid, post [B, S, n] = 2
+    sigmoid, res [B, S, n, n] = Sinkhorn-Knopp of exp, each of its gain
+    times its columns of RMSNorm(vec x) phi, plus its bias."""
+    b, s, n, d = x.shape
+    flat = x.reshape(b, s, n * d).astype(jnp.float32)
+    flat = flat * jax.lax.rsqrt(jnp.mean(flat * flat, axis=-1, keepdims=True)
+                                + cfg.norm_eps)
+    alpha, bias = p[f"mhc_{sub}_alpha"], p[f"mhc_{sub}_bias"]
+    proj = jnp.einsum("bsc,cm->bsm", flat, p[f"mhc_{sub}_phi"])
+    pre = jax.nn.sigmoid(alpha[0] * proj[..., :n] + bias[:n])
+    post = 2.0 * jax.nn.sigmoid(alpha[1] * proj[..., n:2 * n]
+                                + bias[n:2 * n])
+    res = (alpha[2] * proj[..., 2 * n:] + bias[2 * n:]).reshape(b, s, n, n)
+    return pre, post, _sinkhorn(res, cfg.mhc.sinkhorn_iters)
+
+
+def _mhc_pre(p, x, sub: str, cfg: ModelConfig):
+    """(the sublayer's input [B, S, D] = sum_i pre_i x_i, the maps)."""
+    pre, post, res = maps = mhc_maps(p, x, sub, cfg)
+    u = jnp.einsum("bsn,bsnd->bsd", pre, x.astype(jnp.float32))
+    return u.astype(x.dtype), maps
+
+
+def _mhc_post(x, maps, f, cfg: ModelConfig):
+    """The streams after a sublayer: res x + post^T f(.), f [B, S, D] the
+    sublayer's output, clipped to +-cfg.mhc.clamp where it is set."""
+    _, post, res = maps
+    y = (jnp.einsum("bsij,bsjd->bsid", res, x.astype(jnp.float32))
+         + post[..., None] * f.astype(jnp.float32)[:, :, None])
+    if cfg.mhc.clamp is not None:
+        y = jnp.clip(y, -cfg.mhc.clamp, cfg.mhc.clamp)
+    return y.astype(x.dtype)
 
 
 def _moe_group(mp, h2, cfg: ModelConfig, ep_axis, inference=False,
-               kind: Optional[ExpertMLP] = None, extra=None):
+               kind: Optional[ExpertMLP] = None, extra=None, act=None):
     """One routing group's MoE: [tokens, d] -> (y, aux, MoEStats or None).
     `kind`: the layer's ExpertMLP (None: the scalar knobs'); `extra`: its
-    leaves beside MoEParams ({"router_bias", "shared_gate", "shared_up",
-    "shared_down"}, those it has).
+    leaves beside MoEParams (_MOE_EXTRA, those it has); `act`: the layer's
+    LayerSpec.act (None: SiLU).
     The ONE place that picks the layer (see ModelConfig.n_experts): the
     regular path's _mlp and the pipeline's _moe_block both call it, inside
     their shard_maps.
@@ -524,19 +779,23 @@ def _moe_group(mp, h2, cfg: ModelConfig, ep_axis, inference=False,
     kind = _knob_mlp(cfg) if kind is None else kind
     extra = extra or {}
     if ep_axis is None and not inference:
+        shared = ("shared_gate", "shared_up", "shared_down") + (
+            () if act is None else ("shared_poly",))
         return moe_held(
             mp, h2, top_k=kind.top_k, held=kind.held, score=kind.score,
             bias=extra.get("router_bias"), gate_scale=kind.gate_scale,
-            shared=(tuple(extra[k] for k in ("shared_gate", "shared_up",
-                                             "shared_down"))
-                    if kind.shared_ff else None))
-    if (kind.score, kind.choice_bias, kind.gate_scale, kind.shared_ff) != (
-            "softmax", False, 1.0, 0):
+            shared=(tuple(extra[k] for k in shared)
+                    if kind.shared_ff else None),
+            **({} if act is None else dict(
+                act=partial(poly_norm, spec=act),
+                act_weights=extra["expert_poly"])))
+    if (kind.score, kind.choice_bias, kind.gate_scale, kind.shared_ff,
+            act) != ("softmax", False, 1.0, 0, None):
         raise ValueError(
-            "a sigmoid router, a choice bias, a gate scale and shared "
-            "experts are the drop-free trainer layer's (moe.moe_held); "
-            "inference and the expert_axis exchange run the dense-dispatch "
-            "layer, which has none of them")
+            "a sigmoid router, a choice bias, a gate scale, shared experts "
+            "and PolyNorm experts are the drop-free trainer layer's "
+            "(moe.moe_held); inference and the expert_axis exchange run the "
+            "dense-dispatch layer, which has none of them")
     if kind.held is not None:
         raise ValueError(
             "experts_held is the drop-free trainer layer's (moe.moe_held); "
@@ -572,17 +831,20 @@ def _mlp(p, x, cfg: Optional[ModelConfig] = None, mesh=None, inference=False):
     return _mlp_stats(p, x, cfg, mesh, inference)[:2]
 
 
-_MOE_EXTRA = ("router_bias", "shared_gate", "shared_up", "shared_down")
+_MOE_EXTRA = ("router_bias", "shared_gate", "shared_up", "shared_down",
+              "expert_poly", "shared_poly")
 
 
 def _mlp_stats(p, x, cfg: Optional[ModelConfig] = None, mesh=None,
-               inference=False, kind=None):
+               inference=False, kind=None, act=None):
     """_mlp's (out, aux_loss) and, third, what the drop-free expert layer
     did: its moe.MoEStats over the token shards (slots summed, the load
     ratio's worst shard, the choices [B, S, k] sharded like the tokens);
     None for the dense MLP and the dense-dispatch layer.  `kind`: the
-    layer's LayerSpec.mlp (None: the scalar knobs')."""
-    h = _rms_norm(x, p["mlp_norm"])
+    layer's LayerSpec.mlp (None: the scalar knobs'); `act`: its
+    LayerSpec.act, the activation of every gate product (None: SiLU)."""
+    h = _rms_norm(x, p["mlp_norm"],
+                  ModelConfig.norm_eps if cfg is None else cfg.norm_eps)
     if kind is None and cfg is not None:
         kind = _knob_mlp(cfg)
     if isinstance(kind, ExpertMLP):
@@ -600,7 +862,7 @@ def _mlp_stats(p, x, cfg: Optional[ModelConfig] = None, mesh=None,
         def group(mp, h, extra):
             bb, ss, dd = h.shape
             y, aux, stats = _moe_group(mp, h.reshape(bb * ss, dd), cfg,
-                                       ep_axis, inference, kind, extra)
+                                       ep_axis, inference, kind, extra, act)
             # moe_shard pmeans over the expert axis; average the remaining
             # token-sharding axes so aux is replicated
             rest = tuple(a for a in token_axes if a != ep_axis)
@@ -640,7 +902,9 @@ def _mlp_stats(p, x, cfg: Optional[ModelConfig] = None, mesh=None,
         )(mp, h, extra)
     gate = jnp.einsum("bsd,df->bsf", h, p["w_gate"])
     up = jnp.einsum("bsd,df->bsf", h, p["w_up"])
-    out = jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up, p["w_down"])
+    gate = (jax.nn.silu(gate) if act is None
+            else poly_norm(gate, p["poly"], act))
+    out = jnp.einsum("bsf,fd->bsd", gate * up, p["w_down"])
     return out, jnp.float32(0.0), None
 
 
@@ -701,27 +965,58 @@ def forward_with_aux(params: Params, tokens, positions, cfg: ModelConfig, mesh,
     with jax.named_scope("obs.model.embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]
         x = jax.lax.with_sharding_constraint(x, act_spec)
+    n_mhc = 0 if cfg.mhc is None else cfg.mhc.streams
+    if n_mhc:
+        # the carry between blocks is the streams [B, S, n, D]
+        stream_spec = NamedSharding(mesh, P(cfg.batch_axis, seq_spec, None,
+                                            None))
+        with jax.named_scope("obs.model.mhc"):
+            x = jax.lax.with_sharding_constraint(
+                jnp.broadcast_to(x[:, :, None], (*x.shape[:2], n_mhc,
+                                                 x.shape[-1])), stream_spec)
 
     def block(carry, p, spec=None):
         spec = LayerSpec(_knob_mlp(cfg)) if spec is None else spec
+        attend = partial(_attention, p, positions=positions, cfg=cfg,
+                         mesh=mesh, segment_ids=segment_ids, kind=spec.attn,
+                         window=spec.window)
+        if collect_stats:
+            x, aux, stats = carry
+        else:
+            x, aux = carry
+        if n_mhc:
+            with jax.named_scope("obs.model.mhc"):
+                u, maps = _mhc_pre(p, x, "attn", cfg)
+        else:
+            u = x
         if collect_stats:
             from ..obs import devstats
 
-            x, aux, stats = carry
             with jax.named_scope("obs.model.attn"):
-                a, st = _attention(p, x, positions, cfg, mesh,
-                                   segment_ids=segment_ids,
-                                   collect_stats=True, kind=spec.attn)
-                x = x + a
+                a, st = attend(u, collect_stats=True)
+                if not n_mhc:
+                    x = x + a
             stats = st if stats is None else devstats.merge(stats, st)
         else:
-            x, aux = carry
             with jax.named_scope("obs.model.attn"):
-                x = x + _attention(p, x, positions, cfg, mesh,
-                                   segment_ids=segment_ids, kind=spec.attn)
+                a = attend(u)
+                if not n_mhc:
+                    x = x + a
+        if n_mhc:
+            with jax.named_scope("obs.model.mhc"):
+                x = _mhc_post(x, maps, a, cfg)
+                u, maps = _mhc_pre(p, x, "mlp", cfg)
+        else:
+            u = x
         with jax.named_scope("obs.model.mlp"):
-            m, aux_l, moe_l = _mlp_stats(p, x, cfg, mesh, kind=spec.mlp)
-            x = jax.lax.with_sharding_constraint(x + m, act_spec)
+            m, aux_l, moe_l = _mlp_stats(p, u, cfg, mesh, kind=spec.mlp,
+                                         act=spec.act)
+            if not n_mhc:
+                x = jax.lax.with_sharding_constraint(x + m, act_spec)
+        if n_mhc:
+            with jax.named_scope("obs.model.mhc"):
+                x = jax.lax.with_sharding_constraint(
+                    _mhc_post(x, maps, m, cfg), stream_spec)
         if collect_stats:
             return (x, aux + aux_l, stats), moe_l
         return (x, aux + aux_l), moe_l
@@ -747,10 +1042,13 @@ def forward_with_aux(params: Params, tokens, positions, cfg: ModelConfig, mesh,
 
         aux = (aux, fold_stats(moe_layers))
 
+    if n_mhc:
+        with jax.named_scope("obs.model.mhc"):
+            x = jnp.sum(x.astype(jnp.float32), axis=2).astype(cfg.dtype)
     with jax.named_scope("obs.model.loss_head"):
         if head_rows is not None:
             x = jax.lax.slice_in_dim(x, *head_rows, axis=1)
-        x = _rms_norm(x, params["final_norm"])
+        x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = jnp.einsum(
             "bsd,vd->bsv", x, params["lm_head"],
             preferred_element_type=jnp.float32

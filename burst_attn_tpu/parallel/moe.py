@@ -362,7 +362,7 @@ def route(router, x, top_k: int, *, score: str = "softmax", bias=None,
 
 def moe_held(p: MoEParams, x, *, top_k: int, held=None,
              score: str = "softmax", bias=None, gate_scale: float = 1.0,
-             shared=None):
+             shared=None, act=None, act_weights=None):
     """Drop-free MoE on [T, d] tokens over the experts held here.
 
     p.router is [d, E] (all E experts), p.w_* hold experts [lo, hi) = `held`
@@ -378,6 +378,14 @@ def moe_held(p: MoEParams, x, *, top_k: int, held=None,
     experts, one SwiGLU every local token takes, ungated, added once beside
     the held experts' part (every chip of an expert-parallel group computes
     it for its own tokens: it is not a share).
+
+    `act`: the activation of the gate product, SiLU where None; else a
+    function act(u [..., f], w [..., a]) of learned weights w (PolyNorm,
+    ops/polynorm.py): the held experts' are `act_weights` [held, a], one row
+    an expert, and the shared experts' a fourth member of `shared`, [a].
+    The rows past the held slots are undefined (_grouped_matmul), so this
+    path zeroes them on both sides of the activation: its weights' gradient
+    is a sum over every row.
 
     Returns (y [T, d], aux, MoEStats): aux is the load-balancing loss over
     ALL E experts (E * sum_e fraction of the T*k choices on e * mean router
@@ -421,13 +429,24 @@ def moe_held(p: MoEParams, x, *, top_k: int, held=None,
     with jax.named_scope("obs.model.moe.experts"):
         g = _grouped_matmul(xs, p.w_gate, sizes)
         u = _grouped_matmul(xs, p.w_up, sizes)
-        ys = _grouped_matmul(jax.nn.silu(g) * u, p.w_down, sizes)
+        if act is None:
+            hidden = jax.nn.silu(g) * u
+        else:
+            rows = jnp.arange(order.size)
+            live_r = (rows < jnp.sum(sizes))[:, None]
+            expert = jnp.minimum(jnp.searchsorted(jnp.cumsum(sizes), rows,
+                                                  side="right"), n_held - 1)
+            g, u = jnp.where(live_r, g, 0), jnp.where(live_r, u, 0)
+            hidden = jnp.where(live_r, act(g, act_weights[expert]) * u, 0)
+        ys = _grouped_matmul(hidden, p.w_down, sizes)
     with jax.named_scope("obs.model.moe.dispatch"):
         y = _combine(ys, gates, order, inv, live, top_k)
     if shared is not None:
         with jax.named_scope("obs.model.moe.shared"):
-            w_gate, w_up, w_down = shared
-            y = y + (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+            w_gate, w_up, w_down, *w_act = shared
+            gate = x @ w_gate
+            gate = jax.nn.silu(gate) if act is None else act(gate, *w_act)
+            y = y + (gate * (x @ w_up)) @ w_down
     load = sizes.astype(jnp.float32)
     stats = MoEStats(
         slots_here=jnp.sum(load),
