@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from jax import shard_map
 
+from ..ops import mhc as mhc_ops
 from ..ops.polynorm import PolyNorm, init_weights as _poly_init, poly_norm
 from ..parallel.burst import burst_attn
 
@@ -126,8 +127,9 @@ STATE_LEAVES = ("router_bias",)
 
 @dataclass(frozen=True)
 class MHC:
-    """The residual path as `streams` streams [B, S, n, d] (mHC, arXiv
-    2512.24880): _mhc_pre / _mhc_post around each sublayer, the maps' mix
+    """The residual path as `streams` streams, side by side [B, S, n d]
+    (mHC, arXiv 2512.24880): _mhc_pre / _mhc_post around each sublayer (on
+    the TPU ops/mhc.py's kernels: _mhc_passes), the maps' mix
     doubly stochastic by `sinkhorn_iters` Sinkhorn-Knopp rounds; the
     embedding copied to every stream, the streams summed before the final
     norm; the streams clipped to +-`clamp` after each sublayer (None:
@@ -757,6 +759,39 @@ def _mhc_post(x, maps, f, cfg: ModelConfig):
     return y.astype(x.dtype)
 
 
+def _mhc_passes(cfg: ModelConfig, mesh, seq: int):
+    """(pre, post) of a sublayer pass on the streams flat [B, S, n D]:
+    pre(p, x, sub) -> (u, maps, x), post(x, maps, f) -> the streams after.
+    ops/mhc.py's kernels on the TPU (the rule of burst_attn's
+    backend="auto") where the streams tile and one device holds every token
+    (the launches are not partitioned); _mhc_pre / _mhc_post elsewhere."""
+    n, d, mc = cfg.mhc.streams, cfg.d_model, cfg.mhc
+    token_shards = 1
+    for a in (cfg.batch_axis, *cfg.seq_axes):
+        token_shards *= mesh.shape.get(a, 1) if a is not None else 1
+    if (mhc_ops.engaged() and mhc_ops.tiles(seq, d, n)
+            and token_shards == 1):
+        def pre(p, x, sub):
+            return mhc_ops.mhc_pre(
+                x, p[f"mhc_{sub}_phi"], p[f"mhc_{sub}_alpha"],
+                p[f"mhc_{sub}_bias"], streams=n, eps=cfg.norm_eps,
+                iters=mc.sinkhorn_iters)
+
+        def post(x, maps, f):
+            return mhc_ops.mhc_post(x, maps, f, streams=n, clamp=mc.clamp)
+
+        return pre, post
+    split = lambda x: x.reshape(*x.shape[:2], n, d)
+
+    def pre(p, x, sub):
+        return (*_mhc_pre(p, split(x), sub, cfg), x)
+
+    def post(x, maps, f):
+        return _mhc_post(split(x), maps, f, cfg).reshape(x.shape)
+
+    return pre, post
+
+
 def _moe_group(mp, h2, cfg: ModelConfig, ep_axis, inference=False,
                kind: Optional[ExpertMLP] = None, extra=None, act=None):
     """One routing group's MoE: [tokens, d] -> (y, aux, MoEStats or None).
@@ -967,13 +1002,12 @@ def forward_with_aux(params: Params, tokens, positions, cfg: ModelConfig, mesh,
         x = jax.lax.with_sharding_constraint(x, act_spec)
     n_mhc = 0 if cfg.mhc is None else cfg.mhc.streams
     if n_mhc:
-        # the carry between blocks is the streams [B, S, n, D]
-        stream_spec = NamedSharding(mesh, P(cfg.batch_axis, seq_spec, None,
-                                            None))
+        # the carry between blocks is the streams, side by side [B, S, n D]
+        stream_spec = NamedSharding(mesh, P(cfg.batch_axis, seq_spec, None))
+        mhc_pre, mhc_post = _mhc_passes(cfg, mesh, tokens.shape[1])
         with jax.named_scope("obs.model.mhc"):
             x = jax.lax.with_sharding_constraint(
-                jnp.broadcast_to(x[:, :, None], (*x.shape[:2], n_mhc,
-                                                 x.shape[-1])), stream_spec)
+                jnp.concatenate([x] * n_mhc, axis=-1), stream_spec)
 
     def block(carry, p, spec=None):
         spec = LayerSpec(_knob_mlp(cfg)) if spec is None else spec
@@ -986,7 +1020,7 @@ def forward_with_aux(params: Params, tokens, positions, cfg: ModelConfig, mesh,
             x, aux = carry
         if n_mhc:
             with jax.named_scope("obs.model.mhc"):
-                u, maps = _mhc_pre(p, x, "attn", cfg)
+                u, maps, x = mhc_pre(p, x, "attn")
         else:
             u = x
         if collect_stats:
@@ -1004,8 +1038,8 @@ def forward_with_aux(params: Params, tokens, positions, cfg: ModelConfig, mesh,
                     x = x + a
         if n_mhc:
             with jax.named_scope("obs.model.mhc"):
-                x = _mhc_post(x, maps, a, cfg)
-                u, maps = _mhc_pre(p, x, "mlp", cfg)
+                x = mhc_post(x, maps, a)
+                u, maps, x = mhc_pre(p, x, "mlp")
         else:
             u = x
         with jax.named_scope("obs.model.mlp"):
@@ -1016,7 +1050,7 @@ def forward_with_aux(params: Params, tokens, positions, cfg: ModelConfig, mesh,
         if n_mhc:
             with jax.named_scope("obs.model.mhc"):
                 x = jax.lax.with_sharding_constraint(
-                    _mhc_post(x, maps, m, cfg), stream_spec)
+                    mhc_post(x, maps, m), stream_spec)
         if collect_stats:
             return (x, aux + aux_l, stats), moe_l
         return (x, aux + aux_l), moe_l
@@ -1044,7 +1078,9 @@ def forward_with_aux(params: Params, tokens, positions, cfg: ModelConfig, mesh,
 
     if n_mhc:
         with jax.named_scope("obs.model.mhc"):
-            x = jnp.sum(x.astype(jnp.float32), axis=2).astype(cfg.dtype)
+            d = x.shape[-1] // n_mhc
+            x = sum(x[..., i * d:(i + 1) * d].astype(jnp.float32)
+                    for i in range(n_mhc)).astype(cfg.dtype)
     with jax.named_scope("obs.model.loss_head"):
         if head_rows is not None:
             x = jax.lax.slice_in_dim(x, *head_rows, axis=1)

@@ -369,3 +369,33 @@ def test_ragged_paged_attention_compiles(topo, on_chip):
         shape((slots, cols), jnp.int32), shape((slots,), jnp.int32),
         shape((slots,), jnp.int32)).compile().as_text()
     assert _mosaic_calls(text) == 1
+
+
+def test_grad_mhc_pass_at_the_motif_cell_geometry(topo, on_chip):
+    """One mHC sublayer pass of `train_motif3_gdla_1x4k` (1 x 4,096 tokens,
+    4 streams of 4,096, 20 Sinkhorn-Knopp rounds) and its gradient: the
+    four kernels of ops/mhc.py and nothing float32 of the streams' size
+    around them (the float32 views of the streams the jnp path makes)."""
+    from burst_attn_tpu.ops import mhc
+
+    one = SingleDeviceSharding(topo.devices[0])
+    shape = lambda s, dtype: jax.ShapeDtypeStruct(s, dtype, sharding=one)
+    n, d, rows = 4, 4096, 4096
+
+    def loss(x, phi, alpha, bias):
+        u, maps, x = mhc.mhc_pre(x, phi, alpha, bias, streams=n, eps=1e-6,
+                                 iters=20)
+        y = mhc.mhc_post(x, maps, u, streams=n, clamp=1e6)
+        return jnp.sum(y[:, :8].astype(jnp.float32) ** 2)
+
+    c = jax.jit(jax.grad(loss, (0, 1, 2, 3))).lower(
+        shape((1, rows, n * d), jnp.bfloat16),
+        shape((n * d, 2 * n + n * n), jnp.float32),
+        shape((3,), jnp.float32), shape((2 * n + n * n,), jnp.float32),
+    ).compile()
+    text = c.as_text()
+    assert sorted(set(re.findall(r"%(mhc_\w+?)(?:\.\d+)? = ", text))) == [
+        "mhc_post_bwd", "mhc_post_fwd", "mhc_pre_bwd", "mhc_pre_fwd"]
+    assert _mosaic_calls(text) == 4
+    assert f"f32[1,{rows},{n * d}]" not in text
+    assert _device_bytes(c) < 1e9
