@@ -11,7 +11,7 @@ with no device in hand:
                          program (exactly, including elided rounds), and
                          the model's independent stream-bytes derivation
                          == schedule.wire_round_bytes (the single source
-                         the burst.wire_bytes counter integrates) over
+                         of the ring's payload bytes) over
                          pass x wire x opt_comm x itemsize
   tuning-table-sound     the raw tables obey the invariants dispatch
                          assumes: bwd blocks never resolve larger than
@@ -41,7 +41,7 @@ rule("kernel-vmem-budget", "cost",
 rule("cost-model-consistent", "cost",
      "roofline FLOPs == devstats pair algebra over compiled programs "
      "(incl. elided rounds); model stream bytes == wire_round_bytes (the "
-     "burst.wire_bytes formula)")(None)
+     "ring's byte formula)")(None)
 rule("tuning-table-sound", "cost",
      "tuning tables obey dispatch's invariants: bwd blocks <= fwd, "
      "cliff clamps monotone + in-budget, blocks lane-aligned, aliases "
@@ -147,7 +147,7 @@ def check_cost_consistency(pair_fn=None) -> List[Finding]:
                                      f"{wire or 'fp32'}/opt_comm={opt_comm}"
                                      f"/itemsize={itemsize}: model says "
                                      f"{ours}, wire_round_bytes (the "
-                                     f"burst.wire_bytes formula) says "
+                                     f"ring's byte formula) says "
                                      f"{theirs}"),
                             file=wire_f, line=wire_ln))
     return findings

@@ -19,7 +19,7 @@ statically derivable, so this module computes — with no device in hand —
 
 Cross-validation story (analysis/costcheck.py runs both at lint time):
 against the devstats pair/flop counters (closed form == per-round sum over
-the compiled program) and against the burst.wire_bytes counter formula
+the compiled program) and against the ring's byte formula
 (stream_bytes == schedule.wire_round_bytes, the single derivation).
 
 Everything here is host-side integer/float arithmetic over compiled

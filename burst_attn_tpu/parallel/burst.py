@@ -63,8 +63,6 @@ from .ring import (ppermute_by, ppermute_next, my_partition,
 # not per-step execution counts (docs/observability.md, "per-trace").
 _M_DISPATCH = obs.counter(
     "burst.dispatch", "ring dispatches by backend and tile")
-_M_ROUNDS = obs.counter(
-    "burst.ring_rounds", "scheduled ring rounds (incl. the self round)")
 _M_INPLACE = obs.counter(
     "burst.inplace_rounds",
     "scheduled scan-ring rounds after the self round, by pass and by where "
@@ -77,12 +75,6 @@ _M_DIAG = obs.counter(
     "dispatch's tile calls that promise a full-window causal mask, by pass "
     "and by what serves them: path=sub (the kernel computes the tile's live "
     "sub-squares only) or path=whole (its whole area on the masked path)")
-_M_HOPS = obs.counter(
-    "burst.ring_hops", "scheduled KV ring hops, by mesh axis role")
-_M_WIRE = obs.counter(
-    "burst.wire_bytes",
-    "scheduled ring payload bytes per round by pass and stream "
-    "(parallel/schedule.wire_round_bytes; shrinks under cfg.wire_dtype)")
 
 
 @dataclass(frozen=True)
@@ -504,8 +496,13 @@ def _fwd_impl(q, k, v, cfg: BurstConfig, seg=None, collect=False):
     b, n, s, d = q.shape
     scale = cfg.scale if cfg.scale is not None else d**-0.5
     n_inter, n_intra = _sizes(cfg)
-    part_me = my_partition(cfg.intra_axis, cfg.inter_axis)
     wire = cfg.wire_dtype
+    # obs.ring.* named scopes: the ring's ops under the part of the
+    # schedule they serve, in the executable's op_names and so on the
+    # profiler's timeline (docs/observability.md) — metadata only, no
+    # equations
+    with jax.named_scope("obs.ring.entry"):
+        part_me = my_partition(cfg.intra_axis, cfg.inter_axis)
 
     def compute(st, kv_c, r):
         kv_part = partition_at_round(r, cfg.intra_axis, cfg.inter_axis)
@@ -611,8 +608,9 @@ def _fwd_impl(q, k, v, cfg: BurstConfig, seg=None, collect=False):
         # every hop.  The peeled self round below still reads the resident
         # full-precision k/v — only bytes that actually cross a link are
         # quantized.  The int32 seg ids ride unquantized.
-        k8, ksc = wire_quantize(k, wire, (2, 3))
-        v8, vsc = wire_quantize(v, wire, (2, 3))
+        with jax.named_scope("obs.ring.entry"):
+            k8, ksc = wire_quantize(k, wire, (2, 3))
+            v8, vsc = wire_quantize(v, wire, (2, 3))
         kv = (k8, ksc, v8, vsc) if seg is None else (k8, ksc, v8, vsc, seg)
     kv_base = kv
 
@@ -629,16 +627,14 @@ def _fwd_impl(q, k, v, cfg: BurstConfig, seg=None, collect=False):
     spec0 = round_spec(part_me, part_me, s, k.shape[2], cfg.causal,
                        cfg.layout, window=cfg.window)
     tri0 = cfg.causal and k.shape[2] == s
-    # obs.* named scopes: per-round xprof labels matching the span naming
-    # convention (docs/observability.md) — metadata only, no equations
     with jax.named_scope("obs.ring.round0_self"):
         state = _tile_fwd(cfg, q, k, v, None, None, None, scale, spec0,
                           triangular=tri0, segments=segs0)
-    if collect:
-        # devstats accumulators ride NEXT TO the state; every touch is
-        # behind `if collect` so the stats-off trace stays bit-identical
-        dv = tally_add((jnp.int32(0), jnp.float32(0.0)), jnp.int32(0))
-        rounds_exec = 1
+        if collect:
+            # devstats accumulators ride NEXT TO the state; every touch is
+            # behind `if collect` so the stats-off trace stays bit-identical
+            dv = tally_add((jnp.int32(0), jnp.float32(0.0)), jnp.int32(0))
+            rounds_exec = 1
 
     for c in range(n_inter):
         if c < n_inter - 1:
@@ -678,13 +674,14 @@ def _fwd_impl(q, k, v, cfg: BurstConfig, seg=None, collect=False):
         # last round of the cycle: no intra send (reference comm.py:238-251)
         with jax.named_scope(f"obs.ring.cycle{c}.last_round"):
             state = compute(state, kv, jnp.int32(c * n_intra + r_live - 1))
-        if collect:
-            dv = tally_add(dv, jnp.int32(c * n_intra + r_live - 1))
-            rounds_exec += 1
+            if collect:
+                dv = tally_add(dv, jnp.int32(c * n_intra + r_live - 1))
+                rounds_exec += 1
         if c < n_inter - 1:
             kv = kv_base = kv_base_next
     m, lse, acc = state
-    o = jnp_tile.finalize(m, lse, acc, q.dtype)
+    with jax.named_scope("obs.ring.finalize"):
+        o = jnp_tile.finalize(m, lse, acc, q.dtype)
     if collect:
         qam = jnp.float32(0.0)
         if wire is not None:
@@ -721,29 +718,33 @@ def _bwd_impl(cfg: BurstConfig, q, k, v, o, lse, do, seg=None):
     b, n, s, d = q.shape
     scale = cfg.scale if cfg.scale is not None else d**-0.5
     n_inter, n_intra = _sizes(cfg)
-    part_me = my_partition(cfg.intra_axis, cfg.inter_axis)
-
-    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
-    if cfg.optimize_bwd_comm:
-        # ring payload shrinks by a factor of head_dim
-        # (reference burst_attn_interface.py:269-278)
-        payload = (delta, do, q, lse)
-    else:
-        payload = (o, do, q, lse)
-    if seg is not None:
-        payload = payload + (seg,)
-
     wire = cfg.wire_dtype
-    if wire is not None:
-        # quantize the q-side bundle once at ring entry (it rotates
-        # unchanged): per-(batch, head) scalar scales; lse stays fp32 (its
-        # absolute accuracy sets every softmax rescale downstream)
-        first_p, do_p, q_p, lse_p = payload[:4]
-        f8, fsc = wire_quantize(first_p, wire,
-                                (2,) if cfg.optimize_bwd_comm else (2, 3))
-        do8, dosc = wire_quantize(do_p, wire, (2, 3))
-        q8, qsc = wire_quantize(q_p, wire, (2, 3))
-        payload = (f8, fsc, do8, dosc, q8, qsc, lse_p) + payload[4:]
+    # obs.ring.bwd.* named scopes, as the forward's: entry, each cycle's
+    # first round, payload jump, scan and last round, the return home
+    with jax.named_scope("obs.ring.bwd.delta"):
+        part_me = my_partition(cfg.intra_axis, cfg.inter_axis)
+        delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
+                        axis=-1)
+        if cfg.optimize_bwd_comm:
+            # ring payload shrinks by a factor of head_dim
+            # (reference burst_attn_interface.py:269-278)
+            payload = (delta, do, q, lse)
+        else:
+            payload = (o, do, q, lse)
+        if seg is not None:
+            payload = payload + (seg,)
+        if wire is not None:
+            # quantize the q-side bundle once at ring entry (it rotates
+            # unchanged): per-(batch, head) scalar scales; lse stays fp32
+            # (its absolute accuracy sets every softmax rescale downstream)
+            first_p, do_p, q_p, lse_p = payload[:4]
+            f8, fsc = wire_quantize(first_p, wire,
+                                    (2,) if cfg.optimize_bwd_comm else (2, 3))
+            do8, dosc = wire_quantize(do_p, wire, (2, 3))
+            q8, qsc = wire_quantize(q_p, wire, (2, 3))
+            payload = (f8, fsc, do8, dosc, q8, qsc, lse_p) + payload[4:]
+        dq_intra = jnp.zeros(q.shape, jnp.float32)
+        dq_inter = jnp.zeros(q.shape, jnp.float32)
 
     if wire is None:
         dq_hop = ppermute_next
@@ -758,8 +759,6 @@ def _bwd_impl(cfg: BurstConfig, q, k, v, o, lse, do, seg=None):
             return wire_dequantize(g8, gsc, jnp.float32)
 
     dkv = None  # [dk, dv]: resident, folded by the tile round after round
-    dq_intra = jnp.zeros(q.shape, jnp.float32)
-    dq_inter = jnp.zeros(q.shape, jnp.float32)
 
     def compute(pay, r, dkv, own=False):
         """One round: (dq of this round, dk, dv with this round folded in).
@@ -859,26 +858,31 @@ def _bwd_impl(cfg: BurstConfig, q, k, v, o, lse, do, seg=None):
 
     pay_base = payload
     for c in range(n_inter):
-        if c < n_inter - 1:
-            pay_base_next = ppermute_next(pay_base, cfg.inter_axis)
-        if c > 0:
-            # cycle boundary: fold the intra accumulator into the inter-ring
-            # running sum (add-and-forward, reference comm.py:187-218) and
-            # restart the intra accumulator at zero.
-            dq_inter = dq_hop(dq_inter + dq_intra, cfg.inter_axis)
-            dq_intra = jnp.zeros_like(dq_intra)
-        # ---- first round of the cycle (r = c*I): no dq rotation ----
-        dqc, *dkv = compute(payload, jnp.int32(c * n_intra), dkv, own=c == 0)
-        if truncated:
-            dq_home = dqc
-        else:
-            dq_intra = dq_intra + dqc
+        first = ("obs.ring.bwd.round0_self" if c == 0
+                 else f"obs.ring.bwd.cycle{c}.first_round")
+        with jax.named_scope(first):
+            if c < n_inter - 1:
+                pay_base_next = ppermute_next(pay_base, cfg.inter_axis)
+            if c > 0:
+                # cycle boundary: fold the intra accumulator into the
+                # inter-ring running sum (add-and-forward, reference
+                # comm.py:187-218) and restart the intra accumulator at zero.
+                dq_inter = dq_hop(dq_inter + dq_intra, cfg.inter_axis)
+                dq_intra = jnp.zeros_like(dq_intra)
+            # ---- first round of the cycle (r = c*I): no dq rotation ----
+            dqc, *dkv = compute(payload, jnp.int32(c * n_intra), dkv,
+                                own=c == 0)
+            if truncated:
+                dq_home = dqc
+            else:
+                dq_intra = dq_intra + dqc
         if r_live > 1:
             # start == 1 without truncation; the jump is a single hop then.
             # dq_intra is still all-zero at the jump when truncated
             # (rotation-invariant), so only the payload travels.
             start = n_intra - (r_live - 1)
-            payload = ppermute_by(payload, cfg.intra_axis, start)
+            with jax.named_scope(f"obs.ring.bwd.cycle{c}.send0"):
+                payload = ppermute_by(payload, cfg.intra_axis, start)
             if n_intra - 1 > start:
 
                 def body(carry, s_idx, c=c):
@@ -891,28 +895,31 @@ def _bwd_impl(cfg: BurstConfig, q, k, v, o, lse, do, seg=None):
                     # the one full-size float32 add left in a round
                     return (pay_next, dq_rot + dqc, dkv_c), None
 
-                (payload, dq_intra, dkv), _ = lax.scan(
-                    body, (payload, dq_intra, dkv),
-                    jnp.arange(start, n_intra - 1)
-                )
+                with jax.named_scope(f"obs.ring.bwd.cycle{c}.scan_rounds"):
+                    (payload, dq_intra, dkv), _ = lax.scan(
+                        body, (payload, dq_intra, dkv),
+                        jnp.arange(start, n_intra - 1)
+                    )
             # ---- last round of the cycle: rotate dq but not the payload ----
-            dq_rot = dq_hop(dq_intra, cfg.intra_axis)
-            dqc, *dkv = compute(payload,
-                                jnp.int32(c * n_intra + n_intra - 1), dkv)
-            dq_intra = dq_rot + dqc
+            with jax.named_scope(f"obs.ring.bwd.cycle{c}.last_round"):
+                dq_rot = dq_hop(dq_intra, cfg.intra_axis)
+                dqc, *dkv = compute(payload,
+                                    jnp.int32(c * n_intra + n_intra - 1), dkv)
+                dq_intra = dq_rot + dqc
         if c < n_inter - 1:
             payload = pay_base = pay_base_next
 
     # final return-home hops (reference burst_attn_interface.py:391-396,
     # comm.py:206-216): fold, one inter hop, one intra hop; then the
     # held-out round-0 dq (truncated rings only — it never traveled).
-    dq = dq_inter + dq_intra
-    if cfg.inter_axis is not None:
-        dq = dq_hop(dq, cfg.inter_axis)
-    if r_live > 1:
-        dq = dq_hop(dq, cfg.intra_axis)
-    if dq_home is not None:
-        dq = dq + dq_home
+    with jax.named_scope("obs.ring.bwd.return_home"):
+        dq = dq_inter + dq_intra
+        if cfg.inter_axis is not None:
+            dq = dq_hop(dq, cfg.inter_axis)
+        if r_live > 1:
+            dq = dq_hop(dq, cfg.intra_axis)
+        if dq_home is not None:
+            dq = dq + dq_home
     dk, dv = dkv
     return dq, dk, dv
 
@@ -953,6 +960,12 @@ def burst_attn_shard(q, k, v, cfg: BurstConfig, segment_ids=None,
     return _burst_attn_shard_seg(q, k, v, segment_ids, cfg)
 
 
+def _grads_out(grads, q, k, v):
+    """The backward's float32 (dq, dk, dv) in the inputs' dtypes."""
+    with jax.named_scope("obs.ring.bwd.out"):
+        return tuple(g.astype(x.dtype) for g, x in zip(grads, (q, k, v)))
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _burst_attn_shard_plain(q, k, v, cfg: BurstConfig):
     o, _ = _fwd_impl(q, k, v, cfg)
@@ -966,8 +979,7 @@ def _vjp_fwd(q, k, v, cfg):
 
 def _vjp_bwd(cfg, residuals, do):
     q, k, v, o, lse = residuals
-    dq, dk, dv = _bwd_impl(cfg, q, k, v, o, lse, do)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    return _grads_out(_bwd_impl(cfg, q, k, v, o, lse, do), q, k, v)
 
 
 _burst_attn_shard_plain.defvjp(_vjp_fwd, _vjp_bwd)
@@ -988,9 +1000,8 @@ def _seg_vjp_bwd(cfg, residuals, do):
     import numpy as np
 
     q, k, v, seg, o, lse = residuals
-    dq, dk, dv = _bwd_impl(cfg, q, k, v, o, lse, do, seg=seg)
-    dseg = np.zeros(seg.shape, dtype=jax.dtypes.float0)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), dseg
+    grads = _grads_out(_bwd_impl(cfg, q, k, v, o, lse, do, seg=seg), q, k, v)
+    return *grads, np.zeros(seg.shape, dtype=jax.dtypes.float0)
 
 
 _burst_attn_shard_seg.defvjp(_seg_vjp_fwd, _seg_vjp_bwd)
@@ -1017,8 +1028,7 @@ def _stats_vjp_fwd(q, k, v, cfg):
 def _stats_vjp_bwd(cfg, residuals, cts):
     do, _dstats = cts  # stats are telemetry: cotangent ignored
     q, k, v, o, lse = residuals
-    dq, dk, dv = _bwd_impl(cfg, q, k, v, o, lse, do)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    return _grads_out(_bwd_impl(cfg, q, k, v, o, lse, do), q, k, v)
 
 
 _burst_attn_shard_stats.defvjp(_stats_vjp_fwd, _stats_vjp_bwd)
@@ -1040,9 +1050,8 @@ def _stats_seg_vjp_bwd(cfg, residuals, cts):
 
     do, _dstats = cts
     q, k, v, seg, o, lse = residuals
-    dq, dk, dv = _bwd_impl(cfg, q, k, v, o, lse, do, seg=seg)
-    dseg = np.zeros(seg.shape, dtype=jax.dtypes.float0)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), dseg
+    grads = _grads_out(_bwd_impl(cfg, q, k, v, o, lse, do, seg=seg), q, k, v)
+    return *grads, np.zeros(seg.shape, dtype=jax.dtypes.float0)
 
 
 _burst_attn_shard_stats_seg.defvjp(_stats_seg_vjp_fwd, _stats_seg_vjp_bwd)
@@ -1055,9 +1064,10 @@ _burst_attn_shard_stats_seg.defvjp(_stats_seg_vjp_fwd, _stats_seg_vjp_bwd)
 def _note_dispatch(cfg: BurstConfig, mesh, q_shape, k_shape, batch_axes,
                    head_axes, *, seg, d_v) -> None:
     """Record one ring dispatch in the obs registry (burst.dispatch /
-    burst.ring_rounds / burst.inplace_rounds / flash.diag_tiles /
-    burst.ring_hops / burst.wire_bytes).  `seg`: whether the call carries
-    segment ids; `d_v`: v's width.
+    burst.inplace_rounds / flash.diag_tiles).  `seg`: whether the call
+    carries segment ids; `d_v`: v's width.  The rounds and hops a step
+    runs are read off the device trace by scope (`obs.ring.*`), not
+    counted here.
 
     Host-boundary code: called from burst_attn BEFORE shard_map, never from
     inside the traced shard program (burstlint `obs-jit-safe`)."""
@@ -1084,9 +1094,7 @@ def _note_dispatch(cfg: BurstConfig, mesh, q_shape, k_shape, batch_axes,
     _M_DISPATCH.inc(backend=cfg.backend, tile=cfg.backend,
                     d_qk=str(q_shape[3]), d_v=str(d_v))
     r_live = _r_live(cfg, q_local[2], k_local[2], n_inter, n_intra)
-    rounds, intra_hops, inter_hops = ring_round_counts(n_inter, n_intra,
-                                                       r_live)
-    _M_ROUNDS.inc(rounds)
+    rounds, _, _ = ring_round_counts(n_inter, n_intra, r_live)
     # of the rounds after the self round, which fold into their carry inside
     # the kernel: the tile entry's own static gate, per pass
     for pass_ in ("fwd", "bwd"):
@@ -1097,24 +1105,6 @@ def _note_dispatch(cfg: BurstConfig, mesh, q_shape, k_shape, batch_axes,
     for (pass_, path), tiles in _diag_tiles(cfg, q_local, k_local, rounds,
                                             seg, d_v).items():
         _M_DIAG.inc(tiles, **{"pass": pass_, "path": path})
-    if intra_hops:
-        _M_HOPS.inc(intra_hops, axis="intra")
-    if inter_hops:
-        _M_HOPS.inc(inter_hops, axis="inter")
-    # per-round wire bytes from the ONE shared derivation
-    # (schedule.wire_round_bytes), which tests/test_wire_quant.py replays
-    # against the compiled program
-    from . import schedule as sched_ir
-
-    b_l, n_l, s_l, d_l = q_local
-    fwd_b = sched_ir.wire_round_bytes("fwd", cfg.wire_dtype, b=b_l, n=n_l,
-                                      n_kv=k_local[1], s=s_l, d=d_l, d_v=d_v)
-    bwd_b = sched_ir.wire_round_bytes("bwd", cfg.wire_dtype, b=b_l, n=n_l,
-                                      n_kv=k_local[1], s=s_l, d=d_l, d_v=d_v,
-                                      opt_comm=cfg.optimize_bwd_comm)
-    _M_WIRE.inc(fwd_b["kv"], **{"pass": "fwd", "dir": "kv"})
-    _M_WIRE.inc(bwd_b["bundle"], **{"pass": "bwd", "dir": "bundle"})
-    _M_WIRE.inc(bwd_b["dq"], **{"pass": "bwd", "dir": "dq"})
 
 
 def _resolve_backend(backend: str) -> str:

@@ -87,10 +87,11 @@ def wire_dequantize(q, scale, dtype):
 def ring_round_counts(n_inter: int, n_intra: int, r_live=None):
     """Host-side accounting of ONE forward ring schedule: (rounds,
     intra_hops, inter_hops).  The obs dispatch instrumentation
-    (parallel/burst._note_dispatch) records these per traced program, so
-    `burst.ring_rounds` / `burst.ring_hops` always agree with the schedule
-    the verifier proves (burstlint ring-order) instead of being counted by
-    hand at the call site.
+    (parallel/burst._note_dispatch) takes its round count from here, so the
+    dispatch counters agree with the schedule the verifier proves
+    (burstlint ring-order) instead of being counted by hand at the call
+    site; the compiled program's `obs.ring.*` scopes show the same rounds
+    and hops (tests/test_obs.py).
 
     Single ring (n_inter == 1): a windowed contig ring truncates to
     `r_live` live rounds (parallel/burst._r_live) — r_live-1 KV hops.

@@ -899,8 +899,9 @@ def wire_round_bytes(pass_: str, wire: Optional[str], *, b: int, n: int,
     b*n*s fp32.  Shapes are PER-SHARD.  `d` is the width of q and k (and
     dq), `d_v` of v, o and do (None: d).
 
-    This is THE byte derivation: the burst.wire_bytes counters integrate
-    it per dispatch, and the burstcost roofline re-derives it
+    This is THE byte derivation: the permutes of the compiled program ship
+    it (tests/test_wire_quant.py reads them by scope), and the burstcost
+    roofline re-derives it
     independently (analysis/costmodel.stream_bytes) with the
     cost-model-consistent lint rule pinning the two equal — a change
     here that the model doesn't mirror fails the gate."""

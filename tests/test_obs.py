@@ -466,24 +466,27 @@ def test_serve_rejection_counter(model):
 
 
 def test_ring_round_and_hop_counters_match_schedule():
-    """burst.ring_rounds advances by W and burst.ring_hops by W-1 per
-    dispatch on a W-wide simulated ring (the ISSUE 3 acceptance: round
-    counts equal W-1 hops on the 8-device mesh)."""
-    import burst_attn_tpu as bat
+    """The forward's rounds and hops on a W-wide ring, read off the
+    compiled program by its `obs.ring.*` scopes (the device trace shows the
+    same ops a step): the round-0 send and the scan's hop its trip count
+    times, k and v a permute each, W-1 in all; the rounds (self, scan,
+    last) W; both as `ring.ring_round_counts`, the schedule burstlint
+    proves."""
+    from burst_attn_tpu.parallel.ring import ring_round_counts
+    from test_ring_scopes import ZIGZAG, executed_ops, op_program, permutes
 
     world = 8
-    mesh = Mesh(np.asarray(jax.devices()[:world]), ("sp",))
-    q = jax.random.normal(jax.random.PRNGKey(0), (1, 2, world * 16, 8),
-                          jnp.float32)
-    ql = bat.layouts.to_layout(q, "zigzag", world, axis=2)
-    rounds0 = obs.counter("burst.ring_rounds").total()
-    hops0 = obs.counter("burst.ring_hops").get(axis="intra")
-    o = bat.burst_attn(ql, ql, ql, mesh=mesh, causal=True, layout="zigzag",
-                       backend="jnp")
-    jax.block_until_ready(o)
-    assert obs.counter("burst.ring_rounds").total() - rounds0 == world
-    assert obs.counter("burst.ring_hops").get(axis="intra") - hops0 \
-        == world - 1
+    text = op_program({"sp": world}, ZIGZAG, seq_per_dev=16, grad=False,
+                      dtype=jnp.float32)
+    sent = permutes(text)
+    assert sent and all("obs.ring.hop1.sp" in o for o, _, _ in sent)
+    hops = sum(times for _, _, times in sent) / 2  # k and v
+    scopes = obs.scope_map(text)
+    (trip,) = {times for name, _, times in executed_ops(text)
+               if "obs.ring.cycle0.scan_rounds/while/body" in scopes[name]}
+    rounds = 1 + trip + 1  # the self round, the scan's, the last
+    assert (rounds, hops) == ring_round_counts(1, world)[:2] == (world,
+                                                                 world - 1)
 
 
 def _inplace_rounds():

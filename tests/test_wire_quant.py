@@ -8,8 +8,8 @@ The contract under test, end to end:
   bit-identity  wire_dtype=None is the pre-PR program: outputs AND the
                 traced jaxpr are bit-identical to a config that never
                 mentions wire_dtype.
-  accounting    the burst.wire_bytes{pass,dir} counters advance by exactly
-                schedule.wire_round_bytes of the dispatched shard (the ONE
+  accounting    the permutes of the compiled program ship exactly
+                schedule.wire_round_bytes of the shard a hop (the ONE
                 shared derivation), and int8 ships <= 0.5x the fp32 bytes
                 on fwd AND bwd.
 
@@ -147,31 +147,33 @@ def test_wire_none_trace_identical():
 
 
 # ---------------------------------------------------------------------------
-# byte accounting: the counters replay schedule.wire_round_bytes, and the
-# int8 wire ships <= 0.5x fp32 on fwd AND bwd (the acceptance ratio)
+# byte accounting: the compiled program's permutes replay
+# schedule.wire_round_bytes, and the int8 wire ships <= 0.5x fp32 on fwd AND
+# bwd (the acceptance ratio)
 
 
 def test_wire_bytes_counters_replay_schedule():
-    from burst_attn_tpu import obs
+    """The payload bytes one hop ships, read off the compiled program by
+    the ring's scopes (the permutes' operands: the forward's round-0 send,
+    the backward's first jump, the dq's hop home), are
+    schedule.wire_round_bytes of the shard: int8 tensors with their fp32
+    scales, lse at fp32."""
+    from test_ring_scopes import ZIGZAG, op_program, permutes
 
-    mesh = _mesh(4)
-    ql, kl, vl = _qkv(4)
-    c = obs.counter("burst.wire_bytes")
-    labels = ({"pass": "fwd", "dir": "kv"},
-              {"pass": "bwd", "dir": "bundle"},
-              {"pass": "bwd", "dir": "dq"})
-    before = [c.get(**lb) for lb in labels]
-    o = _fwd(mesh, ql, kl, vl, causal=True, layout="zigzag", backend="jnp",
-             wire_dtype="int8")
-    jax.block_until_ready(o)
-    after = [c.get(**lb) for lb in labels]
-    b, n, S, d = ql.shape
-    s_local = S // 4
-    fwd_b = sched.wire_round_bytes("fwd", "int8", b=b, n=n, n_kv=kl.shape[1],
-                                   s=s_local, d=d)
-    bwd_b = sched.wire_round_bytes("bwd", "int8", b=b, n=n, n_kv=kl.shape[1],
-                                   s=s_local, d=d, opt_comm=True)
-    got = [a - bfr for a, bfr in zip(after, before)]
+    world, n, s_local, d = 4, 2, 16, 16
+    text = op_program({"sp": world}, dict(ZIGZAG, wire_dtype="int8"),
+                      seq_per_dev=s_local, heads=n, d=d, dtype=jnp.float32)
+
+    def sent(scope):
+        return sum(size for op_name, size, _ in permutes(text)
+                   if op_name.endswith(scope + "/ppermute"))
+
+    kw = dict(b=1, n=n, n_kv=n, s=s_local, d=d)
+    fwd_b = sched.wire_round_bytes("fwd", "int8", **kw)
+    bwd_b = sched.wire_round_bytes("bwd", "int8", opt_comm=True, **kw)
+    got = [sent("jvp()/shard_map/obs.ring.hop1.sp"),
+           sent("obs.ring.bwd.cycle0.send0/obs.ring.hop1.sp"),
+           sent("obs.ring.bwd.return_home/obs.ring.hop1.sp")]
     assert got == [fwd_b["kv"], bwd_b["bundle"], bwd_b["dq"]], got
 
 
